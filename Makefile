@@ -1,4 +1,5 @@
-.PHONY: test native bench clean verify lint chaos trace-demo multichip
+.PHONY: test native bench clean verify lint chaos trace-demo multichip \
+	chip-smoke
 
 # mirrors the tier-1 invocation (fast variants of the slow suites stay
 # in-tier; `make chaos` runs the full slow schedules)
@@ -100,20 +101,30 @@ trace-demo:
 trace-demo-device:
 	JAX_PLATFORMS=cpu python tools/trace_demo.py --device
 
-# multichip dryrun with a GUARANTEED result record: even a wedged run
-# (rc=124) writes bench_results/multichip_rNN.json with an explicit
-# timeout status instead of silence (ROADMAP item 3 recording gap)
+# multichip dryrun (8 virtual CPU devices) with a GUARANTEED result
+# record: even a run that times out (rc=124) writes
+# bench_results/multichip_rNN.json with an explicit timeout status
+# instead of silence
 multichip:
 	python tools/multichip_run.py --devices 8 --timeout 600
 
 # the mesh-scan A/B under the same always-record discipline: runs
-# BENCH_CONFIG=19 (mesh-on vs single-chip control, in-bench
-# bit-identity + top-k egress assertions) on the 8-virtual-device CPU
-# mesh and ALWAYS writes bench_results/multichip_rNN.json; on a TPU
-# host the same command re-grades with real chips (tpu_verified
-# discipline)
+# BENCH_CONFIG=22 (mesh + fused decode vs the mesh over host windows vs
+# the single-chip control, in-bench bit-identity + top-k egress
+# assertions) on the 8-virtual-device CPU mesh and ALWAYS writes
+# bench_results/multichip_rNN.json — a correctness rung; its walls are
+# not device numbers.  Real chips: `python chip_smoke.py --chips 4`
 multichip-mesh:
 	python tools/multichip_run.py --mode mesh --devices 8 --timeout 900
+
+# the served scan path on the accelerator, end to end (chip_smoke.py's
+# docstring): exits non-zero wherever JAX finds no TPU.  Run it on the
+# chip through the chip tool: `chiprun -- python chip_smoke.py`, and
+# `chiprun --chips 4 -- python chip_smoke.py --chips 4` for the mesh.
+# A dry run of the plumbing on this box:
+#   python chip_smoke.py --platform cpu --rows 200000
+chip-smoke:
+	python chip_smoke.py
 
 # the driver-facing deliverables, end to end: lint + full suite + the
 # fixed-seed chaos gate + the multi-chip dryrun on the virtual CPU mesh
@@ -131,3 +142,4 @@ bench:
 clean:
 	$(MAKE) -C native clean
 	find . -name __pycache__ -type d -exec rm -rf {} +
+	rm -rf .jax_cache

@@ -140,8 +140,7 @@ class FnRecord:
     __slots__ = ("name", "compiles", "compile_seconds", "last_compile_s",
                  "last_key", "dispatches", "dispatch_seconds",
                  "execs", "exec_seconds", "storms", "storm_active",
-                 "_window", "_churn", "_prev_key", "_cache_size",
-                 "_keys")
+                 "_window", "_churn", "_prev_key")
 
     def __init__(self, name: str) -> None:
         self.name = name
@@ -161,12 +160,6 @@ class FnRecord:
         self._window: deque = deque()
         self._churn: dict[str, int] = {}
         self._prev_key: Optional[tuple] = None
-        # compile detection state survives clear(): jit's own cache is
-        # not reset by an engine close, so ours must not be either or
-        # every post-close call would double-count as a compile
-        if not hasattr(self, "_cache_size"):
-            self._cache_size = 0
-            self._keys: set = set()
 
     def snapshot(self) -> dict:
         return {
@@ -201,12 +194,17 @@ class ProfiledJit:
         self.__wrapped__ = fn
         self._owner = owner
         self._rec = owner._record(name)
+        # compile detection reads THIS jitted function's cache size:
+        # builders mint many programs under one ledger name (the mesh
+        # rounds), and a size shared by name would book their compiles
+        # as dispatches.  It survives clear() with the wrapper — jit's
+        # own cache is not reset by an engine close either.
+        self._seen_cache_size = 0
 
     def __call__(self, *args, **kwargs):
         if not self._owner.enabled:
             return self._jitted(*args, **kwargs)
-        return self._owner._profiled_call(self._rec, self._jitted,
-                                          args, kwargs)
+        return self._owner._profiled_call(self, args, kwargs)
 
     def __getattr__(self, item: str):
         return getattr(self._jitted, item)
@@ -272,21 +270,16 @@ class DeviceProfiler:
                 self._recs[name] = rec
             return rec
 
-    def _profiled_call(self, rec: FnRecord, jitted, args, kwargs):
+    def _profiled_call(self, fn: "ProfiledJit", args, kwargs):
+        rec = fn._rec
         t0 = time.perf_counter()
-        out = jitted(*args, **kwargs)
+        out = fn._jitted(*args, **kwargs)
         wall = time.perf_counter() - t0
-        compiled = False
-        try:
-            # jit's OWN cache is the ground truth for "did this call
-            # compile" — it keys on exactly what triggers a recompile
-            size = jitted._cache_size()
-            compiled = size > rec._cache_size
-            rec._cache_size = size
-        except Exception:  # noqa: BLE001 — fall back to our own keys
-            key = _call_key(args, kwargs)
-            compiled = key not in rec._keys
-            rec._keys.add(key)
+        # jit's OWN cache is the ground truth for "did this call
+        # compile" — it keys on exactly what triggers a recompile
+        size = fn._jitted._cache_size()
+        compiled = size > fn._seen_cache_size
+        fn._seen_cache_size = size
         if compiled:
             self._note_compile(rec, _call_key(args, kwargs), wall)
         else:
@@ -455,6 +448,16 @@ class DeviceProfiler:
             "transfer": transfer,
             "rounds": rounds,
         }
+
+    @staticmethod
+    def backend() -> dict:
+        """The device as JAX reports it (GET /debug/device "backend"):
+        whoever reads a served number can name the device it ran on."""
+        import jax
+
+        devices = jax.devices()
+        return {"platform": devices[0].platform,
+                "kind": devices[0].device_kind, "count": len(devices)}
 
     def summary(self) -> dict:
         """Compact rollup for /stats: totals plus any fn currently in

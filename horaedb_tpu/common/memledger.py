@@ -142,9 +142,9 @@ def read_meminfo_total() -> Optional[int]:
 
 def device_memory() -> list[dict]:
     """Per-device live bytes from jax, guarded three ways: jax not yet
-    imported (probing would initialize a backend — the cpu_mesh
-    discipline), devices unavailable, and memory_stats absent/None
-    (CPU backends and older jax return nothing)."""
+    imported (probing would initialize a backend), devices unavailable,
+    and memory_stats() returning None (the CPU backend reports
+    nothing)."""
     if "jax" not in sys.modules:
         return []
     jax = sys.modules["jax"]
@@ -154,13 +154,7 @@ def device_memory() -> list[dict]:
         return []
     out = []
     for d in devices:
-        stats_fn = getattr(d, "memory_stats", None)
-        if stats_fn is None:
-            continue
-        try:
-            stats = stats_fn()
-        except Exception:  # noqa: BLE001 — backend quirk, not an error
-            stats = None
+        stats = d.memory_stats()
         if not stats or "bytes_in_use" not in stats:
             continue
         out.append({

@@ -42,21 +42,17 @@ per-reason counter (`scan_decode_fallback_total{reason=}`) so a
 silently-ineligible plan is visible instead of quietly slow
 (docs/observability.md).  The Pallas partials kernel
 (ops/pallas_kernels.py) slots in behind the same
-HORAEDB_DOWNSAMPLE_IMPL knob, with its failure guard reporting
-"no TPU" and "kernel bug" as distinct reasons instead of a bare
-try/except.
+HORAEDB_DOWNSAMPLE_IMPL knob; selected and failing, it raises.
 """
 
 from __future__ import annotations
 
-import logging
 import time
 from dataclasses import dataclass
 from typing import Optional
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from horaedb_tpu.common import deviceprof
@@ -70,13 +66,9 @@ from horaedb_tpu.ops.filter import (
 )
 from horaedb_tpu.utils import registry, trace_add
 
-logger = logging.getLogger(__name__)
-
 # every way a plan or segment can decline the device-decode path, so
 # operators can tell "misconfigured dashboard" from "unsupported data"
-# (docs/observability.md).  pallas_* reasons come from the kernel-impl
-# guard (see use_pallas_partials): off-TPU interpret failures and real
-# kernel bugs must not be one indistinguishable except clause.
+# (docs/observability.md).
 FALLBACK_REASONS = (
     "mesh",            # meshed scans keep their own round scheduler
     "append_mode",     # BytesMerge needs exact Arrow bytes
@@ -87,8 +79,6 @@ FALLBACK_REASONS = (
     "dtype",           # a column's dtype isn't the device layout
     "budget",          # segment exceeds [scan.decode] max_upload_bytes
     "range",           # epoch-to-range shift overflows int32
-    "pallas_no_tpu",   # pallas impl failed off-TPU (interpret mode)
-    "pallas_error",    # pallas impl failed ON TPU — a real kernel bug
     "kway_runs",       # multi-run segment declined the k-way merge
                        # (run boundaries unknown / runs not per-run
                        # sorted / too many runs) — the dispatch still
@@ -476,7 +466,7 @@ def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
         grids = pallas_window_partials(
             ts_s + shift32 - lo32 * bucket32, gid, val_s, cap, bucket32,
             num_groups=g_pad, num_buckets=width, which=which,
-            interpret=jax.devices()[0].platform != "tpu")
+            interpret=downsample.pallas_interpret())
     else:
         grids = downsample.window_local_partials(
             ts_s, gid, val_s, jnp.arange(g_pad, dtype=jnp.int32),
@@ -585,24 +575,6 @@ def observe_decode_stage(seconds: float, rows: int, nbytes: int) -> None:
     if nbytes:
         _STAGE_BYTES.inc(nbytes)
         trace_add("stage_device_decode_bytes", nbytes)
-
-
-def use_pallas_partials() -> bool:
-    """Whether the fused dispatch should route its aggregate through
-    the Pallas partials kernel — the same measured-before-adoption knob
-    as the fused single-shot aggregate (HORAEDB_DOWNSAMPLE_IMPL)."""
-    return downsample.downsample_impl() == "pallas"
-
-
-def classify_pallas_failure() -> str:
-    """Distinguish 'this host has no TPU' (interpret-mode gaps, an
-    environment fact) from 'the kernel is broken on real hardware' (a
-    bug CI must surface) — the two must not share one except clause."""
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001 — no backend at all counts as no TPU
-        on_tpu = False
-    return "pallas_error" if on_tpu else "pallas_no_tpu"
 
 
 @dataclass
@@ -800,28 +772,19 @@ def execute_plan(dp: DecodePlan) -> DecodeDispatch:
     offs_dev = jnp.int32(0) if dp.run_offsets is None \
         else jnp.asarray(dp.run_offsets)
 
-    def run(pallas: bool):
-        return _decode_aggregate_jit(
-            tuple(cols_dev), es.n, consts_dev,
-            np.int32(dp.shift), np.int32(dp.lo),
-            np.int32(dp.num_buckets), np.int32(dp.bucket_ms), offs_dev,
-            key_slots=dp.key_slots, num_pks=dp.num_pks,
-            group_pos=dp.group_pos, ts_pos=dp.ts_pos,
-            val_slot=dp.val_slot, leaf_prog=dp.leaf_prog,
-            g_pad=dp.g_pad, width=dp.use_width, which=dp.which,
-            use_pallas=pallas, route=dp.route, num_runs=dp.num_runs)
-
-    if use_pallas_partials():
-        try:
-            outs, n_rows = run(True)
-        except Exception as exc:  # noqa: BLE001 — guarded, classified
-            reason = classify_pallas_failure()
-            note_fallback(reason)
-            logger.warning("pallas decode kernel failed (%s): %s; "
-                           "using the XLA program", reason, exc)
-            outs, n_rows = run(False)
-    else:
-        outs, n_rows = run(False)
+    # the Pallas partials kernel rides the same knob as the single-shot
+    # aggregate (HORAEDB_DOWNSAMPLE_IMPL); selected and failing, it
+    # raises — it never quietly serves the XLA program
+    outs, n_rows = _decode_aggregate_jit(
+        tuple(cols_dev), es.n, consts_dev,
+        np.int32(dp.shift), np.int32(dp.lo),
+        np.int32(dp.num_buckets), np.int32(dp.bucket_ms), offs_dev,
+        key_slots=dp.key_slots, num_pks=dp.num_pks,
+        group_pos=dp.group_pos, ts_pos=dp.ts_pos,
+        val_slot=dp.val_slot, leaf_prog=dp.leaf_prog,
+        g_pad=dp.g_pad, width=dp.use_width, which=dp.which,
+        use_pallas=downsample.downsample_impl() == "pallas",
+        route=dp.route, num_runs=dp.num_runs)
     return DecodeDispatch(outs=outs, n_rows=n_rows,
                           values=dp.values, lo=dp.lo, w_eff=dp.w_eff,
                           bucket_ms=dp.bucket_ms,

@@ -22,43 +22,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax: pre-promotion experimental namespace
-    import inspect
-
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    _COMPAT_PARAMS = inspect.signature(_shard_map_compat).parameters
-    _COMPAT_VAR_KW = any(
-        p.kind is inspect.Parameter.VAR_KEYWORD
-        for p in _COMPAT_PARAMS.values())
-
-    def shard_map(f, *, check_vma=True, **kw):
-        """Compat shim for pre-promotion jax: the experimental API
-        spells replication checking `check_rep`.  Every other kwarg
-        forwards verbatim, and one this jax's shard_map does not accept
-        raises HERE with the offending names — silently dropping it
-        would mask future jax API drift behind subtly-wrong programs."""
-        if "check_rep" in _COMPAT_PARAMS or _COMPAT_VAR_KW:
-            kw.setdefault("check_rep", check_vma)
-        elif "check_vma" in _COMPAT_PARAMS:
-            kw.setdefault("check_vma", check_vma)
-        else:
-            raise TypeError(
-                "jax.experimental.shard_map.shard_map accepts neither "
-                "check_rep nor check_vma; update the compat shim in "
-                "horaedb_tpu/parallel/scan.py for this jax version")
-        if not _COMPAT_VAR_KW:
-            unknown = sorted(k for k in kw if k not in _COMPAT_PARAMS)
-            if unknown:
-                raise TypeError(
-                    f"shard_map compat shim: kwargs {unknown} are not "
-                    "accepted by this jax version's experimental "
-                    "shard_map — fix the call site or the shim, do not "
-                    "drop them")
-        return _shard_map_compat(f, **kw)
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horaedb_tpu.common import deviceprof

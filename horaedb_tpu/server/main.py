@@ -1092,13 +1092,19 @@ def build_app(state: ServerState) -> web.Application:
 
     @routes.get("/debug/device")
     async def debug_device(_req: web.Request) -> web.Response:
-        """The device plane (common/deviceprof.py): the compile-cache
+        """The device plane (common/deviceprof.py): the backend as JAX
+        reports it (platform, device kind, count) and where its
+        persistent compile cache lives, the compile-cache
         table (per-fn compile counts/seconds, last cache key, storm
         state), dispatch/exec time split, h2d/d2h transfer totals, the
         mesh round timeline (slot fill, padding waste, per-shard row
         imbalance), and per-device memory with high-water marks.  This
         is the jit seam's /debug/memory."""
+        from horaedb_tpu.utils import compile_cache
+
         out = deviceprof.snapshot()
+        out["backend"] = {**deviceprof.backend(),
+                          "compile_cache_dir": compile_cache.cache_dir()}
         sample = memledger.sample_once()
         out["devices"] = sample.get("devices", [])
         return web.json_response(out)

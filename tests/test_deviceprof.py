@@ -124,6 +124,20 @@ class TestCompileLedger:
         # the triggering key names the dimensions jit keys on
         assert dict(rec.last_key)["a0.shape"] == (16,)
 
+    def test_programs_sharing_a_name_each_book_their_compile(self):
+        """Builders mint many jitted programs under one ledger name
+        (the mesh rounds): each one's first call is a compile, not a
+        dispatch — on the chip that is where a cold round's seconds
+        go."""
+        prof = DeviceProfiler()
+        f = prof.jit(lambda x: x + 1, name="unit_shared")
+        g = prof.jit(lambda x: x + 2, name="unit_shared")
+        f(_arr(8))
+        g(_arr(8))
+        g(_arr(8, seed=1))
+        rec = prof._record("unit_shared")
+        assert (rec.compiles, rec.dispatches) == (2, 1)
+
     def test_decorator_forms_register(self):
         prof = DeviceProfiler()
 
@@ -390,6 +404,11 @@ class TestServerSurface:
                 assert fns["unit_srv"]["last_key"], fns["unit_srv"]
                 assert set(body["transfer"]) == {"h2d", "d2h"}
                 assert "rounds" in body and "devices" in body
+                # the device as JAX reports it, and where its compile
+                # cache lives (off on the CPU backend)
+                assert body["backend"] == {
+                    "platform": "cpu", "kind": "cpu", "count": 8,
+                    "compile_cache_dir": None}
                 r = await client.get("/stats")
                 dp = (await r.json())["deviceprof"]
                 assert dp["fns"] >= 1

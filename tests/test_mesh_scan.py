@@ -674,20 +674,6 @@ def test_mesh_stats_section(runtimes):
     run(go())
 
 
-def test_compat_shim_rejects_unknown_kwargs():
-    """The check_vma->check_rep shim must forward kwargs verbatim and
-    fail loudly on ones this jax's shard_map does not accept, instead
-    of masking API drift (ISSUE 15 satellite)."""
-    import jax as _jax
-
-    from horaedb_tpu.parallel import scan as pscan
-
-    if hasattr(_jax, "shard_map"):
-        pytest.skip("new jax: the shim is not in play")
-    with pytest.raises(TypeError, match="not accepted"):
-        pscan.shard_map(lambda x: x, definitely_not_a_kwarg=1)
-
-
 def test_empty_minmax_cells_canonical():
     """Count-0 min/max cells must read the documented +/-inf
     identities even when a part's span touched them with the device

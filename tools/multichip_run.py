@@ -1,10 +1,8 @@
 #!/usr/bin/env python
 """Multichip dryrun runner that ALWAYS records a result.
 
-ROADMAP item 3 notes the MULTICHIP bench recording gap: the round-1
-multichip run timed out (rc=124, MULTICHIP_r01) and left nothing but a
-truncated log — a wedged run must still produce a structured record so
-the history distinguishes "timed out" from "never ran".  This runner
+A run that times out must still produce a structured record so the
+history distinguishes "timed out" from "never ran".  This runner
 executes `__graft_entry__.dryrun_multichip(N)` in a subprocess under a
 hard timeout and writes `bench_results/multichip_rNN.json` (next free
 index) with an explicit `status` of "ok" | "timeout" | "error" — on
@@ -55,10 +53,11 @@ def run(n_devices: int, timeout_s: float, mode: str = "dryrun",
         # three legs, k-way-merge routing asserts, and the additive
         # top-k egress bound at two group cardinalities
         # (BENCH_CONFIG=19 remains the PR 15 two-leg A/B, selectable
-        # via MESH_BENCH_CONFIG).  On this box the rung is the CPU
-        # virtual mesh (--xla_force_host_platform_device_count); a TPU
-        # host runs the identical command on real chips and the
-        # record's backend/fallback labels say which it was
+        # via MESH_BENCH_CONFIG).  Without an accelerator the rung is
+        # the CPU virtual mesh (--xla_force_host_platform_device_count,
+        # ignored by other backends); the record's backend/fallback
+        # labels say which it was.  This parent never imports jax, so
+        # the child is the only process that can hold a chip
         cmd = [sys.executable, "bench.py"]
         env = dict(os.environ)
         env["BENCH_CONFIG"] = env.get("MESH_BENCH_CONFIG", "22")
@@ -83,7 +82,7 @@ def run(n_devices: int, timeout_s: float, mode: str = "dryrun",
         record["rc"] = proc.returncode
         record["ok"] = proc.returncode == 0
         # rc=124 is how an outer `timeout(1)` reports — classify it as
-        # a timeout even when the wedge happened below us
+        # a timeout even when the hang happened below us
         record["status"] = ("ok" if proc.returncode == 0 else
                             "timeout" if proc.returncode == 124 else
                             "error")
