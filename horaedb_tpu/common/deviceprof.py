@@ -12,14 +12,20 @@ ledger entry answering the three questions XLA keeps to itself:
                     static values), so a recompile names the dimension
                     that churned instead of "it was slow once"
   where did the wall go?   per-dispatch host time (trace/cache-lookup/
-                    enqueue) vs device execution (measured at the
-                    existing block_until_ready seams) — a cold query's
-                    slow-log entry states whether it paid compilation,
-                    dispatch overhead, or the kernel
-  what moved?       device_transfer_bytes_total{direction=h2d|d2h}
-                    charged at the device_put/download seams, with
-                    per-trace twins, reconciled against the memory
-                    ledger's device accounts
+                    enqueue) vs the host's wait for the device at the
+                    sync seams (block_until_ready, download) — a cold
+                    query's slow-log entry states whether it paid
+                    compilation, dispatch overhead, or waited for the
+                    device.  The wait is NOT a kernel time: it holds
+                    the device's queue (other queries' programs ahead
+                    of this one) and the execution; kernel times come
+                    from a jax.profiler trace
+  what moved?       device_transfer_bytes_total{direction=h2d|d2h} and
+                    device_transfer_seconds_total, charged at the
+                    device_put/download seams (a download syncs first,
+                    so its seconds are the copy alone), with per-trace
+                    twins, reconciled against the memory ledger's
+                    device accounts
 
 Recompile STORMS (N compiles of one fn inside a sliding window — the
 shape-churn failure mode of a capacity-padded engine) flag once per
@@ -50,7 +56,7 @@ from collections import deque
 from typing import Any, Callable, Optional
 
 from horaedb_tpu.utils.metrics import registry
-from horaedb_tpu.utils.tracing import trace_add
+from horaedb_tpu.utils.tracing import phase, trace_add
 
 logger = logging.getLogger(__name__)
 # storms land next to slow queries and watchdog stalls: one stream an
@@ -77,8 +83,9 @@ _DISPATCH_SECONDS = registry.histogram(
     "argument processing + async enqueue), per jitted function")
 _EXEC_SECONDS = registry.histogram(
     "device_exec_seconds",
-    "device execution wall measured at block_until_ready seams, per "
-    "jitted function")
+    "the host's wait for the device at a sync seam (block_until_ready, "
+    "download), per jitted function: queue behind other programs plus "
+    "execution, not a kernel time")
 _TRANSFER_BYTES = registry.counter(
     "device_transfer_bytes_total",
     "bytes moved across the host/device boundary at the device_put "
@@ -86,7 +93,8 @@ _TRANSFER_BYTES = registry.counter(
 _TRANSFER_SECONDS = registry.counter(
     "device_transfer_seconds_total",
     "wall seconds spent in instrumented host/device transfers, by "
-    "direction (h2d|d2h; async puts charge the enqueue wall)")
+    "direction (h2d: async puts charge the enqueue wall; d2h: the "
+    "copy after the sync, never the wait for the device)")
 
 
 def _nbytes(x: Any) -> int:
@@ -339,20 +347,46 @@ class DeviceProfiler:
 
     # ---- the exec + transfer seams ----------------------------------------
 
-    def block_until_ready(self, x, fn: str = "device"):
-        """The exec-measurement seam: wall spent here is DEVICE
-        execution (the dispatch already returned; this waits for the
-        computation).  Returns `x` so call sites stay expressions."""
+    def block_until_ready(self, x, fn: str = "device", table: str = ""):
+        """The sync seam: wall spent here is the host blocked on the
+        device (the dispatch already returned; this waits for the
+        queue ahead of the computation and for the computation).
+        A `scan.device_wait` phase span of `table`.  Returns `x` so
+        call sites stay expressions."""
         import jax
 
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(x)
-        self.observe_exec(fn, time.perf_counter() - t0)
+        with phase("scan.device_wait", table, fn=fn):
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(x)
+            waited = time.perf_counter() - t0
+        self.observe_exec(fn, waited)
+        return out
+
+    def download(self, x, fn: str = "device", table: str = ""):
+        """The d2h seam, the sync split from the copy: first
+        `block_until_ready` (charged to `scan.device_wait` and
+        device_exec_seconds{fn}; where every leaf is ready already
+        nothing waits: an observation of 0 and no span), then `np.asarray` of every leaf
+        (the `scan.d2h` phase span, and the seconds of
+        device_transfer_seconds_total{direction="d2h"}).  Returns the
+        same pytree with numpy leaves."""
+        import jax
+        import numpy as np
+
+        if all(leaf.is_ready() for leaf in jax.tree_util.tree_leaves(x)
+               if isinstance(leaf, jax.Array)):
+            self.observe_exec(fn, 0.0)  # the seam was passed: waited 0
+        else:
+            self.block_until_ready(x, fn=fn, table=table)
+        with phase("scan.d2h", table, fn=fn):
+            t0 = time.perf_counter()
+            out = jax.tree_util.tree_map(np.asarray, x)
+            copied = time.perf_counter() - t0
+        self.charge_transfer("d2h", _nbytes(out), seconds=copied)
         return out
 
     def observe_exec(self, fn: str, seconds: float) -> None:
-        """Charge already-measured device-execution wall (seams that
-        time a dispatch+sync span themselves)."""
+        """Charge an already-measured wait for the device."""
         if not self.enabled:
             return
         rec = self._record(fn)
@@ -501,6 +535,7 @@ profiler = DeviceProfiler()
 # `deviceprof.device_put(...)` like the jax names they replace
 jit = profiler.jit
 block_until_ready = profiler.block_until_ready
+download = profiler.download
 observe_exec = profiler.observe_exec
 device_put = profiler.device_put
 charge_transfer = profiler.charge_transfer

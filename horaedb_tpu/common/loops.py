@@ -38,6 +38,16 @@ Heartbeat discipline for loop authors:
               `loop_errors_total{loop=}` and the /debug/tasks error
               surface instead of vanishing into an `except: pass`
 
+What stops the whole server (docs/observability.md, stall sampler): a
+sampler loop registered here like any other sleeps 100 ms at a time
+and takes its lateness as the event loop's lag — the time every
+coroutine that was ready then waited — into `event_loop_lag_seconds`
+and, beyond 50 ms, `event_loop_stall_seconds_total`; `gc.callbacks`
+time every collection into `process_gc_pause_seconds_total
+{generation}`.  A lag over 250 ms writes one slow-log line naming what
+overlapped it: background ops (finished and in flight), collections,
+the loops that woke in it, and each pool's queue depth.
+
 Loops doing legitimately long single iterations (a compaction rewrite, a
 whole-table rollup backfill) pass an explicit `stall_threshold_s`
 sized to their worst case — the watchdog flags *wedged*, not *busy*.
@@ -53,11 +63,15 @@ pruned by the watchdog sweep.
 from __future__ import annotations
 
 import asyncio
+import gc
 import logging
 import threading
 import time
+from collections import deque
 from typing import Callable, Optional
 
+from horaedb_tpu.common import runtimes
+from horaedb_tpu.utils import tracing
 from horaedb_tpu.utils.metrics import registry
 
 logger = logging.getLogger(__name__)
@@ -83,6 +97,49 @@ _HB_AGE = registry.gauge(
     "loop_heartbeat_age_seconds",
     "oldest heartbeat age among live non-idle loops of a kind "
     "(updated each watchdog round)")
+
+# ---- what stops the server: loop lag and collector pauses -------------------
+STALL_PERIOD_S = 0.1    # the sampler's timer
+STALL_COUNT_S = 0.05    # lateness beyond this counts as a stall
+STALL_LOG_S = 0.25      # lateness beyond this writes a slow-log line
+_LAG_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+                1.0, 2.5, 5.0, 10.0)
+_LOOP_LAG = registry.histogram(
+    "event_loop_lag_seconds",
+    "lateness of the stall sampler's 100 ms timer: how long a ready "
+    "coroutine waited for the event loop", buckets=_LAG_BUCKETS)
+_LOOP_STALL = registry.counter(
+    "event_loop_stall_seconds_total",
+    "seconds the event loop stood still, summed over the sampler ticks "
+    "that came more than 50 ms late")
+_GC_PAUSE = registry.counter(
+    "process_gc_pause_seconds_total",
+    "seconds inside the cyclic collector (gc.callbacks start to stop), "
+    "by generation")
+_GC_CHILDREN = {g: _GC_PAUSE.labels(generation=str(g)) for g in (0, 1, 2)}
+# the newest collections (wall start ms, seconds, generation), for the
+# stall line; appended under the GIL by whichever thread collected
+_gc_recent: deque = deque(maxlen=64)
+_gc_started = [0.0, 0.0]
+_gc_hooked = False
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc_started[0] = time.perf_counter()
+        _gc_started[1] = time.time() * 1e3
+    elif _gc_started[0]:
+        pause = time.perf_counter() - _gc_started[0]
+        _GC_CHILDREN[info["generation"]].inc(pause)
+        _gc_recent.append((_gc_started[1], pause, info["generation"]))
+
+
+def hook_gc() -> None:
+    """Time every collection of this process (idempotent)."""
+    global _gc_hooked
+    if not _gc_hooked:
+        _gc_hooked = True
+        gc.callbacks.append(_on_gc)
 
 
 class LoopHandle:
@@ -286,6 +343,46 @@ class LoopRegistry:
         self._watchdog_task = self.spawn(
             self._watchdog_loop, name="watchdog",
             period_s=self.interval_s, owner="loops", _watch=False)
+        # the stall sampler lives and dies with the watchdog: one per
+        # event loop that runs background loops
+        hook_gc()
+        self.spawn(self._stall_loop, name="stall-sampler",
+                   period_s=STALL_PERIOD_S, owner="loops", _watch=False)
+
+    async def _stall_loop(self, hb: LoopHandle) -> None:
+        loop = asyncio.get_running_loop()
+        while True:
+            hb.beat()
+            due = loop.time() + STALL_PERIOD_S
+            await asyncio.sleep(STALL_PERIOD_S)
+            if self.enabled:
+                self.note_lag(max(0.0, loop.time() - due))
+            hb.ok()
+
+    def note_lag(self, lag_s: float) -> None:
+        """One sampler tick that came `lag_s` late (the stall line names
+        the collections of a millisecond or more)."""
+        _LOOP_LAG.observe(lag_s)
+        if lag_s <= STALL_COUNT_S:
+            return
+        _LOOP_STALL.inc(lag_s)
+        if lag_s <= STALL_LOG_S:
+            return
+        end_ms = time.time() * 1e3
+        start_ms = end_ms - lag_s * 1e3
+        now = self._clock()
+        ops = [f"{op}:{dur:.0f}ms" if dur is not None else f"{op}:active"
+               for op, _t0, dur in tracing.recorder.ops_overlapping(
+                   start_ms, end_ms)]
+        pauses = [f"gen{g}:{s * 1e3:.0f}ms" for t0, s, g in list(_gc_recent)
+                  if s >= 0.001 and t0 < end_ms and t0 + s * 1e3 > start_ms]
+        woke = sorted(h.name for h in self.handles()
+                      if h.owner != "loops" and not h.idle_flag
+                      and now - h.last_beat <= lag_s + STALL_PERIOD_S)
+        slow_logger.warning(
+            "[stall] event loop stood still %.3fs: ops=%s gc=%s "
+            "loops_woke=%s pool_queues=%s", lag_s, ops, pauses, woke,
+            runtimes.queue_depths())
 
     async def _watchdog_loop(self, hb: LoopHandle) -> None:
         while True:

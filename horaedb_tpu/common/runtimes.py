@@ -15,15 +15,62 @@ run_in_executor.  Pools:
   compact  — compaction rewrites (so they queue behind each other, not
              in front of serving work)
   manifest — manifest codec/folds
+
+Every hop through `run` is timed where the work waits
+(docs/observability.md, scan phases and waits): submit to the first
+instruction on the worker (`runtime_pool_wait_seconds{pool}`: the
+pool's queue) and the worker's return to the coroutine's resumption on
+the loop (`runtime_pool_resume_seconds{pool}`: the loop's lag as this
+job feels it); with the time on the worker they are the per-trace twins
+`pool_<pool>_{wait,run,resume}_ms` and, in a traced request, the fields
+of the hop's `pool_hop` span (submit to resumption).
 """
 
 from __future__ import annotations
 
 import asyncio
 import contextvars
-import functools
+import time
+import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable
+
+from horaedb_tpu.utils.metrics import registry
+from horaedb_tpu.utils.tracing import record_hop
+
+POOLS = ("sst", "compact", "manifest")
+# waits are short when all is well: the default buckets start at 0.5 ms
+_WAIT_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+
+
+def _per_pool(name: str, help_: str) -> dict:
+    family = registry.histogram(name, help_, buckets=_WAIT_BUCKETS)
+    return {p: family.labels(pool=p) for p in POOLS}
+
+
+_WAIT = _per_pool(
+    "runtime_pool_wait_seconds",
+    "time a job waited in a named pool's queue: submit to its first "
+    "instruction on a worker thread")
+_RESUME = _per_pool(
+    "runtime_pool_resume_seconds",
+    "time from a job's return on the worker to the resumption of the "
+    "coroutine that awaited it: the event loop's lag as the job feels "
+    "it")
+
+
+_LIVE: "weakref.WeakSet[Runtimes]" = weakref.WeakSet()
+
+
+def queue_depths() -> dict:
+    """Jobs waiting (not running) in each named pool, summed over the
+    process's open Runtimes: what the stall line reports."""
+    out = dict.fromkeys(POOLS, 0)
+    for rt in list(_LIVE):
+        for name, pool in rt._pools.items():
+            out[name] += pool._work_queue.qsize()
+    return out
 
 
 class Runtimes:
@@ -32,6 +79,7 @@ class Runtimes:
 
     def __init__(self, sst_threads: int = 4, compact_threads: int = 2,
                  manifest_threads: int = 1):
+        _LIVE.add(self)
         self._pools = {
             "sst": ThreadPoolExecutor(sst_threads,
                                       thread_name_prefix="horaedb-sst"),
@@ -49,10 +97,26 @@ class Runtimes:
         attribution inside pool work."""
         loop = asyncio.get_running_loop()
         ctx = contextvars.copy_context()
-        return await loop.run_in_executor(
-            self._pools[pool],
-            functools.partial(ctx.run,
-                              functools.partial(fn, *args, **kwargs)))
+        on_worker = [0.0, 0.0]
+
+        def job():
+            on_worker[0] = time.perf_counter()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                on_worker[1] = time.perf_counter()
+
+        submitted = time.perf_counter()
+        try:
+            return await loop.run_in_executor(self._pools[pool], ctx.run,
+                                              job)
+        finally:
+            started, returned = on_worker
+            if returned:  # a job cancelled in the queue never ran
+                resumed = time.perf_counter()
+                _WAIT[pool].observe(started - submitted)
+                _RESUME[pool].observe(resumed - returned)
+                record_hop(pool, submitted, started, returned, resumed)
 
     def close(self) -> None:
         # wait=True is load-bearing: shutdown(wait=False) leaves an
@@ -61,6 +125,7 @@ class Runtimes:
         # object teardown and corrupts the heap (observed as later
         # segfaults/aborts inside pyarrow).  Queued-but-unstarted jobs
         # are cancelled; the bounded in-flight ones finish first.
+        _LIVE.discard(self)
         for pool in self._pools.values():
             pool.shutdown(wait=True, cancel_futures=True)
 

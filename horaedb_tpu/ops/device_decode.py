@@ -53,6 +53,7 @@ from typing import Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from horaedb_tpu.common import deviceprof
@@ -355,10 +356,40 @@ def decode_rows_core(cols: tuple, n_valid, leaf_consts: tuple,
     """
     cap = cols[0].shape[0]
     iota = jnp.arange(cap, dtype=jnp.int32)
-    valid = iota < jnp.asarray(n_valid, jnp.int32)
-    for (slot, op), c in zip(leaf_prog, leaf_consts):
-        valid = valid & _leaf_mask(cols[slot], op, c)
+    # stages under jax.named_scope, so a profile's operation metadata
+    # tells this program's fusions apart (docs/observability.md)
+    with jax.named_scope("filter"):
+        valid = iota < jnp.asarray(n_valid, jnp.int32)
+        for (slot, op), c in zip(leaf_prog, leaf_consts):
+            valid = valid & _leaf_mask(cols[slot], op, c)
 
+    with jax.named_scope("merge_" + route):
+        valid_s, keys_s, val_s = _rows_in_order(
+            cols, valid, iota, n_valid, run_offsets, key_slots=key_slots,
+            num_pks=num_pks, val_slot=val_slot, route=route,
+            num_runs=num_runs)
+    # keep-last per PK run among surviving rows (_host_dedup_keep):
+    # a row survives iff valid and (last row | next row invalid | any
+    # pk differs from the next row).  Run boundaries compare the PK
+    # keys ONLY — seq orders within a run, it never splits one.
+    with jax.named_scope("dedup"):
+        differs_next = jnp.zeros(cap - 1, dtype=bool)
+        for c in keys_s[:num_pks]:
+            differs_next = differs_next | (c[:-1] != c[1:])
+        kept = valid_s & jnp.concatenate(
+            [differs_next | ~valid_s[1:], jnp.ones(1, dtype=bool)])
+
+        gid = jnp.where(kept, keys_s[group_pos], jnp.int32(-1))
+        n_rows = jnp.sum(kept.astype(jnp.int32))
+    return keys_s, gid, val_s, n_rows
+
+
+def _rows_in_order(cols: tuple, valid, iota, n_valid, run_offsets, *,
+                   key_slots: tuple, num_pks: int, val_slot: int,
+                   route: str, num_runs: int):
+    """decode_rows_core's route step: (valid, keys, values) with the
+    rows in (pk, seq) order."""
+    cap = cols[0].shape[0]
     if route == "presorted":
         # rows already arrive (pk, seq)-sorted (host-checked, the
         # single-SST/post-compaction shape): the run-boundary masks
@@ -404,19 +435,7 @@ def decode_rows_core(cols: tuple, n_valid, leaf_consts: tuple,
         valid_s = sorted_ops[0] == 0
         keys_s = sorted_ops[1:1 + len(key_slots)]
         val_s = sorted_ops[-1]
-    # keep-last per PK run among surviving rows (_host_dedup_keep):
-    # a row survives iff valid and (last row | next row invalid | any
-    # pk differs from the next row).  Run boundaries compare the PK
-    # keys ONLY — seq orders within a run, it never splits one.
-    differs_next = jnp.zeros(cap - 1, dtype=bool)
-    for c in keys_s[:num_pks]:
-        differs_next = differs_next | (c[:-1] != c[1:])
-    kept = valid_s & jnp.concatenate(
-        [differs_next | ~valid_s[1:], jnp.ones(1, dtype=bool)])
-
-    gid = jnp.where(kept, keys_s[group_pos], jnp.int32(-1))
-    n_rows = jnp.sum(kept.astype(jnp.int32))
-    return keys_s, gid, val_s, n_rows
+    return valid_s, keys_s, val_s
 
 
 @deviceprof.jit(static_argnames=(
@@ -502,10 +521,10 @@ class DecodeDispatch:
     segment k+1's upload while segment k's kernel still runs."""
 
     __slots__ = ("outs", "n_rows", "values", "lo", "w_eff", "bucket_ms",
-                 "t_dispatch", "upload_bytes", "src_rows")
+                 "t_dispatch", "upload_bytes", "src_rows", "table")
 
     def __init__(self, outs, n_rows, values, lo, w_eff, bucket_ms,
-                 t_dispatch, upload_bytes, src_rows):
+                 t_dispatch, upload_bytes, src_rows, table=""):
         self.outs = outs
         self.n_rows = n_rows
         self.values = values
@@ -515,28 +534,26 @@ class DecodeDispatch:
         self.t_dispatch = t_dispatch
         self.upload_bytes = upload_bytes
         self.src_rows = src_rows
+        self.table = table  # the table scanned: labels the phase spans
 
     def finalize(self) -> DevicePart:
         t0 = time.perf_counter()
         g = len(self.values)
-        # the full (g_pad, width) grids cross the device boundary here
-        # (np.asarray downloads the whole buffer before the slice) —
-        # the d2h charge counts what moved, not what was kept
-        d2h_bytes = sum(int(getattr(v, "nbytes", 0))
-                        for v in self.outs.values())
+        # the sync, then the copy, each charged to its own phase: the
+        # wait holds the device's queue and the program's execution
+        # (the jit call returned immediately); the full (g_pad, width)
+        # grids cross the device boundary in the copy — the d2h charge
+        # counts what moved, not what was kept
+        host = deviceprof.download(self.outs, fn="_decode_aggregate_jit",
+                                   table=self.table)
         # mirror _flush_window_batch's emission exactly: slice to the
         # real group count and the query-clipped width, then re-base
         # window-local last_ts to range_start-relative int64.  The
         # slices COPY (ascontiguousarray): a view would pin the full
         # (g_pad, width) download while nbytes counted only the slice
         # — the PartsMemo views-pin-bases defect, not repeated here
-        grids = {k: np.ascontiguousarray(np.asarray(v)[:g, :self.w_eff])
-                 for k, v in self.outs.items()}
-        # the asarray wait IS the device execution for this dispatch
-        # (the jit call returned immediately; this synced)
-        deviceprof.observe_exec("_decode_aggregate_jit",
-                                time.perf_counter() - t0)
-        deviceprof.charge_transfer("d2h", d2h_bytes)
+        grids = {k: np.ascontiguousarray(v[:g, :self.w_eff])
+                 for k, v in host.items()}
         if "last_ts" in grids:
             lt = grids["last_ts"].astype(np.int64)
             grids["last_ts"] = np.where(
@@ -754,10 +771,12 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
         bucket_ms=spec.bucket_ms, num_buckets=spec.num_buckets)
 
 
-def execute_plan(dp: DecodePlan) -> DecodeDispatch:
+def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
     """Upload one planned segment and issue its fused dispatch on the
     default device — the single-device tail of the old prepare path
-    and the per-item fallback when a mesh round declines a plan."""
+    and the per-item fallback when a mesh round declines a plan.
+    `table` labels the phase spans of the dispatch's finalize (the
+    caller wraps this call in its `scan.dispatch` phase)."""
     es = dp.es
     t0 = time.perf_counter()
     upload_bytes = 0
@@ -789,7 +808,8 @@ def execute_plan(dp: DecodePlan) -> DecodeDispatch:
                           values=dp.values, lo=dp.lo, w_eff=dp.w_eff,
                           bucket_ms=dp.bucket_ms,
                           t_dispatch=time.perf_counter() - t0,
-                          upload_bytes=upload_bytes, src_rows=es.n)
+                          upload_bytes=upload_bytes, src_rows=es.n,
+                          table=table)
 
 
 def prepare_dispatch(es, spec, pk_names: list, seq_name: str,

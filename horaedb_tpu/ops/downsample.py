@@ -59,43 +59,54 @@ def partial_aggregate(ts_offset: jax.Array, group_ids: jax.Array,
     iota = jnp.arange(capacity, dtype=jnp.int32)
     valid = iota < jnp.asarray(n_valid, dtype=jnp.int32)
 
-    bucket = ts_offset // jnp.asarray(bucket_ms, dtype=jnp.int32)
-    in_grid = valid & (bucket >= 0) & (bucket < num_buckets) \
-        & (group_ids >= 0) & (group_ids < num_groups)
-    num_cells = num_groups * num_buckets
-    # out-of-grid rows land in an overflow cell that is sliced away
-    seg = jnp.where(in_grid, group_ids * num_buckets + bucket, num_cells)
+    # each stage under a jax.named_scope: the scope rides the HLO
+    # metadata of the operations it emits, so a profile can tell the
+    # scan programs' fusions apart (docs/observability.md)
+    with jax.named_scope("bucket_index"):
+        bucket = ts_offset // jnp.asarray(bucket_ms, dtype=jnp.int32)
+        in_grid = valid & (bucket >= 0) & (bucket < num_buckets) \
+            & (group_ids >= 0) & (group_ids < num_groups)
+        num_cells = num_groups * num_buckets
+        # out-of-grid rows land in an overflow cell that is sliced away
+        seg = jnp.where(in_grid, group_ids * num_buckets + bucket,
+                        num_cells)
 
     grid = lambda a: a.reshape(num_groups, num_buckets)
-    ones = in_grid.astype(jnp.float32)
-    out = {"count": grid(jax.ops.segment_sum(
-        ones, seg, num_segments=num_cells + 1)[:num_cells])}
+    with jax.named_scope("scatter_count"):
+        ones = in_grid.astype(jnp.float32)
+        out = {"count": grid(jax.ops.segment_sum(
+            ones, seg, num_segments=num_cells + 1)[:num_cells])}
     if "sum" in want:
-        out["sum"] = grid(jax.ops.segment_sum(
-            jnp.where(in_grid, values, 0.0), seg,
-            num_segments=num_cells + 1)[:num_cells])
+        with jax.named_scope("scatter_sum"):
+            out["sum"] = grid(jax.ops.segment_sum(
+                jnp.where(in_grid, values, 0.0), seg,
+                num_segments=num_cells + 1)[:num_cells])
     if "min" in want:
-        out["min"] = grid(jax.ops.segment_min(
-            jnp.where(in_grid, values, _F32_MAX), seg,
-            num_segments=num_cells + 1)[:num_cells])
+        with jax.named_scope("scatter_min"):
+            out["min"] = grid(jax.ops.segment_min(
+                jnp.where(in_grid, values, _F32_MAX), seg,
+                num_segments=num_cells + 1)[:num_cells])
     if "max" in want:
-        out["max"] = grid(jax.ops.segment_max(
-            jnp.where(in_grid, values, -_F32_MAX), seg,
-            num_segments=num_cells + 1)[:num_cells])
+        with jax.named_scope("scatter_max"):
+            out["max"] = grid(jax.ops.segment_max(
+                jnp.where(in_grid, values, -_F32_MAX), seg,
+                num_segments=num_cells + 1)[:num_cells])
     if "last" in want:
         # "last" = value at the highest timestamp in the cell (later row
         # wins ties, mirroring last-value merge semantics).  Two segmented
         # passes: max ts per cell, then max row index at that ts.
-        tmax = jax.ops.segment_max(
-            jnp.where(in_grid, ts_offset, _I32_MIN), seg,
-            num_segments=num_cells + 1)
-        at_max_ts = in_grid & (ts_offset == tmax[seg])
-        last_row = jax.ops.segment_max(
-            jnp.where(at_max_ts, iota, -1), seg,
-            num_segments=num_cells + 1)[:num_cells]
-        out["last"] = grid(jnp.where(
-            last_row >= 0, values[jnp.clip(last_row, 0, capacity - 1)], 0.0))
-        out["last_ts"] = grid(tmax[:num_cells])
+        with jax.named_scope("scatter_last"):
+            tmax = jax.ops.segment_max(
+                jnp.where(in_grid, ts_offset, _I32_MIN), seg,
+                num_segments=num_cells + 1)
+            at_max_ts = in_grid & (ts_offset == tmax[seg])
+            last_row = jax.ops.segment_max(
+                jnp.where(at_max_ts, iota, -1), seg,
+                num_segments=num_cells + 1)[:num_cells]
+            out["last"] = grid(jnp.where(
+                last_row >= 0,
+                values[jnp.clip(last_row, 0, capacity - 1)], 0.0))
+            out["last_ts"] = grid(tmax[:num_cells])
     return out
 
 
@@ -120,17 +131,19 @@ def window_local_partials(ts, gid_local, vals, remap, shift, lo,
         beyond it are dropped (windows may overhang the query range).
       num_buckets: static LOCAL grid width.
     """
-    gid_union = jnp.where(
-        gid_local >= 0,
-        remap[jnp.clip(gid_local, 0, remap.shape[0] - 1)], -1)
-    bucket_ms = jnp.asarray(bucket_ms, jnp.int32)
-    ts_global = ts + jnp.asarray(shift, jnp.int32)
-    bucket_global = ts_global // bucket_ms
-    gid_union = jnp.where(
-        bucket_global < jnp.asarray(total_buckets, jnp.int32),
-        gid_union, -1)
-    # exact: (a - lo*b) // b == a//b - lo for integer floor division
-    ts_local = ts_global - jnp.asarray(lo, jnp.int32) * bucket_ms
+    with jax.named_scope("decode_group_ids"):
+        gid_union = jnp.where(
+            gid_local >= 0,
+            remap[jnp.clip(gid_local, 0, remap.shape[0] - 1)], -1)
+    with jax.named_scope("decode_timestamps"):
+        bucket_ms = jnp.asarray(bucket_ms, jnp.int32)
+        ts_global = ts + jnp.asarray(shift, jnp.int32)
+        bucket_global = ts_global // bucket_ms
+        gid_union = jnp.where(
+            bucket_global < jnp.asarray(total_buckets, jnp.int32),
+            gid_union, -1)
+        # exact: (a - lo*b) // b == a//b - lo for integer floor division
+        ts_local = ts_global - jnp.asarray(lo, jnp.int32) * bucket_ms
     return partial_aggregate(ts_local, gid_union, vals, ts.shape[0],
                              bucket_ms, num_groups=num_groups,
                              num_buckets=num_buckets, which=which)

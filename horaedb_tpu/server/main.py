@@ -33,6 +33,7 @@ import time
 from collections import deque
 from typing import Optional
 
+import numpy as np
 from aiohttp import web
 
 from horaedb_tpu.common import Error, ensure, now_ms
@@ -1340,6 +1341,12 @@ def build_app(state: ServerState) -> web.Application:
         bucket_ms = int(bucket_ms) if bucket_ms else None
         return metric, filters, rng, field, bucket_ms
 
+    async def _read_query(req: web.Request):
+        """The `parse` span: the body and _parse_query_body's fields."""
+        with span("parse"):
+            body = await req.json()
+            return (body, *_parse_query_body(body))
+
     def _resolve_fn(fn):
         """Whitelisted rate-family post-functions.  Explicit whitelist:
         getattr dispatch would accept module attributes (fn="np") and
@@ -1359,8 +1366,8 @@ def build_app(state: ServerState) -> web.Application:
     @routes.post("/query")
     async def query(req: web.Request) -> web.Response:
         try:
-            body = await req.json()
-            metric, filters, rng, field, bucket_ms = _parse_query_body(body)
+            body, metric, filters, rng, field, bucket_ms = \
+                await _read_query(req)
             fn = body.get("fn")
         except (KeyError, TypeError, ValueError) as e:
             return web.json_response({"error": f"bad request: {e}"}, status=400)
@@ -1374,11 +1381,13 @@ def build_app(state: ServerState) -> web.Application:
             if bucket_ms:
                 out, meta = await _engine_downsample(metric, filters, rng,
                                                      bucket_ms, field)
-                body_out = _downsample_json(out)
-                if impl is not None and out["tsids"]:
-                    body_out["aggs"][fn] = _grid_json(
-                        impl(out["aggs"], bucket_ms))
-                return web.json_response(_attach_partial(body_out, meta))
+                with span("respond"):
+                    body_out = _downsample_json(out)
+                    if impl is not None and out["tsids"]:
+                        body_out["aggs"][fn] = _grid_json(
+                            impl(out["aggs"], bucket_ms))
+                    return web.json_response(
+                        _attach_partial(body_out, meta))
             tbl, meta = await _engine_query(metric, filters, rng, field)
             return web.json_response(_attach_partial({
                 "tsids": [str(t) for t in tbl.column("tsid").to_pylist()],
@@ -1394,8 +1403,8 @@ def build_app(state: ServerState) -> web.Application:
         {metric, filters?, start, end, bucket_ms, k, by?, largest?,
         field?} — results come back best-first."""
         try:
-            body = await req.json()
-            metric, filters, rng, field, bucket_ms = _parse_query_body(body)
+            body, metric, filters, rng, field, bucket_ms = \
+                await _read_query(req)
             if not bucket_ms:
                 raise ValueError("bucket_ms is required")
             k = int(body["k"])
@@ -1412,7 +1421,8 @@ def build_app(state: ServerState) -> web.Application:
                 largest=largest, field=field)
         except Error as e:
             return _error_response(e)
-        return web.json_response(_downsample_json(out))
+        with span("respond"):
+            return web.json_response(_downsample_json(out))
 
     @routes.post("/query_multi")
     async def query_multi(req: web.Request) -> web.Response:
@@ -1421,8 +1431,8 @@ def build_app(state: ServerState) -> web.Application:
         start, end, bucket_ms, fields: [..]}; response maps field ->
         the /query downsample shape."""
         try:
-            body = await req.json()
-            metric, filters, rng, field, bucket_ms = _parse_query_body(body)
+            body, metric, filters, rng, field, bucket_ms = \
+                await _read_query(req)
             if not bucket_ms:
                 raise ValueError("bucket_ms is required")
             fields = body["fields"]
@@ -1438,8 +1448,9 @@ def build_app(state: ServerState) -> web.Application:
                 metric, filters, rng, bucket_ms, fields=fields)
         except Error as e:
             return _error_response(e)
-        return web.json_response({f: _downsample_json(out)
-                                  for f, out in outs.items()})
+        with span("respond"):
+            return web.json_response({f: _downsample_json(out)
+                                      for f, out in outs.items()})
 
     @routes.post("/query_arrow")
     async def query_arrow(req: web.Request) -> web.Response:
@@ -1456,8 +1467,8 @@ def build_app(state: ServerState) -> web.Application:
                                             serialize_stream)
 
         try:
-            body = await req.json()
-            metric, filters, rng, field, bucket_ms = _parse_query_body(body)
+            body, metric, filters, rng, field, bucket_ms = \
+                await _read_query(req)
             fn = body.get("fn")
             # compressed IPC buffers are OPT-IN ("compression": "zstd"):
             # time-series columns compress well across DCN, but not
@@ -1641,9 +1652,18 @@ def _grid_json(grid) -> list:
 def _downsample_json(out: dict) -> dict:
     """THE wire shape of a downsample result, shared by /query,
     /query_topk and /query_multi so the endpoints cannot drift."""
+    aggs = out["aggs"]
+    # the fused route hands back device grids: their lazy download is
+    # here, the sync split from the copy (a scan.d2h span under
+    # `respond`, seconds in device_transfer_seconds_total)
+    on_device = {k: v for k, v in aggs.items()
+                 if not isinstance(v, np.ndarray)}
+    if on_device:
+        aggs = {**aggs, **deviceprof.download(on_device, fn="respond",
+                                              table="data")}
     return {"tsids": [str(t) for t in out["tsids"]],
             "num_buckets": out["num_buckets"],
-            "aggs": {k: _grid_json(v) for k, v in out["aggs"].items()}}
+            "aggs": {k: _grid_json(v) for k, v in aggs.items()}}
 
 
 def _build_store(config: ServerConfig):
@@ -1692,7 +1712,12 @@ async def run_server(config: ServerConfig,
         state.start_generators()
 
     app = build_app(state)
-    runner = web.AppRunner(app)
+    # the access line per request follows its logger's level: silence
+    # `aiohttp.access` below INFO and none is formatted at all
+    access = logging.getLogger("aiohttp.access")
+    runner = web.AppRunner(
+        app, access_log=access if access.isEnabledFor(logging.INFO)
+        else None)
     await runner.setup()
     site = web.TCPSite(runner, "127.0.0.1", config.port)
     await site.start()

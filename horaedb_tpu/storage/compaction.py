@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Optional
 
@@ -263,6 +264,7 @@ class Executor:
                 await self._do_compaction_traced(task)
 
     async def _do_compaction_traced(self, task: Task) -> None:
+        t_start = time.perf_counter()
         self._trigger_more()
         storage = self.storage
         time_range = task.inputs[0].meta.time_range
@@ -373,6 +375,15 @@ class Executor:
 
         _COMPACTIONS.inc()
         _COMPACTION_ROWS.inc(num_rows)
+        # one line per finished compaction: what a stall line or a
+        # latency step in a served window is matched against
+        logger.info(
+            "compaction done: table=%s segment=%d ssts_in=%d "
+            "expired=%d ssts_out=1 rows=%d bytes_in=%d bytes_out=%d "
+            "seconds=%.3f", storage.reader.table,
+            segment_of(task.inputs[0], storage.segment_duration_ms),
+            len(task.inputs), len(task.expireds), num_rows,
+            task.input_size, size, time.perf_counter() - t_start)
 
         # From here on, errors must not propagate (manifest already updated).
         await self._delete_objects(to_deletes)

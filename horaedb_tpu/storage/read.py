@@ -25,6 +25,7 @@ tests, the analogue of the reference's DisplayableExecutionPlan test
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import functools
 import logging
 import threading
@@ -58,7 +59,8 @@ from horaedb_tpu.storage.types import (
 )
 from horaedb_tpu.ops import device_decode
 from horaedb_tpu.storage import combine as combine_mod, parquet_io, sidecar
-from horaedb_tpu.utils import active_trace, registry, trace_add
+from horaedb_tpu.utils import active_trace, phase, registry, trace_add
+from horaedb_tpu.utils.tracing import clear_phases
 
 logger = logging.getLogger(__name__)
 
@@ -196,17 +198,21 @@ def _stack_counters(key: tuple):
     return _STACK_HITS, _STACK_MISSES
 
 
-def _timed_stage(stage: str):
+def _timed_stage(stage: str, phase_name: Optional[str] = None):
     """Decorator: attribute a function's wall time to a plan stage —
     both the cumulative registry histogram and (when a request trace is
     ambient; runtimes.run copies the context onto pool threads) the
-    per-query trace profile."""
+    per-query trace profile.  `phase_name` also makes the call a phase
+    span of the reader's table (reader methods only)."""
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
+            span_ = (args[0]._phase(phase_name) if phase_name
+                     else contextlib.nullcontext())
             t0 = time.perf_counter()
             try:
-                return fn(*args, **kwargs)
+                with span_:
+                    return fn(*args, **kwargs)
             finally:
                 dt = time.perf_counter() - t0
                 _STAGE_SECONDS[stage].observe(dt)
@@ -415,6 +421,10 @@ class ScanPlan:
     # backend), so mesh rounds and their per-round fallbacks share one
     # rounding schedule and grids stay byte-identical within a query
     force_xla_agg: bool = False
+    # the route ParquetReader.aggregate_route chose for an aggregate
+    # over this plan, set where the plan is built (plan_query): the
+    # `route` field of the query's scan.plan span
+    route: str = ""
 
 
 class ParquetReader:
@@ -430,6 +440,11 @@ class ParquetReader:
         self.config = config
         self.segment_duration_ms = segment_duration_ms
         self.runtimes = runtimes
+        # the table's name (the engine roots each table at
+        # <root>/<name>): the `table` field and label of the scan's
+        # phase spans, so that `resolve`'s scans of the index and tags
+        # tables never count into the data table's numbers
+        self.table = root_path.rstrip("/").rsplit("/", 1)[-1]
         # optional async callback (segment_start) -> current SstFiles:
         # set by CloudObjectStorage so a STREAMED segment can survive a
         # compaction race mid-segment (see _stream_window_batches) —
@@ -633,6 +648,19 @@ class ParquetReader:
         # them zeroed/absent (last-writer semantics)
         deviceprof.profiler.clear()
         memledger.reset_device_high_water()
+        clear_phases(self.table)
+
+    def _phase(self, name: str, **fields):
+        """A phase span of this reader's table (utils/tracing.phase)."""
+        return phase(name, self.table, **fields)
+
+    def _phased(self, name: str, fn, **fields):
+        """`fn` run inside a phase span: for the closures a scan hands
+        to a pool."""
+        def run(*args):
+            with self._phase(name, **fields):
+                return fn(*args)
+        return run
 
     def _scan_cache_resident_bytes(self) -> int:
         """Actual bytes the tier-1 cache holds: column buffers at
@@ -835,13 +863,16 @@ class ParquetReader:
 
         cached: dict[int, list] = {}
         to_read: list[SegmentPlan] = []
-        for seg in plan.segments:
-            windows = (self.scan_cache.get(self._cache_key(seg, plan))
-                       if plan.use_cache else None)
-            if windows is None:
-                to_read.append(seg)
-            else:
-                cached[id(seg)] = windows
+        with self._phase("scan.windows",
+                         segments=len(plan.segments)) as probe:
+            for seg in plan.segments:
+                windows = (self.scan_cache.get(self._cache_key(seg, plan))
+                           if plan.use_cache else None)
+                if windows is None:
+                    to_read.append(seg)
+                else:
+                    cached[id(seg)] = windows
+            probe.fields["cached"] = len(cached)
         if self.mesh is not None:
             mesh_iter = self._cached_windows_mesh(plan, cached, to_read)
             try:
@@ -1052,18 +1083,21 @@ class ParquetReader:
         spec = plan.decode_spec
         leaves = (es.pending_leaves if es.pending_leaves is not None
                   else [])
-        got = device_decode.plan_dispatch(
-            es, spec, pk_names=self._pk_names_in(list(es.names)),
-            seq_name=SEQ_COLUMN_NAME, leaves=leaves,
-            max_bytes=self.config.scan.decode.max_upload_bytes,
-            width=self._window_grid_width(spec),
-            pad_capacity=encode.pad_capacity)
+        with self._phase("scan.group_prep", rows=es.n):
+            got = device_decode.plan_dispatch(
+                es, spec, pk_names=self._pk_names_in(list(es.names)),
+                seq_name=SEQ_COLUMN_NAME, leaves=leaves,
+                max_bytes=self.config.scan.decode.max_upload_bytes,
+                width=self._window_grid_width(spec),
+                pad_capacity=encode.pad_capacity)
         if isinstance(got, str):
             device_decode.note_fallback(got)
             return None
         if isinstance(got, device_decode.DecodePlan) \
                 and not plan.decode_defer:
-            got = device_decode.execute_plan(got)
+            with self._phase("scan.dispatch", h2d_bytes=got.cap * 4
+                             * len(got.upload_names)):
+                got = device_decode.execute_plan(got, self.table)
         return [got]
 
     def _decode_segment_windows(self, table, plan: ScanPlan) -> list:
@@ -1318,14 +1352,15 @@ class ParquetReader:
         t0 = time.perf_counter()
         table = None
         stage = "sidecar_read"
-        if self._sidecar_plan_ok(plan):
-            table = await self._read_segment_encoded(seg, plan,
-                                                     runner=runner)
-        if table is None:
-            stage = "parquet_read"
-            table = await self._read_segment_table(
-                seg, plan.pushdown, pool=plan.pool,
-                leaves=plan.prune_leaves)
+        with self._phase("scan.windows", segment=seg.segment_start):
+            if self._sidecar_plan_ok(plan):
+                table = await self._read_segment_encoded(seg, plan,
+                                                         runner=runner)
+            if table is None:
+                stage = "parquet_read"
+                table = await self._read_segment_table(
+                    seg, plan.pushdown, pool=plan.pool,
+                    leaves=plan.prune_leaves)
         read_s = time.perf_counter() - t0
         _STAGE_SECONDS[stage].observe(read_s)
         _STAGE_ROWS[stage].inc(table.num_rows)
@@ -1392,9 +1427,10 @@ class ParquetReader:
         t0 = time.perf_counter()
         defer = plan.decode_spec is not None
         try:
-            es = sidecar.assemble_parts(
-                parts, list(seg.columns),
-                None if defer else plan.prune_leaves)
+            with self._phase("scan.windows", segment=seg.segment_start):
+                es = sidecar.assemble_parts(
+                    parts, list(seg.columns),
+                    None if defer else plan.prune_leaves)
         except Exception as exc:  # noqa: BLE001 — cache read only
             logger.warning("sidecar assembly raised for segment %s: %s",
                            seg.segment_start, exc)
@@ -1875,7 +1911,7 @@ class ParquetReader:
                 yielded_any = True
                 yield tbl.combine_chunks().to_batches()[0]
 
-    @_timed_stage("encode_merge")
+    @_timed_stage("encode_merge", "scan.windows")
     def _prepare_merge_windows(self, batch: pa.RecordBatch,
                                host_perm: Optional[bool] = None) -> list:
         """Host half of the merge: encode + PK-window planning + padding,
@@ -1892,7 +1928,7 @@ class ParquetReader:
         return self._prepare_windows_dev(dev, list(batch.schema.names),
                                          host_perm)
 
-    @_timed_stage("encode_merge")
+    @_timed_stage("encode_merge", "scan.windows")
     def _prepare_encoded_windows(self, es: "sidecar.EncodedSegment",
                                  host_perm: Optional[bool] = None) -> list:
         """Sidecar twin of _prepare_merge_windows (mesh window prep)."""
@@ -1950,7 +1986,7 @@ class ParquetReader:
             descs.append((padded, n_win, cap, dev.encodings))
         return descs
 
-    @_timed_stage("encode_merge")
+    @_timed_stage("encode_merge", "scan.windows")
     def _dispatch_merged_windows(self, batch: pa.RecordBatch) -> list:
         """Merge one segment with bounded memory: segments above
         scan.max_window_rows are split into PK-code-range windows, each a
@@ -1984,7 +2020,7 @@ class ParquetReader:
         return encode.DeviceBatch(columns=columns, encodings=es.encodings,
                                   n_valid=es.n, capacity=cap)
 
-    @_timed_stage("encode_merge")
+    @_timed_stage("encode_merge", "scan.windows")
     def _dispatch_encoded_windows(self, es: "sidecar.EncodedSegment"
                                   ) -> list:
         """Sidecar twin of _dispatch_merged_windows."""
@@ -2161,6 +2197,27 @@ class ParquetReader:
                 and plan.range is not None
                 and self.scan_router.covers_any(plan.segments))
 
+    def aggregate_route(self, plan: ScanPlan, spec: AggregateSpec) -> str:
+        """The route an aggregate over `plan` takes, from the gates
+        that decide it, none of them counting a fallback (the scan
+        counts where it runs): `replay` and `fused_acc` (the fused
+        device accumulator, re-run from a recorded plan or built),
+        `mesh`, `device_decode`, `parts`.  The `route` field of the
+        query's `scan.plan` span (CloudObjectStorage.plan_query sets it
+        on the plan); scan_aggregate takes the fused path on the first
+        two."""
+        if self.fused_aggregate_ok(plan) and not self.router_covers(plan):
+            if (plan.use_cache and self.mesh is None and self._replay_cache
+                    and self._replay_key(plan, spec) in self._replay_cache):
+                return "replay"
+            return "fused_acc"
+        if self._mesh_plan_ok(plan, count=False):
+            return "mesh"
+        if (plan.mode is UpdateMode.OVERWRITE
+                and self._device_decode_plan_ok(plan, count=False)):
+            return "device_decode"
+        return "parts"
+
     def fused_aggregate_ok(self, plan: Optional[ScanPlan] = None) -> bool:
         """Whether the fused device-accumulated aggregate serves this
         scan (see _fused_agg_ok_base for the structural gates).  An
@@ -2314,11 +2371,11 @@ class ParquetReader:
                 # segment validation touches the (lock-free, event-loop-
                 # owned) scan cache HERE; only the device rounds go to
                 # the pool
-                grids = None
+                fused = None
                 if self._replay_segments_valid(entry):
-                    grids = await self._run_pool(
+                    fused = await self._run_pool(
                         plan.pool, self._fused_replay, entry, spec)
-                if grids is not None:
+                if fused is not None:
                     self._replay_cache.move_to_end(replay_key)
                     self._replay_hits += 1
                     _REPLAY_HITS.inc()
@@ -2332,9 +2389,7 @@ class ParquetReader:
                     if fresh:
                         _REPLAY_ROWS.inc(sum(r for _, r in fresh))
                         counted.update(s for s, _ in fresh)
-                    values, grids = self._drop_empty_groups_dev(
-                        entry["values"], grids)
-                    return values, self._fused_last_ts_to_abs(grids, spec)
+                    return self._fused_result(entry["values"], fused, spec)
                 self._replay_cache.pop(replay_key, None)
             self._replay_misses += 1
             _REPLAY_MISSES.inc()
@@ -2359,7 +2414,9 @@ class ParquetReader:
                             out.append((s, w, pr))
                     return out
 
-                items.extend(await self._run_pool(plan.pool, prep))
+                items.extend(await self._run_pool(
+                    plan.pool,
+                    self._phased("scan.group_prep", prep, segment=s)))
                 if replay_key is not None:
                     seg_records.append((self._cache_key(seg, plan), tuple(
                         weakref.ref(w) for w in windows)))
@@ -2399,9 +2456,10 @@ class ParquetReader:
                 stack_key = self._round_stack_key(
                     chunk[0][0], spec, plan, batch_w, cap, g_pad, width,
                     space_fp) + (i,)
-                arrays = self._build_round_stacks(
-                    chunk, spec, plan, batch_w, cap, g_pad, width,
-                    all_values, local_ok, stack_key=stack_key)
+                with self._phase("scan.group_prep", round=i):
+                    arrays = self._build_round_stacks(
+                        chunk, spec, plan, batch_w, cap, g_pad, width,
+                        all_values, local_ok, stack_key=stack_key)
                 if replay_key is not None:
                     windows = tuple(it[1] for it in chunk)
                     recorded_rounds.append((
@@ -2418,7 +2476,7 @@ class ParquetReader:
             _STAGE_SECONDS["device_aggregate"].observe(t_dev)
             return out
 
-        grids = await self._run_pool(plan.pool, run_rounds)
+        fused = await self._run_pool(plan.pool, run_rounds)
         if replay_key is not None:
             self._replay_cache[replay_key] = {
                 "segments": seg_records,
@@ -2430,8 +2488,7 @@ class ParquetReader:
             self._replay_cache.move_to_end(replay_key)
             while len(self._replay_cache) > _REPLAY_SLOTS:
                 self._replay_cache.popitem(last=False)
-        all_values, grids = self._drop_empty_groups_dev(all_values, grids)
-        return all_values, self._fused_last_ts_to_abs(grids, spec)
+        return self._fused_result(all_values, fused, spec)
 
     def _replay_key(self, plan: ScanPlan, spec: AggregateSpec) -> tuple:
         """Identity of a fused aggregate over a specific plan: the
@@ -2461,7 +2518,8 @@ class ParquetReader:
         dispatch: check every round's stacks are still in the
         (thread-safe) stack LRU — BEFORE any device work — then run the
         accumulate rounds straight from the cached device arrays.
-        Returns device grids, or None to fall back to the full path."""
+        Returns (device grids, any-data mask), or None to fall back to
+        the full path."""
         rounds = []
         for stack_key, col_key, refs in entry["rounds"]:
             ws = tuple(r() for r in refs)
@@ -2483,57 +2541,74 @@ class ParquetReader:
         path and the replay: acc init -> one accumulate per round ->
         finalize -> slice to g -> sync.  `rounds` is any iterable of
         stack tuples (a lazy generator on the full path, so stack
-        building overlaps device execution).  Returns (grids, device
-        seconds) — device time excludes the caller's stack building,
-        which self-reports under stack_build."""
+        building overlaps device execution).  Returns ((grids,
+        per-group any-data mask), device seconds) — device time
+        excludes the caller's stack building, which self-reports under
+        stack_build."""
         total = self._dev_scalar(spec.num_buckets)
         bucket_ms = self._dev_scalar(spec.bucket_ms)
         t_dev = 0.0
         t0 = time.perf_counter()
-        acc = _fused_acc_init_jit(num_groups=g_pad,
-                                  num_buckets=spec.num_buckets,
-                                  which=spec.which)
+        # one scan.dispatch span per enqueue, not one around the loop:
+        # `rounds` is lazy, and its stack building is scan.group_prep
+        with self._phase("scan.dispatch", fn="_fused_acc_init_jit"):
+            acc = _fused_acc_init_jit(num_groups=g_pad,
+                                      num_buckets=spec.num_buckets,
+                                      which=spec.which)
         t_dev += time.perf_counter() - t0
         for ts_s, gid_s, val_s, remap_d, shift_d, lo_dev, _lo in rounds:
             t0 = time.perf_counter()
-            acc = _fused_round_accumulate_jit(
-                acc, ts_s, gid_s, val_s, remap_d, shift_d, lo_dev,
-                total, bucket_ms, num_groups=g_pad, width=width,
-                which=spec.which)
+            with self._phase("scan.dispatch",
+                             fn="_fused_round_accumulate_jit"):
+                acc = _fused_round_accumulate_jit(
+                    acc, ts_s, gid_s, val_s, remap_d, shift_d, lo_dev,
+                    total, bucket_ms, num_groups=g_pad, width=width,
+                    which=spec.which)
             t_dev += time.perf_counter() - t0
         t0 = time.perf_counter()
-        final = _fused_finalize_jit(acc, spec.which)
-        out = {k: v[:g] for k, v in final.items()}
-        deviceprof.block_until_ready(out, fn="fused_rounds")
+        with self._phase("scan.dispatch", fn="_fused_finalize_jit"):
+            final = _fused_finalize_jit(acc, spec.which)
+            out = {k: v[:g] for k, v in final.items()}
+            # the per-group any-data mask rides the same enqueue and the
+            # same sync: _fused_result only copies it
+            has_data = _group_has_data_jit(out["count"])
+        deviceprof.block_until_ready((out, has_data), fn="fused_rounds",
+                                     table=self.table)
         t_dev += time.perf_counter() - t0
-        return out, t_dev
+        return (out, has_data), t_dev
 
-    @staticmethod
-    def _drop_empty_groups_dev(values: np.ndarray, grids: dict):
-        """Fused-path twin of finalize_aggregate's empty-group drop (the
-        aligned fast path can register groups whose rows all fall outside
-        the range — see that docstring).  Device-friendly: only a G-byte
-        any-mask crosses to host; the grids move only in the rare case a
-        leak actually exists, so cached/replay queries stay at zero grid
-        downloads."""
+    def _fused_result(self, values: np.ndarray, fused: tuple,
+                      spec: AggregateSpec):
+        """What the fused path does on the host before the response, in
+        one copy: `fused` is _fused_run_device_rounds' (grids, per-group
+        any-data mask), synced already.
+
+        The empty-group drop is the twin of finalize_aggregate's (the
+        aligned fast path can register groups whose rows all fall
+        outside the range — see that docstring): only the G-byte mask
+        crosses to host, and the grids move only in the rare case a
+        leak exists, so cached/replay queries stay at zero grid
+        downloads.  `last` queries also bring count/last_ts back:
+        absolute float ms needs int64 range, a host conversion."""
+        grids, has_dev = fused
         if not len(values):
             return values, grids
-        has = np.asarray(_group_has_data_jit(grids["count"]))
-        if has.all():
-            return values, grids
-        idx = np.flatnonzero(has)
-        return values[idx], {k: jnp.take(v, idx, axis=0)
-                             for k, v in grids.items()}
-
-    @staticmethod
-    def _fused_last_ts_to_abs(grids: dict, spec: AggregateSpec) -> dict:
+        want = {"has": has_dev}
         if "last_ts" in grids:
-            # absolute float ms needs int64 range: host conversion
-            count_h = np.asarray(grids["count"])
-            lt = np.asarray(grids["last_ts"]).astype(np.float64)
-            grids["last_ts"] = np.where(count_h > 0,
-                                        lt + spec.range_start, np.nan)
-        return grids
+            want.update(count=grids["count"], last_ts=grids["last_ts"])
+        host = deviceprof.download(want, fn="fused_rounds",
+                                   table=self.table)
+        if not host["has"].all():
+            idx = np.flatnonzero(host["has"])
+            values = values[idx]
+            grids = {k: jnp.take(v, idx, axis=0) for k, v in grids.items()}
+            host = {k: v[idx] for k, v in host.items()}
+        if "last_ts" in grids:
+            grids["last_ts"] = np.where(
+                host["count"] > 0,
+                host["last_ts"].astype(np.float64) + spec.range_start,
+                np.nan)
+        return values, grids
 
     async def aggregate_segments(self, plan: ScanPlan, spec: AggregateSpec,
                                  top_k=None):
@@ -2802,8 +2877,10 @@ class ParquetReader:
                                 out.append((w, prep))
                         return out
 
-                    for w, prep in await self._run_pool(plan.pool,
-                                                        prep_windows):
+                    for w, prep in await self._run_pool(
+                            plan.pool, self._phased(
+                                "scan.group_prep", prep_windows,
+                                segment=s)):
                         queue.append((s, w, prep))
                         pending[s] += 1
                     while len(queue) >= batch_w:
@@ -2835,11 +2912,12 @@ class ParquetReader:
 
     # ---- the 2-D scan mesh ([scan.mesh]; docs/parallel.md) -----------------
 
-    def _mesh_plan_ok(self, plan: ScanPlan) -> bool:
+    def _mesh_plan_ok(self, plan: ScanPlan, count: bool = True) -> bool:
         """Plan-level [scan.mesh] routing gate; per-round gates (sum
         overlap, count bound, grid budget) live in _run_mesh_round and
         fall back per round.  Counted reasons mirror the device-decode
-        discipline (scan_mesh_fallback_total{reason=})."""
+        discipline (scan_mesh_fallback_total{reason=}) unless `count`
+        is False (aggregate_route probes without recording)."""
         if self.scan_mesh is None:
             return False
         if plan.mode is not UpdateMode.OVERWRITE:
@@ -2847,7 +2925,8 @@ class ParquetReader:
         if merge_ops.merge_impl() != "host_perm":
             # device_sort windows live sharded on the legacy segment
             # mesh; the 2-D scan consumes host-merged windows
-            note_mesh_fallback("merge_impl")
+            if count:
+                note_mesh_fallback("merge_impl")
             return False
         return True
 
@@ -3050,19 +3129,21 @@ class ParquetReader:
         entries: list = []
         cells = 0
         dl_bytes = 0
-        t_dl = time.perf_counter()
-        for s, a, b in runs:
-            lo_run, grids = self._slice_mesh_part(out, b, g, int(lo[b]),
-                                                  width, spec)
-            cells += sum(int(v.shape[0] * v.shape[1])
-                         for v in grids.values())
-            dl_bytes += sum(int(v.nbytes) for v in grids.values())
-            entries.append((s, (group_space, lo_run, grids), b - a + 1))
-        # the tail-grid downloads above synced the dispatch — exec and
-        # d2h attribution for the round lands here
-        deviceprof.observe_exec("mesh_run_partials",
-                                time.perf_counter() - t_dl)
-        deviceprof.charge_transfer("d2h", dl_bytes)
+        # the sync, then the tail-grid copies, each its own phase
+        deviceprof.block_until_ready(out, fn="mesh_run_partials",
+                                     table=self.table)
+        with self._phase("scan.d2h", fn="mesh_run_partials"):
+            t_dl = time.perf_counter()
+            for s, a, b in runs:
+                lo_run, grids = self._slice_mesh_part(
+                    out, b, g, int(lo[b]), width, spec)
+                cells += sum(int(v.shape[0] * v.shape[1])
+                             for v in grids.values())
+                dl_bytes += sum(int(v.nbytes) for v in grids.values())
+                entries.append((s, (group_space, lo_run, grids),
+                                b - a + 1))
+            t_dl = time.perf_counter() - t_dl
+        deviceprof.charge_transfer("d2h", dl_bytes, seconds=t_dl)
         _STAGE_SECONDS["mesh_aggregate"].observe(time.perf_counter() - t0)
         _MESH_PARTS.inc(len(entries))
         _MESH_PART_CELLS.inc(cells)
@@ -3180,7 +3261,9 @@ class ParquetReader:
                         "mesh decode round failed (%s); running the "
                         "per-segment fused dispatch", exc)
                 for s, dp in chunk:
-                    part = device_decode.execute_plan(dp).finalize()
+                    with self._phase("scan.dispatch"):
+                        disp = device_decode.execute_plan(dp, self.table)
+                    part = disp.finalize()
                     entries.append((s, part.part, 1))
         return entries
 
@@ -3333,29 +3416,31 @@ class ParquetReader:
         src_rows = 0
         dl_bytes = 0
         a = 0
-        t_dl = time.perf_counter()
-        for i in range(len(chunk)):
-            if i + 1 < len(chunk) and seg_ids[i + 1] == seg_ids[i]:
-                continue
-            s, dp = chunk[i]
-            grids = {k: np.ascontiguousarray(
-                np.asarray(v[i])[:dp.g, :dp.w_eff])
-                for k, v in out.items()}
-            if "last_ts" in grids:
-                lt = grids["last_ts"].astype(np.int64)
-                grids["last_ts"] = np.where(
-                    grids["count"] > 0,
-                    lt + dp.lo * spec.bucket_ms, lt)
-            cells += sum(int(v.shape[0] * v.shape[1])
-                         for v in grids.values())
-            dl_bytes += sum(int(v.nbytes) for v in grids.values())
-            src_rows += sum(dp2.es.n for _s2, dp2 in chunk[a:i + 1])
-            entries.append(
-                (s, (dp.values, dp.lo, grids), i - a + 1))
-            a = i + 1
-        deviceprof.observe_exec("mesh_decode_partials",
-                                time.perf_counter() - t_dl)
-        deviceprof.charge_transfer("d2h", dl_bytes)
+        deviceprof.block_until_ready(out, fn="mesh_decode_partials",
+                                     table=self.table)
+        with self._phase("scan.d2h", fn="mesh_decode_partials"):
+            t_dl = time.perf_counter()
+            for i in range(len(chunk)):
+                if i + 1 < len(chunk) and seg_ids[i + 1] == seg_ids[i]:
+                    continue
+                s, dp = chunk[i]
+                grids = {k: np.ascontiguousarray(
+                    np.asarray(v[i])[:dp.g, :dp.w_eff])
+                    for k, v in out.items()}
+                if "last_ts" in grids:
+                    lt = grids["last_ts"].astype(np.int64)
+                    grids["last_ts"] = np.where(
+                        grids["count"] > 0,
+                        lt + dp.lo * spec.bucket_ms, lt)
+                cells += sum(int(v.shape[0] * v.shape[1])
+                             for v in grids.values())
+                dl_bytes += sum(int(v.nbytes) for v in grids.values())
+                src_rows += sum(dp2.es.n for _s2, dp2 in chunk[a:i + 1])
+                entries.append(
+                    (s, (dp.values, dp.lo, grids), i - a + 1))
+                a = i + 1
+            t_dl = time.perf_counter() - t_dl
+        deviceprof.charge_transfer("d2h", dl_bytes, seconds=t_dl)
         _MESH_PARTS.inc(len(entries))
         _MESH_PART_CELLS.inc(cells)
         deviceprof.record_round(
@@ -3461,8 +3546,10 @@ class ParquetReader:
                                 out.append((w, prep))
                         return out
 
-                    for w, prep in await self._run_pool(plan.pool,
-                                                        prep_windows):
+                    for w, prep in await self._run_pool(
+                            plan.pool, self._phased(
+                                "scan.group_prep", prep_windows,
+                                segment=s)):
                         queue.append((s, w, prep))
                         pending[s] += 1
                     while len(queue) >= batch_w:
@@ -3533,8 +3620,9 @@ class ParquetReader:
                             out.append((s, w, prep))
                     return out
 
-                items.extend(await self._run_pool(plan.pool,
-                                                  prep_windows))
+                items.extend(await self._run_pool(
+                    plan.pool, self._phased("scan.group_prep",
+                                            prep_windows, segment=s)))
                 _SCAN_LATENCY.observe(read_s)
         finally:
             await windows_iter.aclose()
@@ -3754,6 +3842,12 @@ class ParquetReader:
 
     def finalize_aggregate(self, parts: list, spec: AggregateSpec,
                            top_k=None):
+        """Parts to grids: the `scan.combine` phase."""
+        with self._phase("scan.combine", parts=len(parts)):
+            return self._finalize_aggregate(parts, spec, top_k)
+
+    def _finalize_aggregate(self, parts: list, spec: AggregateSpec,
+                            top_k=None):
         """Combine per-window parts into the user-facing grids.
 
         Mode-dispatched through storage/combine.py ([scan.combine]):
@@ -4241,34 +4335,37 @@ class ParquetReader:
         width = self._window_grid_width(spec) if local_ok \
             else spec.num_buckets
 
-        ts_s, gid_s, val_s, remap_d, shift_d, lo_dev, lo = \
-            self._build_round_stacks(items, spec, plan, batch_w, cap,
-                                     g_pad, width, round_values, local_ok)
+        with self._phase("scan.group_prep", windows=len(items)):
+            ts_s, gid_s, val_s, remap_d, shift_d, lo_dev, lo = \
+                self._build_round_stacks(items, spec, plan, batch_w, cap,
+                                         g_pad, width, round_values,
+                                         local_ok)
         total = self._dev_scalar(spec.num_buckets)
         t_dev = time.perf_counter()
+        with self._phase("scan.dispatch", windows=len(items)):
+            if self.mesh is not None:
+                from horaedb_tpu.parallel.scan import sharded_remap_partials
 
-        if self.mesh is not None:
-            from horaedb_tpu.parallel.scan import sharded_remap_partials
-
-            # memoize the compiled program per grid shape — rebuilding
-            # the shard_map closure would recompile every round
-            fn_key = (g_pad, width, spec.which)
-            fn = self._mesh_agg_fns.get(fn_key)
-            if fn is None:
-                fn = sharded_remap_partials(self.mesh, num_groups=g_pad,
-                                            num_buckets=width,
-                                            which=spec.which)
-                self._mesh_agg_fns[fn_key] = fn
-            stacked = fn(ts_s, gid_s, val_s, remap_d, shift_d, lo_dev, total,
-                         self._dev_scalar(spec.bucket_ms, "arr1"))
-        else:
-            stacked = _batched_window_partials_jit(
-                ts_s, gid_s, val_s, remap_d, shift_d,
-                lo_dev, total, self._dev_scalar(spec.bucket_ms),
-                num_groups=g_pad, num_buckets=width, which=spec.which)
+                # memoize the compiled program per grid shape — rebuilding
+                # the shard_map closure would recompile every round
+                fn_key = (g_pad, width, spec.which)
+                fn = self._mesh_agg_fns.get(fn_key)
+                if fn is None:
+                    fn = sharded_remap_partials(self.mesh, num_groups=g_pad,
+                                                num_buckets=width,
+                                                which=spec.which)
+                    self._mesh_agg_fns[fn_key] = fn
+                stacked = fn(ts_s, gid_s, val_s, remap_d, shift_d, lo_dev, total,
+                             self._dev_scalar(spec.bucket_ms, "arr1"))
+            else:
+                stacked = _batched_window_partials_jit(
+                    ts_s, gid_s, val_s, remap_d, shift_d,
+                    lo_dev, total, self._dev_scalar(spec.bucket_ms),
+                    num_groups=g_pad, num_buckets=width, which=spec.which)
         # per-window partials fold on host in f64 (bit-equal to the
         # single-window path); padding windows are sliced away
-        host = {k: np.asarray(v) for k, v in stacked.items()}
+        host = deviceprof.download(stacked, fn="batched_window_partials",
+                                   table=self.table)
         _STAGE_SECONDS["device_aggregate"].observe(
             time.perf_counter() - t_dev)
         parts = []
@@ -4487,17 +4584,19 @@ def _fused_acc_init_jit(*, num_groups: int, num_buckets: int, which: tuple):
     want = set(which)
     if "avg" in want:
         want.add("sum")
-    acc = {"count": jnp.zeros(shape, jnp.float32)}
-    if "sum" in want:
-        acc["sum"] = jnp.zeros(shape, jnp.float32)
-    if "min" in want:
-        acc["min"] = jnp.full(shape, jnp.finfo(jnp.float32).max, jnp.float32)
-    if "max" in want:
-        acc["max"] = jnp.full(shape, -jnp.finfo(jnp.float32).max,
-                              jnp.float32)
-    if "last" in want:
-        acc["last"] = jnp.zeros(shape, jnp.float32)
-        acc["last_ts"] = jnp.full(shape, _ACC_TS_MIN, jnp.int32)
+    with jax.named_scope("acc_init"):
+        acc = {"count": jnp.zeros(shape, jnp.float32)}
+        if "sum" in want:
+            acc["sum"] = jnp.zeros(shape, jnp.float32)
+        if "min" in want:
+            acc["min"] = jnp.full(shape, jnp.finfo(jnp.float32).max,
+                                  jnp.float32)
+        if "max" in want:
+            acc["max"] = jnp.full(shape, -jnp.finfo(jnp.float32).max,
+                                  jnp.float32)
+        if "last" in want:
+            acc["last"] = jnp.zeros(shape, jnp.float32)
+            acc["last_ts"] = jnp.full(shape, _ACC_TS_MIN, jnp.int32)
     return acc
 
 
@@ -4534,29 +4633,38 @@ def _fused_round_accumulate_jit(acc, ts, gid, vals, remap, shift, lo, total,
     def body(d, acc):
         cols = lo[d] + w_iota
         out = dict(acc)
-        out["count"] = acc["count"].at[:, cols].add(p["count"][d],
-                                                    mode="drop")
+        with jax.named_scope("accumulate_count"):
+            out["count"] = acc["count"].at[:, cols].add(p["count"][d],
+                                                        mode="drop")
         if "sum" in acc:
-            out["sum"] = acc["sum"].at[:, cols].add(p["sum"][d], mode="drop")
+            with jax.named_scope("accumulate_sum"):
+                out["sum"] = acc["sum"].at[:, cols].add(p["sum"][d],
+                                                        mode="drop")
         if "min" in acc:
-            out["min"] = acc["min"].at[:, cols].min(p["min"][d], mode="drop")
+            with jax.named_scope("accumulate_min"):
+                out["min"] = acc["min"].at[:, cols].min(p["min"][d],
+                                                        mode="drop")
         if "max" in acc:
-            out["max"] = acc["max"].at[:, cols].max(p["max"][d], mode="drop")
+            with jax.named_scope("accumulate_max"):
+                out["max"] = acc["max"].at[:, cols].max(p["max"][d],
+                                                        mode="drop")
         if "last" in acc:
-            # fill_value must be a hashable Python scalar (jaxpr param)
-            cur_ts = acc["last_ts"].at[:, cols].get(mode="fill",
-                                                    fill_value=-(2**31))
-            cur_last = acc["last"].at[:, cols].get(mode="fill",
-                                                   fill_value=0.0)
-            win_has = p["count"][d] > 0
-            win_ts = jnp.where(win_has,
-                               p["last_ts"][d] + lo[d] * bucket_ms,
-                               _ACC_TS_MIN)
-            take = win_has & (win_ts >= cur_ts)
-            out["last"] = acc["last"].at[:, cols].set(
-                jnp.where(take, p["last"][d], cur_last), mode="drop")
-            out["last_ts"] = acc["last_ts"].at[:, cols].set(
-                jnp.where(take, win_ts, cur_ts), mode="drop")
+            with jax.named_scope("accumulate_last"):
+                # fill_value must be a hashable Python scalar (jaxpr
+                # param)
+                cur_ts = acc["last_ts"].at[:, cols].get(
+                    mode="fill", fill_value=-(2**31))
+                cur_last = acc["last"].at[:, cols].get(mode="fill",
+                                                       fill_value=0.0)
+                win_has = p["count"][d] > 0
+                win_ts = jnp.where(win_has,
+                                   p["last_ts"][d] + lo[d] * bucket_ms,
+                                   _ACC_TS_MIN)
+                take = win_has & (win_ts >= cur_ts)
+                out["last"] = acc["last"].at[:, cols].set(
+                    jnp.where(take, p["last"][d], cur_last), mode="drop")
+                out["last_ts"] = acc["last_ts"].at[:, cols].set(
+                    jnp.where(take, win_ts, cur_ts), mode="drop")
         return out
 
     return jax.lax.fori_loop(0, ts.shape[0], body, acc)
@@ -4569,22 +4677,25 @@ def _fused_finalize_jit(acc: dict, which: tuple) -> dict:
     NaN.  last_ts stays int32 (range-relative) — the absolute float
     conversion needs int64 range and happens on host."""
     count = acc["count"]
-    empty = count == 0
-    nan = jnp.float32(jnp.nan)
     requested = set(which) | {"count"}
     out = {"count": count}
-    if "sum" in acc and "sum" in requested:
-        out["sum"] = acc["sum"]
-    if "sum" in acc and "avg" in requested:
-        out["avg"] = jnp.where(empty, nan,
-                               acc["sum"] / jnp.maximum(count, 1.0))
-    if "min" in acc and "min" in requested:
-        out["min"] = jnp.where(empty, jnp.float32(jnp.inf), acc["min"])
-    if "max" in acc and "max" in requested:
-        out["max"] = jnp.where(empty, -jnp.float32(jnp.inf), acc["max"])
-    if "last" in acc and "last" in requested:
-        out["last"] = jnp.where(empty, nan, acc["last"])
-        out["last_ts"] = acc["last_ts"]
+    with jax.named_scope("finalize"):
+        empty = count == 0
+        nan = jnp.float32(jnp.nan)
+        if "sum" in acc and "sum" in requested:
+            out["sum"] = acc["sum"]
+        if "sum" in acc and "avg" in requested:
+            out["avg"] = jnp.where(empty, nan,
+                                   acc["sum"] / jnp.maximum(count, 1.0))
+        if "min" in acc and "min" in requested:
+            out["min"] = jnp.where(empty, jnp.float32(jnp.inf),
+                                   acc["min"])
+        if "max" in acc and "max" in requested:
+            out["max"] = jnp.where(empty, -jnp.float32(jnp.inf),
+                                   acc["max"])
+        if "last" in acc and "last" in requested:
+            out["last"] = jnp.where(empty, nan, acc["last"])
+            out["last_ts"] = acc["last_ts"]
     return out
 
 
@@ -4592,7 +4703,8 @@ def _fused_finalize_jit(acc: dict, which: tuple) -> dict:
 def _group_has_data_jit(count):
     """Per-group any-data mask — G bools, the only bytes the aligned
     fast path's empty-group check ever downloads."""
-    return (count > 0).any(axis=1)
+    with jax.named_scope("group_has_data"):
+        return (count > 0).any(axis=1)
 
 
 @deviceprof.jit(static_argnames=("num_groups", "num_buckets", "which"))

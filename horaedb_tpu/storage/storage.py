@@ -410,14 +410,16 @@ class CloudObjectStorage(TimeMergeStorage):
         groups x buckets grid is never built.  The fused path's grids
         already live on device, so it keeps the host-side slice."""
         if first_plan is None:
-            first_plan = await self.build_scan_plan(req)
+            first_plan = await self._plan_aggregate(req, spec)
+        # a caller-built plan that plan_query did not route is routed here
+        route = (first_plan.route
+                 or self.reader.aggregate_route(first_plan, spec))
         # per-trace memory attribution (common/memledger.py): a cold
         # aggregate moves megabytes into the cache tiers — the trace
         # records which account they landed in
         mem_marks = self.reader._mem_delta_marks()
         try:
-            if (self.reader.fused_aggregate_ok(first_plan)
-                    and not self.reader.router_covers(first_plan)):
+            if route in ("replay", "fused_acc"):
                 from horaedb_tpu.storage.plan import apply_top_k
 
                 counted: set = set()  # ops metrics survive restarts
@@ -464,6 +466,24 @@ class CloudObjectStorage(TimeMergeStorage):
 
     async def build_scan_plan(self, req: ScanRequest,
                               keep_builtin: bool = False) -> ScanPlan:
+        """Manifest lookup + plan build: a `scan.plan` phase span."""
+        with self.reader._phase("scan.plan") as planned:
+            plan = await self._build_scan_plan(req, keep_builtin)
+            planned.fields["segments"] = len(plan.segments)
+        return plan
+
+    async def _plan_aggregate(self, req: ScanRequest, spec) -> ScanPlan:
+        """An aggregate's scan.plan phase, one span: manifest lookup,
+        plan build, and the choice of route (kept on the plan)."""
+        with self.reader._phase("scan.plan") as planned:
+            plan = await self._build_scan_plan(req)
+            plan.route = self.reader.aggregate_route(plan, spec)
+            planned.fields.update(route=plan.route,
+                                  segments=len(plan.segments))
+        return plan
+
+    async def _build_scan_plan(self, req: ScanRequest,
+                               keep_builtin: bool = False) -> ScanPlan:
         ensure(self.manifest is not None, "storage not opened")
         ssts = await self.manifest.find_ssts(req.range)
         return self.reader.build_plan(ssts, req, keep_builtin=keep_builtin)
@@ -475,7 +495,8 @@ class CloudObjectStorage(TimeMergeStorage):
 
         ensure(spec is not None or top_k is None,
                "top-k requires an aggregate stage")
-        scan = await self.build_scan_plan(req)
+        scan = (await self.build_scan_plan(req) if spec is None
+                else await self._plan_aggregate(req, spec))
         return QueryPlan(scan=scan, request=req, aggregate=spec,
                          top_k=top_k)
 

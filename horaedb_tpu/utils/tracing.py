@@ -8,10 +8,16 @@ request-scoped (docs/observability.md):
 - every query/write through the HTTP server gets a `trace_id`
   (returned as the `X-Trace-Id` response header);
 - `span(name, **fields)` records a real span (span_id/parent_id/
-  status/fields) into the ambient trace when one is active — and keeps
-  its original behavior (enter/exit logs + a latency histogram) either
-  way, so background loops (compaction, manifest merge) stay observable
-  without a trace;
+  status/fields) into the ambient trace when one is active — and
+  observes its latency histogram either way, so background loops
+  (compaction, manifest merge) stay observable without a trace; it
+  also enters a `jax.profiler.TraceAnnotation` named `horaedb/<name>`,
+  so while a profiler session runs the program's spans lie in the
+  xplane on the same clock as the device's operations;
+- `phase(name, table, **fields)` is a span of one scan phase (the
+  children of `downsample`): its histogram is the labelled family
+  `scan_phase_seconds{phase=,table=}`, so the data table's scans are
+  told apart from `resolve`'s scans of the index tables;
 - `trace_add(name, n)` attributes counted work (object-store GETs and
   bytes, cache tier hits, per-stage wall time) to the active trace;
 - the trace context propagates across regions via the `X-Trace-Id`
@@ -27,14 +33,13 @@ Context propagates through asyncio tasks natively and into the named
 worker pools via `common.runtimes` (which copies the contextvars
 context onto the pool thread), so stage attribution recorded inside
 parquet decode / merge workers still lands on the right trace.
-
-Env: HORAEDB_TRACE=1 promotes span logs from DEBUG to INFO.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import logging
 import os
@@ -67,14 +72,10 @@ _SLOW_OPS = registry.counter(
 _TRACES_RECORDED = registry.counter(
     "traces_recorded_total", "traces completed into the trace ring")
 
-_current_span: contextvars.ContextVar[str] = contextvars.ContextVar(
-    "horaedb_span", default="")
 _current_trace: contextvars.ContextVar[Optional["Trace"]] = \
     contextvars.ContextVar("horaedb_trace", default=None)
 _current_span_id: contextvars.ContextVar[str] = contextvars.ContextVar(
     "horaedb_span_id", default="")
-
-_LEVEL = logging.INFO if os.environ.get("HORAEDB_TRACE") == "1" else logging.DEBUG
 
 # ids only need uniqueness, not secrecy; one process-wide PRNG seeded
 # from urandom, guarded for thread use
@@ -87,14 +88,15 @@ def new_trace_id() -> str:
         return f"{_id_rng.getrandbits(64):016x}"
 
 
+# span ids come from a counter that starts at a random point of the
+# 32-bit ring: next() on it is atomic under the GIL, so the hot path
+# takes no lock, and two processes whose spans are stitched into one
+# trace collide as rarely as with random ids
+_span_seq = itertools.count(int.from_bytes(os.urandom(4), "big"))
+
+
 def _new_span_id() -> str:
-    with _id_lock:
-        return f"{_id_rng.getrandbits(32):08x}"
-
-
-def current_span() -> str:
-    """Dotted path of the active span ("" outside any span)."""
-    return _current_span.get()
+    return f"{next(_span_seq) & 0xFFFFFFFF:08x}"
 
 
 def active_trace() -> Optional["Trace"]:
@@ -151,6 +153,13 @@ class Trace:
         with self._lock:
             if not self.finished:
                 self.counters[name] = self.counters.get(name, 0) + value
+
+    def add_many(self, pairs) -> None:
+        """Several counters under one lock (a pool hop adds three)."""
+        with self._lock:
+            if not self.finished:
+                for name, value in pairs:
+                    self.counters[name] = self.counters.get(name, 0) + value
 
     # stitching bounds: a trace must stay ring-sized and exportable no
     # matter what its downstream peers send
@@ -361,6 +370,10 @@ class TraceRecorder:
         self.op_sample_rate = 1.0
         self._ring: "OrderedDict[str, dict]" = OrderedDict()
         self._op_ring: "OrderedDict[str, dict]" = OrderedDict()
+        # op traces in flight, {trace_id: (op, start_ms)}: an op that
+        # holds the server up is still running when the stall line is
+        # written (common/loops.py), so the ring alone would miss it
+        self._active_ops: dict = {}
         self._lock = threading.Lock()
         self._rng = random.Random(0xACE)
 
@@ -402,9 +415,14 @@ class TraceRecorder:
             with self._lock:
                 if self._rng.random() >= rate:
                     return None
-        return Trace(trace_id or new_trace_id(), name, kind=kind, op=op,
-                     slow_threshold_s=slow_threshold_s,
-                     root_fields=root_fields)
+        trace = Trace(trace_id or new_trace_id(), name, kind=kind, op=op,
+                      slow_threshold_s=slow_threshold_s,
+                      root_fields=root_fields)
+        if kind == "op":
+            with self._lock:
+                self._active_ops[trace.trace_id] = (op or name,
+                                                    trace.start_ms)
+        return trace
 
     def finish(self, trace: Trace, status: str = "ok") -> dict:
         """Complete a trace into its ring; fires the slow log on
@@ -425,6 +443,7 @@ class TraceRecorder:
                       if trace.kind == "op"
                       else (self._ring, self.ring_size))
         with self._lock:
+            self._active_ops.pop(trace.trace_id, None)
             ring[trace.trace_id] = d
             ring.move_to_end(trace.trace_id)
             while len(ring) > size:
@@ -472,10 +491,23 @@ class TraceRecorder:
                         "spans": len(d["spans"])})
         return out
 
+    def ops_overlapping(self, start_ms: float, end_ms: float) -> list:
+        """[(op, start_ms, duration_ms or None while in flight)] of the
+        background ops whose interval overlaps [start_ms, end_ms)."""
+        with self._lock:
+            done = [(d.get("op") or d["root"], d["start_ms"],
+                     d["duration_ms"]) for d in self._op_ring.values()]
+            out = [(op, t0, None) for op, t0 in self._active_ops.values()
+                   if t0 < end_ms]
+        out += [(op, t0, dur) for op, t0, dur in done
+                if t0 < end_ms and t0 + (dur or 0.0) > start_ms]
+        return sorted(out, key=lambda o: o[1])
+
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
             self._op_ring.clear()
+            self._active_ops.clear()
 
 
 recorder = TraceRecorder()
@@ -489,8 +521,12 @@ def trace_scope(trace: Optional[Trace]) -> Iterator[Optional[Trace]]:
     tok = _current_trace.set(trace)
     tok_span = _current_span_id.set(
         trace.root_span_id if trace is not None else "")
+    # the root lies in the profiler's trace like every span under it
+    ann = (_annotation("horaedb/" + trace.name, trace.trace_id)
+           if trace is not None else contextlib.nullcontext())
     try:
-        yield trace
+        with ann:
+            yield trace
     finally:
         _current_trace.reset(tok)
         _current_span_id.reset(tok_span)
@@ -503,56 +539,170 @@ def trace_add(name: str, value: float = 1.0) -> None:
         trace.add(name, value)
 
 
-@contextlib.contextmanager
-def span(name: str, buckets: Optional[tuple] = None, **fields) -> Iterator[None]:
-    """Traced operation: logs enter/exit, observes a latency histogram
-    (`buckets` overrides the default layout — pass
-    metrics.WIDE_BUCKETS for long-running ops so compaction/flush
-    don't flatten into +Inf), and records a tree span into the active
-    trace when one is bound."""
-    parent_path = _current_span.get()
-    full = f"{parent_path}/{name}" if parent_path else name
-    token = _current_span.set(full)
-    trace = _current_trace.get()
-    span_id = parent_id = ""
-    tok_sid = None
-    if trace is not None and not trace.finished:
-        span_id = _new_span_id()
-        parent_id = _current_span_id.get() or trace.root_span_id
-        tok_sid = _current_span_id.set(span_id)
-    t0 = time.perf_counter()
-    wall_ms = time.time() * 1e3
-    if logger.isEnabledFor(_LEVEL):
-        logger.log(_LEVEL, "-> %s %s", full,
-                   " ".join(f"{k}={v}" for k, v in fields.items()))
-    ok = False
-    try:
-        yield
-        ok = True
-    finally:
-        _current_span.reset(token)
-        if tok_sid is not None:
-            _current_span_id.reset(tok_sid)
-        elapsed = time.perf_counter() - t0
-        if logger.isEnabledFor(_LEVEL):
-            if ok:
-                logger.log(_LEVEL, "<- %s %.1fms", full, elapsed * 1e3)
-            else:
-                logger.log(_LEVEL, "<- %s FAILED after %.1fms", full,
-                           elapsed * 1e3)
+_TraceAnnotation = None
+# span name -> its `span_<name>_seconds` family, so that a span looks
+# its histogram up in a plain dict and not under the registry's lock
+_SPAN_HISTS: dict = {}
+
+
+def _annotation(name: str, trace_id: str):
+    """A `jax.profiler.TraceAnnotation` (a TraceMe) for one span: with
+    no profiler session it costs a constructor and two no-op calls
+    (0.6 us measured on the sandbox CPU); while one runs, the span
+    lands in the xplane's host plane as `horaedb/<name>` with the
+    trace id, on the clock the device's operations are stamped with.
+    Spans held across an `await` interleave on the loop's thread: a
+    TraceMe is recorded whole at its end (start, end), not pushed on a
+    stack, so each keeps its own start and duration.  jax is imported
+    at the first span, not with this module."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, trace_id=trace_id)
+
+
+class span:
+    """Traced operation (a context manager): observes a latency
+    histogram (`span_<name>_seconds`; `buckets` overrides the default
+    layout — pass metrics.WIDE_BUCKETS for long-running ops so
+    compaction/flush don't flatten into +Inf; `hist` replaces the
+    family by an already-bound labelled child), records a tree span
+    into the active trace when one is bound, and annotates the
+    profiler's trace (see _annotation)."""
+
+    __slots__ = ("name", "fields", "_hist", "_buckets", "_trace",
+                 "_span_id", "_parent_id", "_tok", "_ann", "_wall_ms",
+                 "_t0")
+
+    def __init__(self, name: str, buckets: Optional[tuple] = None,
+                 hist=None, **fields) -> None:
+        self.name = name
+        self.fields = fields
+        self._hist = hist
+        self._buckets = buckets
+        self._trace = None
+
+    def __enter__(self) -> "span":
+        trace = _current_trace.get()
+        trace_id = ""
+        if trace is not None:
+            trace_id = trace.trace_id
+            if not trace.finished:
+                self._trace = trace
+                self._span_id = _new_span_id()
+                self._parent_id = (_current_span_id.get()
+                                   or trace.root_span_id)
+                self._tok = _current_span_id.set(self._span_id)
+        self._ann = _annotation("horaedb/" + self.name, trace_id)
+        self._ann.__enter__()
+        self._wall_ms = time.time() * 1e3
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        elapsed = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
         # failures are observed too — failure-path tail latency matters
-        hist_kwargs = {} if buckets is None else {"buckets": buckets}
-        registry.histogram(f"span_{name.replace('.', '_')}_seconds",
-                           f"span {name} duration",
-                           **hist_kwargs).observe(elapsed)
-        if span_id:
+        hist = self._hist or _SPAN_HISTS.get(self.name)
+        if hist is None:
+            name = self.name
+            hist_kwargs = ({} if self._buckets is None
+                           else {"buckets": self._buckets})
+            hist = _SPAN_HISTS[name] = registry.histogram(
+                f"span_{name.replace('.', '_')}_seconds",
+                f"span {name} duration", **hist_kwargs)
+        hist.observe(elapsed)
+        trace = self._trace
+        if trace is not None:
+            _current_span_id.reset(self._tok)
             trace.record({
-                "span_id": span_id, "parent_id": parent_id, "name": name,
-                "start_ms": round(wall_ms, 3),
+                "span_id": self._span_id, "parent_id": self._parent_id,
+                "name": self.name, "start_ms": round(self._wall_ms, 3),
                 "duration_ms": round(elapsed * 1e3, 3),
-                "status": "ok" if ok else "error",
-                "fields": {k: _field(v) for k, v in fields.items()},
+                "status": "ok" if exc_type is None else "error",
+                "fields": {k: _field(v) for k, v in self.fields.items()},
             })
+
+
+# a hop whose two waits together stay under this leaves no span (its
+# counters still count): nine such hops are 3% of a 60 ms scan
+HOP_SPAN_FLOOR_MS = 0.2
+_HOP_TWINS: dict = {}
+
+
+def record_hop(pool: str, submitted: float, started: float,
+               returned: float, resumed: float) -> None:
+    """One hop of a traced request through a worker pool, known by four
+    time.perf_counter readings (common/runtimes.py): the per-trace
+    twins of the pool's wait counters, and — where the hop waited —
+    one `pool_hop` child of the current span from submit to
+    resumption.  It overlaps the spans the job recorded on the worker,
+    so a span that hops between the loop and a pool is closed by its
+    children, waits included.  No histogram and no profiler
+    annotation: the hop is known only after the fact, and its counters
+    are the caller's.  Runs on the loop's thread once per hop: kept
+    short."""
+    trace = _current_trace.get()
+    if trace is None or trace.finished:
+        return
+    wait_ms = (started - submitted) * 1e3
+    run_ms = (returned - started) * 1e3
+    resume_ms = (resumed - returned) * 1e3
+    names = _HOP_TWINS.get(pool)
+    if names is None:
+        names = _HOP_TWINS[pool] = tuple(
+            f"pool_{pool}_{part}_ms" for part in ("wait", "run", "resume"))
+    trace.add_many(zip(names, (wait_ms, run_ms, resume_ms)))
+    if wait_ms + resume_ms < HOP_SPAN_FLOOR_MS:
+        return
+    trace.record({
+        "span_id": _new_span_id(),
+        "parent_id": _current_span_id.get() or trace.root_span_id,
+        "name": "pool_hop",
+        "start_ms": round(trace.start_ms
+                          + (submitted - trace._t0) * 1e3, 3),
+        "duration_ms": round((resumed - submitted) * 1e3, 3),
+        "status": "ok",
+        "fields": {"pool": pool, "wait_ms": wait_ms, "run_ms": run_ms,
+                   "resume_ms": resume_ms},
+    })
+
+
+# The phases of one scan (docs/observability.md, scan phases): the
+# children of the engine's `downsample` span.  One labelled family, so
+# the data table's phases are told apart from those of the index and
+# tags tables, which `resolve` scans through the same reader.  A
+# reader removes its table's children at close (clear-on-close).
+SCAN_PHASES = ("scan.plan", "scan.windows", "scan.group_prep",
+               "scan.dispatch", "scan.device_wait", "scan.d2h",
+               "scan.combine")
+_PHASE_SECONDS = registry.histogram(
+    "scan_phase_seconds",
+    "wall seconds per scan phase (the children of the downsample "
+    "span), by phase and by the table scanned")
+
+
+# (phase, table) -> the labelled child, looked up without the family's
+# lock; dropped with the child at the table's close
+_PHASE_CHILDREN: dict = {}
+
+
+def phase(name: str, table: str, **fields) -> span:
+    """A span of one scan phase on `table`."""
+    hist = _PHASE_CHILDREN.get((name, table))
+    if hist is None:
+        hist = _PHASE_CHILDREN[name, table] = _PHASE_SECONDS.labels(
+            phase=name, table=table)
+    return span(name, hist=hist, table=table, **fields)
+
+
+def clear_phases(table: str) -> None:
+    """Clear-on-close for a table's phase histograms."""
+    for name in SCAN_PHASES:
+        _PHASE_CHILDREN.pop((name, table), None)
+        _PHASE_SECONDS.remove(phase=name, table=table)
 
 
 def _field(v):

@@ -1,0 +1,372 @@
+"""The scan's phase spans and wait counters (docs/observability.md,
+scan phases): the children of `downsample` on both device routes, the
+same spans on the profiler's clock, the waits where the work waits
+(pools, the event loop, the collector), and the seams that split the
+sync from the copy."""
+
+import asyncio
+import gc
+import glob
+import logging
+import time
+
+import jax
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from horaedb_tpu.common import deviceprof
+from horaedb_tpu.common import loops as loops_mod
+from horaedb_tpu.common.loops import LoopRegistry
+from horaedb_tpu.common.runtimes import Runtimes
+from horaedb_tpu.metric_engine import MetricEngine
+from horaedb_tpu.objstore import InstrumentedStore, MemoryObjectStore
+from horaedb_tpu.ops import device_decode
+from horaedb_tpu.server.config import ServerConfig
+from horaedb_tpu.server.main import ServerState, build_app
+from horaedb_tpu.storage import read as read_mod
+from horaedb_tpu.storage.types import TimeRange
+from horaedb_tpu.utils import registry
+from horaedb_tpu.utils.tracing import (
+    SCAN_PHASES,
+    recorder,
+    span,
+    trace_scope,
+)
+
+T0 = 1_700_000_000_000
+HOUR = 3_600_000
+QUERY = {"metric": "cpu", "start": T0 + 7, "end": T0 + 3 * HOUR + 7,
+         "bucket_ms": 600_000}
+
+ROUTES = {
+    # route -> (environment, phases a query of it has)
+    "fused_acc": ({"HORAEDB_FUSED_AGG": "1", "HORAEDB_HOST_AGG": "0"},
+                  set(SCAN_PHASES) - {"scan.combine"}),
+    "device_decode": ({"HORAEDB_DEVICE_DECODE": "1",
+                       "HORAEDB_HOST_AGG": "0"}, set(SCAN_PHASES)),
+}
+
+
+def run(coro):
+    return asyncio.run(coro)
+
+
+def phase_counts(table: str) -> dict:
+    fam = registry.family("scan_phase_seconds")
+    return {p: fam.labels(phase=p, table=table).count for p in SCAN_PHASES}
+
+
+def walk(node):
+    yield node
+    for child in node["children"]:
+        yield from walk(child)
+
+
+async def served(fn):
+    """`fn(client, engine)` against a served engine holding four hosts
+    of 200 one-minute samples, flushed to SSTs."""
+    engine = await MetricEngine.open(
+        "phases_db", InstrumentedStore(MemoryObjectStore()),
+        segment_ms=2 * HOUR)
+    client = TestClient(TestServer(build_app(
+        ServerState(engine, ServerConfig()))))
+    await client.start_server()
+    try:
+        for h in range(4):
+            r = await client.post("/write", json={"samples": [
+                {"name": "cpu", "labels": {"host": f"h{h}"},
+                 "timestamp": T0 + i * 60_000, "value": float(i)}
+                for i in range(200)]})
+            assert r.status == 200
+        return await fn(client, engine)
+    finally:
+        await client.close()
+        await engine.close()
+
+
+class TestPhaseSpans:
+    @pytest.mark.parametrize("route", sorted(ROUTES))
+    def test_served_query_yields_every_phase_under_downsample(
+            self, route, monkeypatch):
+        env, want_phases = ROUTES[route]
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+
+        async def go(client, _engine):
+            body = dict(QUERY, filters={"host": "h1"})
+            r = await client.post("/query", json=body)  # compiles
+            assert r.status == 200
+            before = phase_counts("data")
+            index_before = phase_counts("index")
+            r = await client.post("/query", json=dict(body,
+                                                      start=T0 + 9))
+            assert r.status == 200
+            tid = r.headers["X-Trace-Id"]
+            tree = (await (await client.get(
+                f"/debug/traces/{tid}")).json())["tree"]
+            return tree, before, phase_counts("data"), index_before, \
+                phase_counts("index")
+
+        tree, before, after, index_before, index_after = run(served(go))
+        top = {c["name"]: c for c in tree["children"]}
+        assert {"parse", "resolve", "downsample", "respond"} <= set(top)
+        ds = top["downsample"]
+        lo, hi = ds["start_ms"], ds["start_ms"] + ds["duration_ms"]
+        phases = [c for c in ds["children"] if c["name"] in SCAN_PHASES]
+        assert {c["name"] for c in phases} == want_phases
+        for c in phases:
+            # starts are wall clock, durations perf_counter: 1 ms slack
+            assert lo - 1.0 <= c["start_ms"]
+            assert c["start_ms"] + c["duration_ms"] <= hi + 1.0
+            assert c["fields"]["table"] == "data"
+        routed = [c["fields"]["route"] for c in phases
+                  if c["name"] == "scan.plan" and "route" in c["fields"]]
+        assert routed == [route]
+        # the pool hops close the span too, waits included
+        hops = [c for c in walk(ds) if c["name"] == "pool_hop"]
+        assert hops and all(
+            {"pool", "wait_ms", "run_ms", "resume_ms"} <= set(c["fields"])
+            for c in hops)
+        # resolve scans the index table through the same reader: its
+        # phases carry that table and stay out of the data table's
+        # histograms, which moved by exactly this query's data spans
+        resolve_phases = [c for c in walk(top["resolve"])
+                          if c["name"] in SCAN_PHASES]
+        assert resolve_phases
+        assert all(c["fields"]["table"] != "data" for c in resolve_phases)
+        assert index_after["scan.plan"] > index_before["scan.plan"]
+        data_spans = [c for c in walk(tree) if c["name"] in SCAN_PHASES
+                      and c["fields"]["table"] == "data"]
+        for p in SCAN_PHASES:
+            assert after[p] - before[p] == sum(
+                1 for c in data_spans if c["name"] == p), p
+
+    def test_close_clears_the_tables_phase_children(self):
+        async def go(client, _engine):
+            r = await client.post("/query", json=QUERY)
+            assert r.status == 200
+            return registry.render()
+
+        during = run(served(go))
+        assert 'scan_phase_seconds_count{phase="scan.plan",table="data"}' \
+            in during
+        assert 'table="data"' not in "".join(
+            line for line in registry.render().splitlines()
+            if line.startswith("scan_phase_seconds"))
+
+
+class TestProfilerClock:
+    def test_spans_lie_in_the_xplane_at_their_own_starts(
+            self, tmp_path, monkeypatch):
+        """Under a profiler session `horaedb/downsample` and its phases
+        are TraceMe events whose starts (relative to the root's) match
+        the spans' within 5 ms — two spans held across interleaved
+        awaits on the loop's thread included."""
+        monkeypatch.setenv("HORAEDB_DEVICE_DECODE", "1")
+        monkeypatch.setenv("HORAEDB_HOST_AGG", "0")
+        rng = TimeRange.new(QUERY["start"], QUERY["end"])
+
+        async def held(name, seconds):
+            with span(name):
+                await asyncio.sleep(seconds)
+
+        async def go(_client, engine):
+            await engine.query_downsample("cpu", [], rng,
+                                          QUERY["bucket_ms"])  # compiles
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path),
+                                     profiler_options=opts)
+            try:
+                trace = recorder.start("profiled")
+                with trace_scope(trace):
+                    # another range: the parts memo must not serve it
+                    await engine.query_downsample(
+                        "cpu", [], TimeRange.new(rng.start + 3,
+                                                 rng.end + 3),
+                        QUERY["bucket_ms"])
+                    await asyncio.gather(held("interleaved.a", 0.03),
+                                         held("interleaved.b", 0.06))
+                return recorder.finish(trace)
+            finally:
+                jax.profiler.stop_trace()
+
+        done = run(served(go))
+        spans = {}
+        for s in done["spans"]:
+            spans.setdefault(s["name"], []).append(s)
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[0]
+        events = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("horaedb/"):
+                        events.setdefault(ev.name[len("horaedb/"):],
+                                          []).append(ev)
+        root_ev, = events["profiled"]
+        root_span, = spans["profiled"]
+        want = {"downsample", "interleaved.a", "interleaved.b"} \
+            | set(SCAN_PHASES)
+        assert want <= set(events)
+        for name in want:
+            got = sorted((ev.start_ns - root_ev.start_ns) / 1e6
+                         for ev in events[name])
+            rec = sorted(s["start_ms"] - root_span["start_ms"]
+                         for s in spans[name])
+            assert len(got) == len(rec), name
+            for g, r in zip(got, rec):
+                assert abs(g - r) < 5.0, (name, g, r)
+        # interleaved on one thread, each keeps its own duration
+        for name, seconds in (("interleaved.a", 0.03),
+                              ("interleaved.b", 0.06)):
+            ev, = events[name]
+            assert seconds * 1e3 <= ev.duration_ns / 1e6 < seconds * 1e3 + 25
+            assert abs(ev.duration_ns / 1e6
+                       - spans[name][0]["duration_ms"]) < 5.0
+
+
+class TestWaits:
+    def test_a_busy_one_thread_pool_shows_its_wait(self):
+        wait = registry.family("runtime_pool_wait_seconds").labels(
+            pool="manifest")
+        resume = registry.family("runtime_pool_resume_seconds").labels(
+            pool="manifest")
+
+        async def go():
+            rt = Runtimes(sst_threads=1, compact_threads=1,
+                          manifest_threads=1)
+            trace = recorder.start("hops")
+            try:
+                with trace_scope(trace):
+                    await asyncio.gather(
+                        rt.run("manifest", time.sleep, 0.2),
+                        rt.run("manifest", time.sleep, 0.01))
+            finally:
+                rt.close()
+            return recorder.finish(trace)
+
+        w0, n0, r0 = wait.sum, wait.count, resume.count
+        done = run(go())
+        assert wait.count - n0 == 2 and resume.count - r0 == 2
+        assert wait.sum - w0 >= 0.15  # the second job sat behind the first
+        assert done["counters"]["pool_manifest_wait_ms"] >= 150.0
+        assert done["counters"]["pool_manifest_run_ms"] >= 200.0
+        hops = [s for s in done["spans"] if s["name"] == "pool_hop"]
+        assert len(hops) == 2
+        assert max(s["fields"]["wait_ms"] for s in hops) >= 150.0
+        assert all(s["duration_ms"] >= s["fields"]["wait_ms"]
+                   + s["fields"]["run_ms"] for s in hops)
+
+    def test_a_blocked_loop_shows_as_lag_stall_and_one_slow_log_line(
+            self, caplog):
+        lag = registry.family("event_loop_lag_seconds")
+        stall = registry.family("event_loop_stall_seconds_total")
+
+        async def go():
+            reg = LoopRegistry()
+            reg.ensure_watchdog()
+            await asyncio.sleep(0.25)  # the sampler is ticking
+            # a coroutine that blocks the loop: the tick due inside comes
+            # 0.3 to 0.4 s late, whatever was left of its sleep
+            time.sleep(0.4)
+            await asyncio.sleep(0.25)
+            for h in reg.handles():
+                h.task.cancel()
+            await asyncio.gather(*(h.task for h in reg.handles()),
+                                 return_exceptions=True)
+
+        n0, s0, t0 = lag.count, lag.sum, stall.value
+        with caplog.at_level(logging.WARNING,
+                             logger="horaedb_tpu.trace.slow"):
+            run(go())
+        assert lag.count - n0 >= 3
+        assert lag.sum - s0 >= 0.3
+        assert 0.3 <= stall.value - t0 < 0.7
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("[stall]")]
+        assert len(lines) == 1, lines
+        assert "pool_queues=" in lines[0] and "gc=" in lines[0]
+
+    def test_a_forced_collection_shows_as_a_gc_pause(self):
+        loops_mod.hook_gc()
+        gen2 = registry.family("process_gc_pause_seconds_total").labels(
+            generation="2")
+        before = gen2.value
+        junk = [[i] for i in range(200_000)]
+        del junk
+        gc.collect()
+        assert gen2.value > before
+
+
+async def compiles_per_query(client, engine, fns) -> list:
+    """One query three times, the parts memo off so that each repeat
+    dispatches again: per query, how many programs each of `fns`
+    compiled for it (its jit cache's growth)."""
+    engine.tables["data"].reader.parts_memo.lru.max_bytes = 0
+    out = []
+    for _ in range(3):
+        before = [f._cache_size() for f in fns]
+        r = await client.post("/query", json=QUERY)
+        assert r.status == 200
+        out.append([f._cache_size() - b for f, b in zip(fns, before)])
+    return out
+
+
+class TestSeams:
+    def test_device_decode_query_charges_d2h_seconds_and_compiles_once(
+            self, monkeypatch):
+        """After a device-decode query the d2h seam has seconds, not
+        only bytes; and the named scopes inside the scan programs are
+        metadata: the same calls compile as many programs as before
+        (one per jitted function here)."""
+        monkeypatch.setenv("HORAEDB_DEVICE_DECODE", "1")
+        monkeypatch.setenv("HORAEDB_HOST_AGG", "0")
+        d2h = registry.family("device_transfer_seconds_total")
+
+        async def go(client, engine):
+            sizes = await compiles_per_query(
+                client, engine, [device_decode._decode_aggregate_jit])
+            return (sizes, d2h.labels(direction="d2h").value,
+                    deviceprof.profiler.snapshot()["transfer"]["d2h"])
+
+        sizes, seconds, ledger = run(served(go))
+        assert seconds > 0.0
+        assert ledger["seconds"] > 0.0 and ledger["bytes"] > 0
+        # as before the scopes: at most a program a segment for the
+        # first query, and none for its repeats
+        assert sizes[0][0] <= 2 and sizes[1:] == [[0], [0]]
+
+    def test_fused_programs_compile_once_under_their_scopes(
+            self, monkeypatch):
+        monkeypatch.setenv("HORAEDB_FUSED_AGG", "1")
+        monkeypatch.setenv("HORAEDB_HOST_AGG", "0")
+        fns = [read_mod._fused_acc_init_jit,
+               read_mod._fused_round_accumulate_jit,
+               read_mod._fused_finalize_jit, read_mod._group_has_data_jit]
+
+        async def go(client, engine):
+            return await compiles_per_query(client, engine, fns)
+
+        sizes = run(served(go))
+        assert all(n <= 1 for n in sizes[0])
+        assert sizes[1:] == [[0] * 4, [0] * 4]
+
+    def test_scopes_name_the_stages_in_the_lowered_program(self):
+        """The scope names reach the operation metadata a profile
+        shows (debug info of the lowered module)."""
+        import jax.numpy as jnp
+
+        from horaedb_tpu.ops import downsample
+
+        def partials(ts, gid, vals):
+            return downsample.partial_aggregate(
+                ts, gid, vals, 8, 10, num_groups=2, num_buckets=4,
+                which=("sum", "max"))
+
+        text = jax.jit(partials).lower(
+            jnp.zeros(8, jnp.int32), jnp.zeros(8, jnp.int32),
+            jnp.zeros(8, jnp.float32)).as_text(debug_info=True)
+        for scope in ("bucket_index", "scatter_count", "scatter_sum",
+                      "scatter_max"):
+            assert scope in text, scope
