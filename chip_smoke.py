@@ -733,10 +733,16 @@ class Smoke:
                 "mesh_decode_partials", {"dispatches": 0, "compiles": 0})
             n_decode = (decode_rounds["dispatches"]
                         + decode_rounds["compiles"])
+            rows = srv.metric("scan_decode_rows_total")
             self.step("mesh.rounds", mesh_rounds=rounds,
-                      mesh_decode_rounds=n_decode)
+                      mesh_decode_rounds=n_decode, decode_rows=rows)
             check(rounds > 0, "no mesh rounds ran")
             check(n_decode > 0, "no fused mesh-decode rounds ran")
+            # the point query names one host: its plans reach their
+            # round narrowed to that host's rows
+            check(0 < rows.get('side="uploaded"', 0)
+                  < rows.get('side="stored"', 0),
+                  f"no narrowed plan rode a mesh round: {rows}")
             if self.on_chip:
                 idle = [d for d in out["devices"]
                         if d["bytes_in_use"] <= 0]

@@ -12,11 +12,23 @@ offsets, raw float32 — storage/sidecar.py), yet the host still k-way
 This module moves that whole chain onto the accelerator.  For an
 eligible aggregate plan, an `EncodedSegment`'s encoded buffers upload
 RAW (pad + device_put — memcpy-shaped host work) and one jitted
-program does:
+program does the rest.  The device pays per row handed to it (every
+scatter and gather runs over the padded capacity), so before the pad
+plan_dispatch NARROWS the segment on host to the rows the
+conjunction's Eq/In leaves admit (one numpy compare per leaf, in
+encoded space, with the constants compile_leaves produced) wherever
+that lands it in a smaller capacity bucket: a query for one field of a
+ten-field segment uploads a tenth of the rows.  Range leaves take no
+part in it, so a plan's capacity depends on which keys a query names
+and not on where its window falls (one compiled shape per key
+selectivity, not per offset).  The program itself does:
 
   leaf filter   — the plan's pushed PK-leaf conjunction evaluated in
                   ENCODED space (constants pre-translated host-side via
-                  the same ops.filter helpers the host mask uses);
+                  the same ops.filter helpers the host mask uses): all
+                  of it, on whatever rows were uploaded — on a narrowed
+                  segment the Eq/In leaves are tautologies, kept so
+                  that narrowing adds no compiled program;
   merge-dedup   — lax.sort by (valid, pk codes..., seq, row) and a
                   keep-last-of-PK-run mask: the device twin of the host
                   k-way merge + `_host_dedup_keep`, with dropped rows
@@ -115,6 +127,21 @@ _SORT_RAN = registry.counter(
     "scan_decode_sorted_total",
     "fused decode dispatches that paid the device lax.sort "
     "(multi-run interleaved segments)")
+
+
+# how often the narrowing engages: rows of every planned dispatch as
+# stored in the segment and as handed to the program
+_DECODE_ROWS = {
+    side: registry.counter(
+        "scan_decode_rows_total",
+        "rows of every planned fused decode dispatch: stored = the "
+        "segment's rows as assembled, uploaded = the rows padded and "
+        "uploaded after the host narrowed the segment to what the "
+        "plan's Eq/In leaves admit (equal where no such leaf put it "
+        "in a smaller capacity bucket)"
+    ).labels(side=side)
+    for side in ("stored", "uploaded")
+}
 
 
 def note_fallback(reason: str) -> None:
@@ -314,6 +341,38 @@ def _leaf_mask(col, op: int, c):
         return (col >= c[0]) & (col < c[1])
     # _OP_IN: small resolved-code set, compare-broadcast then any
     return (col[:, None] == c[None, :]).any(axis=1)
+
+
+def _narrow_to_key_leaves(es, prog: tuple, consts: tuple, pad_capacity):
+    """The host half of the leaf filter: the segment cut to the rows
+    its Eq/In leaves admit — the compares _leaf_mask emits, on the same
+    int32 codes and constants — when that puts it in a smaller
+    capacity bucket; the segment itself otherwise (an unselective plan
+    pays one numpy compare per such leaf, nothing else); None when no
+    row passes.  Leaves are PK-only and masking precedes the dedup on
+    the device too, so an equal-PK run passes or fails whole and the
+    narrowed dispatch's grids are the un-narrowed one's, byte for
+    byte.  Range leaves stay with the device: see the module doc."""
+    mask = None
+    for (name, op), c in zip(prog, consts):
+        if op == _OP_EQ:
+            m = es.columns[name] == c[0]
+        elif op == _OP_IN:
+            m = np.isin(es.columns[name], c)
+        else:
+            continue
+        if mask is None:
+            mask = m
+        else:
+            mask &= m
+    if mask is None or not es.n:
+        return es
+    kept = int(np.count_nonzero(mask))
+    if not kept:
+        return None
+    if pad_capacity(kept) >= pad_capacity(es.n):
+        return es
+    return es.keep_rows(mask)
 
 
 def _lex_sorted_np(keys: list) -> bool:
@@ -604,7 +663,8 @@ class DecodePlan:
     (read._run_mesh_decode_round), so decode shards along the time
     axis with the aggregation instead of serializing ahead of it."""
 
-    es: object
+    es: object                # the segment AS UPLOADED (narrowed or not)
+    src_rows: int             # its rows as stored, before any narrowing
     cap: int
     shift: int
     lo: int
@@ -633,7 +693,7 @@ class DecodePlan:
     def n_valid(self) -> int:
         # windows-list accounting parity (DeviceBatch/DevicePart ride
         # the same lists): source rows, pre-filter/dedup
-        return self.es.n
+        return self.src_rows
 
     def static_key(self) -> tuple:
         """Everything that must match for two plans to share one
@@ -649,8 +709,9 @@ class DecodePlan:
 def plan_dispatch(es, spec, pk_names: list, seq_name: str,
                   leaves, max_bytes: int, width: int,
                   pad_capacity) -> "DecodePlan | DevicePart | str":
-    """Validate one EncodedSegment against the fused program's layout
-    and plan its dispatch WITHOUT touching the device.  Returns a
+    """Validate one EncodedSegment against the fused program's layout,
+    narrow it to what its key leaves admit (_narrow_to_key_leaves) and
+    plan its dispatch WITHOUT touching the device.  Returns a
     DecodePlan (ready to execute or to join a mesh round), a DevicePart
     (provably-empty segment, no dispatch), or a fallback reason string
     (the caller counts it and takes the host path)."""
@@ -675,7 +736,6 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
     shift = int(ts_enc.epoch) - spec.range_start
     if abs(shift) >= 2**31:
         return "range"
-    cap = pad_capacity(es.n)
 
     try:
         prog, consts = compile_leaves(leaves, encs)
@@ -683,6 +743,13 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
         return DevicePart(part=None, n_valid=0, nbytes=0)
     except (ValueError, OverflowError):
         return "predicate"
+    # from here on `es` is what uploads: the budget gate, the route
+    # and the geometry all follow the narrowed rows
+    src_rows = es.n
+    es = _narrow_to_key_leaves(es, prog, consts, pad_capacity)
+    if es is None:
+        return DevicePart(part=None, n_valid=0, nbytes=0)
+    cap = pad_capacity(es.n)
 
     # upload slots: pk codes, then seq (the dedup order), then any
     # non-PK group/ts column appended AFTER seq — sort keys past
@@ -759,9 +826,12 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
     group_pos = key_names.index(spec.group_col)
     ts_pos = key_names.index(spec.ts_col)
     leaf_prog = tuple((slot_of[c], op) for c, op in prog)
+    _DECODE_ROWS["stored"].inc(src_rows)
+    _DECODE_ROWS["uploaded"].inc(es.n)
     return DecodePlan(
-        es=es, cap=cap, shift=shift, lo=lo, local_ok=local_ok,
-        use_width=use_width, w_eff=w_eff, g=g, g_pad=g_pad,
+        es=es, src_rows=src_rows, cap=cap, shift=shift, lo=lo,
+        local_ok=local_ok, use_width=use_width, w_eff=w_eff, g=g,
+        g_pad=g_pad,
         values=g_enc.dictionary, upload_names=upload_names,
         key_slots=key_slots, num_pks=len(pk_names),
         group_pos=group_pos, ts_pos=ts_pos,
@@ -808,17 +878,6 @@ def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
                           values=dp.values, lo=dp.lo, w_eff=dp.w_eff,
                           bucket_ms=dp.bucket_ms,
                           t_dispatch=time.perf_counter() - t0,
-                          upload_bytes=upload_bytes, src_rows=es.n,
+                          upload_bytes=upload_bytes, src_rows=dp.src_rows,
                           table=table)
 
-
-def prepare_dispatch(es, spec, pk_names: list, seq_name: str,
-                     leaves, max_bytes: int, width: int,
-                     pad_capacity) -> "DecodeDispatch | DevicePart | str":
-    """plan_dispatch + execute_plan in one step — the non-mesh entry
-    point (and the shape every existing caller/test expects)."""
-    dp = plan_dispatch(es, spec, pk_names, seq_name, leaves, max_bytes,
-                       width, pad_capacity)
-    if not isinstance(dp, DecodePlan):
-        return dp
-    return execute_plan(dp)

@@ -1054,10 +1054,11 @@ class ParquetReader:
         so the two cannot drift.
 
         Device-decode-routed plans (plan.decode_spec set) short-circuit
-        here: the segment's ENCODED buffers upload raw and one fused
-        program does filter + merge-dedup + bucket-aggregate
-        (ops/device_decode.py) — the decode pool dispatch shrinks to a
-        memcpy-shaped pad + upload.  Per-segment ineligibility falls
+        here: the segment's ENCODED buffers, narrowed to the rows its
+        Eq/In leaves admit, upload raw and one fused program does
+        filter + merge-dedup + bucket-aggregate (ops/device_decode.py)
+        — the decode pool dispatch shrinks to a mask, a memcpy-shaped
+        pad and an upload.  Per-segment ineligibility falls
         back to the host path with its reason counted, resolving any
         deferred leaf mask first."""
         if isinstance(table, sidecar.EncodedSegment):
@@ -1425,6 +1426,9 @@ class ParquetReader:
         the event loop and falls back to parquet — the cache's negative
         memos are loop-owned)."""
         t0 = time.perf_counter()
+        # device-decode plans defer the leaf mask to the dispatch, which
+        # narrows by the Eq/In leaves on host and leaves the rest to the
+        # device (see _read_segment_encoded)
         defer = plan.decode_spec is not None
         try:
             with self._phase("scan.windows", segment=seg.segment_start):
@@ -1515,11 +1519,15 @@ class ParquetReader:
             # returned a row subset tied to this plan's leaves
             if res[1] == f.meta.num_rows:
                 self.encoded_cache.put(f.id, res[0], res[1])
-        # device-decode plans DEFER the exact leaf mask: the fused
-        # dispatch evaluates the conjunction in encoded space on
-        # device, so the host never pays the mask + per-column
-        # compaction (ops/device_decode.py; a per-segment fallback
-        # resolves pending leaves host-side)
+        # device-decode plans DEFER the leaf mask to the dispatch
+        # (ops/device_decode.plan_dispatch): there the host narrows the
+        # segment by the conjunction's Eq/In leaves where that puts it
+        # in a smaller capacity bucket (the device pays per row handed
+        # to it), and the fused program evaluates the whole conjunction
+        # in encoded space on whatever uploads — range leaves always on
+        # the device, so capacities follow the keys a query names, not
+        # its window.  A per-segment fallback resolves pending leaves
+        # host-side (sidecar.apply_leaves_host)
         defer = plan.decode_spec is not None
         try:
             es = await runner(sidecar.assemble_parts, parts,
@@ -3435,7 +3443,8 @@ class ParquetReader:
                 cells += sum(int(v.shape[0] * v.shape[1])
                              for v in grids.values())
                 dl_bytes += sum(int(v.nbytes) for v in grids.values())
-                src_rows += sum(dp2.es.n for _s2, dp2 in chunk[a:i + 1])
+                src_rows += sum(dp2.src_rows
+                                for _s2, dp2 in chunk[a:i + 1])
                 entries.append(
                     (s, (dp.values, dp.lo, grids), i - a + 1))
                 a = i + 1

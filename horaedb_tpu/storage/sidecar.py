@@ -34,6 +34,7 @@ int64.
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 import json
 import struct
 from dataclasses import dataclass
@@ -464,12 +465,13 @@ class EncodedSegment:
     order, ready for the merge's window prep.
 
     `pending_leaves` is set (a list, possibly empty) when the assemble
-    DEFERRED the exact leaf mask for the device-decode dispatch
-    (ops/device_decode.py): the fused program evaluates the conjunction
-    in encoded space on device, so the host never compacts rows.  None
-    means leaves were applied at assemble (the host-decode contract);
-    a host fallback for a deferred segment must apply_leaves_host
-    first."""
+    DEFERRED the exact leaf mask to the device-decode dispatch
+    (ops/device_decode.py): plan_dispatch narrows the segment on host
+    by the conjunction's Eq/In leaves where that puts it in a smaller
+    capacity bucket, and the fused program evaluates the whole
+    conjunction in encoded space on device.  None means leaves were
+    applied at assemble (the host-decode contract); a host fallback
+    for a deferred segment must apply_leaves_host first."""
 
     columns: dict
     encodings: dict
@@ -498,6 +500,23 @@ class EncodedSegment:
     def nbytes(self) -> int:
         return sum(int(a.nbytes) for a in self.columns.values())
 
+    def keep_rows(self, mask: np.ndarray) -> "EncodedSegment":
+        """The rows `mask` admits, in stored order: every column
+        compacted, and `run_lengths` counted per run — the survivors of
+        a sorted run are a sorted run, so the k-way route's bounds stay
+        valid.  The one compaction body behind apply_leaves_host (the
+        whole conjunction) and the device-decode dispatch's narrowing
+        (its key leaves, ops/device_decode.plan_dispatch)."""
+        idx = np.flatnonzero(mask)
+        run_lengths = self.run_lengths
+        if run_lengths is not None:
+            bounds = np.searchsorted(
+                idx, np.cumsum((0,) + tuple(run_lengths)))
+            run_lengths = tuple(int(c) for c in np.diff(bounds))
+        return dataclasses.replace(
+            self, columns={nm: a[idx] for nm, a in self.columns.items()},
+            n=len(idx), run_lengths=run_lengths)
+
 
 def apply_leaves_host(es: EncodedSegment) -> EncodedSegment:
     """Resolve a deferred leaf conjunction on host — the fallback when
@@ -513,29 +532,15 @@ def apply_leaves_host(es: EncodedSegment) -> EncodedSegment:
         if leaves is not None:
             es.pending_leaves = None
         return es
-    cols = es.columns
-    run_lengths = es.run_lengths
     if es.n:
-        batch = encode.DeviceBatch(columns=cols, encodings=es.encodings,
+        batch = encode.DeviceBatch(columns=es.columns,
+                                   encodings=es.encodings,
                                    n_valid=es.n, capacity=es.n)
         mask = np.asarray(filter_ops.eval_predicate(
             filter_ops.And(tuple(leaves)), batch))
         if not mask.all():
-            idx = np.flatnonzero(mask)
-            cols = {nm: a[idx] for nm, a in cols.items()}
-            if run_lengths is not None:
-                # survivors per run: the run boundaries stay valid for
-                # the k-way route because compaction happens per run
-                counts, pos = [], 0
-                for rl in run_lengths:
-                    counts.append(int(mask[pos:pos + rl].sum()))
-                    pos += rl
-                run_lengths = tuple(counts)
-    n = len(next(iter(cols.values()))) if cols else 0
-    return EncodedSegment(columns=cols, encodings=es.encodings, n=n,
-                          names=es.names, pending_leaves=None,
-                          source_runs=es.source_runs,
-                          run_lengths=run_lengths)
+            es = es.keep_rows(mask)
+    return dataclasses.replace(es, pending_leaves=None)
 
 
 def assemble_segment(bufs: list[bytes], columns: list,

@@ -25,7 +25,7 @@ from horaedb_tpu.common import ReadableDuration
 from horaedb_tpu.common import runtimes as runtimes_mod
 from horaedb_tpu.common.error import Error
 from horaedb_tpu.objstore import MemoryObjectStore
-from horaedb_tpu.ops import device_decode
+from horaedb_tpu.ops import device_decode, encode
 from horaedb_tpu.ops import filter as F
 from horaedb_tpu.ops.downsample import ALL_AGGS
 from horaedb_tpu.storage.config import (
@@ -37,6 +37,7 @@ from horaedb_tpu.storage.plan import TopKSpec
 from horaedb_tpu.storage.read import AggregateSpec, ScanRequest
 from horaedb_tpu.storage.storage import CloudObjectStorage, WriteRequest
 from horaedb_tpu.storage.types import TimeRange
+from horaedb_tpu.utils import registry
 
 SEED = int(os.environ.get("DECODE_SEED", "1337"), 0)
 SCHEDULES = int(os.environ.get("DECODE_SCHEDULES", "20"), 0)
@@ -284,6 +285,248 @@ def test_sort_free_routing_counted(runtimes):
             await s.close()
 
     run(go())
+
+
+# ---------------------------------------------------------------------------
+# narrowing: a segment cut to its key leaves' rows before the upload
+# ---------------------------------------------------------------------------
+
+# the data table's key shape: (metric, series, field, timestamp)
+NARROW_SCHEMA = pa.schema([("m", pa.string()), ("host", pa.string()),
+                           ("field", pa.string()), ("ts", pa.int64()),
+                           ("v", pa.float64())])
+NARROW_HOSTS, NARROW_FIELDS, NARROW_TICKS = 6, 4, 40
+
+
+def narrow_rows(rng, ticks, base=0.0):
+    """One run of the segment: every host x field x tick, except that
+    the last host reports its last field only."""
+    rows = []
+    for h in range(NARROW_HOSTS):
+        for f in range(NARROW_FIELDS):
+            if h == NARROW_HOSTS - 1 and f != NARROW_FIELDS - 1:
+                continue
+            for t in ticks:
+                rows.append(("cpu", f"h{h}", f"f{f}", t * 60_000 + 7,
+                             base + float(rng.randint(0, 10**6))))
+    return rows
+
+
+def narrow_wreq(rows):
+    cols = list(zip(*rows))
+    rb = pa.record_batch(
+        [pa.array(list(c), type=f.type)
+         for c, f in zip(cols, NARROW_SCHEMA)], schema=NARROW_SCHEMA)
+    return WriteRequest(rb, TimeRange.new(min(cols[3]), max(cols[3]) + 1))
+
+
+async def open_narrow_storage(runtimes, route: str):
+    """One segment that takes `route`: one SST for `presorted`, two
+    interleaved ones with duplicate keys across them otherwise."""
+    s = await CloudObjectStorage.open(
+        "db", SEGMENT_MS, MemoryObjectStore(), NARROW_SCHEMA, 4,
+        storage_config(decode={"mode": "device"}), runtimes=runtimes)
+    rng = random.Random(SEED + 7)
+    await s.write(narrow_wreq(narrow_rows(rng, range(NARROW_TICKS))))
+    if route != "presorted":
+        # rewrites of every fourth tick (keep-last must win) and ticks
+        # the first run lacks: the concatenation is unsorted
+        await s.write(narrow_wreq(narrow_rows(
+            rng, list(range(0, NARROW_TICKS, 4))
+            + list(range(NARROW_TICKS, NARROW_TICKS + 8)), base=0.5)))
+    return s
+
+
+def narrow_spec(lo, hi, which=ALL_AGGS):
+    return AggregateSpec(group_col="host", ts_col="ts", value_col="v",
+                         range_start=lo, bucket_ms=300_000,
+                         num_buckets=-(-(hi - lo) // 300_000),
+                         which=which)
+
+
+class _PlanSpy:
+    """Records (segment handed in, what plan_dispatch made of it)."""
+
+    def __init__(self, monkeypatch):
+        self.calls = []
+        real = device_decode.plan_dispatch
+
+        def spy(es, *a, **kw):
+            got = real(es, *a, **kw)
+            self.calls.append((es, got))
+            return got
+
+        monkeypatch.setattr(device_decode, "plan_dispatch", spy)
+
+    def plans(self):
+        return [(es, got) for es, got in self.calls
+                if isinstance(got, device_decode.DecodePlan)]
+
+
+def decode_row_sides():
+    return (device_decode._DECODE_ROWS["stored"].value,
+            device_decode._DECODE_ROWS["uploaded"].value)
+
+
+def route_counts():
+    return {"presorted": device_decode._SORT_SKIPPED["compacted"].value
+            + device_decode._SORT_SKIPPED["checked"].value,
+            "kway": device_decode._SORT_SKIPPED["kway"].value,
+            "sorted": device_decode._SORT_RAN.value}
+
+
+MID = (SEGMENT_MS // 4 + 7, 3 * SEGMENT_MS // 4 + 7)
+# leaves -> (predicate, scan range, whether the upload shrinks;
+#            None = no dispatch at all)
+NARROW_LEAVES = {
+    "eq_field": (F.Eq("field", "f1"), (0, SEGMENT_MS), True),
+    "eq_field_in_tsid": (F.And((F.Eq("field", "f1"),
+                                F.In("host", ["h0", "h3", "zz"]))),
+                         (0, SEGMENT_MS), True),
+    "eq_field_time_range": (F.And((F.Eq("field", "f1"),
+                                   F.TimeRangePred("ts", *MID))),
+                            MID, True),
+    "eq_keeps_everything": (F.Eq("m", "cpu"), (0, SEGMENT_MS), False),
+    "eq_absent_from_dictionary": (F.Eq("field", "nope"),
+                                  (0, SEGMENT_MS), None),
+    "eq_zero_rows_of_present_codes": (
+        F.And((F.Eq("field", "f1"), F.Eq("host", "h5"))),
+        (0, SEGMENT_MS), None),
+}
+
+
+@pytest.mark.parametrize("leaves", sorted(NARROW_LEAVES))
+@pytest.mark.parametrize("route", ["presorted", "kway", "sorted"])
+def test_narrowed_dispatch_is_byte_equal(runtimes, monkeypatch, route,
+                                         leaves):
+    """A dispatch narrowed on host to its Eq/In leaves' rows gives the
+    grids of the same dispatch un-narrowed (every stored row uploaded
+    and masked on the device) and of the host-decode control, on every
+    route."""
+    pred, (lo, hi), shrinks = NARROW_LEAVES[leaves]
+    if route == "sorted":  # decline the k-way merge: the full sort runs
+        monkeypatch.setattr(device_decode, "_KWAY_MAX_RUNS", 1)
+
+    async def go():
+        s = await open_narrow_storage(runtimes, route)
+        try:
+            spec = narrow_spec(lo, hi)
+            req = ScanRequest(range=TimeRange.new(lo, hi), predicate=pred)
+            with _ForceXlaAgg():
+                clear_caches(s)
+                sides0, routes0 = decode_row_sides(), route_counts()
+                narrowed = await s.scan_aggregate(req, spec)
+                sides1, routes1 = decode_row_sides(), route_counts()
+                with monkeypatch.context() as m:
+                    m.setattr(device_decode, "_narrow_to_key_leaves",
+                              lambda es, *_a: es)
+                    clear_caches(s)
+                    whole = await s.scan_aggregate(req, spec)
+                sides2 = decode_row_sides()
+                clear_caches(s)
+                s.config.scan.decode.mode = "host"
+                host = await s.scan_aggregate(req, spec)
+            stored = sides1[0] - sides0[0]
+            uploaded = sides1[1] - sides0[1]
+            if shrinks is None:
+                assert (stored, uploaded) == (0, 0)
+                assert routes1 == routes0
+            else:
+                assert stored > 0
+                assert (uploaded < stored) == shrinks
+                assert routes1[route] == routes0[route] + 1, \
+                    (routes0, routes1)
+            if leaves != "eq_absent_from_dictionary":
+                # the control leg dispatched every stored row
+                assert sides2[0] - sides1[0] == sides2[1] - sides1[1] > 0
+            _assert_same(narrowed, whole, f"{route} {leaves} un-narrowed")
+            _assert_same(narrowed, host, f"{route} {leaves} host")
+            if shrinks is not None:
+                assert len(narrowed[0]) > 0
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_capacity_follows_key_leaves_not_the_window(runtimes, monkeypatch):
+    """Two windows at different offsets over one segment, the same
+    Eq leaf: range leaves stay on the device, so both plans have one
+    capacity and the second compiles nothing."""
+    spy = _PlanSpy(monkeypatch)
+
+    async def go():
+        s = await open_narrow_storage(runtimes, "presorted")
+        try:
+            sizes = []
+            with _ForceXlaAgg():
+                for off in (0, 11 * 60_000):
+                    lo, hi = MID[0] + off, MID[1] + off
+                    req = ScanRequest(
+                        range=TimeRange.new(lo, hi),
+                        predicate=F.And((F.Eq("field", "f2"),
+                                         F.TimeRangePred("ts", lo, hi))))
+                    clear_caches(s)
+                    await s.scan_aggregate(req, narrow_spec(
+                        lo, hi, which=("avg",)))
+                    sizes.append(
+                        device_decode._decode_aggregate_jit._cache_size())
+            return sizes
+        finally:
+            await s.close()
+
+    sizes = run(go())
+    (es_a, plan_a), (es_b, plan_b) = spy.plans()
+    assert plan_a.cap == plan_b.cap < encode.pad_capacity(es_a.n)
+    assert plan_a.es.n == plan_b.es.n  # the field's rows, whatever the window
+    assert plan_a.consts[1].tolist() != plan_b.consts[1].tolist()
+    assert sizes[1] == sizes[0]
+
+
+def test_decode_rows_counter_and_unselective_plan(runtimes, monkeypatch):
+    """scan_decode_rows_total counts each planned dispatch's stored and
+    uploaded rows; a plan with no Eq/In leaf, or one that stays in its
+    capacity bucket, reports equal sides and uploads the arrays it was
+    handed."""
+    spy = _PlanSpy(monkeypatch)
+
+    async def go():
+        s = await open_narrow_storage(runtimes, "presorted")
+        try:
+            out = []
+            with _ForceXlaAgg():
+                for pred in (F.Eq("field", "f0"), None,
+                             F.In("field", ["f0", "f1", "f2"])):
+                    req = ScanRequest(range=TimeRange.new(0, SEGMENT_MS),
+                                      predicate=pred)
+                    before = decode_row_sides()
+                    rows0 = decode_rows()
+                    clear_caches(s)
+                    await s.scan_aggregate(
+                        req, narrow_spec(0, SEGMENT_MS, which=("max",)))
+                    after = decode_row_sides()
+                    out.append((after[0] - before[0],
+                                after[1] - before[1],
+                                decode_rows() - rows0))
+            return out
+        finally:
+            await s.close()
+
+    selective, no_leaf, same_bucket = run(go())
+    (es0, p0), (es1, p1), (es2, p2) = spy.plans()
+    stored = es0.n
+    per_field = (NARROW_HOSTS - 1) * NARROW_TICKS
+    assert stored == per_field * NARROW_FIELDS + NARROW_TICKS
+    # ops-metric parity: the stage's rows stay the stored rows
+    assert selective == (stored, per_field, stored)
+    assert p0.es is not es0 and p0.es.n == per_field
+    assert p0.src_rows == p0.n_valid == stored
+    assert all(len(a) == per_field for a in p0.es.columns.values())
+    for got, (es, plan) in ((no_leaf, (es1, p1)),
+                            (same_bucket, (es2, p2))):
+        assert got == (stored, stored, stored)
+        assert plan.es is es and plan.cap == encode.pad_capacity(stored)
+    assert 'scan_decode_rows_total{side="uploaded"}' in registry.render()
 
 
 # ---------------------------------------------------------------------------

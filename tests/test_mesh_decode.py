@@ -269,6 +269,55 @@ def test_mesh_decode_vs_both_controls_bit_identity(runtimes):
         run(go())
 
 
+def test_round_over_narrowed_plans_of_unequal_kept_counts(runtimes,
+                                                          monkeypatch):
+    """Plans narrowed on host to their Eq leaf's rows
+    (ops/device_decode.plan_dispatch) keep one static_key, so they
+    still stack into ONE round, which pads every slot to its largest
+    narrowed capacity: grids byte-equal to the single-device dispatches
+    and to host decode."""
+    kept = (10, 100, 200, 30)
+    rounds: list = []
+    real_round = read_mod.ParquetReader._run_mesh_decode_round
+
+    def spy(self, chunk, spec):
+        rounds.append([(dp.src_rows, dp.es.n, dp.cap) for _s, dp in chunk])
+        return real_round(self, chunk, spec)
+
+    monkeypatch.setattr(read_mod.ParquetReader, "_run_mesh_decode_round",
+                        spy)
+
+    async def go():
+        s = await open_storage(MemoryObjectStore(), runtimes)
+        try:
+            rng = random.Random(SEED + 5)
+            for seg, n in enumerate(kept):
+                step = (SEGMENT_MS - 1000) // 300
+                rows = [(f"k{1 + i % 5}", seg * SEGMENT_MS + i * step,
+                         float(rng.randint(0, 10**6)))
+                        for i in range(300)]
+                rows += [("k0", seg * SEGMENT_MS + 3 + i * step,
+                          float(rng.randint(0, 10**6)))
+                         for i in range(n)]
+                await s.write(wreq(rows))
+            lo, hi = 0, len(kept) * SEGMENT_MS
+            req = ScanRequest(range=TimeRange.new(lo, hi),
+                              predicate=F.Eq("k", "k0"))
+            got = await _query_three(
+                s, req, agg_spec(lo, hi, which=ALL_AGGS), ctx="narrowed")
+            assert got[0].tolist() == ["k0"]
+        finally:
+            await s.close()
+
+    with _ForceXlaAgg():
+        run(go())
+    assert rounds, "no fused-decode round ran"
+    for chunk in rounds:  # warm and cold: the same one round
+        assert [(src, n) for src, n, _cap in chunk] \
+            == [(300 + n, n) for n in kept]
+        assert sorted({cap for _src, _n, cap in chunk}) == [128, 256]
+
+
 def test_additive_topk_identity_device_served(runtimes):
     """count/sum/avg rankings ride the compensated (hi, lo) device
     score plane: each query must be DEVICE-served (the mesh top-k

@@ -38,6 +38,7 @@ HOUR = 3_600_000
 QUERY = {"metric": "cpu", "start": T0 + 7, "end": T0 + 3 * HOUR + 7,
          "bucket_ms": 600_000}
 
+DECODE_FN = "_decode_aggregate_jit"
 ROUTES = {
     # route -> (environment, phases a query of it has)
     "fused_acc": ({"HORAEDB_FUSED_AGG": "1", "HORAEDB_HOST_AGG": "0"},
@@ -54,6 +55,24 @@ def run(coro):
 def phase_counts(table: str) -> dict:
     fam = registry.family("scan_phase_seconds")
     return {p: fam.labels(phase=p, table=table).count for p in SCAN_PHASES}
+
+
+def sync_seams(fn: str) -> int:
+    """How often the sync seam of `fn` was passed: every pass observes
+    device_exec_seconds{fn} once, the wait it found or 0."""
+    return registry.family("device_exec_seconds").labels(fn=fn).count
+
+
+def assert_sync_seam_per_dispatch(seams: int, dispatches, waits):
+    """The device-decode route passes `deviceprof.download`'s sync seam
+    once per dispatch.  By that seam's contract a dispatch still
+    running at its finalize leaves a `scan.device_wait` span and one
+    that has finished (XLA-CPU over a few hundred rows) an observation
+    of 0 and no span: which of the two is the device's pace, that one
+    of them happened per dispatch is the route's."""
+    assert dispatches and seams == len(dispatches)
+    assert len(waits) <= seams
+    assert all(w["fields"]["fn"] == DECODE_FN for w in waits)
 
 
 def walk(node):
@@ -98,6 +117,7 @@ class TestPhaseSpans:
             assert r.status == 200
             before = phase_counts("data")
             index_before = phase_counts("index")
+            seams_before = sync_seams(DECODE_FN)
             r = await client.post("/query", json=dict(body,
                                                       start=T0 + 9))
             assert r.status == 200
@@ -105,15 +125,25 @@ class TestPhaseSpans:
             tree = (await (await client.get(
                 f"/debug/traces/{tid}")).json())["tree"]
             return tree, before, phase_counts("data"), index_before, \
-                phase_counts("index")
+                phase_counts("index"), sync_seams(DECODE_FN) - seams_before
 
-        tree, before, after, index_before, index_after = run(served(go))
+        tree, before, after, index_before, index_after, seams = \
+            run(served(go))
         top = {c["name"]: c for c in tree["children"]}
         assert {"parse", "resolve", "downsample", "respond"} <= set(top)
         ds = top["downsample"]
         lo, hi = ds["start_ms"], ds["start_ms"] + ds["duration_ms"]
         phases = [c for c in ds["children"] if c["name"] in SCAN_PHASES]
-        assert {c["name"] for c in phases} == want_phases
+        got_phases = {c["name"] for c in phases}
+        if route == "device_decode":
+            # the wait seam, span or not: once per dispatch
+            assert_sync_seam_per_dispatch(
+                seams,
+                [c for c in phases if c["name"] == "scan.dispatch"],
+                [c for c in phases if c["name"] == "scan.device_wait"])
+            assert got_phases | {"scan.device_wait"} == want_phases
+        else:
+            assert got_phases == want_phases
         for c in phases:
             # starts are wall clock, durations perf_counter: 1 ms slack
             assert lo - 1.0 <= c["start_ms"]
@@ -140,6 +170,59 @@ class TestPhaseSpans:
         for p in SCAN_PHASES:
             assert after[p] - before[p] == sum(
                 1 for c in data_spans if c["name"] == p), p
+
+    def test_narrowing_runs_inside_group_prep_and_adds_no_span(
+            self, monkeypatch):
+        """The device-decode dispatch's host narrowing (a segment cut
+        to its key leaves' rows before the upload) is charged to the
+        `scan.group_prep` span that already wraps plan_dispatch: one
+        such span a segment as before, no span of a new name, and the
+        `scan.dispatch` span beside it carries the smaller upload."""
+        for k, v in ROUTES["device_decode"][0].items():
+            monkeypatch.setenv(k, v)
+        calls = []
+        real = device_decode._narrow_to_key_leaves
+
+        def timed(es, *a):
+            t0 = time.time() * 1e3
+            out = real(es, *a)
+            calls.append((t0, time.time() * 1e3, es.n, out.n))
+            return out
+
+        monkeypatch.setattr(device_decode, "_narrow_to_key_leaves", timed)
+
+        async def go(client, _engine):
+            body = dict(QUERY, filters={"host": "h1"})
+            r = await client.post("/query", json=body)  # compiles
+            assert r.status == 200
+            del calls[:]
+            r = await client.post("/query", json=dict(body,
+                                                      start=T0 + 9))
+            assert r.status == 200
+            return (await (await client.get(
+                f"/debug/traces/{r.headers['X-Trace-Id']}")).json())["tree"]
+
+        tree = run(served(go))
+        ds, = [c for c in tree["children"] if c["name"] == "downsample"]
+        under = list(walk(ds))
+        assert {c["name"] for c in under} \
+            <= set(SCAN_PHASES) | {"downsample", "pool_hop"}
+        # plan_dispatch's spans: the group_prep ones that carry rows
+        preps = sorted((c for c in under if c["name"] == "scan.group_prep"
+                        and "rows" in c["fields"]),
+                       key=lambda c: c["start_ms"])
+        dispatches = sorted((c for c in under
+                             if c["name"] == "scan.dispatch"),
+                            key=lambda c: c["start_ms"])
+        assert len(calls) == len(preps) == len(dispatches) >= 1
+        for (t0, t1, stored, kept), prep, disp in zip(
+                sorted(calls), preps, dispatches):
+            assert prep["fields"]["rows"] == stored and kept * 4 == stored
+            # starts are wall clock, durations perf_counter: 1 ms slack
+            assert prep["start_ms"] - 1.0 <= t0
+            assert t1 <= prep["start_ms"] + prep["duration_ms"] + 1.0
+            assert disp["fields"]["h2d_bytes"] == 6 * 4 * 128 \
+                < 6 * 4 * stored
 
     def test_close_clears_the_tables_phase_children(self):
         async def go(client, _engine):
@@ -179,6 +262,7 @@ class TestProfilerClock:
                                      profiler_options=opts)
             try:
                 trace = recorder.start("profiled")
+                seams_before = sync_seams(DECODE_FN)
                 with trace_scope(trace):
                     # another range: the parts memo must not serve it
                     await engine.query_downsample(
@@ -187,11 +271,12 @@ class TestProfilerClock:
                         QUERY["bucket_ms"])
                     await asyncio.gather(held("interleaved.a", 0.03),
                                          held("interleaved.b", 0.06))
-                return recorder.finish(trace)
+                return recorder.finish(trace), \
+                    sync_seams(DECODE_FN) - seams_before
             finally:
                 jax.profiler.stop_trace()
 
-        done = run(served(go))
+        done, seams = run(served(go))
         spans = {}
         for s in done["spans"]:
             spans.setdefault(s["name"], []).append(s)
@@ -208,12 +293,17 @@ class TestProfilerClock:
         root_span, = spans["profiled"]
         want = {"downsample", "interleaved.a", "interleaved.b"} \
             | set(SCAN_PHASES)
-        assert want <= set(events)
+        # every phase is on both clocks; `scan.device_wait` as often as
+        # a dispatch was still running at its finalize, the seam passed
+        # once per dispatch either way
+        assert_sync_seam_per_dispatch(seams, spans["scan.dispatch"],
+                                      spans.get("scan.device_wait", []))
+        assert want - {"scan.device_wait"} <= set(events)
         for name in want:
             got = sorted((ev.start_ns - root_ev.start_ns) / 1e6
-                         for ev in events[name])
+                         for ev in events.get(name, []))
             rec = sorted(s["start_ms"] - root_span["start_ms"]
-                         for s in spans[name])
+                         for s in spans.get(name, []))
             assert len(got) == len(rec), name
             for g, r in zip(got, rec):
                 assert abs(g - r) < 5.0, (name, g, r)
@@ -336,6 +426,39 @@ class TestSeams:
         # as before the scopes: at most a program a segment for the
         # first query, and none for its repeats
         assert sizes[0][0] <= 2 and sizes[1:] == [[0], [0]]
+
+    def test_download_waits_in_a_span_only_where_something_runs(self):
+        """`deviceprof.download`'s sync half: a computation still
+        running at the download leaves one `scan.device_wait` span and
+        its wait in device_exec_seconds{fn}; one that has finished an
+        observation of 0 and no span; the `scan.d2h` span either way."""
+        import jax.numpy as jnp
+
+        @jax.jit
+        def slow(x):  # ~70 ms on XLA-CPU against ~0.1 ms to get here
+            return jax.lax.fori_loop(0, 30, lambda _i, a: jnp.tanh(a @ a),
+                                     x)
+
+        x = jnp.full((512, 512), 0.01, jnp.float32)
+        jax.block_until_ready(slow(x))  # compiles
+        hist = registry.family("device_exec_seconds").labels(fn="slow")
+
+        def downloaded(y):
+            n0, s0 = hist.count, hist.sum
+            trace = recorder.start("seam")
+            with trace_scope(trace):
+                deviceprof.download(y, fn="slow", table="seam_t")
+            names = [s["name"] for s in recorder.finish(trace)["spans"]]
+            return names, hist.count - n0, hist.sum - s0
+
+        running = slow(x)
+        assert not running.is_ready()
+        names, n, waited = downloaded(running)
+        assert names.count("scan.device_wait") == 1 == n
+        assert names.count("scan.d2h") == 1 and waited > 0.0
+        names, n, waited = downloaded(running)  # ready by now
+        assert "scan.device_wait" not in names and n == 1
+        assert names.count("scan.d2h") == 1 and waited == 0.0
 
     def test_fused_programs_compile_once_under_their_scopes(
             self, monkeypatch):
