@@ -20,7 +20,12 @@ landing right after a flush reads nothing from the store at all.
 Correctness is structural, exactly like tier 1: SST ids are immutable
 and never reused, so an entry can never be stale.  Entries hold the
 columns of ONE complete SST — block-pruned partial loads are never
-admitted (they are row subsets tied to one predicate).
+admitted (they are row subsets tied to one predicate).  What such a
+load learns of the SST itself is kept, though: its footer
+(sidecar.SstFooter: parsed header, block statistics, dictionaries; a
+few KB against a part's MBs), by the same id, charged to the same
+budget and dropped with the SST, so that a store many times this cache
+pays a point query's column ranges and not its metadata again.
 
 The cache also owns the negative path: SST ids known to lack a usable
 sidecar (pre-feature files, failed best-effort writes) are memoized
@@ -63,6 +68,14 @@ _INVALIDATED = registry.counter(
 _BYTES = registry.gauge(
     "scan_cache_bytes",
     "resident cache bytes by tier (host RAM)").labels(tier="tier2")
+# the footers kept beside the parts: their own label, so that
+# tier="tier2" stays "a segment's rows served from host RAM"
+_FOOTER_HITS = registry.counter(
+    "scan_cache_hits_total",
+    "scan cache hits by tier").labels(tier="tier2_footer")
+_FOOTER_MISSES = registry.counter(
+    "scan_cache_misses_total",
+    "scan cache misses by tier").labels(tier="tier2_footer")
 
 # negative-entry bound: clear-all on overflow (re-learning a miss costs
 # one GET; unbounded growth costs RAM forever)
@@ -128,6 +141,8 @@ class EncodedSegmentCache:
         # sst_id -> (cols dict, n_rows, charged bytes)
         self._entries: "OrderedDict[int, tuple[dict, int, int]]" = \
             OrderedDict()
+        # sst_id -> (sidecar.SstFooter, charged bytes)
+        self._footers: "OrderedDict[int, tuple]" = OrderedDict()
         self._total_bytes = 0
         self._missing: set[int] = set()
         self._failed_assemblies: set[frozenset] = set()
@@ -136,6 +151,8 @@ class EncodedSegmentCache:
         self.admissions = 0
         self.evictions = 0
         self.invalidated = 0
+        self.footer_hits = 0
+        self.footer_misses = 0
 
     @property
     def enabled(self) -> bool:
@@ -177,6 +194,34 @@ class EncodedSegmentCache:
         read that follows does the counting)."""
         entry = self._entries.get(sst_id)
         return entry is not None and set(want) <= entry[0].keys()
+
+    def get_footer(self, sst_id: int):
+        """The SST's footer as far as earlier pruned loads fetched it
+        (sidecar.load_sst_encoded fills it further), or None."""
+        entry = self._footers.get(sst_id)
+        if entry is None:
+            self.footer_misses += 1
+            _FOOTER_MISSES.inc()
+            return None
+        self._footers.move_to_end(sst_id)
+        self.footer_hits += 1
+        _FOOTER_HITS.inc()
+        return entry[0]
+
+    def put_footer(self, sst_id: int, footer) -> None:
+        """Keep (or re-charge, after a load added sections to it) the
+        footer of one SST."""
+        if not self.enabled:
+            return
+        old = self._footers.pop(sst_id, None)
+        if old is not None:
+            self._account(-old[1])
+        nbytes = footer.nbytes
+        if nbytes > self.max_bytes:
+            return
+        self._footers[sst_id] = (footer, nbytes)
+        self._account(nbytes)
+        self._evict()
 
     def put(self, sst_id: int, cols: dict, n_rows: int) -> None:
         """Read-path insert of a COMPLETE part (all rows of the SST for
@@ -222,8 +267,19 @@ class EncodedSegmentCache:
         self._entries[sst_id] = (cols, n_rows, nbytes)
         self._account(nbytes)
         self._missing.discard(sst_id)
-        while self._total_bytes > self.max_bytes and self._entries:
-            _, (_, _, evicted) = self._entries.popitem(last=False)
+        self._evict()
+
+    def _evict(self) -> None:
+        """Back under the budget: parts in LRU order, and footers only
+        once no part is left (a footer is a part's ten-thousandth and
+        costs a pruned load eight store calls to learn again)."""
+        while self._total_bytes > self.max_bytes:
+            if self._entries:
+                _, (_, _, evicted) = self._entries.popitem(last=False)
+            elif self._footers:
+                _, (_, evicted) = self._footers.popitem(last=False)
+            else:
+                return
             self._account(-evicted)
             self.evictions += 1
             _EVICTIONS.inc()
@@ -242,6 +298,9 @@ class EncodedSegmentCache:
             if entry is not None:
                 self._account(-entry[2])
                 n += 1
+            footer = self._footers.pop(sid, None)
+            if footer is not None:
+                self._account(-footer[1])
             self._missing.discard(sid)
         if n:
             self.invalidated += n
@@ -255,6 +314,7 @@ class EncodedSegmentCache:
         record broken OBJECTS, not cache state."""
         self._account(-self._total_bytes)
         self._entries.clear()
+        self._footers.clear()
         self._failed_assemblies.clear()
 
     def _account(self, delta: int) -> None:
@@ -303,6 +363,9 @@ class EncodedSegmentCache:
             "admissions": self.admissions,
             "evictions": self.evictions,
             "invalidated": self.invalidated,
+            "footers": len(self._footers),
+            "footer_hits": self.footer_hits,
+            "footer_misses": self.footer_misses,
             "negative_entries": len(self._missing),
             "failed_assemblies": len(self._failed_assemblies),
         }

@@ -1409,13 +1409,11 @@ class ParquetReader:
             return None
         want = set(seg.columns) | {lf.column
                                    for lf in plan.prune_leaves or []}
-        parts = []
-        for f in seg.ssts:
-            part = self.encoded_cache.get(f.id, want)
-            if part is None:
-                return None
-            parts.append(part)
-        return parts
+        if not all(self.encoded_cache.peek(f.id, want) for f in seg.ssts):
+            # the full fetch path probes again and counts the misses:
+            # counted here too, every miss would read as two
+            return None
+        return [self.encoded_cache.get(f.id, want) for f in seg.ssts]
 
     def _assemble_resident_segment(self, seg: SegmentPlan, parts: list,
                                    plan: ScanPlan
@@ -1493,7 +1491,8 @@ class ParquetReader:
         got = await asyncio.gather(*(
             sidecar.load_sst_encoded(
                 self.store, sidecar.sidecar_path(self.root_path, f.id),
-                want, leaves, runner=runner)
+                want, leaves, runner=runner,
+                footers=self.encoded_cache, sst_id=f.id)
             for _i, f in fetch), return_exceptions=True)
         for (i, f), res in zip(fetch, got):
             if isinstance(res, NotFoundError):

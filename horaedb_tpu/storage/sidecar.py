@@ -37,7 +37,8 @@ import asyncio
 import dataclasses
 import json
 import struct
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field
 from typing import Optional
 
 import numpy as np
@@ -47,6 +48,7 @@ from horaedb_tpu.common.error import Error
 from horaedb_tpu.objstore import NotFoundError
 from horaedb_tpu.ops import encode
 from horaedb_tpu.storage.types import RESERVED_COLUMN_NAME
+from horaedb_tpu.utils import registry
 
 _MAGIC = b"HDTPENC1"
 _VERSION = 1
@@ -614,6 +616,67 @@ _HEAD_BYTES = 64 << 10
 # which must stay under this at the common 4-8 block SST sizes)
 _PARTIAL_MAX_FRAC = 0.5
 
+# what a load fetched against what the SST holds, and where its time
+# went: counters, not a span per GET (a point query over a large store
+# makes a dozen ranged reads a segment)
+_LOAD_ROWS = {
+    side: registry.counter(
+        "sidecar_load_rows_total",
+        "rows a sidecar load fetched from the store, and rows of the "
+        "SSTs it was for").labels(side=side)
+    for side in ("fetched", "stored")
+}
+_LOAD_SECONDS = {
+    step: registry.histogram(
+        "sidecar_load_seconds",
+        "seconds a sidecar load awaited, by step: the header probe, "
+        "block statistics, dictionaries, column bytes, deserialize; "
+        "the steps of one load overlap").labels(step=step)
+    for step in ("head", "stats", "dict", "columns", "deserialize")
+}
+
+
+async def _timed(step: str, aw):
+    t0 = time.perf_counter()
+    try:
+        return await aw
+    finally:
+        _LOAD_SECONDS[step].observe(time.perf_counter() - t0)
+
+
+@dataclass
+class SstFooter:
+    """What a pruned load learns of an SST beside its column bytes:
+    the parsed header, and the small sections (block statistics,
+    dictionaries) and decoded encodings it has fetched so far.  The
+    SST is immutable, so none of it can go stale; the tier-2 cache
+    keeps it by SST id (EncodedSegmentCache.get_footer / put_footer)
+    and the next pruned load of the SST then costs its column ranges
+    only.  Loop-owned, like the cache: loads of one SST that overlap
+    share the two dicts and fill them between awaits."""
+
+    header: dict
+    data_start: int
+    header_len: int
+    sections: dict = field(default_factory=dict)
+    encodings: dict = field(default_factory=dict)
+
+    @property
+    def fetched(self) -> int:
+        """How many sections and encodings the loads have filled in."""
+        return len(self.sections) + len(self.encodings)
+
+    @property
+    def nbytes(self) -> int:
+        total = self.header_len + sum(
+            len(raw) for raw in self.sections.values())
+        for enc in self.encodings.values():
+            d = enc.dictionary
+            if d is not None and d.dtype == object:
+                # decoded objects live beside their raw blob section
+                total += int(d.nbytes) + sum(len(v) for v in d)
+        return total
+
 
 def _block_mask_for_leaf(leaf, enc, mins: np.ndarray,
                          maxs: np.ndarray) -> Optional[np.ndarray]:
@@ -667,23 +730,26 @@ class _Sections:
     cache, so a dictionary needed by both the pruning loop and the
     column load downloads once."""
 
-    def __init__(self, store, path: str, data_start: int):
+    def __init__(self, store, path: str, data_start: int,
+                 footer: Optional[SstFooter] = None):
         self.store = store
         self.path = path
         self.data_start = data_start
-        self._cache: dict = {}
+        # a footer's two dicts outlive the load (tier 2 keeps them)
+        self._cache: dict = {} if footer is None else footer.sections
         # decoded ColumnEncoding per column name — a leaf column that is
         # also a wanted column builds its (possibly large) dictionary
         # exactly once per SST load
-        self.enc_cache: dict = {}
+        self.enc_cache: dict = {} if footer is None else footer.encodings
 
-    async def fetch(self, offset: int, nbytes: int,
+    async def fetch(self, offset: int, nbytes: int, step: str,
                     cache: bool = True) -> bytes:
         key = (offset, nbytes)
         got = self._cache.get(key)
         if got is None:
             lo = self.data_start + offset
-            got = await self.store.get_range(self.path, lo, lo + nbytes)
+            got = await _timed(step, self.store.get_range(
+                self.path, lo, lo + nbytes))
             # data-column chunks pass cache=False: a streamed session
             # reads each window's disjoint ranges exactly once, and
             # pinning them would re-materialize the whole segment —
@@ -719,15 +785,15 @@ async def _dict_for(meta: dict, header: dict, secs: _Sections,
     if sec is None or dlen < 0:
         return None
     if meta.get("dict_kind") == "i64":
-        raw = await secs.fetch(offsets[sec], dlen * 8)
+        raw = await secs.fetch(offsets[sec], dlen * 8, "dict")
         return np.frombuffer(raw, dtype=np.int64, count=dlen)
     if meta.get("dict_kind") == "blob":
-        raw = await secs.fetch(offsets[sec], (dlen + 1) * 4)
+        raw = await secs.fetch(offsets[sec], (dlen + 1) * 4, "dict")
         offs = np.frombuffer(raw, dtype=np.int32, count=dlen + 1)
         if len(offs) == 0 or int(offs[0]) != 0 \
                 or bool(np.any(offs[1:] < offs[:-1])):
             return None  # wrapped/corrupt offsets: invalid, not garbage
-        blob = await secs.fetch(offsets[sec + 1], int(offs[-1]))
+        blob = await secs.fetch(offsets[sec + 1], int(offs[-1]), "dict")
         if len(blob) < int(offs[-1]):
             return None  # truncated object
         is_binary = meta["arrow"] == "binary"
@@ -762,7 +828,8 @@ async def _encoding_for(meta: dict, header: dict, secs: _Sections,
 
 
 async def load_sst_encoded(store, path: str, want: set,
-                           leaves: Optional[list], runner=None):
+                           leaves: Optional[list], runner=None,
+                           footers=None, sst_id=None):
     """Fetch one SST's sidecar columns as ({name: (arr, enc)}, n_rows).
 
     When the leaf conjunction is selective, per-block stats narrow the
@@ -773,35 +840,62 @@ async def load_sst_encoded(store, path: str, want: set,
     the probed head bytes) when pruning cannot help.  `runner`
     (async callable(fn, *args), e.g. a worker-pool dispatch) carries
     the CPU-bound deserialize so callers keep it off the event loop.
-    None = invalid sidecar (caller falls back to parquet);
-    NotFoundError propagates."""
+    `footers` (get_footer / put_footer by `sst_id`: the tier-2 cache)
+    keeps what a pruned load learns of the SST beside its column
+    bytes, so the next one skips the probe, the statistics and the
+    dictionaries.  None = invalid sidecar (caller falls back to
+    parquet); NotFoundError propagates."""
+    got = await _load_sst(store, path, want, leaves or [], runner,
+                          footers, sst_id)
+    if got is None:
+        return None
+    cols, n, stored = got
+    _LOAD_ROWS["fetched"].inc(n)
+    _LOAD_ROWS["stored"].inc(stored)
+    return cols, n
+
+
+async def _load_sst(store, path, want, leaves, runner, footers, sst_id):
+    """load_sst_encoded's body: (cols, rows fetched, rows stored)."""
     async def _des(buf):
         if runner is None:
             return deserialize(buf, want)
         return await runner(deserialize, buf, want)
 
-    leaves = leaves or []
+    async def _whole(buf=None):
+        if buf is None:
+            buf = await _timed("columns", store.get(path))
+        got = await _timed("deserialize", _des(buf))
+        return None if got is None else (*got, got[1])
+
     if not leaves:
         # nothing to prune with: one whole-object GET, no header probe
-        return await _des(await store.get(path))
-    head = await store.get_range(path, 0, _HEAD_BYTES)
-    if len(head) < _HEAD_BYTES:
-        # short read = the WHOLE object is already in hand; larger
-        # objects that turn out unprunable pay probe + one plain GET
-        # (the deliberate trade documented at _HEAD_BYTES — a plain
-        # GET is zero-copy on host-backed stores)
-        return await _des(head)
+        return await _whole()
+    footer = footers.get_footer(sst_id) if footers is not None else None
+    # what the footer held when this load took it (-1: nothing kept yet)
+    kept = -1 if footer is None else footer.fetched
     try:
-        span = header_span(head)
-        if span is not None and span > len(head):
-            head = bytes(head) + bytes(
-                await store.get_range(path, len(head), span))
-        parsed = _parse_header(head)
-        if parsed is None:
-            # not a (readable) header: a full read preserves the
-            # corrupt-blob fallback semantics
-            return await _des(await store.get(path))
-        header, data_start = parsed
+        if footer is None:
+            head = await _timed("head",
+                                store.get_range(path, 0, _HEAD_BYTES))
+            if len(head) < _HEAD_BYTES:
+                # short read = the WHOLE object is already in hand;
+                # larger objects that turn out unprunable pay probe +
+                # one plain GET (the deliberate trade documented at
+                # _HEAD_BYTES — a plain GET is zero-copy on host-backed
+                # stores)
+                return await _whole(head)
+            span = header_span(head)
+            if span is not None and span > len(head):
+                head = bytes(head) + bytes(await _timed(
+                    "head", store.get_range(path, len(head), span)))
+            parsed = _parse_header(head)
+            if parsed is None:
+                # not a (readable) header: a full read preserves the
+                # corrupt-blob fallback semantics
+                return await _whole()
+            footer = SstFooter(*parsed, header_len=span)
+        header, data_start = footer.header, footer.data_start
         n_rows = int(header["n_rows"])
         by_name = {m["name"]: m for m in header["columns"]}
         if any(nm not in by_name for nm in want):
@@ -811,13 +905,21 @@ async def load_sst_encoded(store, path: str, want: set,
         nblocks = -(-n_rows // BLOCK_ROWS) if n_rows else 0
         # leaf columns are always in `want` (callers build it that
         # way), so their presence was vetted by the want check above
-        prunable = (leaves and nblocks > 1
-                    and approx_bytes >= _PARTIAL_MIN_BYTES)
-        if not prunable:
-            return await _des(await store.get(path))
-        return await _load_pruned(store, path, want, leaves, runner,
-                                  header, data_start, n_rows, nblocks,
-                                  _des)
+        if nblocks <= 1 or approx_bytes < _PARTIAL_MIN_BYTES:
+            return await _whole()
+        secs = _Sections(store, path, data_start, footer)
+        ranges = await _pruned_ranges(leaves, by_name, header, secs,
+                                      n_rows, nblocks, runner)
+        got = None if ranges is None else await _load_columns(
+            by_name, header, secs, want, ranges, runner)
+        if footers is not None and footer.fetched != kept:
+            # charged once the load has fetched what it needed of the
+            # SST's small sections (a put replaces the entry's bytes);
+            # a load that found everything there re-charges nothing
+            footers.put_footer(sst_id, footer)
+        if ranges is None:
+            return await _whole()
+        return None if got is None else (*got, n_rows)
     except (KeyError, IndexError, ValueError, TypeError, struct.error):
         # a magic-valid but malformed header (bad indices, truncated
         # sections) must read as INVALID — the caller memoizes the miss
@@ -864,7 +966,8 @@ async def _leaf_block_mask(leaves, by_name, header, secs, nblocks,
     async def load(meta):
         enc, raw = await _gather_or_cancel(
             _encoding_for(meta, header, secs, runner),
-            secs.fetch(offsets[meta["bstats_section"]], nblocks * 8))
+            secs.fetch(offsets[meta["bstats_section"]], nblocks * 8,
+                       "stats"))
         return meta["name"], enc, raw
 
     by_col = {}
@@ -920,7 +1023,8 @@ async def _load_columns(by_name, header, secs, want, ranges, runner):
         base = offsets[meta["section"]]
         isz = np.dtype(dtype).itemsize
         chunks = await asyncio.gather(*(
-            secs.fetch(base + isz * lo, isz * (hi - lo), cache=False)
+            secs.fetch(base + isz * lo, isz * (hi - lo), "columns",
+                       cache=False)
             for lo, hi in ranges))
         arrs = [np.frombuffer(c, dtype=dtype) for c in chunks]
         if not arrs:
@@ -939,22 +1043,22 @@ async def _load_columns(by_name, header, secs, want, ranges, runner):
     return cols, total
 
 
-async def _load_pruned(store, path, want, leaves, runner, header,
-                       data_start, n_rows, nblocks, _des):
-    by_name = {m["name"]: m for m in header["columns"]}
-    secs = _Sections(store, path, data_start)
+async def _pruned_ranges(leaves, by_name, header, secs, n_rows, nblocks,
+                         runner):
+    """Row ranges of the blocks the leaves' statistics keep, or None
+    where pruning does not pay (no statistics, an encoding that cannot
+    be built, more than _PARTIAL_MAX_FRAC of the rows kept) and the
+    caller reads the whole object."""
     got = await _leaf_block_mask(leaves, by_name, header, secs, nblocks,
                                  runner)
     if got is None:
-        return await _des(await store.get(path))
+        return None
     mask, pruned_any = got
     kept = int(mask.sum())
     if (not pruned_any or kept == nblocks
             or kept * BLOCK_ROWS > _PARTIAL_MAX_FRAC * n_rows):
-        return await _des(await store.get(path))
-    ranges = _mask_to_ranges(mask, n_rows)
-    return await _load_columns(by_name, header, secs, want, ranges,
-                               runner)
+        return None
+    return _mask_to_ranges(mask, n_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -1016,7 +1120,7 @@ class SstStreamSession:
             lo_c, hi_c = int(codes.min()), int(codes.max())
             off = self.header["sections"][meta["dict_section"]]
             raw = await self.secs.fetch(off + 8 * lo_c,
-                                        8 * (hi_c - lo_c + 1))
+                                        8 * (hi_c - lo_c + 1), "dict")
             span = np.frombuffer(raw, dtype=np.int64,
                                  count=hi_c - lo_c + 1)
             return span[codes.astype(np.int64) - lo_c]
@@ -1045,7 +1149,7 @@ class SstStreamSession:
             return None
         raw = await self.secs.fetch(
             self.header["sections"][meta["bstats_section"]],
-            self.nblocks * 8)
+            self.nblocks * 8, "stats")
         stats = np.frombuffer(raw, dtype=np.int32, count=2 * self.nblocks)
         mins_c, maxs_c = stats[:self.nblocks], stats[self.nblocks:]
         if meta["kind"] == "offset":
