@@ -21,7 +21,14 @@ that lands it in a smaller capacity bucket: a query for one field of a
 ten-field segment uploads a tenth of the rows.  Range leaves take no
 part in it, so a plan's capacity depends on which keys a query names
 and not on where its window falls (one compiled shape per key
-selectivity, not per offset).  The program itself does:
+selectivity, not per offset).  For the same reason what a key decides
+(plan_segment: the narrowed rows, their layout and route, a
+SegmentSlice) is planned apart from what a window decides
+(plan_window), and the reader's scan cache keeps the uploaded slice
+of a whole segment on the device: a later query with the same key
+leaves dispatches from it with nothing read, narrowed or uploaded
+(storage/scan_cache.py; scan_decode_resident_total).  The program
+itself does:
 
   leaf filter   — the plan's pushed PK-leaf conjunction evaluated in
                   ENCODED space (constants pre-translated host-side via
@@ -59,6 +66,7 @@ HORAEDB_DOWNSAMPLE_IMPL knob; selected and failing, it raises.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass
 from typing import Optional
@@ -138,10 +146,34 @@ _DECODE_ROWS = {
         "segment's rows as assembled, uploaded = the rows padded and "
         "uploaded after the host narrowed the segment to what the "
         "plan's Eq/In leaves admit (equal where no such leaf put it "
-        "in a smaller capacity bucket)"
+        "in a smaller capacity bucket; 0 for a dispatch that ran from "
+        "a slice resident on the device: nothing crossed)"
     ).labels(side=side)
     for side in ("stored", "uploaded")
 }
+
+
+# the scan cache's resident slices (storage/scan_cache.py, tier hbm):
+# per segment of a device-decode plan, whether its narrowed, padded
+# upload set was found on the device
+_RESIDENT = {
+    outcome: registry.counter(
+        "scan_decode_resident_total",
+        "segments of device-decode plans by what the scan cache held "
+        "for them: hit = the narrowed, padded upload set was resident "
+        "on the device and the dispatch ran from it (nothing read, "
+        "assembled, narrowed or uploaded); miss = probed and not "
+        "found, the segment was read and uploaded; bypass = not "
+        "probed (mesh rounds group host plans, or the plan opted out "
+        "of caching)"
+    ).labels(outcome=outcome)
+    for outcome in ("hit", "miss", "bypass")
+}
+
+
+def note_resident(outcome: str, n: int = 1) -> None:
+    if n:
+        _RESIDENT[outcome].inc(n)
 
 
 def note_fallback(reason: str) -> None:
@@ -566,11 +598,14 @@ class DevicePart:
     `part` is (group_values, bucket_lo, grids) — exactly what
     `_flush_window_batch` emits — or None when the segment provably
     contributes nothing (an Eq/In constant absent from the
-    dictionary)."""
+    dictionary).  `resident` is the SegmentSlice the dispatch
+    uploaded, where the scan cache may keep it (it held every row of
+    its SSTs): the reader's loop admits it and clears the field."""
 
     part: Optional[tuple]
     n_valid: int   # post-dedup surviving rows (ops-metric parity)
     nbytes: int    # host bytes of the downloaded grids
+    resident: Optional["SegmentSlice"] = None
 
 
 class DecodeDispatch:
@@ -580,10 +615,12 @@ class DecodeDispatch:
     segment k+1's upload while segment k's kernel still runs."""
 
     __slots__ = ("outs", "n_rows", "values", "lo", "w_eff", "bucket_ms",
-                 "t_dispatch", "upload_bytes", "src_rows", "table")
+                 "t_dispatch", "upload_bytes", "src_rows", "table",
+                 "slice")
 
     def __init__(self, outs, n_rows, values, lo, w_eff, bucket_ms,
-                 t_dispatch, upload_bytes, src_rows, table=""):
+                 t_dispatch, upload_bytes, src_rows, table="",
+                 slice=None):
         self.outs = outs
         self.n_rows = n_rows
         self.values = values
@@ -594,6 +631,8 @@ class DecodeDispatch:
         self.upload_bytes = upload_bytes
         self.src_rows = src_rows
         self.table = table  # the table scanned: labels the phase spans
+        # the slice this dispatch uploaded, if the scan cache may keep it
+        self.slice = slice
 
     def finalize(self) -> DevicePart:
         t0 = time.perf_counter()
@@ -619,8 +658,11 @@ class DecodeDispatch:
                 grids["count"] > 0, lt + self.lo * self.bucket_ms, lt)
         n_rows = int(self.n_rows)
         nbytes = sum(int(a.nbytes) for a in grids.values())
-        part = DevicePart(part=(self.values, self.lo, grids),
-                          n_valid=n_rows, nbytes=nbytes)
+        part = DevicePart(
+            part=(self.values, self.lo, grids), n_valid=n_rows,
+            nbytes=nbytes,
+            resident=None if self.slice is None
+            else self.slice.resident())
         observe_decode_stage(self.t_dispatch
                              + (time.perf_counter() - t0),
                              rows=self.src_rows,
@@ -654,23 +696,27 @@ def observe_decode_stage(seconds: float, rows: int, nbytes: int) -> None:
 
 
 @dataclass
-class DecodePlan:
-    """One segment's fused dispatch, PLANNED but not yet on the device:
-    all gates passed, leaves compiled, routing decided, geometry
-    computed — no upload issued.  `execute_plan` runs it standalone on
-    the default device; the mesh scheduler instead groups compatible
-    plans (same `static_key`) into one sharded per-round program
-    (read._run_mesh_decode_round), so decode shards along the time
-    axis with the aggregation instead of serializing ahead of it."""
+class SegmentSlice:
+    """What a plan's KEY decides about one segment and no window does:
+    the segment narrowed to the rows its Eq/In leaves admit, the layout
+    of its upload, the route its rows take to sorted order, its group
+    dictionary and timestamp encoding — and, once `upload_slice` has
+    run, the padded columns and the key leaves' constants as device
+    arrays.  The scan cache (storage/scan_cache.py) keeps a slice that
+    held every row of its SSTs under (segment, SST ids, columns, key
+    leaves' values): a later query with the same key plans its window
+    against it (plan_window) and dispatches from the resident arrays,
+    with nothing read, assembled, narrowed or uploaded."""
 
-    es: object                # the segment AS UPLOADED (narrowed or not)
+    es: object                # the narrowed host segment; None on a
+    #                           slice the scan cache holds (resident())
     src_rows: int             # its rows as stored, before any narrowing
+    n: int                    # rows kept = rows uploaded
     cap: int
-    shift: int
-    lo: int
-    local_ok: bool
-    use_width: int
-    w_eff: int
+    admissible: bool          # held every row of every SST of its key
+    encodings: dict           # a window's other leaves compile against
+    ts_epoch: int
+    local_ok: bool            # timestamps are offsets from ts_epoch
     g: int
     g_pad: int
     values: object            # the group dictionary (host array)
@@ -680,20 +726,61 @@ class DecodePlan:
     group_pos: int
     ts_pos: int
     val_slot: int
-    leaf_prog: tuple
-    consts: tuple             # host int32 arrays, one per leaf
+    key_prog: tuple           # ((column, opcode), ...): the Eq/In leaves
+    key_consts: tuple         # host int32 arrays, one per key leaf
     route: str                # "presorted" | "kway" | "sorted"
+    sort_skipped: Optional[str]   # scan_decode_sort_skipped_total's route
     run_offsets: Optional[np.ndarray]
     num_runs: int
+    cols_dev: Optional[tuple] = None
+    key_consts_dev: tuple = ()
+    offs_dev: object = None
+
+    @property
+    def nbytes(self) -> int:
+        """Device bytes of the padded columns: the HBM admission gate's
+        and the scan cache's charge."""
+        return self.cap * 4 * len(self.upload_names)
+
+    def resident(self) -> "SegmentSlice":
+        """The slice as the scan cache keeps it: the device arrays and
+        the layout, not the host columns they were padded from."""
+        return dataclasses.replace(self, es=None)
+
+
+@dataclass
+class DecodePlan:
+    """One segment's fused dispatch, PLANNED but not yet on the device:
+    all gates passed, leaves compiled, routing decided, geometry
+    computed — no upload issued.  `seg` is what the plan's key decided
+    (read through: `plan.cap`, `plan.es`, `plan.route`, ...), the rest
+    what this query's window did.  `execute_plan` runs it standalone on
+    the default device; the mesh scheduler instead groups compatible
+    plans (same `static_key`) into one sharded per-round program
+    (read._run_mesh_decode_round), so decode shards along the time
+    axis with the aggregation instead of serializing ahead of it."""
+
+    seg: SegmentSlice
+    shift: int
+    lo: int
+    use_width: int
+    w_eff: int
+    leaf_prog: tuple          # ((upload slot, opcode), ...), every leaf
+    consts: tuple             # host int32 arrays, one per leaf
     which: tuple
     bucket_ms: int
     num_buckets: int
+
+    def __getattr__(self, name: str):
+        if name == "seg":  # unset (copy/pickle protocols): no recursion
+            raise AttributeError(name)
+        return getattr(self.seg, name)
 
     @property
     def n_valid(self) -> int:
         # windows-list accounting parity (DeviceBatch/DevicePart ride
         # the same lists): source rows, pre-filter/dedup
-        return self.src_rows
+        return self.seg.src_rows
 
     def static_key(self) -> tuple:
         """Everything that must match for two plans to share one
@@ -706,39 +793,50 @@ class DecodePlan:
                 self.which)
 
 
-def plan_dispatch(es, spec, pk_names: list, seq_name: str,
-                  leaves, max_bytes: int, width: int,
-                  pad_capacity) -> "DecodePlan | DevicePart | str":
-    """Validate one EncodedSegment against the fused program's layout,
-    narrow it to what its key leaves admit (_narrow_to_key_leaves) and
-    plan its dispatch WITHOUT touching the device.  Returns a
-    DecodePlan (ready to execute or to join a mesh round), a DevicePart
-    (provably-empty segment, no dispatch), or a fallback reason string
-    (the caller counts it and takes the host path)."""
+def _is_key_leaf(leaf) -> bool:
+    return isinstance(leaf, (filter_ops.Eq, filter_ops.In))
+
+
+def key_leaves_token(leaves) -> tuple:
+    """The leaves' part of a resident slice's cache key, in leaf order:
+    an Eq/In leaf in full (its VALUES, not their codes: the probe runs
+    before any dictionary is read), any other leaf as its kind and
+    column (which decide the upload set and the program; its constants
+    are the window's)."""
+    return tuple(
+        filter_ops.canonical_predicate_key(leaf) if _is_key_leaf(leaf)
+        else f"({type(leaf).__name__.lower()} {leaf.column})"
+        for leaf in leaves or [])
+
+
+def plan_segment(es, group_col: str, ts_col: str, value_col: str,
+                 pk_names: list, seq_name: str, leaves, max_bytes: int,
+                 pad_capacity) -> "SegmentSlice | DevicePart | str":
+    """The half of plan_dispatch that a plan's key decides: validate
+    one EncodedSegment against the fused program's layout, narrow it to
+    what its Eq/In leaves admit (_narrow_to_key_leaves), lay out its
+    upload and choose its route, WITHOUT touching the device."""
     encs = es.encodings
     # layout gates, cheapest first; reasons mirror FALLBACK_REASONS
-    for name in (spec.group_col, spec.ts_col, spec.value_col, seq_name,
-                 *pk_names):
+    for name in (group_col, ts_col, value_col, seq_name, *pk_names):
         if name not in es.columns:
             return "encoding"
-    ts_enc = encs[spec.ts_col]
+    ts_enc = encs[ts_col]
     if ts_enc.kind not in ("offset", "numeric"):
         return "encoding"
-    g_enc = encs[spec.group_col]
+    g_enc = encs[group_col]
     if g_enc.kind != "dict" or g_enc.dictionary is None \
             or len(g_enc.dictionary) == 0:
         return "encoding"  # codes must BE dense ids over a known space
-    if es.columns[spec.value_col].dtype != np.float32:
+    if es.columns[value_col].dtype != np.float32:
         return "dtype"
-    for name in (spec.ts_col, seq_name, *pk_names):
+    for name in (ts_col, seq_name, *pk_names):
         if es.columns[name].dtype != np.int32:
             return "dtype"
-    shift = int(ts_enc.epoch) - spec.range_start
-    if abs(shift) >= 2**31:
-        return "range"
 
     try:
-        prog, consts = compile_leaves(leaves, encs)
+        key_prog, key_consts = compile_leaves(
+            [leaf for leaf in leaves or [] if _is_key_leaf(leaf)], encs)
     except _EmptyMatch:
         return DevicePart(part=None, n_valid=0, nbytes=0)
     except (ValueError, OverflowError):
@@ -746,7 +844,8 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
     # from here on `es` is what uploads: the budget gate, the route
     # and the geometry all follow the narrowed rows
     src_rows = es.n
-    es = _narrow_to_key_leaves(es, prog, consts, pad_capacity)
+    admissible = es.whole
+    es = _narrow_to_key_leaves(es, key_prog, key_consts, pad_capacity)
     if es is None:
         return DevicePart(part=None, n_valid=0, nbytes=0)
     cap = pad_capacity(es.n)
@@ -758,14 +857,16 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
     # column and any leaf-only columns complete the upload set
     key_names = list(pk_names)
     key_names.append(seq_name)
-    for nm in (spec.group_col, spec.ts_col):
+    for nm in (group_col, ts_col):
         if nm not in key_names:
             key_names.append(nm)
     slot_of: dict = {}
     upload_names: list = []
-    for nm in key_names + [spec.value_col] \
-            + [c for c, _op in prog]:
+    for nm in key_names + [value_col] \
+            + [leaf.column for leaf in leaves or []]:
         if nm not in slot_of:
+            if nm not in es.columns:
+                return "predicate"  # a leaf on a column not read
             slot_of[nm] = len(upload_names)
             upload_names.append(nm)
     # HBM admission over the ACTUAL upload set (non-PK group/ts and
@@ -784,16 +885,15 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
     # preserved, grids byte-identical); only segments neither route
     # admits pay the device lax.sort, counted reason="kway_runs".
     route = "sorted"
+    sort_skipped = None
     run_offsets = None
     num_runs = 0
     key_arrs = [es.columns[nm] for nm in pk_names] \
         + [es.columns[seq_name]]
     if es.source_runs == 1:
-        route = "presorted"
-        _SORT_SKIPPED["compacted"].inc()
+        route, sort_skipped = "presorted", "compacted"
     elif _lex_sorted_np(key_arrs):
-        route = "presorted"
-        _SORT_SKIPPED["checked"].inc()
+        route, sort_skipped = "presorted", "checked"
     else:
         rl = getattr(es, "run_lengths", None)
         offs = None
@@ -804,80 +904,152 @@ def plan_dispatch(es, spec, pk_names: list, seq_name: str,
             if not merge_ops.runs_lex_sorted_np(key_arrs, offs):
                 offs = None
         if offs is not None:
-            route = "kway"
+            route, sort_skipped = "kway", "kway"
             # runs + the trailing pad zone as its own run, padded to a
             # power of two with empty runs (static merge-tree depth)
             num_runs = 1 << max(1, int(len(rl))).bit_length()
             run_offsets = np.full(num_runs + 1, cap, dtype=np.int32)
             run_offsets[:len(offs)] = offs
             run_offsets[len(rl)] = es.n  # real runs end at n
-            _SORT_SKIPPED["kway"].inc()
-        else:
-            note_fallback("kway_runs")
-            _SORT_RAN.inc()
-    local_ok = ts_enc.kind == "offset"
-    lo = max(0, shift // spec.bucket_ms) if local_ok else 0
-    use_width = width if local_ok else spec.num_buckets
     g = len(g_enc.dictionary)
-    g_pad = max(8, 1 << (g - 1).bit_length())
-    w_eff = min(use_width, spec.num_buckets - lo)
-    key_slots = tuple(slot_of[nm] for nm in key_names)
-    # group/ts positions INSIDE the sorted key outputs
-    group_pos = key_names.index(spec.group_col)
-    ts_pos = key_names.index(spec.ts_col)
-    leaf_prog = tuple((slot_of[c], op) for c, op in prog)
-    _DECODE_ROWS["stored"].inc(src_rows)
-    _DECODE_ROWS["uploaded"].inc(es.n)
-    return DecodePlan(
-        es=es, src_rows=src_rows, cap=cap, shift=shift, lo=lo,
-        local_ok=local_ok, use_width=use_width, w_eff=w_eff, g=g,
-        g_pad=g_pad,
+    return SegmentSlice(
+        es=es, src_rows=src_rows, n=es.n, cap=cap, admissible=admissible,
+        encodings={nm: encs[nm] for nm in upload_names},
+        ts_epoch=int(ts_enc.epoch), local_ok=ts_enc.kind == "offset",
+        g=g, g_pad=max(8, 1 << (g - 1).bit_length()),
         values=g_enc.dictionary, upload_names=upload_names,
-        key_slots=key_slots, num_pks=len(pk_names),
-        group_pos=group_pos, ts_pos=ts_pos,
-        val_slot=slot_of[spec.value_col], leaf_prog=leaf_prog,
-        consts=consts, route=route, run_offsets=run_offsets,
-        num_runs=num_runs, which=spec.which,
+        key_slots=tuple(slot_of[nm] for nm in key_names),
+        num_pks=len(pk_names),
+        # group/ts positions INSIDE the sorted key outputs
+        group_pos=key_names.index(group_col),
+        ts_pos=key_names.index(ts_col), val_slot=slot_of[value_col],
+        key_prog=key_prog, key_consts=key_consts, route=route,
+        sort_skipped=sort_skipped, run_offsets=run_offsets,
+        num_runs=num_runs)
+
+
+def plan_window(seg: SegmentSlice, spec, leaves,
+                width: int) -> "DecodePlan | DevicePart | str":
+    """The half of plan_dispatch that a query's window decides, from
+    the slice and the AggregateSpec alone (no host column is touched,
+    so it serves a slice the scan cache held as it serves a fresh
+    one): the shift to range-relative time, the first bucket, the grid
+    width, and the constants of every leaf that is not a key leaf.
+    Counts the dispatch (rows, route) once the plan stands."""
+    shift = seg.ts_epoch - spec.range_start
+    if abs(shift) >= 2**31:
+        return "range"
+    # every leaf in its own place: leaf_prog is a static argument of
+    # the program, so the order is part of which program runs
+    prog: list = []
+    consts: list = []
+    keyed = iter(zip(seg.key_prog, seg.key_consts))
+    try:
+        for leaf in leaves or []:
+            if _is_key_leaf(leaf):
+                p, c = next(keyed)
+                prog.append(p)
+                consts.append(c)
+            else:
+                p, c = compile_leaves([leaf], seg.encodings)
+                prog.extend(p)
+                consts.extend(c)
+    except _EmptyMatch:
+        return DevicePart(part=None, n_valid=0, nbytes=0)
+    except (ValueError, OverflowError):
+        return "predicate"
+    lo = max(0, shift // spec.bucket_ms) if seg.local_ok else 0
+    use_width = width if seg.local_ok else spec.num_buckets
+    if seg.sort_skipped is not None:
+        _SORT_SKIPPED[seg.sort_skipped].inc()
+    else:
+        note_fallback("kway_runs")
+        _SORT_RAN.inc()
+    _DECODE_ROWS["stored"].inc(seg.src_rows)
+    if seg.cols_dev is None:
+        _DECODE_ROWS["uploaded"].inc(seg.n)
+    return DecodePlan(
+        seg=seg, shift=shift, lo=lo, use_width=use_width,
+        w_eff=min(use_width, spec.num_buckets - lo),
+        leaf_prog=tuple((seg.upload_names.index(c), op)
+                        for c, op in prog),
+        consts=tuple(consts), which=spec.which,
         bucket_ms=spec.bucket_ms, num_buckets=spec.num_buckets)
 
 
-def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
-    """Upload one planned segment and issue its fused dispatch on the
-    default device — the single-device tail of the old prepare path
-    and the per-item fallback when a mesh round declines a plan.
-    `table` labels the phase spans of the dispatch's finalize (the
-    caller wraps this call in its `scan.dispatch` phase)."""
-    es = dp.es
-    t0 = time.perf_counter()
+def plan_dispatch(es, spec, pk_names: list, seq_name: str,
+                  leaves, max_bytes: int, width: int,
+                  pad_capacity) -> "DecodePlan | DevicePart | str":
+    """Plan one EncodedSegment's fused dispatch WITHOUT touching the
+    device: what its key decides (plan_segment), then what the query's
+    window does (plan_window).  Returns a DecodePlan (ready to execute
+    or to join a mesh round), a DevicePart (provably-empty segment, no
+    dispatch), or a fallback reason string (the caller counts it and
+    takes the host path)."""
+    seg = plan_segment(es, spec.group_col, spec.ts_col, spec.value_col,
+                       pk_names, seq_name, leaves, max_bytes,
+                       pad_capacity)
+    if not isinstance(seg, SegmentSlice):
+        return seg
+    return plan_window(seg, spec, leaves, width)
+
+
+def upload_slice(seg: SegmentSlice) -> int:
+    """Pad a slice's columns to its capacity and put them, its key
+    leaves' constants and its run bounds on the default device; the
+    bytes that crossed."""
+    es = seg.es
     upload_bytes = 0
     cols_dev = []
-    for nm in dp.upload_names:
+    for nm in seg.upload_names:
         arr = es.columns[nm]
-        padded = np.zeros(dp.cap, dtype=arr.dtype)  # calloc: tail free
+        padded = np.zeros(seg.cap, dtype=arr.dtype)  # calloc: tail free
         padded[:es.n] = arr
         upload_bytes += int(padded.nbytes)
         cols_dev.append(deviceprof.device_put(padded))
-    consts_dev = tuple(jnp.asarray(c) for c in dp.consts)
-    offs_dev = jnp.int32(0) if dp.run_offsets is None \
-        else jnp.asarray(dp.run_offsets)
+    seg.key_consts_dev = tuple(jnp.asarray(c) for c in seg.key_consts)
+    seg.offs_dev = jnp.int32(0) if seg.run_offsets is None \
+        else jnp.asarray(seg.run_offsets)
+    seg.cols_dev = tuple(cols_dev)
+    return upload_bytes
+
+
+def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
+    """Issue one planned segment's fused dispatch on the default
+    device, uploading its slice first unless that is resident — the
+    single-device tail of the old prepare path and the per-item
+    fallback when a mesh round declines a plan.  `table` labels the
+    phase spans of the dispatch's finalize (the caller wraps this call
+    in its `scan.dispatch` phase).  One call site issues the program
+    for a fresh slice and for a resident one, so both pass it
+    arguments of the same kinds and a hit compiles nothing."""
+    seg = dp.seg
+    t0 = time.perf_counter()
+    fresh = seg.cols_dev is None
+    upload_bytes = upload_slice(seg) if fresh else 0
+    keyed = iter(seg.key_consts_dev)
+    consts_dev = tuple(
+        next(keyed) if op in (_OP_EQ, _OP_IN) else jnp.asarray(c)
+        for (_slot, op), c in zip(dp.leaf_prog, dp.consts))
 
     # the Pallas partials kernel rides the same knob as the single-shot
     # aggregate (HORAEDB_DOWNSAMPLE_IMPL); selected and failing, it
     # raises — it never quietly serves the XLA program
     outs, n_rows = _decode_aggregate_jit(
-        tuple(cols_dev), es.n, consts_dev,
+        seg.cols_dev, seg.n, consts_dev,
         np.int32(dp.shift), np.int32(dp.lo),
-        np.int32(dp.num_buckets), np.int32(dp.bucket_ms), offs_dev,
-        key_slots=dp.key_slots, num_pks=dp.num_pks,
-        group_pos=dp.group_pos, ts_pos=dp.ts_pos,
-        val_slot=dp.val_slot, leaf_prog=dp.leaf_prog,
-        g_pad=dp.g_pad, width=dp.use_width, which=dp.which,
+        np.int32(dp.num_buckets), np.int32(dp.bucket_ms), seg.offs_dev,
+        key_slots=seg.key_slots, num_pks=seg.num_pks,
+        group_pos=seg.group_pos, ts_pos=seg.ts_pos,
+        val_slot=seg.val_slot, leaf_prog=dp.leaf_prog,
+        g_pad=seg.g_pad, width=dp.use_width, which=dp.which,
         use_pallas=downsample.downsample_impl() == "pallas",
-        route=dp.route, num_runs=dp.num_runs)
+        route=seg.route, num_runs=seg.num_runs)
     return DecodeDispatch(outs=outs, n_rows=n_rows,
-                          values=dp.values, lo=dp.lo, w_eff=dp.w_eff,
+                          values=seg.values, lo=dp.lo, w_eff=dp.w_eff,
                           bucket_ms=dp.bucket_ms,
                           t_dispatch=time.perf_counter() - t0,
-                          upload_bytes=upload_bytes, src_rows=dp.src_rows,
-                          table=table)
-
+                          upload_bytes=upload_bytes,
+                          src_rows=seg.src_rows, table=table,
+                          slice=seg if fresh and seg.admissible
+                          else None)

@@ -671,8 +671,11 @@ class ParquetReader:
         ledger must report residency).  Event-loop owned, like the
         cache itself."""
         total = 0
-        for windows in self.scan_cache.values():
-            for w in windows:
+        for entry in self.scan_cache.values():
+            if isinstance(entry, device_decode.SegmentSlice):
+                total += entry.nbytes  # padded device columns, no memo
+                continue
+            for w in entry:
                 total += sum(int(c.dtype.itemsize) * w.capacity
                              for c in w.columns.values())
                 total += int(w.memo_bytes)
@@ -862,10 +865,23 @@ class ParquetReader:
         from collections import deque
 
         cached: dict[int, list] = {}
+        # device-decode plans: segments whose narrowed, padded slice is
+        # resident on the device, as this window's DecodePlan over it
+        resident: dict[int, device_decode.DecodePlan] = {}
         to_read: list[SegmentPlan] = []
+        slice_columns = self._decode_slice_columns(plan)
         with self._phase("scan.windows",
                          segments=len(plan.segments)) as probe:
             for seg in plan.segments:
+                if slice_columns is not None:
+                    got = self._probe_decode_slice(seg, plan,
+                                                   slice_columns)
+                    if isinstance(got, device_decode.DecodePlan):
+                        resident[id(seg)] = got
+                        continue
+                    if got is not None:  # provably empty: no dispatch
+                        cached[id(seg)] = [got]
+                        continue
                 windows = (self.scan_cache.get(self._cache_key(seg, plan))
                            if plan.use_cache else None)
                 if windows is None:
@@ -873,6 +889,8 @@ class ParquetReader:
                 else:
                     cached[id(seg)] = windows
             probe.fields["cached"] = len(cached)
+            if slice_columns is not None:
+                probe.fields["resident"] = len(resident)
         if self.mesh is not None:
             mesh_iter = self._cached_windows_mesh(plan, cached, to_read)
             try:
@@ -883,8 +901,8 @@ class ParquetReader:
             return
         if self.pipeline_on() and self._pipeline_has_io(plan, to_read):
             plan.pipeline_active = True
-            pipe_iter = self._cached_windows_pipelined(plan, cached,
-                                                       to_read)
+            pipe_iter = self._cached_windows_pipelined(
+                plan, cached, to_read, resident, slice_columns)
             try:
                 async for out in pipe_iter:
                     yield out
@@ -903,14 +921,27 @@ class ParquetReader:
         feed = self._segment_feed(plan, to_read).__aiter__()
         pending: "deque[tuple[SegmentPlan, str, list, float]]" = deque()
         exhausted = False
+        # what the pump works through, in plan order: the segments to
+        # read (the feed's order) and, between them, the resident ones
+        work = iter([seg for seg in plan.segments
+                     if id(seg) not in cached])
 
         async def pump() -> None:
             nonlocal exhausted
-            try:
-                fseg, is_streamed, table, read_s = await feed.__anext__()
-            except StopAsyncIteration:
+            wseg = next(work, None)
+            if wseg is None:
                 exhausted = True
                 return
+            if id(wseg) in resident:
+                # nothing to read: one pool job issues the program on
+                # the resident arrays, ahead of the yield position like
+                # any other dispatch
+                pending.append((wseg, "bulk", await self._run_pool(
+                    plan.pool, self._dispatch_resident_slice,
+                    resident[id(wseg)]), 0.0))
+                return
+            fseg, is_streamed, table, read_s = await feed.__anext__()
+            assert fseg is wseg
             if is_streamed:
                 # a marker only: the actual streaming happens when this
                 # segment reaches the yield position
@@ -943,9 +974,86 @@ class ParquetReader:
                 if plan.use_cache and self._cacheable_windows(windows):
                     self.scan_cache.put(self._cache_key(seg, plan),
                                         windows)
+                self._admit_decode_slice(seg, slice_columns, windows)
                 yield seg, windows, read_s
         finally:
             await feed.aclose()
+
+    # ---- device-decode slices in the scan cache ----------------------------
+
+    def _decode_slice_columns(self, plan: ScanPlan) -> Optional[tuple]:
+        """The `columns` part of a device-decode plan's scan-cache keys
+        (ops/device_decode.SegmentSlice): the spec's columns and the
+        key leaves' token, one per plan — or None where the plan's
+        segments are neither probed nor admitted: no device-decode
+        plan, one that opted out of caching, or one whose dispatch is
+        deferred to the mesh rounds, which group host DecodePlans
+        (counted outcome="bypass")."""
+        spec = plan.decode_spec
+        if spec is None:
+            return None
+        if plan.decode_defer or self.mesh is not None \
+                or not plan.use_cache:
+            device_decode.note_resident("bypass", len(plan.segments))
+            return None
+        return ("decode", spec.group_col, spec.ts_col, spec.value_col) \
+            + device_decode.key_leaves_token(plan.prune_leaves)
+
+    def _decode_slice_key(self, seg: SegmentPlan, slice_columns: tuple):
+        from horaedb_tpu.storage.scan_cache import segment_cache_key
+
+        # the window is NOT in the key: range leaves stay with the
+        # device, so what a segment narrows to is the same rows for
+        # every window over the same key leaves.  The SST ids are: a
+        # write or a compaction changes them, and misses
+        return segment_cache_key(
+            seg.segment_start, (f.id for f in seg.ssts),
+            tuple(seg.columns) + slice_columns)
+
+    def _probe_decode_slice(self, seg: SegmentPlan, plan: ScanPlan,
+                            slice_columns: tuple):
+        """Loop-side probe for one segment of a device-decode plan: the
+        window's DecodePlan over the resident slice (a hit), a
+        DevicePart where the window's own leaves provably match
+        nothing, or None (a miss: the segment is read)."""
+        entry = self.scan_cache.get(
+            self._decode_slice_key(seg, slice_columns))
+        got = None
+        if entry is not None:
+            got = device_decode.plan_window(
+                entry, plan.decode_spec, plan.prune_leaves,
+                self._window_grid_width(plan.decode_spec))
+            if isinstance(got, str):
+                got = None  # the miss path counts the fallback
+        device_decode.note_resident("miss" if got is None else "hit")
+        return got
+
+    def _dispatch_resident_slice(self, dp: "device_decode.DecodePlan"
+                                 ) -> list:
+        """Pool-side dispatch of a hit, as _dispatch_device_decode
+        leaves a miss: one in-flight fused dispatch."""
+        with self._phase("scan.dispatch", h2d_bytes=0):
+            return [device_decode.execute_plan(dp, self.table)]
+
+    def _resident_slice_windows(self, dp: "device_decode.DecodePlan"
+                                ) -> list:
+        return self._finalize_windows(self._dispatch_resident_slice(dp))
+
+    def _admit_decode_slice(self, seg: SegmentPlan,
+                            slice_columns: Optional[tuple],
+                            windows: list) -> None:
+        """Loop-side admission of what a miss uploaded (the arrays
+        exist anyway: no copy).  Only a bulk read's one dispatch
+        carries a slice, and only where the segment held every row of
+        its SSTs (EncodedSegment.whole)."""
+        if slice_columns is None or len(windows) != 1:
+            return
+        part, = windows
+        if isinstance(part, device_decode.DevicePart) \
+                and part.resident is not None:
+            seg_slice, part.resident = part.resident, None
+            self.scan_cache.put_slice(
+                self._decode_slice_key(seg, slice_columns), seg_slice)
 
     def pipeline_on(self) -> bool:
         """Whether OVERWRITE cold scans run through the bounded
@@ -985,7 +1093,9 @@ class ParquetReader:
                    for seg in to_read)
 
     async def _cached_windows_pipelined(self, plan: ScanPlan,
-                                        cached: dict, to_read: list):
+                                        cached: dict, to_read: list,
+                                        resident: dict,
+                                        slice_columns: Optional[tuple]):
         """Pipelined twin of the pump below: fetch and decode/merge run
         as background stages (storage/pipeline.py) while this consumer
         — the device stage's doorstep — yields segments in plan order.
@@ -1003,11 +1113,20 @@ class ParquetReader:
                 if id(seg) in cached:
                     yield seg, cached[id(seg)], 0.0
                     continue
+                if id(seg) in resident:
+                    # a device-decode slice resident on the device:
+                    # dispatch and finalize in one pool job (the
+                    # stages have nothing to fetch or decode for it)
+                    yield seg, await self._run_pool(
+                        plan.pool, self._resident_slice_windows,
+                        resident[id(seg)]), 0.0
+                    continue
                 got, windows, read_s = await pipe.next_segment()
                 assert got is seg
                 if plan.use_cache and self._cacheable_windows(windows):
                     self.scan_cache.put(self._cache_key(seg, plan),
                                         windows)
+                self._admit_decode_slice(seg, slice_columns, windows)
                 yield seg, windows, read_s
         finally:
             # deterministic teardown: cancels the stage tasks and
@@ -1415,6 +1534,17 @@ class ParquetReader:
             return None
         return [self.encoded_cache.get(f.id, want) for f in seg.ssts]
 
+    @staticmethod
+    def _parts_whole(seg: SegmentPlan, parts: list) -> bool:
+        """Whether the (cols, n) parts hold every row of the segment's
+        SSTs: the test tier 2 applies before it keeps a part.  A
+        block-pruned load fails it — its blocks were chosen by the
+        plan's range leaf too, so what a device-decode dispatch
+        narrows it to belongs to one window and may not be kept under
+        a key that holds none (EncodedSegment.whole)."""
+        return all(n == f.meta.num_rows
+                   for (_cols, n), f in zip(parts, seg.ssts))
+
     def _assemble_resident_segment(self, seg: SegmentPlan, parts: list,
                                    plan: ScanPlan
                                    ) -> Optional[sidecar.EncodedSegment]:
@@ -1441,6 +1571,7 @@ class ParquetReader:
             return None
         if defer:
             es.pending_leaves = list(plan.prune_leaves or [])
+            es.whole = self._parts_whole(seg, parts)
         read_s = time.perf_counter() - t0
         _STAGE_SECONDS["sidecar_read"].observe(read_s)
         _STAGE_ROWS["sidecar_read"].inc(es.n)
@@ -1541,6 +1672,7 @@ class ParquetReader:
             es = None
         if es is not None and defer:
             es.pending_leaves = list(leaves or [])
+            es.whole = self._parts_whole(seg, parts)
         if es is None:
             # cross-SST assembly failed (e.g. an irreconcilable column
             # type across parts).  Do NOT memoize the member SSTs as
@@ -1654,6 +1786,9 @@ class ParquetReader:
         # tiny device scalars (num_buckets, bucket_ms) are HBM too on
         # accelerators; re-uploading them is part of 'HBM evicted'
         self._scalar_cache.clear()
+        # device-decode slices ARE device arrays: dropped whole (the
+        # next query reads, narrows and uploads again)
+        self.scan_cache.drop_slices()
         with _MEMO_LOCK:
             for windows in self.scan_cache.values():
                 for w in windows:
@@ -1663,6 +1798,7 @@ class ParquetReader:
     def cache_stats(self) -> dict:
         """The /stats cache section: every reader-owned cache tier's
         residency and effectiveness, one dict per tier."""
+        slices = self.scan_cache.slices()
         return {
             "scan_cache": {
                 "entries": len(self.scan_cache),
@@ -1670,6 +1806,10 @@ class ParquetReader:
                 "max_bytes": self.scan_cache.max_bytes,
                 "hits": self.scan_cache.hits,
                 "misses": self.scan_cache.misses,
+                # of those entries and bytes, the device-decode slices
+                # resident on the device (ops/device_decode.py)
+                "decode_slices": len(slices),
+                "decode_slice_bytes": sum(e.nbytes for e in slices),
             },
             "encoded_cache": self.encoded_cache.stats(),
             "parts_memo": self.parts_memo.stats(),
@@ -2840,6 +2980,12 @@ class ParquetReader:
             nonlocal flush_task
             chunk = queue[:k]
             del queue[:k]
+            if all(prep is None for _s, _w, prep in chunk):
+                # finished partials only: nothing to aggregate, so no
+                # pool job — applied here, behind any round in flight
+                await settle_flush()
+                _apply([(s, w.part) for s, w, _prep in chunk])
+                return
             if not pipelined():
                 _apply(await self._run_pool(
                     plan.pool, self._flush_window_batch, chunk, spec,
@@ -2884,10 +3030,17 @@ class ParquetReader:
                                 out.append((w, prep))
                         return out
 
-                    for w, prep in await self._run_pool(
+                    if all(isinstance(w, device_decode.DevicePart)
+                           for w in windows):
+                        # a fused dispatch's finished partials: nothing
+                        # to group, so no pool job and no phase
+                        prepped = prep_windows()
+                    else:
+                        prepped = await self._run_pool(
                             plan.pool, self._phased(
                                 "scan.group_prep", prep_windows,
-                                segment=s)):
+                                segment=s))
+                    for w, prep in prepped:
                         queue.append((s, w, prep))
                         pending[s] += 1
                     while len(queue) >= batch_w:

@@ -13,6 +13,16 @@ invalidation hooks, no staleness).  Predicates and aggregation run AFTER
 the merge, so one cached entry serves every query shape over the same
 data.
 
+A device-decode plan's segments cache differently (ops/device_decode.py):
+their rows never become windows, so the entry is the segment's
+SegmentSlice — the rows its Eq/In leaves admit, padded, ON THE DEVICE,
+with the layout and route that go with them — keyed by the same
+(segment_start, SST ids) and, as columns, the plan's columns plus the
+canonical form of those leaves.  A later query with the same key
+dispatches from it whatever its window, with nothing read, assembled,
+narrowed or uploaded.  Both kinds share the one byte budget and the one
+LRU order.
+
 Eviction is LRU by total cached BYTES — column buffers across their
 real widths plus an allowance for the per-window aggregation memos
 (each memo slot can hold a capacity-sized gid array); dropping an entry
@@ -172,6 +182,24 @@ class ScanCache(ByteLRU):
 
     def put(self, key: CacheKey, windows: list) -> None:  # type: ignore[override]
         super().put(key, windows, windows_nbytes(windows))
+
+    def put_slice(self, key: CacheKey, seg_slice) -> None:
+        """Admit a device-decode SegmentSlice, charged at the device
+        bytes of its padded columns (capacity x 4 B x columns); one
+        over the whole budget is declined like any other entry."""
+        super().put(key, seg_slice, seg_slice.nbytes)
+
+    def slices(self) -> list:
+        """The resident SegmentSlices (every entry that is not a
+        windows list), in LRU order."""
+        return [v for v in self.values() if not isinstance(v, list)]
+
+    def drop_slices(self) -> None:
+        """Release every SegmentSlice's device arrays and keep the
+        windows: the reader's HBM-evicted state (tests, benchmarks)."""
+        for key in [k for k, (v, _n) in self._entries.items()
+                    if not isinstance(v, list)]:
+            self._total_bytes -= self._entries.pop(key)[1]
 
     def clear(self) -> None:
         """Drop every entry (releases device buffers via refcounting).

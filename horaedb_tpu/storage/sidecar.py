@@ -493,6 +493,13 @@ class EncodedSegment:
     # None = run boundaries unknown (single-part shortcuts, legacy
     # callers) — the decode then falls back to the sort route.
     run_lengths: Optional[tuple] = None
+    # True when the segment holds EVERY row of every SST it was read
+    # for and nothing else (no block-pruned load, no leaf applied, no
+    # stream window): only then may the scan cache keep what a
+    # device-decode dispatch narrows it to under the SST set's key
+    # (ops/device_decode.SegmentSlice); set by the reader, kept by
+    # keep_rows (a narrowing OF a whole segment)
+    whole: bool = False
 
     @property
     def num_rows(self) -> int:

@@ -21,7 +21,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
-from horaedb_tpu.common import ReadableDuration
+from horaedb_tpu.common import ReadableDuration, deviceprof
 from horaedb_tpu.common import runtimes as runtimes_mod
 from horaedb_tpu.common.error import Error
 from horaedb_tpu.objstore import MemoryObjectStore
@@ -527,6 +527,329 @@ def test_decode_rows_counter_and_unselective_plan(runtimes, monkeypatch):
         assert got == (stored, stored, stored)
         assert plan.es is es and plan.cap == encode.pad_capacity(stored)
     assert 'scan_decode_rows_total{side="uploaded"}' in registry.render()
+
+
+# ---------------------------------------------------------------------------
+# resident slices: what a miss narrowed and uploaded stays on the device
+# ---------------------------------------------------------------------------
+
+
+def resident_outcomes():
+    return {o: c.value for o, c in device_decode._RESIDENT.items()}
+
+
+def moved(before: dict, after: dict) -> dict:
+    return {k: after[k] - before[k] for k in after}
+
+
+def compiles_so_far() -> int:
+    """What `device.compiles_in_window` reads: the compile ledger."""
+    return sum(f["compiles"] for f in deviceprof.profiler.snapshot()["fns"])
+
+
+def field_query(lo, hi, field="f1", which=ALL_AGGS):
+    """One field's rows of the window [lo, hi): the data table's query
+    shape, a key leaf and a range leaf."""
+    pred = F.And((F.Eq("field", field), F.TimeRangePred("ts", lo, hi)))
+    return (ScanRequest(range=TimeRange.new(lo, hi), predicate=pred),
+            narrow_spec(lo, hi, which=which))
+
+
+async def host_control(s, req, spec):
+    s.config.scan.decode.mode = "host"
+    try:
+        clear_caches(s)
+        return await s.scan_aggregate(req, spec)
+    finally:
+        s.config.scan.decode.mode = "device"
+        clear_caches(s)
+
+
+def memo_off(s):
+    """Every query dispatches: the parts memo serves none."""
+    s.reader.parts_memo.lru.max_bytes = 0
+
+
+# two windows over the one segment, at different offsets
+WINDOW_A = (SEGMENT_MS // 8 + 7, 5 * SEGMENT_MS // 8 + 7)
+WINDOW_B = (3 * SEGMENT_MS // 8 + 11, 7 * SEGMENT_MS // 8 + 11)
+
+
+@pytest.mark.parametrize("route", ["presorted", "kway"])
+def test_resident_hit_is_the_miss_byte_for_byte(runtimes, route):
+    """What a miss narrowed, padded and uploaded is kept by the scan
+    cache; a query with the same key leaves and ANOTHER window
+    dispatches from it: no tier-2 read, no upload, no compile, and the
+    grids a miss gives for that window, byte for byte."""
+    async def go():
+        s = await open_narrow_storage(runtimes, route)
+        try:
+            memo_off(s)
+            with _ForceXlaAgg():
+                clear_caches(s)
+                tier2 = s.reader.encoded_cache
+                c0, r0 = resident_outcomes(), decode_row_sides()
+                miss_a = await s.scan_aggregate(*field_query(*WINDOW_A))
+                c1, r1 = resident_outcomes(), decode_row_sides()
+                assert moved(c0, c1) == {"hit": 0, "miss": 1, "bypass": 0}
+                # the slice: one field's rows in their capacity bucket,
+                # six 4-byte columns, charged to the one byte budget
+                # and reported by the ledger's account as it stands
+                kept = r1[1] - r0[1]
+                stats = s.reader.cache_stats()["scan_cache"]
+                nbytes = encode.pad_capacity(int(kept)) * 4 * 6
+                assert 0 < kept < r1[0] - r0[0]
+                assert (stats["entries"], stats["decode_slices"]) == (1, 1)
+                assert stats["bytes"] == stats["decode_slice_bytes"] \
+                    == s.reader._scan_cache_resident_bytes() == nbytes
+                programs = device_decode._decode_aggregate_jit._cache_size()
+                compiles, reads = compiles_so_far(), tier2.hits
+                h2d = deviceprof.profiler.snapshot()["transfer"]["h2d"]
+                hit_b = await s.scan_aggregate(*field_query(*WINDOW_B))
+                hit_a = await s.scan_aggregate(*field_query(*WINDOW_A))
+                c2, r2 = resident_outcomes(), decode_row_sides()
+                assert moved(c1, c2) == {"hit": 2, "miss": 0, "bypass": 0}
+                # stored counts as before, nothing crossed to the device
+                assert r2[0] - r1[0] == 2 * (r1[0] - r0[0])
+                assert r2[1] == r1[1]
+                assert deviceprof.profiler.snapshot()["transfer"]["h2d"] \
+                    == h2d
+                assert tier2.hits == reads
+                assert compiles_so_far() == compiles
+                assert device_decode._decode_aggregate_jit._cache_size() \
+                    == programs
+                s.reader.scan_cache.clear()
+                miss_b = await s.scan_aggregate(*field_query(*WINDOW_B))
+                assert resident_outcomes()["miss"] == c2["miss"] + 1
+                host_b = await host_control(s, *field_query(*WINDOW_B))
+            _assert_same(hit_a, miss_a, f"{route} window A hit-vs-miss")
+            _assert_same(hit_b, miss_b, f"{route} window B hit-vs-miss")
+            _assert_same(hit_b, host_b, f"{route} window B hit-vs-host")
+            assert len(hit_b[0]) > 0
+            assert hit_a[1]["sum"].tobytes() != hit_b[1]["sum"].tobytes()
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_resident_slice_misses_after_a_write_and_after_a_compaction(
+        runtimes):
+    """The SST ids are the key: a write into the segment (one more
+    SST) and a compaction of it (one new SST for all) each miss, and
+    answer with the rows the segment then holds."""
+    async def go():
+        s = await open_narrow_storage(runtimes, "presorted")
+        try:
+            memo_off(s)
+            rng = random.Random(SEED + 11)
+            answers = []
+
+            async def hit_after_miss(what):
+                c0 = resident_outcomes()
+                first = await s.scan_aggregate(*field_query(*WINDOW_A))
+                c1 = resident_outcomes()
+                assert moved(c0, c1) == {"hit": 0, "miss": 1, "bypass": 0}, \
+                    what
+                again = await s.scan_aggregate(*field_query(*WINDOW_A))
+                assert moved(c1, resident_outcomes()) \
+                    == {"hit": 1, "miss": 0, "bypass": 0}, what
+                _assert_same(first, again, what)
+                host = await host_control(s, *field_query(*WINDOW_A))
+                _assert_same(first, host, f"{what} vs host")
+                answers.append(first)
+
+            with _ForceXlaAgg():
+                clear_caches(s)
+                await hit_after_miss("as loaded")
+                await s.scan_aggregate(*field_query(*WINDOW_A))  # resident
+                # rewrites of every fourth tick: keep-last must show
+                await s.write(narrow_wreq(narrow_rows(
+                    rng, list(range(0, NARROW_TICKS, 4)), base=0.25)))
+                await hit_after_miss("after a write")
+                await s.scan_aggregate(*field_query(*WINDOW_A))  # resident
+                task = await s.compact_scheduler.picker.pick_candidate()
+                await s.compact_scheduler.executor.execute(task)
+                assert len(await s.manifest.all_ssts()) == 1
+                await hit_after_miss("after a compaction")
+            loaded, written, compacted = answers
+            assert loaded[1]["last"].tobytes() \
+                != written[1]["last"].tobytes()
+            _assert_same(written, compacted, "a compaction moves no value")
+        finally:
+            await s.close()
+
+    run(go())
+
+
+async def write_second_segment(s, rng):
+    rows = [(m, h, f, ts + SEGMENT_MS, v)
+            for m, h, f, ts, v in narrow_rows(rng, range(NARROW_TICKS))]
+    await s.write(narrow_wreq(rows))
+
+
+@pytest.mark.parametrize("budget", ["one_slice", "no_slice"])
+def test_resident_slices_evict_under_a_small_budget_and_stay_correct(
+        runtimes, budget):
+    """Two segments' slices under a scan-cache budget that holds one
+    of them, or none: LRU eviction (or a declined put) sends the next
+    query down the miss path, which answers as the host does."""
+    slice_bytes = encode.pad_capacity(
+        (NARROW_HOSTS - 1) * NARROW_TICKS) * 4 * 6
+    max_bytes = {"one_slice": slice_bytes + 100,
+                 "no_slice": slice_bytes - 100}[budget]
+
+    async def go():
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), NARROW_SCHEMA, 4,
+            storage_config(decode={"mode": "device"},
+                           cache_max_bytes=max_bytes), runtimes=runtimes)
+        try:
+            memo_off(s)
+            rng = random.Random(SEED + 7)
+            await s.write(narrow_wreq(narrow_rows(rng,
+                                                  range(NARROW_TICKS))))
+            await write_second_segment(s, rng)
+            lo, hi = WINDOW_A[0], SEGMENT_MS + WINDOW_A[1]
+            evictions = registry.family("scan_cache_evictions_total") \
+                .labels(tier="hbm")
+            with _ForceXlaAgg():
+                clear_caches(s)
+                c0, e0 = resident_outcomes(), evictions.value
+                first = await s.scan_aggregate(*field_query(lo, hi))
+                second = await s.scan_aggregate(
+                    *field_query(lo + 60_000, hi + 60_000))
+                stats = s.reader.cache_stats()["scan_cache"]
+                got = moved(c0, resident_outcomes())
+                if budget == "one_slice":
+                    # each admission evicts the other segment's slice.
+                    # The second query finds segment 1's, and holds it
+                    # through the eviction that segment 0's re-admission
+                    # causes while it is still in flight
+                    assert stats["decode_slices"] == 1
+                    assert stats["bytes"] == slice_bytes <= max_bytes
+                    assert evictions.value - e0 == 2
+                    assert got == {"hit": 1, "miss": 3, "bypass": 0}
+                else:
+                    assert (stats["decode_slices"], stats["bytes"]) == (0, 0)
+                    assert evictions.value == e0
+                    assert got == {"hit": 0, "miss": 4, "bypass": 0}
+                host = await host_control(
+                    s, *field_query(lo + 60_000, hi + 60_000))
+            _assert_same(second, host, f"{budget} second query vs host")
+            assert len(first[0]) > 0
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_streamed_windows_are_never_admitted(runtimes):
+    """A segment read window by window is never whole in one piece
+    (its rows came by synthetic range leaves): no slice is kept, a
+    second query over the same key reads again and is right."""
+    async def go():
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), NARROW_SCHEMA, 4,
+            storage_config(decode={"mode": "device"},
+                           stream_read_min_rows=64, max_window_rows=128),
+            runtimes=runtimes)
+        try:
+            memo_off(s)
+            rng = random.Random(SEED + 7)
+            await s.write(narrow_wreq(narrow_rows(rng,
+                                                  range(NARROW_TICKS))))
+            with _ForceXlaAgg():
+                clear_caches(s)
+                c0, r0 = resident_outcomes(), decode_row_sides()
+                await s.scan_aggregate(*field_query(*WINDOW_A))
+                r1 = decode_row_sides()
+                got = await s.scan_aggregate(*field_query(*WINDOW_B))
+                r2 = decode_row_sides()
+                assert moved(c0, resident_outcomes()) \
+                    == {"hit": 0, "miss": 2, "bypass": 0}
+                assert s.reader.cache_stats()["scan_cache"]["entries"] == 0
+                assert r2[1] - r1[1] == r1[1] - r0[1] > 0  # uploaded again
+                host = await host_control(s, *field_query(*WINDOW_B))
+            _assert_same(got, host, "streamed, second window vs host")
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_only_a_whole_segment_carries_its_slice_to_the_cache(runtimes):
+    """The dispatch hands the loop a slice to keep only for a segment
+    that held every row of its SSTs (EncodedSegment.whole, set by the
+    reader where tier 2's own completeness test passes): a block-pruned
+    load or an overlaid segment leaves the flag down, the dispatch
+    runs the same and the cache is offered nothing."""
+    async def go():
+        s = await open_narrow_storage(runtimes, "presorted")
+        try:
+            memo_off(s)
+            seen = []
+            real = s.reader._dispatch_device_decode
+
+            def unwhole(es, plan):
+                seen.append(es.whole)
+                es.whole = False  # as a pruned or overlaid read leaves it
+                return real(es, plan)
+
+            with _ForceXlaAgg():
+                clear_caches(s)
+                s.reader._dispatch_device_decode = unwhole
+                c0 = resident_outcomes()
+                a = await s.scan_aggregate(*field_query(*WINDOW_A))
+                b = await s.scan_aggregate(*field_query(*WINDOW_B))
+                assert seen == [True, True]  # the reader saw whole reads
+                assert moved(c0, resident_outcomes()) \
+                    == {"hit": 0, "miss": 2, "bypass": 0}
+                assert s.reader.cache_stats()["scan_cache"]["entries"] == 0
+                s.reader._dispatch_device_decode = real
+                host = await host_control(s, *field_query(*WINDOW_B))
+            _assert_same(b, host, "un-whole, second window vs host")
+            assert len(a[0]) > 0
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_mesh_round_plans_bypass_the_resident_slices(runtimes):
+    """[scan.mesh] defers the dispatch to rounds that group HOST
+    DecodePlans: such a plan neither probes nor admits."""
+    async def go():
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), NARROW_SCHEMA, 4,
+            storage_config(decode={"mode": "device"},
+                           mesh={"enabled": True}), runtimes=runtimes)
+        try:
+            memo_off(s)
+            rng = random.Random(SEED + 7)
+            await s.write(narrow_wreq(narrow_rows(rng,
+                                                  range(NARROW_TICKS))))
+            with _ForceXlaAgg():
+                clear_caches(s)
+                c0 = resident_outcomes()
+                await s.scan_aggregate(*field_query(*WINDOW_A))
+                got = await s.scan_aggregate(*field_query(*WINDOW_B))
+                assert moved(c0, resident_outcomes()) \
+                    == {"hit": 0, "miss": 0, "bypass": 2}
+                assert s.reader.cache_stats()["scan_cache"]["entries"] == 0
+                s.config.scan.mesh.enabled = False
+                host = await host_control(s, *field_query(*WINDOW_B))
+            _assert_same(got, host, "mesh rounds vs host")
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_resident_counter_is_exported_at_rest():
+    text = registry.render()
+    for outcome in ("hit", "miss", "bypass"):
+        assert f'scan_decode_resident_total{{outcome="{outcome}"}}' in text
 
 
 # ---------------------------------------------------------------------------

@@ -296,6 +296,45 @@ def test_point_queries_over_a_pruned_store_match_numpy(
     asyncio.run(go())
 
 
+def test_a_block_pruned_load_is_never_kept_as_a_resident_slice(
+        runtimes, small_blocks):
+    """A pruned load's blocks were chosen by the range leaf too, so
+    what a dispatch narrows it to belongs to one window: the scan cache
+    is offered nothing, and a second query for the same host with
+    another window over the same segment loads its own blocks from the
+    store and is right."""
+    lo, hi = WINDOWS["one_segment"]
+
+    async def go():
+        rng = np.random.default_rng(31)
+        store, model = CountingStore(), Model(2)
+        s = await open_storage(store, runtimes, cache_max_rows=4096,
+                               cache={"tier2_max_bytes": TIER2_ONE_PART})
+        try:
+            await load_two_segments(s, model, rng)
+            empty_caches(s)
+            s.reader.parts_memo.lru.max_bytes = 0  # every query dispatches
+            probes = {o: c.value
+                      for o, c in device_decode._RESIDENT.items()}
+            ranged = []
+            for shift in (0, 300_000):
+                before = store.calls["get_range"]
+                got = await s.scan_aggregate(
+                    *point_query(17, lo + shift, hi + shift))
+                check_against_numpy(got, model, 17, lo + shift, hi + shift)
+                ranged.append(store.calls["get_range"] - before)
+            assert {o: c.value - probes[o]
+                    for o, c in device_decode._RESIDENT.items()} \
+                == {"hit": 0, "miss": 2, "bypass": 0}
+            assert all(n > 0 for n in ranged), ranged
+            assert s.reader.cache_stats()["scan_cache"]["entries"] == 0
+            assert len(s.reader.encoded_cache) == 0  # no whole part either
+        finally:
+            await s.close()
+
+    asyncio.run(go())
+
+
 def test_a_second_pruned_load_pays_its_column_ranges_only(
         runtimes, small_blocks):
     async def go():
@@ -338,8 +377,10 @@ def test_a_store_that_fits_its_caches_makes_the_parents_calls(
         runtimes, small_blocks):
     """Default budgets: the compactor's write-through leaves both parts
     in tier 2, the queries read nothing from the store and never probe
-    a footer.  The numbers are the parent commit's (PR 26), taken by
-    running this test there."""
+    a footer.  Six dispatches as on PR 26's tree; since PR 29 the two
+    that meet a segment a host's earlier window already narrowed and
+    uploaded run from the slice resident on the device: no tier-2
+    read and no upload for those."""
     async def go():
         rng = np.random.default_rng(9)
         store, model = CountingStore(), Model(2)
@@ -351,6 +392,8 @@ def test_a_store_that_fits_its_caches_makes_the_parents_calls(
             hits = cache.hits
             rows = {side: c.value
                     for side, c in device_decode._DECODE_ROWS.items()}
+            probes = {o: c.value
+                      for o, c in device_decode._RESIDENT.items()}
             for shape, (lo, hi) in WINDOWS.items():
                 for host in (3, 17):
                     got = await s.scan_aggregate(
@@ -358,15 +401,21 @@ def test_a_store_that_fits_its_caches_makes_the_parents_calls(
                     check_against_numpy(got, model, host, lo, hi)
             assert store.snapshot() == ({"get": 0, "get_range": 0},
                                         {"get": 0, "get_range": 0})
-            assert cache.hits - hits == 6       # 2 x 1 + 2 x 2 segments
+            # 2 x 1 + 2 x 2 segments; segment 0 of the second window
+            # is resident for either host
+            assert {o: c.value - probes[o]
+                    for o, c in device_decode._RESIDENT.items()} \
+                == {"hit": 2, "miss": 4, "bypass": 0}
+            assert cache.hits - hits == 4
             assert (cache.footer_hits, cache.footer_misses) == (0, 0)
             assert cache.stats()["footers"] == 0
-            # six dispatches, each planned over a whole resident
-            # segment and handed its host's rows of it
+            # six dispatches, each planned over a whole segment of
+            # tier 2 and handed its host's rows of it: four uploaded
+            # them, two found them on the device
             moved = {side: c.value - rows[side]
                      for side, c in device_decode._DECODE_ROWS.items()}
             assert moved == {"stored": 6 * HOSTS * TICKS,
-                             "uploaded": 6 * TICKS}
+                             "uploaded": 4 * TICKS}
         finally:
             await s.close()
 

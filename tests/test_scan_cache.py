@@ -479,6 +479,116 @@ def test_payload_buffers_all_empty_binary_falls_back(monkeypatch):
     assert ptr is None and n == 0
 
 
+# ---------------------------------------------------------------------------
+# tier hbm: device-decode slices beside the windows (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+
+class _Slice:
+    """What ScanCache needs of a device_decode.SegmentSlice."""
+
+    def __init__(self, nbytes: int):
+        self.nbytes = nbytes
+
+
+def _windows(capacity: int) -> list:
+    cols = {"a": np.zeros(capacity, dtype=np.int32)}
+    return [encode.DeviceBatch(
+        columns=cols,
+        encodings={"a": encode.ColumnEncoding("numeric", pa.int32())},
+        n_valid=capacity, capacity=capacity)]
+
+
+def test_slices_share_the_windows_budget_and_lru_order():
+    from horaedb_tpu.storage.scan_cache import (
+        ScanCache,
+        segment_cache_key,
+        windows_nbytes,
+    )
+
+    windows = _windows(64)
+    w_bytes = windows_nbytes(windows)
+    cache = ScanCache(max_bytes=w_bytes + 2_500)
+    kw = segment_cache_key(0, [1], ("a",))
+    # the window is not in a slice's key; the key leaves' values are
+    k1 = segment_cache_key(0, [1], ("a", "decode", "(eq field 'f1')"))
+    k2 = segment_cache_key(0, [1], ("a", "decode", "(eq field 'f2')"))
+    assert len({kw, k1, k2}) == 3
+    cache.put(kw, windows)
+    one, two = _Slice(1_000), _Slice(1_200)
+    cache.put_slice(k1, one)
+    cache.put_slice(k2, two)
+    assert cache.total_bytes == w_bytes + 2_200 and len(cache) == 3
+    assert cache.slices() == [one, two]
+    assert cache.values() == [windows, one, two]
+    # LRU over both kinds: the windows become MRU, the older slice goes
+    assert cache.get(kw) is windows
+    three = _Slice(1_100)
+    cache.put_slice(segment_cache_key(3_600_000, [2], ("a", "decode")),
+                    three)
+    assert cache.get(k1) is None and cache.get(k2) is two
+    assert cache.total_bytes == w_bytes + 2_300
+    # a changed SST set is another key: nothing to invalidate
+    assert cache.get(segment_cache_key(
+        0, [1, 9], ("a", "decode", "(eq field 'f2')"))) is None
+    # larger than the whole budget: declined, nothing evicted for it
+    cache.put_slice(k1, _Slice(w_bytes + 2_501))
+    assert cache.get(k1) is None and len(cache) == 3
+    # the HBM-evicted state drops the slices and keeps the windows
+    cache.drop_slices()
+    assert cache.slices() == [] and cache.values() == [windows]
+    assert cache.total_bytes == w_bytes
+    cache.clear()
+    assert cache.total_bytes == 0 and len(cache) == 0
+
+
+def test_ledger_account_and_stats_report_a_resident_slice(
+        runtimes, monkeypatch):
+    """One device-decode aggregate leaves its segment's slice in the
+    scan cache: the `scan_cache` ledger account reports the padded
+    device columns' bytes (no memo allowance), /stats counts the entry
+    apart, drop_hbm_state releases it, and close() leaves nothing."""
+    from horaedb_tpu.common import memledger
+    from horaedb_tpu.ops.downsample import ALL_AGGS
+    from horaedb_tpu.storage.read import AggregateSpec
+
+    monkeypatch.setenv("HORAEDB_DEVICE_DECODE", "1")
+    monkeypatch.setenv("HORAEDB_HOST_AGG", "0")
+
+    def account_bytes():
+        kinds = memledger.ledger.snapshot()["accounts"]
+        return kinds["scan_cache"]["bytes"] if "scan_cache" in kinds else 0
+
+    async def go():
+        s = await open_storage(MemoryObjectStore(), runtimes)
+        try:
+            await s.write(wreq([(f"k{i % 5}", 1_000 * i, float(i))
+                                for i in range(300)]))
+            spec = AggregateSpec(group_col="k", ts_col="ts", value_col="v",
+                                 range_start=0, bucket_ms=60_000,
+                                 num_buckets=60, which=ALL_AGGS)
+            req = ScanRequest(range=TimeRange.new(0, SEGMENT_MS))
+            await s.scan_aggregate(req, spec)
+            stats = s.reader.cache_stats()["scan_cache"]
+            # k, ts, seq, v padded to 300 rows' capacity bucket
+            nbytes = encode.pad_capacity(300) * 4 * 4
+            assert (stats["entries"], stats["decode_slices"]) == (1, 1)
+            assert stats["bytes"] == stats["decode_slice_bytes"] == nbytes
+            assert s.reader._scan_cache_resident_bytes() == nbytes
+            assert account_bytes() == nbytes
+            s.reader.drop_hbm_state()
+            assert s.reader.cache_stats()["scan_cache"]["entries"] == 0
+            assert account_bytes() == 0
+            s.reader.parts_memo.clear()
+            await s.scan_aggregate(req, spec)
+            assert account_bytes() == nbytes
+        finally:
+            await s.close()
+        assert account_bytes() == 0
+
+    run(go())
+
+
 def test_stats_cache_section(runtimes):
     async def go():
         s = await open_storage(MemoryObjectStore(), runtimes)
@@ -494,6 +604,8 @@ def test_stats_cache_section(runtimes):
             assert stats["encoded_cache"]["entries"] == 1
             assert stats["encoded_cache"]["admissions"] == 1
             assert stats["scan_cache"]["bytes"] >= 0
+            assert stats["scan_cache"]["decode_slices"] == 0
+            assert stats["scan_cache"]["decode_slice_bytes"] == 0
         finally:
             await s.close()
 

@@ -39,13 +39,29 @@ QUERY = {"metric": "cpu", "start": T0 + 7, "end": T0 + 3 * HOUR + 7,
          "bucket_ms": 600_000}
 
 DECODE_FN = "_decode_aggregate_jit"
+DECODE_ENV = {"HORAEDB_DEVICE_DECODE": "1", "HORAEDB_HOST_AGG": "0"}
 ROUTES = {
-    # route -> (environment, phases a query of it has)
+    # case -> (environment, phases a query of it has, the plan's route)
     "fused_acc": ({"HORAEDB_FUSED_AGG": "1", "HORAEDB_HOST_AGG": "0"},
-                  set(SCAN_PHASES) - {"scan.combine"}),
-    "device_decode": ({"HORAEDB_DEVICE_DECODE": "1",
-                       "HORAEDB_HOST_AGG": "0"}, set(SCAN_PHASES)),
+                  set(SCAN_PHASES) - {"scan.combine"}, "fused_acc"),
+    # every segment read, narrowed and uploaded (the scan cache holds
+    # no slice of it)
+    "device_decode": (DECODE_ENV, set(SCAN_PHASES), "device_decode"),
+    # every segment's narrowed slice resident on the device: nothing
+    # to narrow and nothing to group, one dispatch a segment
+    "device_decode_hit": (DECODE_ENV,
+                          set(SCAN_PHASES) - {"scan.group_prep"},
+                          "device_decode"),
 }
+
+
+def resident_outcomes() -> dict:
+    return {o: c.value for o, c in device_decode._RESIDENT.items()}
+
+
+def drop_slices(engine) -> None:
+    """The next device-decode query reads, narrows and uploads."""
+    engine.tables["data"].reader.scan_cache.clear()
 
 
 def run(coro):
@@ -107,27 +123,40 @@ class TestPhaseSpans:
     @pytest.mark.parametrize("route", sorted(ROUTES))
     def test_served_query_yields_every_phase_under_downsample(
             self, route, monkeypatch):
-        env, want_phases = ROUTES[route]
+        env, want_phases, plan_route = ROUTES[route]
         for k, v in env.items():
             monkeypatch.setenv(k, v)
 
-        async def go(client, _engine):
+        async def go(client, engine):
             body = dict(QUERY, filters={"host": "h1"})
             r = await client.post("/query", json=body)  # compiles
             assert r.status == 200
-            before = phase_counts("data")
-            index_before = phase_counts("index")
-            seams_before = sync_seams(DECODE_FN)
-            r = await client.post("/query", json=dict(body,
-                                                      start=T0 + 9))
-            assert r.status == 200
+            # another window each time, so the parts memo serves none.
+            # The hit case asks until a query found every slice on the
+            # device: a compaction behind the first flush changes the
+            # SST set once, and with it the keys
+            for start in range(T0 + 9, T0 + 14):
+                if route == "device_decode":
+                    drop_slices(engine)
+                before = phase_counts("data")
+                index_before = phase_counts("index")
+                seams_before = sync_seams(DECODE_FN)
+                probes = resident_outcomes()
+                r = await client.post("/query",
+                                      json=dict(body, start=start))
+                assert r.status == 200
+                probes = {o: n - probes[o]
+                          for o, n in resident_outcomes().items()}
+                if route != "device_decode_hit" or not probes["miss"]:
+                    break
             tid = r.headers["X-Trace-Id"]
             tree = (await (await client.get(
                 f"/debug/traces/{tid}")).json())["tree"]
             return tree, before, phase_counts("data"), index_before, \
-                phase_counts("index"), sync_seams(DECODE_FN) - seams_before
+                phase_counts("index"), \
+                sync_seams(DECODE_FN) - seams_before, probes
 
-        tree, before, after, index_before, index_after, seams = \
+        tree, before, after, index_before, index_after, seams, probes = \
             run(served(go))
         top = {c["name"]: c for c in tree["children"]}
         assert {"parse", "resolve", "downsample", "respond"} <= set(top)
@@ -135,13 +164,21 @@ class TestPhaseSpans:
         lo, hi = ds["start_ms"], ds["start_ms"] + ds["duration_ms"]
         phases = [c for c in ds["children"] if c["name"] in SCAN_PHASES]
         got_phases = {c["name"] for c in phases}
-        if route == "device_decode":
+        if plan_route == "device_decode":
             # the wait seam, span or not: once per dispatch
+            dispatches = [c for c in phases if c["name"] == "scan.dispatch"]
             assert_sync_seam_per_dispatch(
-                seams,
-                [c for c in phases if c["name"] == "scan.dispatch"],
+                seams, dispatches,
                 [c for c in phases if c["name"] == "scan.device_wait"])
             assert got_phases | {"scan.device_wait"} == want_phases
+            # one dispatch a segment, from the device or after an
+            # upload: a hit's carries no bytes
+            hit = route == "device_decode_hit"
+            assert probes == {"hit": len(dispatches) if hit else 0,
+                              "miss": 0 if hit else len(dispatches),
+                              "bypass": 0}
+            assert all((c["fields"]["h2d_bytes"] == 0) == hit
+                       for c in dispatches)
         else:
             assert got_phases == want_phases
         for c in phases:
@@ -151,7 +188,7 @@ class TestPhaseSpans:
             assert c["fields"]["table"] == "data"
         routed = [c["fields"]["route"] for c in phases
                   if c["name"] == "scan.plan" and "route" in c["fields"]]
-        assert routed == [route]
+        assert routed == [plan_route]
         # the pool hops close the span too, waits included
         hops = [c for c in walk(ds) if c["name"] == "pool_hop"]
         assert hops and all(
@@ -178,7 +215,7 @@ class TestPhaseSpans:
         `scan.group_prep` span that already wraps plan_dispatch: one
         such span a segment as before, no span of a new name, and the
         `scan.dispatch` span beside it carries the smaller upload."""
-        for k, v in ROUTES["device_decode"][0].items():
+        for k, v in DECODE_ENV.items():
             monkeypatch.setenv(k, v)
         calls = []
         real = device_decode._narrow_to_key_leaves
@@ -191,11 +228,12 @@ class TestPhaseSpans:
 
         monkeypatch.setattr(device_decode, "_narrow_to_key_leaves", timed)
 
-        async def go(client, _engine):
+        async def go(client, engine):
             body = dict(QUERY, filters={"host": "h1"})
             r = await client.post("/query", json=body)  # compiles
             assert r.status == 200
             del calls[:]
+            drop_slices(engine)  # or a resident slice narrows nothing
             r = await client.post("/query", json=dict(body,
                                                       start=T0 + 9))
             assert r.status == 200
@@ -263,6 +301,7 @@ class TestProfilerClock:
             try:
                 trace = recorder.start("profiled")
                 seams_before = sync_seams(DECODE_FN)
+                drop_slices(engine)  # every phase: read, narrow, upload
                 with trace_scope(trace):
                     # another range: the parts memo must not serve it
                     await engine.query_downsample(
