@@ -12,21 +12,18 @@ back; five query shapes go over HTTP and are checked against a numpy
 reference built from --seed in this process; the route that served
 each is read from the server's own counters (GET /debug/device,
 GET /metrics).  Then the server is stopped and a SECOND process on the
-same data and compile-cache directories repeats two queries.  Last, a
-child process compiles both Pallas entry points at serving shapes and
-compares them with the XLA program (tools/pallas_check.py; a one-chip matter, skipped under --chips 4).
+same data and compile-cache directories repeats two queries.
 
-This process never imports jax: each server child (and the Pallas
-child) is the one holder of the chip, and each exits before the next
-starts.  The platform it expects defaults to `tpu`; a server that
-reports another fails the run.  A tiny dry run is by explicit
-`--platform cpu --rows N`, never by detection.
+This process never imports jax: each server child is the one holder of
+the chip, and each exits before the next starts.  The platform it
+expects defaults to `tpu`; a server that reports another fails the run.
+A tiny dry run is by explicit `--platform cpu --rows N`, never by
+detection.
 
 `--chips 4` runs the same data and queries through ONE server with
 `[scan.mesh] enabled = true` and `[scan.decode] mode = "device"` (a
 2x2 mesh), then a single-chip control server on the same data whose
-answers the mesh's must equal (counts exact, sums to f32 ulp), then the
-legacy 1-D `mesh_devices` path once.
+answers the mesh's must equal (counts exact, sums to f32 ulp).
 
 It writes only logs and a JSON summary (ending `"claim": null` — the
 timings in it are observations from one run, not benchmark results) to
@@ -611,14 +608,13 @@ class Smoke:
         self.step("query.topk", wall_s=round(wall, 3), **delta)
         return got
 
-    def final_counters(self, srv: Server, allow_mesh=(),
-                       allow_decode=()) -> dict:
+    def final_counters(self, srv: Server, allow_mesh=()) -> dict:
         """Fallback counters must be zero but for the reasons a leg
         declares structural; returns the device-plane totals."""
         dev = srv.device()
         fallbacks = {}
         for family, allowed in (
-                ("scan_decode_fallback_total", allow_decode),
+                ("scan_decode_fallback_total", ()),
                 ("scan_mesh_fallback_total", allow_mesh)):
             seen = {k: v for k, v in srv.metric(family).items() if v}
             bad = {k: v for k, v in seen.items()
@@ -781,41 +777,6 @@ class Smoke:
                 check(same, f"mesh vs control {key}: {agg} differs")
         self.step("mesh_vs_control", equal=sorted(ctl))
 
-        # the legacy 1-D segment mesh ([scan] mesh_devices), once
-        srv = self.start("legacy_mesh",
-                         {("", "mesh_devices"): str(self.args.chips)})
-        try:
-            legacy = {"legacy": ("sharded_remap_partials",
-                                 "sharded_downsample_query")}
-            _, a = self.query_grid(srv, "legacy.full", self.full,
-                                   routes=legacy, route="legacy")
-            _, b = self.query_grid(srv, "legacy.sub", self.sub_ranges[0],
-                                   routes=legacy, route="legacy")
-            # every plan declines device decode under the 1-D mesh,
-            # counted reason="mesh" — that IS this leg's route
-            self.final_counters(srv, allow_decode=("mesh",))
-            self.step("legacy_mesh", full_wall_s=a["wall_s"],
-                      sub_wall_s=b["wall_s"], fns=a["fns"])
-        finally:
-            srv.stop()
-        self.check_logs(srv)
-
-    def run_pallas(self) -> None:
-        """Both Pallas entry points vs the XLA program, in a child that
-        holds the chip alone (every server has exited)."""
-        cap = 1 << 20 if self.on_chip else 1 << 10
-        log_path = os.path.join(self.out_dir, "pallas_check.log")
-        with open(log_path, "w", encoding="utf-8") as log:
-            proc = subprocess.run(
-                [sys.executable, os.path.join(ROOT, "tools",
-                                              "pallas_check.py"),
-                 "--platform", self.args.platform, "--cap", str(cap)],
-                cwd=ROOT, stdout=subprocess.PIPE, stderr=log, text=True,
-                timeout=900)
-        check(proc.returncode == 0,
-              f"pallas_check rc={proc.returncode}; see {log_path}")
-        self.step("pallas", **json.loads(proc.stdout.splitlines()[-1]))
-
     def run(self) -> None:
         d = self.data
         say(f"chip_smoke: rows={d.rows} hosts={HOSTS} ticks={d.ticks} "
@@ -827,7 +788,6 @@ class Smoke:
                         "clean"], check=True, capture_output=True)
         if self.args.chips == 1:
             self.run_one_chip()
-            self.run_pallas()
         else:
             self.run_four_chips()
         check("jax" not in sys.modules,

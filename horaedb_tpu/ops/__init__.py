@@ -27,7 +27,6 @@ from horaedb_tpu.ops.encode import (
     pad_capacity,
 )
 from horaedb_tpu.ops.merge import (dedup_sorted_last, merge_dedup_last,
-                                   merge_impl, set_merge_impl,
                                    sorted_run_starts)
 from horaedb_tpu.ops.downsample import time_bucket_aggregate
 from horaedb_tpu.ops.filter import (
@@ -50,6 +49,6 @@ __all__ = [
     "And", "ColumnEncoding", "DeviceBatch", "Eq", "Ge", "Gt", "In", "Le",
     "Lt", "Ne", "Not", "Or", "TimeRangePred", "decode_to_arrow",
     "dedup_sorted_last", "encode_batch", "eval_predicate", "merge_dedup_last",
-    "merge_impl", "set_merge_impl", "pad_capacity",
+    "pad_capacity",
     "sorted_run_starts", "time_bucket_aggregate", "top_k_groups",
 ]

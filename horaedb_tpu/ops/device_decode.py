@@ -59,9 +59,7 @@ suite byte-compares the two, tests/test_device_decode.py).
 Ineligible plans/segments fall back to host decode with an explicit
 per-reason counter (`scan_decode_fallback_total{reason=}`) so a
 silently-ineligible plan is visible instead of quietly slow
-(docs/observability.md).  The Pallas partials kernel
-(ops/pallas_kernels.py) slots in behind the same
-HORAEDB_DOWNSAMPLE_IMPL knob; selected and failing, it raises.
+(docs/observability.md).
 """
 
 from __future__ import annotations
@@ -91,7 +89,6 @@ from horaedb_tpu.utils import registry, trace_add
 # operators can tell "misconfigured dashboard" from "unsupported data"
 # (docs/observability.md).
 FALLBACK_REASONS = (
-    "mesh",            # meshed scans keep their own round scheduler
     "append_mode",     # BytesMerge needs exact Arrow bytes
     "no_sidecar",      # plan can't serve from sidecars at all
     "predicate",       # predicate not a device-evaluable PK conjunction
@@ -531,7 +528,7 @@ def _rows_in_order(cols: tuple, valid, iota, n_valid, run_offsets, *,
 
 @deviceprof.jit(static_argnames=(
     "key_slots", "num_pks", "group_pos", "ts_pos", "val_slot",
-    "leaf_prog", "g_pad", "width", "which", "use_pallas", "route",
+    "leaf_prog", "g_pad", "width", "which", "route",
     "num_runs"))
 def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
                           shift, lo, total, bucket_ms, run_offsets, *,
@@ -539,7 +536,7 @@ def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
                           group_pos: int, ts_pos: int,
                           val_slot: int, leaf_prog: tuple,
                           g_pad: int, width: int, which: tuple,
-                          use_pallas: bool, route: str = "sorted",
+                          route: str = "sorted",
                           num_runs: int = 0):
     """THE fused dispatch: encoded columns in, partial grids out.
 
@@ -558,30 +555,15 @@ def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
     Dropped rows (padding, leaf-filtered, dup-shadowed) are masked to
     gid = -1, never compacted — static shapes, no host round trip.
     Returns ({partial grids}, kept_rows)."""
-    cap = cols[0].shape[0]
     keys_s, gid, val_s, n_rows = decode_rows_core(
         cols, n_valid, leaf_consts, run_offsets, key_slots=key_slots,
         num_pks=num_pks, group_pos=group_pos, val_slot=val_slot,
         leaf_prog=leaf_prog, route=route, num_runs=num_runs)
     ts_s = keys_s[ts_pos]
-    if use_pallas:
-        from horaedb_tpu.ops.pallas_kernels import pallas_window_partials
-
-        shift32 = jnp.asarray(shift, jnp.int32)
-        lo32 = jnp.asarray(lo, jnp.int32)
-        bucket32 = jnp.asarray(bucket_ms, jnp.int32)
-        gid = jnp.where(
-            (ts_s + shift32) // bucket32
-            < jnp.asarray(total, jnp.int32), gid, -1)
-        grids = pallas_window_partials(
-            ts_s + shift32 - lo32 * bucket32, gid, val_s, cap, bucket32,
-            num_groups=g_pad, num_buckets=width, which=which,
-            interpret=downsample.pallas_interpret())
-    else:
-        grids = downsample.window_local_partials(
-            ts_s, gid, val_s, jnp.arange(g_pad, dtype=jnp.int32),
-            shift, lo, total, bucket_ms, num_groups=g_pad,
-            num_buckets=width, which=which)
+    grids = downsample.window_local_partials(
+        ts_s, gid, val_s, jnp.arange(g_pad, dtype=jnp.int32),
+        shift, lo, total, bucket_ms, num_groups=g_pad,
+        num_buckets=width, which=which)
     return grids, n_rows
 
 
@@ -1032,9 +1014,6 @@ def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
         next(keyed) if op in (_OP_EQ, _OP_IN) else jnp.asarray(c)
         for (_slot, op), c in zip(dp.leaf_prog, dp.consts))
 
-    # the Pallas partials kernel rides the same knob as the single-shot
-    # aggregate (HORAEDB_DOWNSAMPLE_IMPL); selected and failing, it
-    # raises — it never quietly serves the XLA program
     outs, n_rows = _decode_aggregate_jit(
         seg.cols_dev, seg.n, consts_dev,
         np.int32(dp.shift), np.int32(dp.lo),
@@ -1043,7 +1022,6 @@ def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
         group_pos=seg.group_pos, ts_pos=seg.ts_pos,
         val_slot=seg.val_slot, leaf_prog=dp.leaf_prog,
         g_pad=seg.g_pad, width=dp.use_width, which=dp.which,
-        use_pallas=downsample.downsample_impl() == "pallas",
         route=seg.route, num_runs=seg.num_runs)
     return DecodeDispatch(outs=outs, n_rows=n_rows,
                           values=seg.values, lo=dp.lo, w_eff=dp.w_eff,

@@ -12,14 +12,12 @@ bucket_ms.  Both counts are static per query, so jit compiles one program
 per (capacity, groups, buckets) signature.
 
 Split into partial_aggregate / finalize_aggregate so the multi-chip path
-(parallel/scan.py) can psum/pmax partial grids across the segment mesh
+(parallel/scan.py) can combine partial grids across the mesh's time
 axis before finalizing — the identity elements (0, +/-inf, INT32_MIN)
 combine correctly under collectives, NaNs would not.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -200,64 +198,17 @@ def finalize_aggregate(partial: dict, which: tuple = ALL_AGGS) -> dict:
     return out
 
 
-_IMPLS = ("xla", "pallas")
-_impl = "xla"
-
-
-def downsample_impl() -> str:
-    """The selected fused-downsample implementation (see
-    set_downsample_impl) — read by ops/device_decode.py so the fused
-    decode dispatch rides the same measured-before-adoption knob."""
-    return _impl
-
-
-def pallas_interpret() -> bool:
-    """Pallas kernels compile through Mosaic on TPU only; every other
-    backend (the CPU test rung) runs them in interpret mode."""
-    return jax.default_backend() != "tpu"
-
-
-def set_downsample_impl(name: str) -> None:
-    """Select the fused downsample implementation: "xla" (segment ops,
-    the default) or "pallas" (ops.pallas_kernels compare-broadcast
-    kernel; see pallas_interpret).  The default flips only when the
-    hardware benchmark says the kernel wins — measured, not assumed."""
-    if name not in _IMPLS:
-        raise ValueError(f"unknown downsample impl {name!r}; "
-                         f"expected one of {_IMPLS}")
-    global _impl
-    _impl = name
-
-
-# route the env knob through the setter so typos fail at import instead
-# of silently running the XLA path
-set_downsample_impl(os.environ.get("HORAEDB_DOWNSAMPLE_IMPL", "xla"))
-
-
 def time_bucket_aggregate(ts_offset: jax.Array, group_ids: jax.Array,
                           values: jax.Array, n_valid, bucket_ms,
                           num_groups: int, num_buckets: int,
                           which: tuple = ALL_AGGS) -> dict:
     """See _time_bucket_aggregate_impl; this thin wrapper canonicalizes
-    `which` so permutations/duplicates share one compiled program, and
-    dispatches to the Pallas kernel when selected."""
+    `which` so permutations/duplicates share one compiled program."""
     which = tuple(sorted(set(which)))
     unknown = set(which) - set(ALL_AGGS)
     if unknown:
         raise ValueError(f"unknown aggregates {sorted(unknown)}; "
                          f"supported: {ALL_AGGS}")
-    if _impl == "pallas":
-        from horaedb_tpu.ops.pallas_kernels import (
-            pallas_time_bucket_aggregate,
-        )
-
-        # a selected kernel that fails RAISES (a Mosaic refusal on the
-        # chip included): quietly serving the XLA program would report
-        # the wrong implementation's answer under this one's name
-        return pallas_time_bucket_aggregate(
-            ts_offset, group_ids, values, n_valid, bucket_ms,
-            num_groups=num_groups, num_buckets=num_buckets,
-            which=which, interpret=pallas_interpret())
     return _time_bucket_aggregate_impl(
         ts_offset, group_ids, values, n_valid, bucket_ms,
         num_groups=num_groups, num_buckets=num_buckets, which=which)

@@ -1,9 +1,15 @@
-"""Device merge-dedup: the north-star scan kernel.
+"""Device merge-dedup: the reference and the device twin of the scan's
+host merge — not a mode the scan selects.
 
-Replaces the reference's streaming merge path — SortPreservingMergeExec
-feeding MergeExec's row-at-a-time `primary_key_eq` scalar loop
-(ref: src/storage/src/read.rs:154-156, 262-343) — with a single compiled
-program over the concatenation of all SST batches in a segment:
+The served scan merges on the host (storage/read.py
+`_host_merge_window_descs`: a k-way permutation plan over pre-sorted SST
+runs, last row kept per PK run); the fused decode program sorts through
+`lex_sort` / `kway_merge_perm` here.  `merge_dedup_last` is the plain
+statement of the semantics that tests hold the host merge and
+`dedup_sorted_last` (its sort-free device twin) to — the reference's
+SortPreservingMergeExec + MergeExec `primary_key_eq` loop
+(ref: src/storage/src/read.rs:154-156, 262-343) as one compiled program
+over the concatenation of all SST batches in a segment:
 
   1. lexicographic sort by (pk..., seq)      — XLA variadic sort
   2. run-boundary mask (neighbor compare)    — vectorized, replaces the
@@ -20,43 +26,12 @@ too (first `num_runs` rows valid), so downstream ops stay compiled.
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
 from horaedb_tpu.common import deviceprof
 
 _PAD_SENTINEL = jnp.int32(2**31 - 1)
-
-# Which merge strategy the scan uses (see storage/read.py):
-#   host_perm   — exploit pre-sorted SST runs: the host plans a k-way
-#                 merge permutation (or proves none is needed) and keeps
-#                 the last row per PK run in one numpy pass
-#                 (read._host_merge_window_descs); rows reach the device
-#                 only as batched aggregation stacks.  The default.
-#   device_sort — the original full `lax.sort` device program
-#                 (`merge_dedup_last`); kept for A/B runs.
-# `dedup_sorted_last` below is the DEVICE twin of the host dedup —
-# exported for device-resident consumers and validated against
-# merge_dedup_last in tests; the default scan path does not call it.
-_MERGE_IMPLS = ("host_perm", "device_sort")
-_merge_impl = "host_perm"
-
-
-def set_merge_impl(name: str) -> None:
-    global _merge_impl
-    if name not in _MERGE_IMPLS:
-        raise ValueError(f"unknown merge impl {name!r}; "
-                         f"expected one of {_MERGE_IMPLS}")
-    _merge_impl = name
-
-
-def merge_impl() -> str:
-    return _merge_impl
-
-
-set_merge_impl(os.environ.get("HORAEDB_MERGE_IMPL", "host_perm"))
 
 
 def lex_sort(operands: tuple, num_keys: int,

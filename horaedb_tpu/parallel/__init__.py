@@ -1,27 +1,21 @@
-"""Multi-chip execution: segment-axis mesh + shard_map scan programs.
+"""Multi-chip execution: the 2-D (time, series) scan mesh + shard_map
+scan programs ([scan.mesh]; docs/parallel.md).
 
 The TPU-native replacement for the reference's cross-partition merge
 (SortPreservingMergeExec under UnionExec, SURVEY.md section 2.5 P2/P3):
 time segments are independent by construction (storage.rs:342-368 builds
-one plan per segment), so segments ARE the shard axis.  Each chip
-merge-dedups and partially aggregates its own segments; only the small
-dense (group, bucket) grids cross chips, as psum/pmax/pmin collectives
-over ICI — never row data.
+one plan per segment), so segments shard along the time axis.  Each chip
+aggregates its own windows; only the small dense (group, bucket) grids
+cross chips, in the segmented time-axis combine over ICI — never row
+data.
 """
 
 # Lazy exports (PEP 562): importing this package must not initialize
-# the XLA backend (scan.py builds jnp constants at import), because
-# multihost users have to call jax.distributed.initialize() FIRST —
-# `from horaedb_tpu.parallel import multihost` stays backend-free.
+# the XLA backend (scan.py builds jnp constants at import).
 _EXPORTS = {
-    "segment_mesh": "horaedb_tpu.parallel.mesh",
     "scan_mesh": "horaedb_tpu.parallel.mesh",
     "default_scan_shape": "horaedb_tpu.parallel.mesh",
-    "sharded_downsample_query": "horaedb_tpu.parallel.scan",
-    "sharded_merge_dedup": "horaedb_tpu.parallel.scan",
-    "sharded_remap_partials": "horaedb_tpu.parallel.scan",
     "mesh_run_partials": "horaedb_tpu.parallel.scan",
-    "multihost": "horaedb_tpu.parallel.multihost",
 }
 
 __all__ = list(_EXPORTS)
@@ -33,7 +27,6 @@ def __getattr__(name: str):
             f"module {__name__!r} has no attribute {name!r}")
     import importlib
 
-    mod = importlib.import_module(_EXPORTS[name])
-    val = mod if name == "multihost" else getattr(mod, name)
+    val = getattr(importlib.import_module(_EXPORTS[name]), name)
     globals()[name] = val  # cache: next access skips __getattr__
     return val

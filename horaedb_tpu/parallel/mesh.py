@@ -1,18 +1,11 @@
 """Device mesh construction — THE module that declares mesh topology.
 
-Two shapes live here:
-
-  segment_mesh  the original 1-D mesh over the segment (time-window)
-                axis, kept for the device_sort merge rounds and the
-                multihost DCN tier;
-  scan_mesh     the 2-D (time, series) mesh of the in-region scan
-                ([scan.mesh]): plan segments shard along the `time`
-                axis (one merge window per time slot, plan order),
-                group/tsid blocks along the `series` axis.  The time
-                axis carries the segmented-reduction combine
-                (parallel/scan.py mesh_run_partials); the series axis
-                divides the resident grid state and the per-chip
-                combine egress by its size.
+scan_mesh is the 2-D (time, series) mesh of the in-region scan
+([scan.mesh]): plan segments shard along the `time` axis (one merge
+window per time slot, plan order), group/tsid blocks along the `series`
+axis.  The time axis carries the segmented-reduction combine
+(parallel/scan.py mesh_run_partials); the series axis divides the
+resident grid state and the per-chip combine egress by its size.
 
 tools/lint.py enforces that Mesh/shard_map/NamedSharding construction
 happens only under horaedb_tpu/parallel/ — mesh topology stays declared
@@ -28,30 +21,9 @@ from jax.sharding import Mesh
 
 from horaedb_tpu.common.error import ensure
 
-SEGMENT_AXIS = "seg"
-
 # the 2-D scan mesh's axis names ([scan.mesh]; docs/parallel.md)
 TIME_AXIS = "time"
 SERIES_AXIS = "series"
-
-
-def segment_mesh(n_devices: Optional[int] = None,
-                 devices: Optional[Sequence] = None) -> Mesh:
-    """1-D mesh over the segment (time-window) axis.
-
-    A single axis is the right topology for the scan workload: segments
-    are embarrassingly parallel and only grid-sized aggregates cross the
-    axis, so a v5e-8's ring handles the psum without any 2-D layout.
-    """
-    devs = list(devices) if devices is not None else jax.devices()
-    if n_devices is not None:
-        ensure(len(devs) >= n_devices,
-               f"requested a {n_devices}-device mesh but only "
-               f"{len(devs)} devices are available")
-        devs = devs[:n_devices]
-    import numpy as np
-
-    return Mesh(np.array(devs), axis_names=(SEGMENT_AXIS,))
 
 
 def default_scan_shape(n_devices: int) -> tuple[int, int]:

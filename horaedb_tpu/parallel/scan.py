@@ -1,21 +1,12 @@
-"""shard_map scan programs over the segment mesh.
+"""shard_map scan programs over the 2-D (time, series) scan mesh
+([scan.mesh]; docs/parallel.md).
 
-Data layout: host stacks per-segment device batches into
-(n_devices, capacity) arrays, sharded on the leading (segment) axis.
-Segments never share primary keys with each other in OVERWRITE semantics
-terms (a PK's rows live in one segment at a time... strictly: dedup is
-segment-scoped by design, matching the reference where each segment gets
-its own MergeExec), so:
-
-- merge-dedup is purely shard-local (no collective at all);
-- downsampling combines per-shard partial grids with psum (sum/count),
-  pmin/pmax (min/max), and an argmax-by-timestamp scheme for `last`
-  (later shard wins ties, mirroring later-file-wins);
-- top-k runs on the replicated combined grid.
-
-Collectives ride ICI inside one compiled program — the XLA analogue of
-the reference's cross-partition SortPreservingMergeExec, except only
-(groups x buckets) floats cross chips instead of row streams.
+Data layout: the host stacks one merge window per time slot into
+(time, capacity) arrays sharded on the leading axis.  Dedup is
+segment-scoped by design (each segment gets its own merge, as in the
+reference), so the row work is shard-local; only (groups x buckets)
+grids cross chips, in the segmented time-axis combine — never row
+streams.
 """
 
 from __future__ import annotations
@@ -27,10 +18,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horaedb_tpu.common import deviceprof
 from horaedb_tpu.common.error import Error
-from horaedb_tpu.ops import downsample, merge as merge_ops
-from horaedb_tpu.ops.topk import (pair_add, pair_max_normalized,
-                                  top_k_groups)
-from horaedb_tpu.parallel.mesh import SEGMENT_AXIS, SERIES_AXIS, TIME_AXIS
+from horaedb_tpu.ops import downsample
+from horaedb_tpu.ops.topk import pair_add, pair_max_normalized
+from horaedb_tpu.parallel.mesh import SERIES_AXIS, TIME_AXIS
 
 
 def _check_block_is_one(block) -> None:
@@ -41,168 +31,6 @@ def _check_block_is_one(block) -> None:
             f"leading axis {block.shape[0]} exceeds the mesh: stack exactly "
             "one segment batch per device (pad the device axis, or scan in "
             "rounds)")
-
-
-def _combine_partials(p: dict) -> dict:
-    """Cross-shard combination of partial aggregate grids."""
-    ax = SEGMENT_AXIS
-    combined = {
-        "count": jax.lax.psum(p["count"], ax),
-        "sum": jax.lax.psum(p["sum"], ax),
-        "min": jax.lax.pmin(p["min"], ax),
-        "max": jax.lax.pmax(p["max"], ax),
-    }
-    # `last`: the shard holding the globally-latest timestamp wins; ties
-    # break toward the higher shard index (later segment).
-    g_last_ts = jax.lax.pmax(p["last_ts"], ax)
-    rank = jax.lax.axis_index(ax)
-    eligible = p["last_ts"] == g_last_ts
-    g_rank = jax.lax.pmax(jnp.where(eligible, rank, -1), ax)
-    winner = eligible & (rank == g_rank)
-    combined["last"] = jax.lax.psum(jnp.where(winner, p["last"], 0.0), ax)
-    combined["last_ts"] = g_last_ts
-    return combined
-
-
-def sharded_downsample_query(mesh, *, num_groups: int, num_buckets: int,
-                             k: int):
-    """Build the compiled multi-chip downsample+topk query.
-
-    Returns fn(ts_offset, group_ids, values, n_valid, bucket_ms) where the
-    first three args are (n_devices, capacity) int32/int32/float32 arrays
-    sharded on the leading axis, n_valid is (n_devices,) int32, and
-    bucket_ms is a replicated scalar.  Output: replicated dict of
-    (num_groups, num_buckets) finalized grids + (top_k values, indices).
-    """
-
-    def shard_fn(ts, gid, vals, n_valid, bucket_ms):
-        p = _shard_partial(ts, gid, vals, n_valid, bucket_ms,
-                           num_groups=num_groups, num_buckets=num_buckets)
-        combined = _combine_partials(p)
-        final = downsample.finalize_aggregate(combined)
-        scores = jnp.max(jnp.where(final["count"] > 0, final["max"],
-                                   -jnp.inf), axis=1).astype(jnp.float32)
-        top_vals, top_idx = top_k_groups(scores, k=k)
-        return final, top_vals, top_idx
-
-    mapped = shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=_ROW_SPECS,
-        out_specs=(P(), P(), P()),
-        check_vma=False,
-    )
-    return deviceprof.jit(mapped, name="sharded_downsample_query")
-
-
-def _shard_partial(ts, gid, vals, n_valid, bucket_ms, *, num_groups: int,
-                   num_buckets: int) -> dict:
-    """Per-shard prelude shared by the mesh aggregation programs: one
-    window's partial grids from its (1, capacity) block."""
-    _check_block_is_one(ts)
-    return downsample.partial_aggregate(
-        ts[0], gid[0], vals[0], n_valid[0], bucket_ms[0],
-        num_groups=num_groups, num_buckets=num_buckets)
-
-
-_ROW_SPECS = (P(SEGMENT_AXIS, None), P(SEGMENT_AXIS, None),
-              P(SEGMENT_AXIS, None), P(SEGMENT_AXIS), P())
-
-
-def sharded_remap_partials(mesh, *, num_groups: int, num_buckets: int,
-                           which: tuple = downsample.ALL_AGGS):
-    """Batched multi-chip partial aggregation with the per-window group
-    remap fused into the compiled program.
-
-    Windows from DIFFERENT segments batch onto the mesh (the reference's
-    UnionExec axis, storage.rs:342-368): each chip remaps its window's
-    local dense group ids into the round's union group space via a
-    (num_groups,) remap row, shifts timestamps into query-range offsets,
-    and aggregates into a window-LOCAL grid (num_buckets wide, starting
-    at the window's `lo` bucket) — all without leaving the device.
-    Per-shard grids come back stacked (n_devices, G, B) for the host's
-    float64 fold (bit-equal to the single-device path).
-
-    fn(ts, gid, vals, remap, shift, lo, total_buckets, bucket_ms):
-      ts/gid/vals: (n_devices, capacity) sharded on the leading axis,
-        gid rows are window-local dense codes with -1 = dropped row;
-      remap: (n_devices, num_groups) int32 — local code -> union row;
-      shift: (n_devices,) int32 added to ts (per-window epoch offset);
-      lo: (n_devices,) int32 first covered bucket per window;
-      total_buckets: replicated scalar — global bucket count;
-      bucket_ms: (1,) replicated.
-    """
-
-    def shard_fn(ts, gid, vals, remap, shift, lo, total, bucket_ms):
-        _check_block_is_one(ts)
-        p = downsample.window_local_partials(
-            ts[0], gid[0], vals[0], remap[0], shift[0], lo[0], total,
-            bucket_ms[0], num_groups=num_groups, num_buckets=num_buckets,
-            which=which)
-        return {k: v[None] for k, v in p.items()}
-
-    mapped = shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(P(SEGMENT_AXIS, None), P(SEGMENT_AXIS, None),
-                  P(SEGMENT_AXIS, None), P(SEGMENT_AXIS, None),
-                  P(SEGMENT_AXIS), P(SEGMENT_AXIS), P(), P()),
-        out_specs=P(SEGMENT_AXIS),
-        check_vma=False,
-    )
-    return deviceprof.jit(mapped, name="sharded_remap_partials")
-
-
-def _build_sharded_merge(mesh, merge_fn):
-    """Shared shard_map plumbing for the two merge kernels: unwrap the
-    (1, capacity) blocks, run `merge_fn` shard-locally (dedup is
-    segment-scoped, so NO collectives), re-expand the leading axis."""
-
-    def shard_fn(pks, seq, values, n_valid):
-        _check_block_is_one(seq)
-        out_pks, out_seq, out_vals, out_valid, num_runs = merge_fn(
-            tuple(c[0] for c in pks), seq[0],
-            tuple(v[0] for v in values), n_valid[0])
-        expand = lambda a: a[None, :]
-        return (tuple(expand(c) for c in out_pks), expand(out_seq),
-                tuple(expand(v) for v in out_vals), expand(out_valid),
-                num_runs[None])
-
-    mapped = shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(P(SEGMENT_AXIS, None), P(SEGMENT_AXIS, None),
-                  P(SEGMENT_AXIS, None), P(SEGMENT_AXIS)),
-        out_specs=(P(SEGMENT_AXIS, None), P(SEGMENT_AXIS, None),
-                   P(SEGMENT_AXIS, None), P(SEGMENT_AXIS, None),
-                   P(SEGMENT_AXIS)),
-        check_vma=False,
-    )
-    return deviceprof.jit(
-        mapped, name=f"sharded_merge[{merge_fn.__name__}]")
-
-
-def sharded_merge_dedup(mesh, *, num_pks: int):
-    """Build the compiled multi-chip merge-dedup.
-
-    Segments are the shard axis and dedup is segment-scoped, so this is
-    shard-local compute with NO collectives — the mesh exists so the same
-    program scales from 1 to N chips and composes with the downsample
-    collectives in one jit.
-
-    Returns fn(pks, seq, values, n_valid) over (n_devices, capacity)
-    arrays; outputs keep the same sharded layout plus a per-shard
-    (n_devices,) run count.
-    """
-    del num_pks  # shape-polymorphic: the tuple arity fixes it at trace
-    return _build_sharded_merge(mesh, merge_ops.merge_dedup_last)
-
-
-def shard_leading_axis(mesh, arr):
-    """Place an (n_devices, ...) host array sharded over the segment axis."""
-    return deviceprof.device_put(arr, NamedSharding(mesh, P(SEGMENT_AXIS)))
-
-
-# ---------------------------------------------------------------------------
-# the 2-D (time, series) scan mesh ([scan.mesh]; docs/parallel.md)
-# ---------------------------------------------------------------------------
 
 
 def shard_time_axis(mesh, arr):

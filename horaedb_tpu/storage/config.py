@@ -274,19 +274,13 @@ class ScanConfig:
     # cache_max_bytes overrides it.
     cache_max_rows: int = 4 << 20
     # explicit budget in bytes for the scan cache (0 = derive from
-    # cache_max_rows).  Under the default host_perm merge, cached scan
-    # windows are HOST-resident (RAM) and the flush-stack cache — the
-    # stacked aggregation inputs actually living in HBM — gets the same
-    # budget; worst-case HBM is 1x this value (2x in the device_sort
-    # A/B mode, where windows also occupy HBM).
+    # cache_max_rows).  Cached scan windows are HOST-resident (RAM: the
+    # merge runs on host) and the flush-stack cache — the stacked
+    # aggregation inputs actually living in HBM — gets the same budget;
+    # worst-case HBM is 1x this value.
     cache_max_bytes: int = 0
-    # devices for the multi-chip aggregate path (0 = single-device);
-    # windows batch onto a 1-D segment mesh in rounds of this size with
-    # partial grids combined via ICI psum/pmin/pmax
-    mesh_devices: int = 0
     # single-device aggregate rounds: windows (across segments) batched
     # into one compiled program per round — the UnionExec axis as a vmap.
-    # Meshed scans use mesh_devices as the round size instead.
     agg_batch_windows: int = 16
     # segments whose manifest row count exceeds this stream window-by-
     # window: a first pass over one PK column plans value-range windows,
@@ -329,8 +323,7 @@ class ScanConfig:
     # filter + bucket-aggregate into one device dispatch for eligible
     # aggregate scans; "host" reproduces the pre-change path exactly
     decode: ScanDecodeConfig = field(default_factory=ScanDecodeConfig)
-    # 2-D (time x series) mesh scan knobs ([scan.mesh]); mutually
-    # exclusive with the legacy 1-D mesh_devices knob above
+    # 2-D (time x series) mesh scan knobs ([scan.mesh])
     mesh: ScanMeshConfig = field(default_factory=ScanMeshConfig)
 
 

@@ -47,7 +47,7 @@ from horaedb_tpu.common.memledger import ledger as memledger
 from horaedb_tpu.common.tenant import charge_scan_bytes
 from horaedb_tpu.objstore import NotFoundError, ObjectStore
 from horaedb_tpu.ops import downsample as downsample_ops
-from horaedb_tpu.ops import encode, filter as filter_ops, merge as merge_ops
+from horaedb_tpu.ops import encode, filter as filter_ops
 from horaedb_tpu.storage.config import StorageConfig, UpdateMode
 from horaedb_tpu.storage.operator import build_operator
 from horaedb_tpu.storage.sst import SstFile, segment_of, sst_path
@@ -149,7 +149,6 @@ _MESH_TOPK = registry.counter(
 # misconfigured mesh from unsupported data (mirrors
 # scan_decode_fallback_total's discipline)
 MESH_FALLBACK_REASONS = (
-    "merge_impl",    # non-host_perm merge layouts keep the legacy path
     "sum_overlap",   # a run's windows share a (group, bucket) sum cell
     "count_bound",   # time_axis x capacity would overflow f32 counts
     "grid_budget",   # round's transient grid exceeds max_grid_bytes
@@ -487,10 +486,8 @@ class ParquetReader:
         # live bytes of device-resident mesh top-k score state (the
         # mesh_state ledger account's pull gauge; event-loop owned)
         self._mesh_state_bytes = 0
-        # Under the default host_perm merge, windows live in HOST RAM and
-        # the stacks ARE the HBM working set — they get the full budget.
-        # (In device_sort A/B mode windows also occupy HBM, so worst
-        # case there is 2x the configured budget; see ScanConfig.)
+        # windows live in HOST RAM (the merge runs on host), so the
+        # stacks ARE the HBM working set — they get the full budget
         self._stack_cache_max = cache_bytes
         self._stack_cache_lock = threading.Lock()
         # tier 2: host-RAM per-SST encoded parts under the HBM windows
@@ -514,17 +511,6 @@ class ParquetReader:
                f"unknown [scan.decode] mode "
                f"{config.scan.decode.mode!r}; expected one of "
                f"{DECODE_MODES}")
-        # conflicting mode COMBINATIONS fail at open too (the PR 9
-        # bad-mode precedent): decode.mode="device" under the legacy
-        # 1-D segment mesh would decline EVERY query with a counted
-        # fallback — a standing misconfiguration, not a data property
-        ensure(not (config.scan.decode.mode == "device"
-                    and config.scan.mesh_devices > 0),
-               '[scan.decode] mode="device" cannot run on the legacy '
-               "1-D segment mesh ([scan] mesh_devices > 0): the fused "
-               "decode dispatch targets the default device or the 2-D "
-               "[scan.mesh] rounds — change the decode mode or the "
-               "mesh config")
         # delta-summation tier: per-segment aggregate partials keyed by
         # the segment's exact SST set (event-loop owned, like the scan
         # cache) — narrowed/refined dashboard ranges recompute only
@@ -539,13 +525,6 @@ class ParquetReader:
         # agents and folds the returned partials through the normal
         # combine (scanagent/client.py); None = the direct-scan control
         self.scan_router = None
-        self.mesh = None
-        self._mesh_agg_fns: dict = {}
-        self._mesh_merge_fns: dict = {}
-        if config.scan.mesh_devices > 0:
-            from horaedb_tpu.parallel import segment_mesh
-
-            self.mesh = segment_mesh(config.scan.mesh_devices)
         # the 2-D (time, series) scan mesh ([scan.mesh]): segments
         # shard along `time` (plan-order slot admission), group blocks
         # along `series`, segmented-reduction combine on the mesh —
@@ -554,10 +533,6 @@ class ParquetReader:
         self.scan_mesh = None
         self._mesh_run_fns: dict = {}
         if config.scan.mesh.enabled:
-            ensure(config.scan.mesh_devices == 0,
-                   "[scan] mesh_devices and [scan.mesh] are mutually "
-                   "exclusive — the 2-D mesh supersedes the legacy "
-                   "1-D segment mesh")
             from horaedb_tpu.parallel import scan_mesh as build_scan_mesh
 
             self.scan_mesh = build_scan_mesh(config.scan.mesh.time,
@@ -891,14 +866,6 @@ class ParquetReader:
             probe.fields["cached"] = len(cached)
             if slice_columns is not None:
                 probe.fields["resident"] = len(resident)
-        if self.mesh is not None:
-            mesh_iter = self._cached_windows_mesh(plan, cached, to_read)
-            try:
-                async for out in mesh_iter:
-                    yield out
-            finally:
-                await mesh_iter.aclose()
-            return
         if self.pipeline_on() and self._pipeline_has_io(plan, to_read):
             plan.pipeline_active = True
             pipe_iter = self._cached_windows_pipelined(
@@ -992,8 +959,7 @@ class ParquetReader:
         spec = plan.decode_spec
         if spec is None:
             return None
-        if plan.decode_defer or self.mesh is not None \
-                or not plan.use_cache:
+        if plan.decode_defer or not plan.use_cache:
             device_decode.note_resident("bypass", len(plan.segments))
             return None
         return ("decode", spec.group_col, spec.ts_col, spec.value_col) \
@@ -1057,10 +1023,10 @@ class ParquetReader:
 
     def pipeline_on(self) -> bool:
         """Whether OVERWRITE cold scans run through the bounded
-        producer/consumer pipeline (storage/pipeline.py).  Meshed scans
-        keep their own round scheduler; [scan.pipeline] enabled = false
-        reproduces the pre-pipeline pump exactly."""
-        return self.config.scan.pipeline.enabled and self.mesh is None
+        producer/consumer pipeline (storage/pipeline.py);
+        [scan.pipeline] enabled = false reproduces the pre-pipeline
+        pump exactly."""
+        return self.config.scan.pipeline.enabled
 
     def _pipeline_has_io(self, plan: ScanPlan, to_read: list) -> bool:
         """Whether pipelining this scan can pay for itself: the
@@ -1227,171 +1193,6 @@ class ParquetReader:
         them.  `table` is a pa.Table or sidecar.EncodedSegment."""
         return self._finalize_windows(
             self._dispatch_segment_table(table, plan))
-
-    async def _cached_windows_mesh(self, plan: ScanPlan, cached: dict,
-                                   to_read: list):
-        """Mesh twin of _cached_windows' read path: merge windows from
-        DIFFERENT segments batch into rounds of mesh-size
-        sharded_merge_dedup programs (shard-local sort/dedup, no
-        collectives), so every query shape drives all chips — the
-        reference's UnionExec-parallel merge (storage.rs:342-368) with
-        segments as the shard axis.  Segments still yield in plan order,
-        each one only after all its windows' rounds have run."""
-        from horaedb_tpu.parallel.scan import shard_leading_axis
-
-        n_dev = self.mesh.devices.size
-        # pinned for the whole scan: window prep (sort normalization)
-        # and the round kernel must use the SAME impl even if
-        # set_merge_impl flips mid-scan
-        scan_host_perm = merge_ops.merge_impl() == "host_perm"
-        feed = self._segment_feed(plan, to_read).__aiter__()
-        # buffer entries: [seg, windows(list, filled in round order),
-        #                  outstanding window count, read_s]
-        buffer: list[list] = []
-        pending: list[tuple[list, dict, int, int, dict]] = []
-
-        def run_round(round_items: list) -> None:
-            cap = max(it[3] for it in round_items)
-            names = list(round_items[0][1].keys())
-            stacks = {}
-            for name in names:
-                rows = np.zeros(
-                    (n_dev, cap), dtype=round_items[0][1][name].dtype)
-                for d, (_e, cols, n_win, wcap, _enc) in enumerate(round_items):
-                    rows[d, :wcap] = cols[name]
-                stacks[name] = shard_leading_axis(self.mesh, rows)
-            n_valid = np.zeros(n_dev, dtype=np.int32)
-            for d, it in enumerate(round_items):
-                n_valid[d] = it[2]
-            pk_names = self._pk_names_in(names)
-            value_names = [nm for nm in names
-                           if nm not in pk_names and nm != SEQ_COLUMN_NAME]
-            # only the device_sort A/B mode reaches here (host_perm
-            # windows arrive pre-merged and skip the rounds entirely)
-            fn = self._mesh_merge_fns.get(len(pk_names))
-            if fn is None:
-                from horaedb_tpu.parallel.scan import sharded_merge_dedup
-
-                fn = sharded_merge_dedup(self.mesh, num_pks=len(pk_names))
-                self._mesh_merge_fns[len(pk_names)] = fn
-            out_pks, out_seq, out_vals, _valid, num_runs = fn(
-                tuple(stacks[nm] for nm in pk_names),
-                stacks[SEQ_COLUMN_NAME],
-                tuple(stacks[nm] for nm in value_names),
-                shard_leading_axis(self.mesh, n_valid))
-            runs_host = np.asarray(num_runs)
-            for d, (entry, _cols, _n, _wcap, enc) in enumerate(round_items):
-                columns = {
-                    **{nm: a[d] for nm, a in zip(pk_names, out_pks)},
-                    SEQ_COLUMN_NAME: out_seq[d],
-                    **{nm: a[d] for nm, a in zip(value_names, out_vals)},
-                }
-                entry[1].append(encode.DeviceBatch(
-                    columns=columns, encodings=enc,
-                    n_valid=int(runs_host[d]), capacity=cap))
-                entry[2] -= 1
-
-        async def enqueue(entry: list, descs: list) -> None:
-            if scan_host_perm:
-                # windows arrive merged+deduped on host (_prepare does
-                # the k-way merge): no shard merge rounds to run — the
-                # mesh engages at the AGGREGATE stage, where stacked
-                # windows shard over chips with psum combines
-                for cols, n_win, wcap, enc in descs:
-                    entry[1].append(encode.DeviceBatch(
-                        columns=cols, encodings=enc, n_valid=n_win,
-                        capacity=wcap))
-                return
-            entry[2] += len(descs)
-            for cols, n_win, wcap, enc in descs:
-                pending.append((entry, cols, n_win, wcap, enc))
-            while len(pending) >= n_dev:
-                await self._run_pool(plan.pool, run_round, pending[:n_dev])
-                del pending[:n_dev]
-
-        try:
-            for seg in plan.segments:
-                deadline_checkpoint()  # between-segment cancellation point
-                if id(seg) in cached:
-                    buffer.append([seg, cached[id(seg)], 0, 0.0])
-                else:
-                    fseg, is_streamed, table, read_s = await feed.__anext__()
-                    assert fseg is seg
-                    if is_streamed:
-                        # feed rounds window-by-window: at most a round's
-                        # worth of un-merged host windows is ever resident
-                        t0 = time.perf_counter()
-                        entry = [seg, [], 0, 0.0]
-                        buffer.append(entry)
-                        es_iter = await self._open_sidecar_stream(seg,
-                                                                  plan)
-                        if es_iter is not None:
-                            try:
-                                async for es in es_iter:
-                                    await enqueue(entry, await
-                                                  self._run_pool(
-                                        plan.pool,
-                                        self._prepare_encoded_windows,
-                                        es, scan_host_perm))
-                            except Exception as exc:  # noqa: BLE001
-                                # windows already enqueued into mesh
-                                # rounds can't be retracted: fail to the
-                                # outer replan (same as a mid-stream
-                                # compaction race), not a silent retry
-                                raise Error(
-                                    "sidecar stream failed mid-mesh-"
-                                    f"round: {exc}") from exc
-                        else:
-                            async for batch in self._stream_window_batches(
-                                    seg, plan):
-                                await enqueue(entry, await self._run_pool(
-                                    plan.pool,
-                                    self._prepare_merge_windows, batch,
-                                    scan_host_perm))
-                        entry[3] = time.perf_counter() - t0
-                    else:
-                        descs = []
-                        if table.num_rows:
-                            def encode_windows(tbl=table):
-                                if isinstance(tbl, sidecar.EncodedSegment):
-                                    return self._prepare_encoded_windows(
-                                        tbl, scan_host_perm)
-                                batch = tbl.combine_chunks().to_batches()[0]
-                                return self._prepare_merge_windows(
-                                    batch, scan_host_perm)
-
-                            descs = await self._run_pool(plan.pool,
-                                                         encode_windows)
-                        entry = [seg, [], 0, read_s]
-                        buffer.append(entry)
-                        await enqueue(entry, descs)
-                while buffer and buffer[0][2] == 0:
-                    seg0, windows, _outstanding, read_s0 = buffer.pop(0)
-                    if plan.use_cache and id(seg0) not in cached:
-                        self.scan_cache.put(self._cache_key(seg0, plan),
-                                            windows)
-                    yield seg0, windows, read_s0
-            if pending:
-                # tail round: pad with empty windows bound to a discard
-                # entry so real segments' window lists stay exact
-                discard = [None, [], len(pending) - n_dev, 0.0]
-                _e, cols0, _n, wcap0, enc0 = pending[-1]
-                tail = list(pending)
-                while len(tail) < n_dev:
-                    tail.append((discard, cols0, 0, wcap0, enc0))
-                await self._run_pool(plan.pool, run_round, tail)
-                pending.clear()
-            while buffer:
-                seg0, windows, outstanding, read_s0 = buffer.pop(0)
-                assert outstanding == 0
-                if plan.use_cache and id(seg0) not in cached:
-                    self.scan_cache.put(self._cache_key(seg0, plan),
-                                        windows)
-                yield seg0, windows, read_s0
-
-        finally:
-            # deterministic cleanup of the feed's primed prefetch task
-            await feed.aclose()
 
     async def _segment_feed(self, plan: ScanPlan,
                             segments: list[SegmentPlan]):
@@ -1772,9 +1573,9 @@ class ParquetReader:
         """Evict everything HBM-RESIDENT that derives from cached
         windows — round stacks, fused-replay plans, per-window memos
         (device column copies, aggregation grids) — while KEEPING the
-        post-merge windows themselves, which live in host RAM under the
-        default host_perm merge.  This is the 'HBM evicted' state the
-        bench ladder measures: the next query re-stacks/re-uploads from
+        post-merge windows themselves, which live in host RAM.  This is
+        the 'HBM evicted' state the bench ladder measures: the next
+        query re-stacks/re-uploads from
         host windows instead of re-reading and re-merging.  (Tests and
         benchmarks only; production eviction is the LRUs' own.)"""
         with self._stack_cache_lock:
@@ -2059,81 +1860,6 @@ class ParquetReader:
                 yield tbl.combine_chunks().to_batches()[0]
 
     @_timed_stage("encode_merge", "scan.windows")
-    def _prepare_merge_windows(self, batch: pa.RecordBatch,
-                               host_perm: Optional[bool] = None) -> list:
-        """Host half of the merge: encode + PK-window planning + padding,
-        WITHOUT dispatching any device program.  Returns
-        [(padded host cols, n_win, capacity, encodings)] — the mesh
-        round scheduler stacks these onto the shard axis.
-
-        `host_perm` pins the merge-impl decision for a whole scan (the
-        caller captures merge_impl() once): window prep and the round
-        kernel must agree, or an impl flip mid-scan would hand unsorted
-        windows to the sort-free kernel."""
-        _STAGE_ROWS["encode_merge"].inc(batch.num_rows)
-        dev = encode.encode_batch(batch)
-        return self._prepare_windows_dev(dev, list(batch.schema.names),
-                                         host_perm)
-
-    @_timed_stage("encode_merge", "scan.windows")
-    def _prepare_encoded_windows(self, es: "sidecar.EncodedSegment",
-                                 host_perm: Optional[bool] = None) -> list:
-        """Sidecar twin of _prepare_merge_windows (mesh window prep)."""
-        _STAGE_ROWS["encode_merge"].inc(es.n)
-        return self._prepare_windows_dev(self._encoded_to_device_batch(es),
-                                         list(es.names), host_perm)
-
-    def _prepare_windows_dev(self, dev: encode.DeviceBatch, names: list,
-                             host_perm: Optional[bool] = None) -> list:
-        pk_names = self._pk_names_in(names)
-        ensure(len(pk_names) == self.schema.num_primary_keys,
-               "projection lost primary key columns")
-        n = dev.n_valid
-        window = self.config.scan.max_window_rows
-        if n == 0:
-            return []
-        if host_perm is None:
-            host_perm = merge_ops.merge_impl() == "host_perm"
-        if host_perm:
-            seq_h = np.asarray(dev.columns[SEQ_COLUMN_NAME])[:n]
-            seq_ordered = bool(np.all(seq_h[1:] >= seq_h[:-1]))
-        host_cols = {name: np.asarray(c)[:n]
-                     for name, c in dev.columns.items()}
-        if n <= window:
-            selections: list[Optional[np.ndarray]] = [None]
-        else:
-            # partition on the first NON-constant pk (same as the
-            # non-mesh path): windowing on a constant column would
-            # produce one unbounded window and defeat the HBM budget
-            part_name = next(
-                (nm for nm in pk_names
-                 if host_cols[nm][0] != host_cols[nm][-1]
-                 or not bool((host_cols[nm] == host_cols[nm][0]).all())),
-                pk_names[0])
-            selections = _plan_pk_windows(host_cols[part_name], window)
-        if host_perm:
-            # same host merge+dedup as _dispatch_merged_windows: the
-            # shard round then needs NO merge kernel at all
-            return _host_merge_window_descs(dev, host_cols, pk_names,
-                                            seq_h, seq_ordered, selections,
-                                            n)
-        descs = []
-        for sel in selections:
-            if sel is not None and not len(sel):
-                continue
-            if sel is None:
-                descs.append(({kk: np.asarray(v) for kk, v
-                               in dev.columns.items()},
-                              n, dev.capacity, dev.encodings))
-                continue
-            n_win = len(sel)
-            cap = encode.pad_capacity(n_win)
-            padded = {kk: np.pad(v[sel], (0, cap - n_win))
-                      for kk, v in host_cols.items()}
-            descs.append((padded, n_win, cap, dev.encodings))
-        return descs
-
-    @_timed_stage("encode_merge", "scan.windows")
     def _dispatch_merged_windows(self, batch: pa.RecordBatch) -> list:
         """Merge one segment with bounded memory: segments above
         scan.max_window_rows are split into PK-code-range windows, each a
@@ -2142,12 +1868,10 @@ class ParquetReader:
         streaming analogue of the reference's pull-based MergeStream
         (SURVEY.md hard part #5).
 
-        Under the default host_perm impl the merge is a host
-        permutation-plan + run-keep over the pre-sorted SST runs and the
-        windows stay HOST-resident (rows cross to the device only as
-        batched stacks in the aggregate path).  Under device_sort the
-        original per-window lax.sort programs dispatch WITHOUT syncing;
-        _finalize_windows syncs the run counts either way.
+        The merge is a host permutation-plan + run-keep over the
+        pre-sorted SST runs and the windows stay HOST-resident (rows
+        cross to the device only as batched stacks in the aggregate
+        path).
         """
         _STAGE_ROWS["encode_merge"].inc(batch.num_rows)
         dev = encode.encode_batch(batch)  # host-resident numpy columns
@@ -2182,19 +1906,15 @@ class ParquetReader:
         pk_names = self._pk_names_in(names)
         ensure(len(pk_names) == self.schema.num_primary_keys,
                "projection lost primary key columns")
-        value_names = [n for n in names
-                       if n not in pk_names and n != SEQ_COLUMN_NAME]
         n = dev.n_valid
         host_cols = {name: np.asarray(c)[:n] for name, c in dev.columns.items()}
 
-        # sort-operand elision (the variadic sort is the scan's hottest
-        # kernel; comparator cost and data movement scale with operands):
+        # merge-key elision (comparator cost scales with the key count):
         # - PK columns constant across the segment (e.g. a single-metric
-        #   table's metric/field ids) can't affect the order — carry them
-        #   as values instead of sorting by them;
+        #   table's metric/field ids) can't affect the order;
         # - seq non-decreasing with row index (SSTs are concatenated in
         #   file-id order and seq IS the file id) means the stable PK
-        #   sort already leaves the highest-seq row last per run.
+        #   merge already leaves the highest-seq row last per run.
         def is_const(a: np.ndarray) -> bool:
             # first!=last shortcuts the full scan for sorted columns
             return len(a) == 0 or (a[0] == a[-1] and bool((a == a[0]).all()))
@@ -2203,8 +1923,6 @@ class ParquetReader:
                          if not is_const(host_cols[nm])]
         if not sort_pk_names:
             sort_pk_names = pk_names[:1]
-        carry_names = [nm for nm in pk_names
-                       if nm not in sort_pk_names] + value_names
         seq_h = host_cols[SEQ_COLUMN_NAME]
         seq_ordered = bool(n == 0 or np.all(seq_h[1:] >= seq_h[:-1]))
 
@@ -2216,52 +1934,19 @@ class ParquetReader:
             # meaningfully bounded even when pk 0 is constant
             selections = _plan_pk_windows(host_cols[sort_pk_names[0]], window)
 
-        if merge_ops.merge_impl() == "host_perm":
-            # The merge runs ENTIRELY on host: plan the k-way-merge
-            # permutation over the pre-sorted SST runs, keep the last
-            # row per PK run, and hand out HOST-resident windows.  No
-            # per-window device round trips — the device sees rows only
-            # as large stacked uploads in the aggregate path, and row
-            # scans decode without a device->host fetch.
-            return [
-                (cols, enc, k, cap)
-                for cols, k, cap, enc in _host_merge_window_descs(
-                    dev, host_cols, sort_pk_names, seq_h, seq_ordered,
-                    selections, n)
-            ]
-
-        dispatched = []
-        for sel in selections:
-            if sel is None:
-                # single-window fast path: encode_batch already padded
-                padded, n_win, cap = dev.columns, n, dev.capacity
-            else:
-                sub = {k: v[sel] for k, v in host_cols.items()}
-                n_win = len(sel)
-                cap = encode.pad_capacity(n_win)
-                padded = {k: np.pad(v, (0, cap - n_win))
-                          for k, v in sub.items()}
-            if n_win == 0:
-                continue
-            dev_cols = {name: deviceprof.device_put(c)
-                        for name, c in padded.items()}
-            pks = tuple(dev_cols[name] for name in sort_pk_names)
-            seq = dev_cols[SEQ_COLUMN_NAME]
-            values = tuple(dev_cols[name] for name in carry_names)
-            out_pks, out_seq, out_values, _out_valid, num_runs = \
-                merge_ops.merge_dedup_last(pks, seq, values, n_win,
-                                           seq_in_row_order=seq_ordered)
-            columns = {**{name: a for name, a in zip(sort_pk_names, out_pks)},
-                       SEQ_COLUMN_NAME: out_seq,
-                       **{name: a for name, a in zip(carry_names, out_values)}}
-            dispatched.append((columns, dev.encodings, num_runs, cap))
-        return dispatched
+        # The merge runs ENTIRELY on host: plan the k-way-merge
+        # permutation over the pre-sorted SST runs, keep the last row
+        # per PK run, and hand out HOST-resident windows.  No per-window
+        # device round trips — the device sees rows only as large
+        # stacked uploads in the aggregate path, and row scans decode
+        # without a device->host fetch.
+        return _host_merge_window_descs(dev, host_cols, sort_pk_names,
+                                        seq_h, seq_ordered, selections, n)
 
     @staticmethod
     def _finalize_windows(dispatched: list) -> list:
-        """Sync the dispatched merges' run counts (int() blocks until the
-        device finishes) and wrap them as DeviceBatches.  Split from
-        dispatch so callers can overlap merge compute across segments.
+        """Wrap the dispatched host merges as DeviceBatches.  Split from
+        dispatch so callers can overlap device work across segments.
         Device-decode entries (in-flight fused dispatches) finalize
         into DeviceParts — finished per-segment aggregate partials that
         ride the same windows list."""
@@ -2354,11 +2039,11 @@ class ParquetReader:
         on the plan); scan_aggregate takes the fused path on the first
         two."""
         if self.fused_aggregate_ok(plan) and not self.router_covers(plan):
-            if (plan.use_cache and self.mesh is None and self._replay_cache
+            if (plan.use_cache and self._replay_cache
                     and self._replay_key(plan, spec) in self._replay_cache):
                 return "replay"
             return "fused_acc"
-        if self._mesh_plan_ok(plan, count=False):
+        if self._mesh_plan_ok(plan):
             return "mesh"
         if (plan.mode is UpdateMode.OVERWRITE
                 and self._device_decode_plan_ok(plan, count=False)):
@@ -2385,8 +2070,8 @@ class ParquetReader:
         return True
 
     def _fused_agg_ok_base(self, plan: Optional[ScanPlan] = None) -> bool:
-        """The fused aggregate's own gates: single-device host_perm
-        mode, and by default ACCELERATOR backends only — there,
+        """The fused aggregate's own gates: single-device mode, and
+        by default ACCELERATOR backends only — there,
         device->host is the scarce resource (the per-flush partial
         downloads dominate) and scatters are fast; on XLA-CPU the trade
         inverts — downloads are free and scatter is the slow op, so the
@@ -2400,8 +2085,6 @@ class ParquetReader:
         two-phase (all windows collected before the union group space is
         known), so unlike the parts pipeline it pins every window in
         host RAM for the query's duration — the budget is the bound."""
-        if self.mesh is not None or merge_ops.merge_impl() != "host_perm":
-            return False
         if self.scan_mesh is not None:
             # [scan.mesh] supersedes the fused single-chip accumulator:
             # the mesh's parts path is the one that scales across chips
@@ -2466,9 +2149,6 @@ class ParquetReader:
             # decode rounds (plan.decode_defer; _run_mesh_decode_round)
             # — decode shards along the time axis with the aggregation
             # instead of declining here
-        if self.mesh is not None:
-            note("mesh")
-            return False
         if plan.mode is not UpdateMode.OVERWRITE:
             note("append_mode")
             return False
@@ -2511,7 +2191,7 @@ class ParquetReader:
         if counted is None:
             counted = set()
         replay_key = None
-        if plan.use_cache and self.mesh is None:
+        if plan.use_cache:
             replay_key = self._replay_key(plan, spec)
             entry = self._replay_cache.get(replay_key)
             if entry is not None:
@@ -2919,17 +2599,16 @@ class ParquetReader:
         device rounds) over `plan.segments`.
 
         Windows from different segments batch into rounds of
-        `scan.agg_batch_windows` (mesh size when meshed) and run as ONE
-        compiled program per round — the reference parallelizes segments
-        under UnionExec (storage.rs:342-368); here segments share the
-        batch/mesh leading axis.  Cross-segment batching is safe because
+        `scan.agg_batch_windows` and run as ONE compiled program per
+        round — the reference parallelizes segments under UnionExec
+        (storage.rs:342-368); here segments share the batch leading
+        axis.  Cross-segment batching is safe because
         segments partition time and windows partition PKs: no two
         windows share a (group, bucket, timestamp) cell, so the host
         combine has no tie-break subtleties."""
         from collections import deque
 
-        batch_w = (self.mesh.devices.size if self.mesh is not None
-                   else max(1, self.config.scan.agg_batch_windows))
+        batch_w = max(1, self.config.scan.agg_batch_windows)
         queue: list[tuple[int, encode.DeviceBatch, tuple]] = []
         parts: dict[int, list] = {}
         pending: dict[int, int] = {}
@@ -3072,23 +2751,13 @@ class ParquetReader:
 
     # ---- the 2-D scan mesh ([scan.mesh]; docs/parallel.md) -----------------
 
-    def _mesh_plan_ok(self, plan: ScanPlan, count: bool = True) -> bool:
+    def _mesh_plan_ok(self, plan: ScanPlan) -> bool:
         """Plan-level [scan.mesh] routing gate; per-round gates (sum
         overlap, count bound, grid budget) live in _run_mesh_round and
-        fall back per round.  Counted reasons mirror the device-decode
-        discipline (scan_mesh_fallback_total{reason=}) unless `count`
-        is False (aggregate_route probes without recording)."""
-        if self.scan_mesh is None:
-            return False
-        if plan.mode is not UpdateMode.OVERWRITE:
-            return False
-        if merge_ops.merge_impl() != "host_perm":
-            # device_sort windows live sharded on the legacy segment
-            # mesh; the 2-D scan consumes host-merged windows
-            if count:
-                note_mesh_fallback("merge_impl")
-            return False
-        return True
+        fall back per round, each with its counted reason
+        (scan_mesh_fallback_total{reason=})."""
+        return (self.scan_mesh is not None
+                and plan.mode is UpdateMode.OVERWRITE)
 
     def _mesh_topk_ok(self, plan: ScanPlan, spec: AggregateSpec,
                       tk) -> bool:
@@ -4221,11 +3890,8 @@ class ParquetReader:
         (distinct specs -> full-stack misses) re-stack cached HBM arrays
         with only KB-sized remap/shift uploads; on XLA-CPU the numpy
         stack is a memcpy and the extra dispatches would only slow it.
-        Meshed scans keep the sharded bulk upload (device copies would
-        live on one device).  HORAEDB_DEVCOL_STACK=1/0 forces (tests
-        cover the device-col path on the CPU backend)."""
-        if self.mesh is not None:
-            return False
+        HORAEDB_DEVCOL_STACK=1/0 forces (tests cover the device-col
+        path on the CPU backend)."""
         import os
 
         forced = os.environ.get("HORAEDB_DEVCOL_STACK", "")
@@ -4242,8 +3908,6 @@ class ParquetReader:
         segmented scatters ~20x), device elsewhere.  HORAEDB_HOST_AGG=1/0
         forces, mirroring HORAEDB_DEVCOL_STACK, so CPU CI keeps coverage
         of the device parts kernel."""
-        if self.mesh is not None:
-            return False
         return host_agg_default()
 
     def _window_device_cols(self, w: encode.DeviceBatch,
@@ -4310,10 +3974,7 @@ class ParquetReader:
           memoized device columns (_window_device_cols) so only the
           FIRST query over a window pays the upload;
         - remap/shift/lo are placed on device HERE and cached, so a
-          full cache hit issues ZERO transfers;
-        - under a mesh, placement uses the segment-axis sharding
-          directly (cached rounds live sharded — re-placing per query
-          would re-pay the transfer).
+          full cache hit issues ZERO transfers.
 
         Stacked inputs live in a reader-level LRU split in TWO entries:
         the big ts/gid/val stacks under a range-independent key
@@ -4333,13 +3994,7 @@ class ParquetReader:
         # stacks of one composition never alias in the LRU
         sharded = put is not None
         if put is None:
-            if self.mesh is not None:
-                from horaedb_tpu.parallel.scan import shard_leading_axis
-
-                put = functools.partial(shard_leading_axis, self.mesh)
-                sharded = True
-            else:
-                put = deviceprof.device_put
+            put = deviceprof.device_put
         if stack_key is None:
             space_fp = (len(group_space), hash(group_space.tobytes()))
             stack_key = self._round_stack_key(items[0][0], spec, plan,
@@ -4477,13 +4132,10 @@ class ParquetReader:
             # queries slice cached grids instead of re-scanning rows.
             return _host_window_partials(items, spec, plan)
 
-        if self.mesh is not None:
-            batch_w = self.mesh.devices.size
-        else:
-            # pow2 width >= len(items): full rounds share one program,
-            # tail/small queries use narrower ones (bounded variants)
-            batch_w = min(max(1, self.config.scan.agg_batch_windows),
-                          1 << (len(items) - 1).bit_length())
+        # pow2 width >= len(items): full rounds share one program,
+        # tail/small queries use narrower ones (bounded variants)
+        batch_w = min(max(1, self.config.scan.agg_batch_windows),
+                      1 << (len(items) - 1).bit_length())
         round_values = np.unique(np.concatenate([it[2][0] for it in items]))
         g = len(round_values)
         g_pad = max(8, 1 << (g - 1).bit_length())
@@ -4504,25 +4156,10 @@ class ParquetReader:
         total = self._dev_scalar(spec.num_buckets)
         t_dev = time.perf_counter()
         with self._phase("scan.dispatch", windows=len(items)):
-            if self.mesh is not None:
-                from horaedb_tpu.parallel.scan import sharded_remap_partials
-
-                # memoize the compiled program per grid shape — rebuilding
-                # the shard_map closure would recompile every round
-                fn_key = (g_pad, width, spec.which)
-                fn = self._mesh_agg_fns.get(fn_key)
-                if fn is None:
-                    fn = sharded_remap_partials(self.mesh, num_groups=g_pad,
-                                                num_buckets=width,
-                                                which=spec.which)
-                    self._mesh_agg_fns[fn_key] = fn
-                stacked = fn(ts_s, gid_s, val_s, remap_d, shift_d, lo_dev, total,
-                             self._dev_scalar(spec.bucket_ms, "arr1"))
-            else:
-                stacked = _batched_window_partials_jit(
-                    ts_s, gid_s, val_s, remap_d, shift_d,
-                    lo_dev, total, self._dev_scalar(spec.bucket_ms),
-                    num_groups=g_pad, num_buckets=width, which=spec.which)
+            stacked = _batched_window_partials_jit(
+                ts_s, gid_s, val_s, remap_d, shift_d,
+                lo_dev, total, self._dev_scalar(spec.bucket_ms),
+                num_groups=g_pad, num_buckets=width, which=spec.which)
         # per-window partials fold on host in f64 (bit-equal to the
         # single-window path); padding windows are sliced away
         host = deviceprof.download(stacked, fn="batched_window_partials",
@@ -4872,10 +4509,9 @@ def _group_has_data_jit(count):
 def _batched_window_partials_jit(ts, gid, vals, remap, shift, lo, total,
                                  bucket_ms, num_groups: int,
                                  num_buckets: int, which: tuple):
-    """Single-device twin of parallel.scan.sharded_remap_partials: vmap
-    over the window axis instead of shard_map over the mesh — one device
-    dispatch aggregates a whole round of windows into window-LOCAL grids
-    of `num_buckets` buckets starting at each window's `lo` bucket."""
+    """vmap over the window axis — one device dispatch aggregates a
+    whole round of windows into window-LOCAL grids of `num_buckets`
+    buckets starting at each window's `lo` bucket."""
     from horaedb_tpu.ops import downsample
 
     def one(ts_b, gid_b, vals_b, remap_b, shift_b, lo_b):
@@ -4996,14 +4632,12 @@ def _host_merge_window_descs(dev: encode.DeviceBatch, host_cols: dict,
                              sort_pk_names: list[str], seq_h: np.ndarray,
                              seq_ordered: bool, selections: list,
                              n: int) -> list:
-    """THE host merge under the default host_perm impl, shared by the
-    single-device and mesh window preps so the two paths cannot drift:
-    per window, plan the k-way-merge permutation over pre-sorted SST
-    runs (_plan_merge_perm contract), keep the last row of each PK run,
-    and emit padded HOST-resident column dicts.
+    """THE host merge: per window, plan the k-way-merge permutation
+    over pre-sorted SST runs (_plan_merge_perm contract), keep the last
+    row of each PK run, and emit padded HOST-resident column dicts.
 
-    Returns [(cols, n_valid, capacity, encodings)] — deduped, PK-sorted
-    windows ready to wrap as DeviceBatches."""
+    Returns [(cols, encodings, n_valid, capacity)] — deduped, PK-sorted
+    windows ready for _finalize_windows to wrap as DeviceBatches."""
     descs = []
     sort_cols = [host_cols[nm] for nm in sort_pk_names]
     for sel in selections:
@@ -5024,7 +4658,7 @@ def _host_merge_window_descs(dev: encode.DeviceBatch, host_cols: dict,
                 # no duplicates, already padded by encode_batch
                 descs.append(({kk: np.asarray(v) for kk, v
                                in dev.columns.items()},
-                              n, dev.capacity, dev.encodings))
+                              dev.encodings, n, dev.capacity))
                 continue
             idx = np.flatnonzero(keep)
         else:
@@ -5032,7 +4666,7 @@ def _host_merge_window_descs(dev: encode.DeviceBatch, host_cols: dict,
         cap = encode.pad_capacity(k)
         cols = {kk: np.pad(v[idx], (0, cap - k))
                 for kk, v in host_cols.items()}
-        descs.append((cols, k, cap, dev.encodings))
+        descs.append((cols, dev.encodings, k, cap))
     return descs
 
 
@@ -5041,9 +4675,8 @@ def _host_dedup_keep(sort_cols: list[np.ndarray]) -> np.ndarray:
     equal-PK run survives (rows arrive with the preferred — highest
     sequence — row last; see _plan_merge_perm's ordering contract).
 
-    This is the host half of last-value dedup under the default
-    host_perm merge: with the permutation already planned on host, the
-    run-boundary compare is a single vectorized pass over columns the
+    This is the host half of last-value dedup: with the permutation
+    already planned on host, the run-boundary compare is a single vectorized pass over columns the
     host just decoded — shipping rows to the device only to compare
     neighbours and ship survivors back would pay two transfers for
     an O(n) bandwidth-bound op.  The devices' FLOPs are saved for the

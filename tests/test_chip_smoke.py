@@ -71,9 +71,9 @@ def _smoke(tmp_path, *args) -> subprocess.CompletedProcess:
 def test_chip_smoke_dry_run_completes(tmp_path):
     """`--platform cpu --rows 200000`: ingest over HTTP with read-back,
     the five query shapes against the numpy reference, the restart leg
-    against the compile cache, the Pallas child (interpret mode) — and
-    the driving process never imports jax (asserted by the script from
-    its own sys.modules before it prints the result)."""
+    against the compile cache — and the driving process never imports
+    jax (asserted by the script from its own sys.modules before it
+    prints the result)."""
     out = _smoke(tmp_path, "--platform", "cpu")
     assert out.returncode == 0, (out.stdout[-3000:], out.stderr[-3000:])
     last = json.loads(out.stdout.strip().splitlines()[-1])
@@ -84,7 +84,7 @@ def test_chip_smoke_dry_run_completes(tmp_path):
     assert list(summary)[-1] == "claim" and summary["claim"] is None
     steps = {s["step"] for s in summary["steps"]}
     assert {"ingest", "query.full.cold", "query.sub.cold", "query.point",
-            "query.topk", "query.raw", "restart", "pallas"} <= steps
+            "query.topk", "query.raw", "restart"} <= steps
     # the data directory lived in TMPDIR and is gone
     assert not list(tmp_path.glob("horaedb-chip-smoke-*"))
 

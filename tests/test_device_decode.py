@@ -2,8 +2,8 @@
 filter + merge-dedup + bucket-aggregate dispatch (ops/device_decode.py)
 byte-compared against the host-decode control across agg sets, filters,
 ranges, top-k, and seeded write/flush/compact/evict interleavings, plus
-per-reason fallback counters, `[scan.decode]` config plumbing, the
-decode-seam lint rule, and the classified pallas fallback guard.
+per-reason fallback counters, `[scan.decode]` config plumbing, and the
+decode-seam lint rule.
 
 The seeded chaos test rides `make chaos` with knobs DECODE_SEED /
 DECODE_SCHEDULES; the fast tier-1 variant runs a fixed small subset.
@@ -1100,34 +1100,6 @@ def test_env_force_overrides_config(runtimes):
             await s.close()
 
     run(go())
-
-
-# ---------------------------------------------------------------------------
-# pallas guard: a selected kernel that fails raises
-# ---------------------------------------------------------------------------
-
-
-def test_pallas_failure_raises_instead_of_serving_xla(monkeypatch):
-    import jax.numpy as jnp
-
-    from horaedb_tpu.ops import downsample
-    from horaedb_tpu.ops import pallas_kernels as pk
-
-    def boom(*a, **k):
-        raise RuntimeError("injected kernel bug")
-
-    monkeypatch.setattr(pk, "pallas_time_bucket_aggregate", boom)
-    downsample.set_downsample_impl("pallas")
-    try:
-        # the XLA program could answer this — it must not, under the
-        # Pallas kernel's name
-        with pytest.raises(RuntimeError, match="injected kernel bug"):
-            downsample.time_bucket_aggregate(
-                jnp.zeros(128, jnp.int32), jnp.zeros(128, jnp.int32),
-                jnp.zeros(128, jnp.float32), 10, 100,
-                num_groups=4, num_buckets=4)
-    finally:
-        downsample.set_downsample_impl("xla")
 
 
 # ---------------------------------------------------------------------------

@@ -33,7 +33,6 @@ import pytest
 
 from horaedb_tpu.common import ReadableDuration
 from horaedb_tpu.common import runtimes as runtimes_mod
-from horaedb_tpu.common.error import Error
 from horaedb_tpu.objstore import MemoryObjectStore
 from horaedb_tpu.ops import device_decode as dd_mod
 from horaedb_tpu.ops import filter as F
@@ -597,21 +596,6 @@ def test_seeded_mesh_decode_chaos_fast(runtimes):
 # ---------------------------------------------------------------------------
 
 
-def test_decode_mesh_mode_conflict_rejected_at_open(runtimes):
-    """decode.mode="device" under the legacy 1-D segment mesh is a
-    standing misconfiguration (every query would decline with a
-    counted fallback): it must fail AT OPEN, not at query time."""
-
-    async def go():
-        with pytest.raises(Error, match="legacy"):
-            await open_storage(MemoryObjectStore(), runtimes,
-                               mesh={"enabled": False},
-                               decode={"mode": "device"},
-                               mesh_devices=4)
-
-    run(go())
-
-
 def test_close_evicts_mesh_decode_state(runtimes):
     """drop_hbm_state() must evict the fused-round stacks and device
     scalars; close() must additionally drop the compiled mesh programs
@@ -716,3 +700,45 @@ def test_existing_lax_sort_sites_enumerated():
     assert sites, "no device lax.sort site found at all"
     outside = [x for x in sites if x[0] != "ops/merge.py"]
     assert not outside, f"device lax.sort outside ops/merge.py: {outside}"
+
+
+def test_lint_env_switch_rule(tmp_path):
+    """tools/lint.py must flag an os.environ / os.getenv access of a
+    HORAEDB_* name under horaedb_tpu/ outside the five that remain, in
+    every form a read takes, so a deleted switch (the downsample and
+    merge implementations went with theirs) does not come back as a
+    new one; the remaining names, other prefixes and files outside
+    the package pass."""
+    import importlib.util
+    import pathlib
+
+    spec = importlib.util.spec_from_file_location(
+        "lint_under_test",
+        pathlib.Path(__file__).resolve().parent.parent / "tools" / "lint.py")
+    lint = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lint)
+
+    pkg = tmp_path / "horaedb_tpu" / "ops"
+    pkg.mkdir(parents=True)
+    for i, read in enumerate((
+            'os.environ.get("HORAEDB_MERGE_IMPL", "host_perm")',
+            'os.getenv("HORAEDB_DOWNSAMPLE_IMPL")',
+            'os.environ["HORAEDB_NEW_ROUTE"]',
+            '"HORAEDB_NEW_ROUTE" in os.environ',
+            'os.environ.pop("HORAEDB_NEW_ROUTE", None)')):
+        bad = pkg / f"rogue{i}.py"
+        bad.write_text(f"import os\n\nX = {read}\n")
+        assert any("environment switch" in p
+                   for p in lint.lint_file(bad)), read
+    fine = pkg / "fine.py"
+    fine.write_text(
+        "import os\n\n"
+        + "".join(f'{n[8:]} = os.environ.get("{n}", "")\n'
+                  for n in sorted(lint._ENV_SWITCHES))
+        + 'ROWS = os.environ.get("BENCH_ROWS")\n'
+        + 'DOC = "HORAEDB_MERGE_IMPL went in PR 30"\n')
+    assert not lint.lint_file(fine)
+    outside = tmp_path / "tests" / "x.py"
+    outside.parent.mkdir()
+    outside.write_text('import os\n\nX = os.environ["HORAEDB_ANYTHING"]\n')
+    assert not lint.lint_file(outside)

@@ -19,6 +19,7 @@ import asyncio
 import os
 import random
 
+import jax
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -191,7 +192,7 @@ async def _query_both(s, req, spec, tk=None, ctx=""):
 # ---------------------------------------------------------------------------
 
 
-def test_mesh_vs_off_bit_identity_basic(runtimes):
+def test_mesh_vs_off_bit_identity_basic(runtimes, monkeypatch):
     """Overlapping writes (cross-SST duplicate PKs exercising dedup
     through the mesh rounds), every agg set, filters incl. In/range,
     and top-k by every ranking: mesh-on grids must be byte-identical
@@ -229,6 +230,31 @@ def test_mesh_vs_off_bit_identity_basic(runtimes):
                 await _query_both(s, req, spec, tk=tk, ctx=f"tk={tk}")
             assert read_mod._MESH_ROUNDS.value > rounds0, \
                 "mesh never dispatched a round"
+            # a repeat whose rounds run again (parts memo emptied,
+            # windows still cached) takes its time-sharded round
+            # stacks from the stack cache: no (T, cap) stack is built
+            # or uploaded again, only the rounds' (T,) segment ids
+            spec = agg_spec(lo, hi, which=("avg",))
+            req = ScanRequest(range=TimeRange.new(lo, hi))
+            first = await s.scan_aggregate(req, spec)
+            s.reader.parts_memo.clear()
+            hits0 = s.reader._stack_cache_hits
+            misses0 = s.reader._stack_cache_misses
+            puts = []
+            real_put = jax.device_put
+
+            def counting_put(x, *a, **kw):
+                puts.append(np.shape(x))
+                return real_put(x, *a, **kw)
+
+            monkeypatch.setattr(jax, "device_put", counting_put)
+            again = await s.scan_aggregate(req, spec)
+            monkeypatch.setattr(jax, "device_put", real_put)
+            assert s.reader._stack_cache_hits > hits0
+            assert s.reader._stack_cache_misses == misses0
+            assert all(len(shape) <= 1 for shape in puts), \
+                f"repeat meshed query uploaded: {puts}"
+            _assert_same(first, again, "repeat from the stack cache")
         finally:
             await s.close()
 
@@ -642,10 +668,6 @@ def test_bad_mesh_shapes_rejected_at_open(runtimes):
             await open_storage(MemoryObjectStore(), runtimes,
                                mesh={"enabled": True, "time": 1,
                                      "series": 3})
-        # legacy 1-D mesh and the 2-D mesh are mutually exclusive
-        with pytest.raises(Error, match="mutually exclusive"):
-            await open_storage(MemoryObjectStore(), runtimes,
-                               mesh={"enabled": True}, mesh_devices=4)
 
     run(go())
 
@@ -751,5 +773,4 @@ def test_existing_mesh_call_sites_enumerated():
                 sites.append((str(path.relative_to(root)), node.lineno))
     outside = [s for s in sites if not s[0].startswith("parallel/")]
     assert not outside, f"mesh construction outside parallel/: {outside}"
-    assert {s[0].split("/")[1] for s in sites} == {
-        "mesh.py", "scan.py", "multihost.py"}
+    assert {s[0].split("/")[1] for s in sites} == {"mesh.py", "scan.py"}

@@ -273,57 +273,6 @@ class TestDedupSorted:
         want = np.lexsort(tuple(reversed(cols)))
         np.testing.assert_array_equal(perm, want)
 
-    def test_scan_output_identical_across_impls(self):
-        """End-to-end: the same multi-SST overwrite workload scanned
-        under host_perm and device_sort yields identical batches."""
-        import asyncio
-
-        from horaedb_tpu.objstore import MemoryObjectStore
-        from horaedb_tpu.ops import merge as merge_mod
-        from horaedb_tpu.storage.read import ScanRequest
-        from horaedb_tpu.storage.storage import CloudObjectStorage, WriteRequest
-        from horaedb_tpu.storage.types import TimeRange
-
-        schema = pa.schema([("tag", pa.int32()), ("ts", pa.int64()),
-                            ("v", pa.float64())])
-
-        async def build_and_scan():
-            rng = np.random.default_rng(3)  # identical data per impl
-            s = await CloudObjectStorage.open(
-                "t", 3600_000, MemoryObjectStore(), schema, 2)
-            try:
-                for _ in range(4):  # 4 overlapping SSTs in one segment
-                    n = 200
-                    tags = rng.integers(0, 5, n).astype(np.int32)
-                    ts = rng.integers(0, 3600_000, n).astype(np.int64)
-                    batch = pa.record_batch({
-                        "tag": pa.array(tags),
-                        "ts": pa.array(ts, type=pa.int64()),
-                        "v": pa.array(rng.random(n)),
-                    })
-                    await s.write(WriteRequest(
-                        batch, TimeRange.new(int(ts.min()),
-                                             int(ts.max()) + 1)))
-                out = []
-                async for b in s.scan(ScanRequest(
-                        range=TimeRange.new(0, 3600_000),
-                        predicate=None, projections=None)):
-                    out.append(b)
-                return pa.Table.from_batches(out)
-            finally:
-                await s.close()
-
-        results = {}
-        prev = merge_mod.merge_impl()
-        for impl in ("host_perm", "device_sort"):
-            merge_mod.set_merge_impl(impl)
-            try:
-                results[impl] = asyncio.run(build_and_scan())
-            finally:
-                merge_mod.set_merge_impl(prev)
-        assert results["host_perm"].equals(results["device_sort"])
-        assert results["host_perm"].num_rows > 0
-
 
 class TestDownsample:
     def np_reference(self, ts, gid, vals, n, bucket_ms, G, B):

@@ -1,197 +1,12 @@
-"""Multi-chip scan tests on the 8-virtual-device CPU mesh.
-
-Validates that the shard_map programs produce EXACTLY the same results as
-running the single-device ops over the concatenated data — the
-distributed path must be semantically invisible.
+"""Fused-aggregate, replay and stacking tests, and the program-level
+contract of the 2-D scan mesh's segmented reduction on the
+8-virtual-device CPU mesh (tests/test_mesh_scan.py covers the engine
+half).
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from horaedb_tpu.ops import merge_dedup_last, time_bucket_aggregate, top_k_groups
-from horaedb_tpu.parallel import (
-    segment_mesh,
-    sharded_downsample_query,
-    sharded_merge_dedup,
-)
-from horaedb_tpu.parallel.scan import shard_leading_axis
-
-NDEV = 8
-CAP = 256
-G, B = 5, 7
-BUCKET = 60_000
-
-
-@pytest.fixture(scope="module")
-def mesh():
-    assert len(jax.devices()) >= NDEV
-    return segment_mesh(NDEV)
-
-
-def make_shards(rng):
-    """Per-device segment data: disjoint group-id spaces are NOT required —
-    groups span devices; segments only partition time."""
-    ts = rng.integers(0, B * BUCKET, (NDEV, CAP)).astype(np.int32)
-    gid = rng.integers(0, G, (NDEV, CAP)).astype(np.int32)
-    vals = (rng.random((NDEV, CAP)) * 100).astype(np.float32)
-    n_valid = rng.integers(1, CAP + 1, NDEV).astype(np.int32)
-    return ts, gid, vals, n_valid
-
-
-class TestShardedDownsample:
-    @pytest.mark.parametrize("seed", [0, 1])
-    def test_matches_single_device(self, mesh, seed):
-        rng = np.random.default_rng(seed)
-        ts, gid, vals, n_valid = make_shards(rng)
-
-        fn = sharded_downsample_query(mesh, num_groups=G, num_buckets=B, k=3)
-        final, top_vals, top_idx = fn(
-            shard_leading_axis(mesh, ts), shard_leading_axis(mesh, gid),
-            shard_leading_axis(mesh, vals),
-            shard_leading_axis(mesh, n_valid),
-            jnp.asarray([BUCKET], dtype=jnp.int32))
-
-        # single-device reference: mask out per-shard padding, concatenate
-        keep = np.zeros((NDEV, CAP), dtype=bool)
-        for d in range(NDEV):
-            keep[d, : n_valid[d]] = True
-        flat_ts = ts[keep]
-        flat_gid = gid[keep]
-        flat_vals = vals[keep]
-        n = len(flat_ts)
-        cap_all = 1 << (n - 1).bit_length()
-        pad = lambda a: np.pad(a, (0, cap_all - n))
-        ref = time_bucket_aggregate(
-            jnp.asarray(pad(flat_ts)), jnp.asarray(pad(flat_gid)),
-            jnp.asarray(pad(flat_vals)), n, BUCKET,
-            num_groups=G, num_buckets=B)
-
-        np.testing.assert_array_equal(np.asarray(final["count"]),
-                                      np.asarray(ref["count"]))
-        np.testing.assert_allclose(np.asarray(final["sum"]),
-                                   np.asarray(ref["sum"]), rtol=1e-5)
-        np.testing.assert_allclose(np.asarray(final["min"]),
-                                   np.asarray(ref["min"]), rtol=1e-6)
-        np.testing.assert_allclose(np.asarray(final["max"]),
-                                   np.asarray(ref["max"]), rtol=1e-6)
-        occ = np.asarray(ref["count"]) > 0
-        np.testing.assert_allclose(np.asarray(final["avg"])[occ],
-                                   np.asarray(ref["avg"])[occ], rtol=1e-5)
-
-        # top-k agrees with a host-side reference over the combined grid
-        scores = np.where(occ.any(axis=1),
-                          np.asarray(ref["max"]).max(axis=1,
-                                                     where=occ, initial=-np.inf),
-                          np.nan).astype(np.float32)
-        ref_vals, ref_idx = top_k_groups(jnp.asarray(scores), k=3)
-        np.testing.assert_array_equal(np.asarray(top_idx), np.asarray(ref_idx))
-        np.testing.assert_allclose(np.asarray(top_vals), np.asarray(ref_vals),
-                                   rtol=1e-6)
-
-    def test_last_cross_shard(self, mesh):
-        """`last` must come from the shard holding the latest timestamp."""
-        ts = np.zeros((NDEV, CAP), dtype=np.int32)
-        gid = np.zeros((NDEV, CAP), dtype=np.int32)
-        vals = np.zeros((NDEV, CAP), dtype=np.float32)
-        n_valid = np.ones(NDEV, dtype=np.int32)
-        for d in range(NDEV):
-            ts[d, 0] = d * 1000  # later shards have later timestamps
-            vals[d, 0] = float(d + 1) * 10
-        fn = sharded_downsample_query(mesh, num_groups=1, num_buckets=1, k=1)
-        final, _, _ = fn(
-            shard_leading_axis(mesh, ts), shard_leading_axis(mesh, gid),
-            shard_leading_axis(mesh, vals), shard_leading_axis(mesh, n_valid),
-            jnp.asarray([10**9], dtype=jnp.int32))
-        assert float(np.asarray(final["last"])[0, 0]) == 80.0
-        assert float(np.asarray(final["count"])[0, 0]) == NDEV
-
-
-class TestShardedMergeDedup:
-    def test_matches_per_shard_single_device(self, mesh):
-        rng = np.random.default_rng(7)
-        pk = rng.integers(0, 16, (NDEV, CAP)).astype(np.int32)
-        seq = np.stack([rng.permutation(CAP) for _ in range(NDEV)]).astype(np.int32)
-        val = rng.random((NDEV, CAP)).astype(np.float32)
-        n_valid = rng.integers(1, CAP + 1, NDEV).astype(np.int32)
-
-        fn = sharded_merge_dedup(mesh, num_pks=1)
-        out_pks, out_seq, out_vals, out_valid, num_runs = fn(
-            (shard_leading_axis(mesh, pk),), shard_leading_axis(mesh, seq),
-            (shard_leading_axis(mesh, val),), shard_leading_axis(mesh, n_valid))
-
-        for d in range(NDEV):
-            ref_pks, ref_seq, ref_vals, ref_valid, ref_runs = merge_dedup_last(
-                (jnp.asarray(pk[d]),), jnp.asarray(seq[d]),
-                (jnp.asarray(val[d]),), int(n_valid[d]))
-            k = int(ref_runs)
-            assert int(np.asarray(num_runs)[d]) == k
-            np.testing.assert_array_equal(
-                np.asarray(out_pks[0])[d, :k], np.asarray(ref_pks[0])[:k])
-            np.testing.assert_array_equal(
-                np.asarray(out_vals[0])[d, :k], np.asarray(ref_vals[0])[:k])
-
-
-class TestGuards:
-    def test_mesh_too_few_devices_raises(self):
-        from horaedb_tpu.common import Error
-        with pytest.raises(Error, match="devices are available"):
-            segment_mesh(1000)
-
-    def test_oversubscribed_leading_axis_raises(self, mesh):
-        from horaedb_tpu.common import Error
-        fn = sharded_downsample_query(mesh, num_groups=2, num_buckets=2, k=1)
-        big = np.zeros((NDEV * 2, CAP), dtype=np.int32)  # 2 segments/device
-        with pytest.raises(Error, match="leading axis"):
-            fn(shard_leading_axis(mesh, big), shard_leading_axis(mesh, big),
-               shard_leading_axis(mesh, big.astype(np.float32)),
-               shard_leading_axis(mesh, np.ones(NDEV * 2, dtype=np.int32)),
-               jnp.asarray([1000], dtype=jnp.int32))
-
-
-class TestShardedRemapPartials:
-    def test_window_local_grids_match_host(self, mesh):
-        """Each shard remaps its local gids into the union space, shifts
-        into query offsets, and aggregates a window-LOCAL grid starting
-        at its `lo` bucket; rows at/past `total` buckets drop."""
-        from horaedb_tpu.parallel import sharded_remap_partials
-
-        rng = np.random.default_rng(2)
-        W = 4  # local grid width
-        total = 20
-        ts = rng.integers(0, W * BUCKET, (NDEV, CAP)).astype(np.int32)
-        gid = rng.integers(-1, 3, (NDEV, CAP)).astype(np.int32)  # -1 drops
-        vals = (rng.random((NDEV, CAP)) * 10).astype(np.float32)
-        # each shard owns buckets [lo_d, lo_d + W) of the global range
-        lo = (np.arange(NDEV, dtype=np.int32) * 3) % (total + 2)
-        shift = (lo * BUCKET).astype(np.int32)
-        remap = np.tile(np.asarray([2, 0, 1], dtype=np.int32), (NDEV, 1))
-        remap = np.pad(remap, ((0, 0), (0, 5)))  # pad to g_pad=8
-
-        fn = sharded_remap_partials(mesh, num_groups=8, num_buckets=W)
-        out = fn(shard_leading_axis(mesh, ts),
-                 shard_leading_axis(mesh, gid),
-                 shard_leading_axis(mesh, vals),
-                 shard_leading_axis(mesh, remap),
-                 shard_leading_axis(mesh, shift),
-                 shard_leading_axis(mesh, lo),
-                 jnp.int32(total),
-                 jnp.asarray([BUCKET], dtype=jnp.int32))
-        counts = np.asarray(out["count"])
-        sums = np.asarray(out["sum"])
-        assert counts.shape == (NDEV, 8, W)
-        for d in range(NDEV):
-            b_local = ts[d] // BUCKET
-            b_global = b_local + lo[d]
-            ok = (gid[d] >= 0) & (b_global < total)
-            for u in range(3):
-                sel = ok & (remap[d][np.clip(gid[d], 0, 7)] == u)
-                for b in range(W):
-                    m = sel & (b_local == b)
-                    assert counts[d, u, b] == m.sum()
-                    np.testing.assert_allclose(
-                        sums[d, u, b], vals[d][m].sum(), rtol=1e-5)
 
 
 class TestFusedAggregate:
@@ -689,289 +504,6 @@ class TestVariedRangeStacking:
                     np.asarray(x["aggs"][key]),
                     np.asarray(y["aggs"][key]),
                     rtol=2e-5, atol=1e-5, err_msg=f"range {i} {key}")
-
-
-class TestCachedMeshResidency:
-    """VERDICT r2 item 6: a repeat meshed query must run from the
-    mesh-sharded stack cache — ZERO host->device transfers."""
-
-    def test_repeat_meshed_query_issues_no_transfers(self, monkeypatch):
-        import asyncio
-
-        import pyarrow as pa
-
-        from horaedb_tpu.metric_engine import MetricEngine
-        from horaedb_tpu.objstore import MemoryObjectStore
-        from horaedb_tpu.storage.config import StorageConfig, from_dict
-        from horaedb_tpu.storage.types import TimeRange
-
-        T0 = (1_700_000_000_000 // 7_200_000) * 7_200_000
-        SPAN = 4 * 3_600_000
-
-        async def go():
-            cfg = from_dict(StorageConfig, {
-                "scan": {"mesh_devices": 4, "max_window_rows": 512,
-                         # this test exercises the mesh stack cache;
-                         # the parts memo would serve the repeat query
-                         # before the stack path is ever consulted
-                         "combine": {"memo_max_bytes": 0}}})
-            e = await MetricEngine.open("resid", MemoryObjectStore(),
-                                        segment_ms=7_200_000, config=cfg)
-            try:
-                rng = np.random.default_rng(3)
-                n = 5000
-                batch = pa.record_batch({
-                    "host": pa.array(
-                        np.char.add("h", rng.integers(0, 9, n).astype(str))),
-                    "timestamp": pa.array(
-                        T0 + rng.integers(0, SPAN - 1, n), type=pa.int64()),
-                    "value": pa.array(rng.random(n)),
-                })
-                await e.write_arrow("cpu", ["host"], batch)
-                rng_q = TimeRange.new(T0, T0 + SPAN)
-                first = await e.query_downsample("cpu", [], rng_q,
-                                                 bucket_ms=600_000,
-                                                 aggs=("avg",))
-                reader = e.tables["data"].reader
-                assert reader._stack_cache_hits == 0
-                misses_after_first = reader._stack_cache_misses
-                assert misses_after_first > 0
-
-                puts = []
-                real_put = jax.device_put
-
-                def counting_put(x, *a, **kw):
-                    puts.append(np.shape(x))
-                    return real_put(x, *a, **kw)
-
-                monkeypatch.setattr(jax, "device_put", counting_put)
-                second = await e.query_downsample("cpu", [], rng_q,
-                                                  bucket_ms=600_000,
-                                                  aggs=("avg",))
-                monkeypatch.setattr(jax, "device_put", real_put)
-                assert reader._stack_cache_hits >= 1
-                assert reader._stack_cache_misses == misses_after_first
-                assert puts == [], f"repeat query uploaded: {puts}"
-                np.testing.assert_array_equal(
-                    np.asarray(first["aggs"]["avg"]),
-                    np.asarray(second["aggs"]["avg"]))
-            finally:
-                await e.close()
-
-        asyncio.run(go())
-
-
-class TestEngineMeshAggregation:
-    """The engine's multi-chip aggregate path folds per-shard partials on
-    host in f64.  With identical windowing it matches the single-device
-    path BIT-FOR-BIT; across different window sizes a small f32
-    within-window accumulation tolerance applies."""
-
-    def test_mesh_downsample_equals_single_device(self, monkeypatch):
-        # pin the parts f64 fold on both legs so equality is exact
-        monkeypatch.setenv("HORAEDB_FUSED_AGG", "0")
-        import asyncio
-
-        import pyarrow as pa
-
-        from horaedb_tpu.metric_engine import MetricEngine
-        from horaedb_tpu.objstore import MemoryObjectStore
-        from horaedb_tpu.storage.config import StorageConfig, from_dict
-        from horaedb_tpu.storage.types import TimeRange
-
-        T0 = (1_700_000_000_000 // 7_200_000) * 7_200_000
-        H = 3_600_000
-
-        async def run(mesh_devices, window_rows):
-            cfg = from_dict(StorageConfig, {
-                "scheduler": {"schedule_interval": "1h"},
-                "scan": {"mesh_devices": mesh_devices,
-                         "max_window_rows": window_rows},
-            })
-            e = await MetricEngine.open("m", MemoryObjectStore(),
-                                        segment_ms=2 * H, config=cfg)
-            try:
-                rng = np.random.default_rng(0)
-                n, hosts = 4000, 30
-                names = np.array([f"h{i:02d}" for i in range(hosts)],
-                                 dtype=object)
-                sel = rng.integers(0, hosts, n)
-                batch = pa.record_batch({
-                    "host": pa.array(names[sel]),
-                    "timestamp": pa.array(
-                        T0 + rng.integers(0, 2 * H - 1, n), type=pa.int64()),
-                    "value": pa.array(rng.random(n) * 100,
-                                      type=pa.float64()),
-                })
-                await e.write_arrow("cpu", ["host"], batch)
-                return await e.query_downsample(
-                    "cpu", [], TimeRange.new(T0, T0 + 2 * H),
-                    bucket_ms=600_000)
-            finally:
-                await e.close()
-
-        async def go():
-            # small windows force many windows per segment -> mesh rounds
-            single = await run(mesh_devices=0, window_rows=1 << 20)
-            meshed = await run(mesh_devices=4, window_rows=256)
-            assert single["tsids"] == meshed["tsids"]
-            for key in ("count", "sum", "min", "max", "avg", "last"):
-                np.testing.assert_allclose(
-                    np.asarray(single["aggs"][key]),
-                    np.asarray(meshed["aggs"][key]), rtol=2e-4,
-                    err_msg=key)
-            # identical windowing: counts must be BIT-equal.  Floats get
-            # f32-ulp tolerance: the single-device CPU leg computes
-            # window partials with the numpy host twin (f64 bincount,
-            # _host_window_partials), the mesh leg with the device
-            # kernel (f32 segment ops) — same windows, different
-            # accumulation precision.
-            single_small = await run(mesh_devices=0, window_rows=256)
-            meshed_small = await run(mesh_devices=4, window_rows=256)
-            assert single_small["tsids"] == meshed_small["tsids"]
-            np.testing.assert_array_equal(
-                np.asarray(single_small["aggs"]["count"]),
-                np.asarray(meshed_small["aggs"]["count"]), err_msg="count")
-            for key in ("sum", "min", "max", "avg", "last"):
-                np.testing.assert_allclose(
-                    np.asarray(single_small["aggs"][key]),
-                    np.asarray(meshed_small["aggs"][key]), rtol=1e-6,
-                    err_msg=key)
-
-        asyncio.run(go())
-
-    def test_mesh_row_scan_equals_single_device(self):
-        """The ROW scan path (not just the aggregate pushdown) must
-        produce identical tables when merges run as mesh rounds."""
-        import asyncio
-
-        import pyarrow as pa
-
-        from horaedb_tpu.metric_engine import MetricEngine
-        from horaedb_tpu.objstore import MemoryObjectStore
-        from horaedb_tpu.storage.config import StorageConfig, from_dict
-        from horaedb_tpu.storage.types import TimeRange
-
-        H = 3_600_000
-        T0 = (1_700_000_000_000 // (2 * H)) * 2 * H
-        SPAN = 8 * H  # 4 segments
-
-        async def run(mesh_devices):
-            cfg = from_dict(StorageConfig, {
-                "scheduler": {"schedule_interval": "1h"},
-                "scan": {"mesh_devices": mesh_devices,
-                         "max_window_rows": 512},
-            })
-            e = await MetricEngine.open("m", MemoryObjectStore(),
-                                        segment_ms=2 * H, config=cfg)
-            try:
-                rng = np.random.default_rng(11)
-                n, hosts = 5000, 12
-                names = np.array([f"h{i:02d}" for i in range(hosts)],
-                                 dtype=object)
-                # duplicate (host, ts) pairs across two writes so dedup
-                # actually bites on the mesh merge
-                ts_vals = T0 + rng.integers(0, SPAN, n)
-                for round_i in range(2):
-                    batch = pa.record_batch({
-                        "host": pa.array(names[rng.integers(0, hosts, n)]),
-                        "timestamp": pa.array(ts_vals, type=pa.int64()),
-                        "value": pa.array(
-                            rng.random(n) * 100 + round_i,
-                            type=pa.float64()),
-                    })
-                    await e.write_arrow("cpu", ["host"], batch)
-                tbl = await e.query("cpu", [],
-                                    TimeRange.new(T0, T0 + SPAN))
-                return tbl.sort_by([("tsid", "ascending"),
-                                    ("timestamp", "ascending")])
-            finally:
-                await e.close()
-
-        async def go():
-            single = await run(0)
-            meshed = await run(4)
-            assert single.num_rows == meshed.num_rows
-            assert single.equals(meshed)
-
-        asyncio.run(go())
-
-    def test_mesh_spans_segments_and_agg_subset(self, monkeypatch):
-        """Windows from DIFFERENT segments batch onto one mesh round (the
-        UnionExec axis); restricting `aggs` must not change the computed
-        grids."""
-        monkeypatch.setenv("HORAEDB_FUSED_AGG", "0")  # parts on both legs
-        import asyncio
-
-        import pyarrow as pa
-
-        from horaedb_tpu.metric_engine import MetricEngine
-        from horaedb_tpu.objstore import MemoryObjectStore
-        from horaedb_tpu.storage.config import StorageConfig, from_dict
-        from horaedb_tpu.storage.types import TimeRange
-
-        H = 3_600_000
-        T0 = (1_700_000_000_000 // (2 * H)) * 2 * H
-        SPAN = 12 * H  # 6 two-hour segments, one window each
-
-        async def run(mesh_devices, aggs):
-            cfg = from_dict(StorageConfig, {
-                "scheduler": {"schedule_interval": "1h"},
-                "scan": {"mesh_devices": mesh_devices,
-                         "agg_batch_windows": 4},
-            })
-            e = await MetricEngine.open("m", MemoryObjectStore(),
-                                        segment_ms=2 * H, config=cfg)
-            try:
-                rng = np.random.default_rng(3)
-                n, hosts = 6000, 10
-                names = np.array([f"h{i:02d}" for i in range(hosts)],
-                                 dtype=object)
-                sel = rng.integers(0, hosts, n)
-                batch = pa.record_batch({
-                    "host": pa.array(names[sel]),
-                    "timestamp": pa.array(
-                        T0 + rng.integers(0, SPAN, n), type=pa.int64()),
-                    "value": pa.array(rng.random(n) * 100,
-                                      type=pa.float64()),
-                })
-                await e.write_arrow("cpu", ["host"], batch)
-                return await e.query_downsample(
-                    "cpu", [], TimeRange.new(T0, T0 + SPAN),
-                    bucket_ms=600_000, aggs=aggs)
-            finally:
-                await e.close()
-
-        async def go():
-            from horaedb_tpu.ops.downsample import ALL_AGGS
-
-            single = await run(0, ALL_AGGS)
-            meshed = await run(4, ALL_AGGS)
-            assert single["tsids"] == meshed["tsids"]
-            # counts exact; float grids to f32 ulp (fused f32 device
-            # accumulator vs the mesh's host f64 fold)
-            np.testing.assert_array_equal(
-                np.asarray(single["aggs"]["count"]),
-                np.asarray(meshed["aggs"]["count"]))
-            for key in ("sum", "min", "max", "avg", "last"):
-                np.testing.assert_allclose(
-                    np.asarray(single["aggs"][key]),
-                    np.asarray(meshed["aggs"][key]), rtol=1e-6,
-                    err_msg=key)
-            # restricted aggregates: same numbers, fewer grids; both
-            # single-device runs share the fused path, so EXACT equality
-            subset = await run(0, ("avg",))
-            assert "min" not in subset["aggs"] and "last" not in subset["aggs"]
-            # sum is avg's dependency but was not requested
-            assert "sum" not in subset["aggs"]
-            np.testing.assert_array_equal(
-                np.asarray(subset["aggs"]["avg"]),
-                np.asarray(single["aggs"]["avg"]))
-            np.testing.assert_array_equal(
-                np.asarray(subset["aggs"]["count"]),
-                np.asarray(single["aggs"]["count"]))
-
-        asyncio.run(go())
 
 
 class TestMeshRunPartials:

@@ -467,3 +467,5 @@ def test_footers_alone_stay_within_the_budget_and_off_when_disabled():
     off = EncodedSegmentCache(max_bytes=0)
     off.put_footer(1, _footer(1))
     assert off.get_footer(1) is None and off.total_bytes == 0
+    # off the process-global scan_cache_bytes{tier="tier2"} gauge again
+    cache.clear()

@@ -1,0 +1,407 @@
+"""The route table (ISSUE 30): which aggregate plan takes which route.
+
+For each row: build the reader and the plan, assert
+`ParquetReader.aggregate_route`, run the aggregate, and assert that the
+route that RAN is the one named — from the device plane's own ledger
+(per-program dispatch counts, what `GET /debug/device` serves and the
+benchmark's `route.dispatches_per_query` reads), the fallback counters
+(`scan_decode_fallback_total{reason}`, `scan_mesh_fallback_total
+{reason}`), the mesh round counter and the replay hits — and that the
+answer equals a numpy reference of the same semantics.  A row fails if
+the label and the programs that ran disagree.
+
+Rows that say `accel` monkeypatch the one backend observation the gates
+read (`jax.default_backend`): the programs then run on XLA-CPU, as the
+chip's would.  Three rows are the benchmark's cells: `accel_under_budget`
+(s100_single_groupby), `accel_over_budget` (s100_double_groupby) and
+`accel_over_budget_block_pruned_host` (s1000_single_groupby)."""
+
+import asyncio
+import dataclasses
+import pathlib
+
+import jax
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from horaedb_tpu.common import ReadableDuration, deviceprof
+from horaedb_tpu.common import runtimes as runtimes_mod
+from horaedb_tpu.common.error import Error
+from horaedb_tpu.objstore import MemoryObjectStore
+from horaedb_tpu.ops import device_decode
+from horaedb_tpu.ops import filter as F
+from horaedb_tpu.ops.downsample import ALL_AGGS
+from horaedb_tpu.storage import read as read_mod
+from horaedb_tpu.storage import sidecar
+from horaedb_tpu.storage.config import (ScanConfig, StorageConfig,
+                                        ThreadsConfig, UpdateMode,
+                                        from_dict)
+from horaedb_tpu.storage.plan import TopKSpec
+from horaedb_tpu.storage.read import AggregateSpec, ScanRequest
+from horaedb_tpu.storage.storage import CloudObjectStorage, WriteRequest
+from horaedb_tpu.storage.types import TimeRange
+
+SEGMENT_MS = 3_600_000
+TICK_MS = 10_000
+TICKS = SEGMENT_MS // TICK_MS
+HOSTS = 40
+SEGMENTS = 2
+BUCKET_MS = 60_000
+SCHEMA = pa.schema([("k", pa.string()), ("ts", pa.int64()),
+                    ("v", pa.float64())])
+# 2 segments x 40 hosts x 360 ticks = 28,800 stored rows: under the
+# default scan-cache budget (4,194,304 rows), over this one
+OVER_BUDGET = {"cache_max_rows": 4096}
+# a window that starts and ends inside a segment, across the boundary
+LO, HI = SEGMENT_MS - 1_200_000 + 7, SEGMENT_MS + 1_500_000 + 7
+
+FUSED = ("_fused_acc_init_jit", "_fused_round_accumulate_jit",
+         "_fused_finalize_jit", "_group_has_data_jit")
+DECODE = ("_decode_aggregate_jit",)
+PARTS_XLA = ("_batched_window_partials_jit",)
+MESH = ("mesh_run_partials", "mesh_decode_partials")
+ROUTE_FNS = FUSED + DECODE + PARTS_XLA + MESH
+
+
+@pytest.fixture(scope="module")
+def runtimes():
+    rt = runtimes_mod.from_config(ThreadsConfig())
+    yield rt
+    rt.close()
+
+
+class World:
+    """What was written (one body a segment, every host at every tick)
+    and the numpy side of every comparison."""
+
+    def __init__(self, seed: int):
+        rng = np.random.default_rng(seed)
+        self.values = (rng.random((HOSTS, SEGMENTS * TICKS)) * 100
+                       ).astype(np.float32)
+
+    def write_requests(self):
+        for seg in range(SEGMENTS):
+            ticks = np.arange(seg * TICKS, (seg + 1) * TICKS)
+            h = np.repeat(np.arange(HOSTS), TICKS)
+            t = np.tile(ticks, HOSTS)
+            ts = t.astype(np.int64) * TICK_MS
+            batch = pa.record_batch(
+                [pa.array([f"host_{i:02d}" for i in h]), pa.array(ts),
+                 pa.array(self.values[h, t].astype(np.float64))],
+                schema=SCHEMA)
+            yield WriteRequest(batch, TimeRange.new(int(ts.min()),
+                                                    int(ts.max()) + 1))
+
+    def reference(self, lo: int, hi: int, hosts, keep=None) -> dict:
+        """{host name: {agg: (buckets,) f64}} over [lo, hi), rows
+        admitted by `keep(values)`; hosts without a row are absent, as
+        the engine drops empty groups."""
+        n = -(-(hi - lo) // BUCKET_MS)
+        ts = np.arange(self.values.shape[1], dtype=np.int64) * TICK_MS
+        out = {}
+        for h in hosts:
+            v = self.values[h]
+            m = (ts >= lo) & (ts < hi)
+            if keep is not None:
+                m &= keep(v)
+            if not m.any():
+                continue
+            b = ((ts[m] - lo) // BUCKET_MS).astype(np.int64)
+            vv = v[m].astype(np.float64)
+            g = {"count": np.bincount(b, minlength=n).astype(np.float64),
+                 "sum": np.bincount(b, weights=vv, minlength=n),
+                 "min": np.full(n, np.inf), "max": np.full(n, -np.inf),
+                 "last": np.zeros(n)}
+            np.minimum.at(g["min"], b, vv)
+            np.maximum.at(g["max"], b, vv)
+            g["last"][b] = vv  # ticks ascend: the later row stays
+            g["avg"] = g["sum"] / np.maximum(g["count"], 1)
+            out[f"host_{h:02d}"] = g
+        return out
+
+
+def check_answer(got, ref: dict, which=ALL_AGGS, top_k=None):
+    values, grids = got
+    names = [str(v) for v in values]
+    if top_k is not None:
+        score = {nm: (g[top_k.by][g["count"] > 0].max()) for nm, g
+                 in ref.items()}
+        best = sorted(score, key=score.get, reverse=True)[:top_k.k]
+        assert names == best
+    else:
+        assert names == sorted(ref)
+    for row, nm in enumerate(names):
+        want = ref[nm]
+        occupied = want["count"] > 0
+        assert np.array_equal(np.asarray(grids["count"])[row],
+                              want["count"]), nm
+        for a in set(which) & {"min", "max", "last"}:
+            g = np.asarray(grids[a], dtype=np.float64)[row]
+            assert np.array_equal(g[occupied], want[a][occupied]), (nm, a)
+        for a in set(which) & {"sum", "avg"}:
+            g = np.asarray(grids[a], dtype=np.float64)[row]
+            err = np.abs(g[occupied] - want[a][occupied]) / np.maximum(
+                np.abs(want[a][occupied]), 1e-30)
+            assert err.max() <= 1e-5, (nm, a)
+
+
+def calls() -> dict:
+    """Calls (a compile is one too) of the programs a route is made
+    of, from the device plane's ledger."""
+    return {r["fn"]: r["compiles"] + r["dispatches"]
+            for r in deviceprof.profiler.snapshot()["fns"]
+            if r["fn"] in ROUTE_FNS}
+
+
+def decode_fallbacks() -> dict:
+    return {r: c.value for r, c in device_decode._FALLBACK_CHILDREN.items()}
+
+
+def mesh_fallbacks() -> dict:
+    return {r: c.value for r, c in read_mod._MESH_FALLBACK_CHILDREN.items()}
+
+
+def delta(after: dict, before: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+class _CoveringRouter:
+    """A near-data router that covers the plan's segments and is not
+    active: the gate asks `covers_any`, the pump asks `active`."""
+
+    active = False
+
+    @staticmethod
+    def covers_any(segments) -> bool:
+        return bool(segments)
+
+
+@dataclasses.dataclass
+class Row:
+    name: str
+    route: str
+    ran: tuple                    # the programs that may be dispatched
+    accel: bool = False
+    scan: dict = dataclasses.field(default_factory=dict)
+    env: dict = dataclasses.field(default_factory=dict)
+    hosts: tuple = tuple(range(HOSTS))
+    predicate: object = None
+    keep: object = None           # the reference's side of `predicate`
+    decode_reason: dict = dataclasses.field(default_factory=dict)
+    mesh_reason: dict = dataclasses.field(default_factory=dict)
+    top_k: object = None
+    which: tuple = ALL_AGGS
+    warm: bool = False            # the same plan once before, cached
+    router: bool = False
+    small_blocks: bool = False    # the sidecar's block sizes cut down
+    n_decode: int = 0             # exact `_decode_aggregate_jit` count
+    raises: str = ""              # the scan is refused with this Error
+    mode: UpdateMode = UpdateMode.OVERWRITE
+
+
+HOST7 = dict(hosts=(7,), predicate=F.Eq("k", "host_07"))
+MESH_ON = {"mesh": {"enabled": True}}
+MANY = [f"host_{i:02d}" for i in range(HOSTS)] \
+    + [f"absent_{i}" for i in range(30)]
+
+ROWS = [
+    Row("cpu_auto", "parts", ()),
+    Row("accel_under_budget", "fused_acc", FUSED, accel=True, **HOST7),
+    Row("accel_under_budget_again", "replay", FUSED, accel=True,
+        warm=True, **HOST7),
+    Row("accel_over_budget", "device_decode", DECODE, accel=True,
+        scan=OVER_BUDGET, n_decode=SEGMENTS),
+    Row("accel_over_budget_block_pruned_host", "device_decode", DECODE,
+        accel=True, scan=OVER_BUDGET, small_blocks=True,
+        n_decode=SEGMENTS, **HOST7),
+    Row("accel_over_budget_no_sidecar", "parts", PARTS_XLA, accel=True,
+        scan={**OVER_BUDGET, "use_sidecar": False},
+        decode_reason={"no_sidecar": 1}),
+    Row("accel_mode_device_under_budget", "device_decode", DECODE,
+        accel=True, scan={"decode": {"mode": "device"}},
+        n_decode=SEGMENTS),
+    Row("mode_device_value_leaf", "parts", (),
+        scan={"decode": {"mode": "device"}},
+        predicate=F.Gt("v", 50.0), keep=lambda v: v > 50.0,
+        decode_reason={"predicate": 1}),
+    Row("mode_device_oversized_in_list", "parts", (),
+        scan={"decode": {"mode": "device"}},
+        predicate=F.In("k", MANY), decode_reason={"predicate": 1}),
+    Row("accel_mode_host_over_budget", "parts", PARTS_XLA, accel=True,
+        scan={**OVER_BUDGET, "decode": {"mode": "host"}}),
+    # the aggregate pushdown is Overwrite-only: the label is one no
+    # scan runs under, and `append_mode` is probed, never counted
+    Row("append_mode", "parts", (), scan={"decode": {"mode": "device"}},
+        mode=UpdateMode.APPEND, raises="Overwrite"),
+    Row("mesh_eligible", "mesh", MESH, scan=MESH_ON),
+    Row("mesh_topk_by_unrequested_agg", "mesh", MESH, scan=MESH_ON,
+        top_k=TopKSpec(k=3, by="max"), which=("avg",),
+        mesh_reason={"topk_by": 1},
+        # counted, scanned on the mesh, and refused by the combine
+        raises="needs that aggregate"),
+    Row("mesh_topk_decode_parts", "mesh", MESH,
+        scan={**MESH_ON, "decode": {"mode": "device"}},
+        top_k=TopKSpec(k=3, by="max"), mesh_reason={"topk_decode": 1}),
+    Row("mesh_topk_over_budget", "mesh", MESH,
+        scan={**MESH_ON, **OVER_BUDGET},
+        top_k=TopKSpec(k=3, by="max"), mesh_reason={"topk_budget": 1}),
+    Row("mesh_topk_winner_sliced", "mesh", MESH, scan=MESH_ON,
+        top_k=TopKSpec(k=3, by="max")),
+    Row("accel_router_covers_under_budget", "parts", PARTS_XLA,
+        accel=True, router=True),
+    Row("fused_forced_over_budget", "fused_acc", FUSED,
+        scan=OVER_BUDGET, env={"HORAEDB_FUSED_AGG": "1"}),
+    Row("accel_fused_forced_off_under_budget", "device_decode", DECODE,
+        accel=True, env={"HORAEDB_FUSED_AGG": "0"}, n_decode=SEGMENTS),
+    Row("accel_decode_forced_off_over_budget", "parts", PARTS_XLA,
+        accel=True, scan=OVER_BUDGET,
+        env={"HORAEDB_DEVICE_DECODE": "0"}),
+    Row("benchmark_rehearsal_environment", "device_decode", DECODE,
+        env={"HORAEDB_HOST_AGG": "0", "HORAEDB_DEVICE_DECODE": "1"},
+        n_decode=SEGMENTS),
+]
+
+
+def storage_config(row: Row) -> StorageConfig:
+    cfg = from_dict(StorageConfig, {
+        "scheduler": {"schedule_interval": "1h", "input_sst_min_num": 2},
+        "scan": row.scan})
+    cfg.manifest.merge_interval = ReadableDuration.parse("1h")
+    cfg.scrub.interval = ReadableDuration.parse("1h")
+    cfg.update_mode = row.mode
+    return cfg
+
+
+@pytest.mark.parametrize("row", ROWS, ids=[r.name for r in ROWS])
+def test_route_named_is_the_route_that_ran(row, runtimes, monkeypatch):
+    for name in ("HORAEDB_HOST_AGG", "HORAEDB_DEVICE_DECODE",
+                 "HORAEDB_FUSED_AGG", "HORAEDB_DEVCOL_STACK"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in row.env.items():
+        monkeypatch.setenv(name, value)
+    if row.accel:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if row.small_blocks:
+        monkeypatch.setattr(sidecar, "BLOCK_ROWS", 512)
+        monkeypatch.setattr(sidecar, "_HEAD_BYTES", 8192)
+        monkeypatch.setattr(sidecar, "_PARTIAL_MIN_BYTES", 4096)
+
+    async def go():
+        world = World(seed=30)
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), SCHEMA, 2,
+            storage_config(row), runtimes=runtimes)
+        try:
+            for wr in world.write_requests():
+                await s.write(wr)
+            reader = s.reader
+            if row.router:
+                reader.scan_router = _CoveringRouter()
+            spec = AggregateSpec(
+                group_col="k", ts_col="ts", value_col="v", range_start=LO,
+                bucket_ms=BUCKET_MS,
+                num_buckets=-(-(HI - LO) // BUCKET_MS), which=row.which)
+            req = ScanRequest(range=TimeRange.new(LO, HI),
+                              predicate=row.predicate)
+            ref = world.reference(LO, HI, row.hosts, row.keep)
+            if row.warm:
+                check_answer(await s.scan_aggregate(req, spec), ref)
+            else:
+                # cold: nothing a write left behind serves the scan
+                reader.scan_cache.clear()
+                reader.encoded_cache.clear()
+                reader.parts_memo.clear()
+
+            plan = await s._plan_aggregate(req, spec)
+            assert len(plan.segments) == SEGMENTS
+            assert plan.route == row.route \
+                == reader.aggregate_route(plan, spec)
+
+            fns0, dec0, mesh0 = calls(), decode_fallbacks(), \
+                mesh_fallbacks()
+            rounds0 = read_mod._MESH_ROUNDS.value
+            replays0 = reader._replay_hits
+            fetched0 = sidecar._LOAD_ROWS["fetched"].value
+            stored0 = sidecar._LOAD_ROWS["stored"].value
+            if row.raises:
+                with pytest.raises(Error, match=row.raises):
+                    await s.scan_aggregate(req, spec, first_plan=plan,
+                                           top_k=row.top_k)
+            else:
+                got = await s.scan_aggregate(req, spec, first_plan=plan,
+                                             top_k=row.top_k)
+                check_answer(got, ref, row.which, row.top_k)
+
+            ran = delta(calls(), fns0)
+            assert set(ran) <= set(row.ran), ran
+            assert bool(ran) == bool(row.ran) or row.raises, ran
+            if row.route in ("fused_acc", "replay"):
+                assert set(ran) == set(FUSED)
+            if row.n_decode:
+                assert ran == {"_decode_aggregate_jit": row.n_decode}
+            assert (read_mod._MESH_ROUNDS.value > rounds0) \
+                == (row.route == "mesh" and bool(ran))
+            assert reader._replay_hits - replays0 \
+                == (1 if row.route == "replay" else 0)
+            assert delta(decode_fallbacks(), dec0) == row.decode_reason
+            assert delta(mesh_fallbacks(), mesh0) == row.mesh_reason
+            if row.small_blocks:
+                # one host's blocks of each SST, not the SSTs
+                fetched = sidecar._LOAD_ROWS["fetched"].value - fetched0
+                stored = sidecar._LOAD_ROWS["stored"].value - stored0
+                assert 0 < fetched < stored == SEGMENTS * HOSTS * TICKS
+        finally:
+            await s.close()
+
+    asyncio.run(go())
+
+
+def test_every_plan_level_reason_has_a_row():
+    """The counted reasons a PLAN can show (the per-segment and
+    per-round ones belong to tests/test_device_decode.py and
+    tests/test_mesh_scan.py).  `append_mode` is probed without counting
+    only (aggregate_segments refuses a non-Overwrite plan before its
+    gate): its row pins that.  `topk_router` needs live agents
+    (tests/test_scanagent.py)."""
+    seen_decode = {r for row in ROWS for r in row.decode_reason}
+    assert seen_decode == {"no_sidecar", "predicate"}
+    seen_mesh = {r for row in ROWS for r in row.mesh_reason}
+    assert seen_mesh == {"topk_by", "topk_decode", "topk_budget"}
+    assert "merge_impl" not in read_mod.MESH_FALLBACK_REASONS
+    assert "mesh" not in device_decode.FALLBACK_REASONS
+    assert {r.route for r in ROWS} == {
+        "parts", "fused_acc", "replay", "device_decode", "mesh"}
+
+
+# ---------------------------------------------------------------------------
+# the options that went
+# ---------------------------------------------------------------------------
+
+
+def test_mesh_devices_is_an_unknown_key():
+    with pytest.raises(Error, match="mesh_devices"):
+        from_dict(StorageConfig, {"scan": {"mesh_devices": 4}})
+
+
+def test_example_toml_parses_and_names_every_scan_field():
+    """docs/example.toml is the file the benchmark's server starts
+    from: it parses (unknown keys are rejected) to the defaults, and
+    every field of [scan] has its key there, commented or set — but
+    for `use_sidecar`, which the file never had (ISSUE 30 leaves every
+    other line of it letter for letter, so the gap is pinned here, not
+    filled)."""
+    import tomllib
+
+    from horaedb_tpu.server.config import load_config
+
+    path = pathlib.Path(__file__).resolve().parent.parent \
+        / "docs" / "example.toml"
+    cfg = load_config(str(path))
+    assert cfg.metric_engine.time_merge_storage.scan == ScanConfig()
+    text = path.read_text()
+    table = tomllib.loads(text)["metric_engine"]["time_merge_storage"]["scan"]
+    missing = {f.name for f in dataclasses.fields(ScanConfig)
+               if f.name not in table and f"# {f.name} = " not in text}
+    assert missing == {"use_sidecar"}

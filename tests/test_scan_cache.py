@@ -117,9 +117,26 @@ def part(names_arrays):
 # ---------------------------------------------------------------------------
 
 
-def test_byte_lru_eviction_order_and_accounting():
+@pytest.fixture
+def tier2():
+    """Builds EncodedSegmentCaches and clears them on exit: their bytes
+    ride the process-global scan_cache_bytes{tier="tier2"} gauge, which
+    another file's test (test_memledger's close discipline) reads as 0
+    when it shares this worker."""
+    built = []
+
+    def make(**kw):
+        built.append(EncodedSegmentCache(**kw))
+        return built[-1]
+
+    yield make
+    for c in built:
+        c.clear()
+
+
+def test_byte_lru_eviction_order_and_accounting(tier2):
     one = part({"a": np.zeros(100)})  # 400 bytes
-    c = EncodedSegmentCache(max_bytes=1000)
+    c = tier2(max_bytes=1000)
     c.put(1, one, 100)
     c.put(2, one, 100)
     assert len(c) == 2 and c.total_bytes == 800
@@ -135,8 +152,8 @@ def test_byte_lru_eviction_order_and_accounting():
     assert c.total_bytes == 800
 
 
-def test_get_subset_semantics_and_widening():
-    c = EncodedSegmentCache(max_bytes=1 << 20)
+def test_get_subset_semantics_and_widening(tier2):
+    c = tier2(max_bytes=1 << 20)
     c.put(7, part({"a": np.arange(10), "b": np.arange(10)}), 10)
     got = c.get(7, {"a"})
     assert got is not None and set(got[0]) == {"a"} and got[1] == 10
@@ -148,8 +165,8 @@ def test_get_subset_semantics_and_widening():
     assert got is not None and set(got[0]) == {"a", "b", "c"}
 
 
-def test_invalidate_missing_and_disabled():
-    c = EncodedSegmentCache(max_bytes=1 << 20)
+def test_invalidate_missing_and_disabled(tier2):
+    c = tier2(max_bytes=1 << 20)
     c.put(1, part({"a": np.arange(4)}), 4)
     c.mark_missing(2)
     assert c.is_missing(2)
@@ -160,14 +177,14 @@ def test_invalidate_missing_and_disabled():
     assert c.admit(3, part({"a": np.arange(4)}), 4)
     assert not c.is_missing(3)
     # disabled tier: put/admit are no-ops, negative memo still works
-    off = EncodedSegmentCache(max_bytes=0)
+    off = tier2(max_bytes=0)
     off.put(1, part({"a": np.arange(4)}), 4)
     assert not off.admit(2, part({"a": np.arange(4)}), 4)
     assert len(off) == 0 and off.get(1, {"a"}) is None
     off.mark_missing(9)
     assert off.is_missing(9)
     # write_through=False refuses admission but keeps the read path
-    ro = EncodedSegmentCache(max_bytes=1 << 20, write_through=False)
+    ro = tier2(max_bytes=1 << 20, write_through=False)
     assert not ro.admit(1, part({"a": np.arange(4)}), 4)
     ro.put(1, part({"a": np.arange(4)}), 4)
     assert ro.get(1, {"a"}) is not None

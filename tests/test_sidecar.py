@@ -523,7 +523,7 @@ class TestStreamedSidecar:
             cfg = from_dict(StorageConfig, {
                 "scan": {"stream_read_min_rows": 4096,
                          "max_window_rows": 2048,
-                         "mesh_devices": 4}})
+                         "mesh": {"enabled": True}}})
             e = await MetricEngine.open("ssm", store, segment_ms=2 * HOUR,
                                         config=cfg)
             try:

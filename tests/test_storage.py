@@ -713,7 +713,7 @@ class TestStreamedRead:
     def test_streamed_mesh_equals_bulk(self):
         streamed = self._run(
             {"stream_read_min_rows": 2000, "max_window_rows": 1024,
-             "mesh_devices": 4})
+             "mesh": {"enabled": True}})
         bulk = self._run({"stream_read_min_rows": 0,
                           "max_window_rows": 1 << 20})
         assert streamed == bulk

@@ -94,6 +94,13 @@ Checks, all hard failures:
     fallback that let five driver benches report a numpy number as
     the device's; a run that finds no chip fails (chip_smoke.py)
 
+  - no new environment switch under horaedb_tpu/: an `os.environ` /
+    `os.getenv` access naming a `HORAEDB_*` variable outside
+    _ENV_SWITCHES is an error — the two implementation switches of
+    the downsample and the merge went with the code they selected
+    (PR 30); a route is selected from what the code can observe
+    (platform, size, residency), not from a new variable
+
 Usage: python tools/lint.py [paths...]   (default: horaedb_tpu tests
 tools bench.py chip_smoke.py __graft_entry__.py)
 """
@@ -430,6 +437,38 @@ def _lax_sort_outside_merge(node: ast.Call) -> bool:
     return "lax" in chain
 
 
+# the HORAEDB_* variables the package still reads: three route forcers
+# for CPU coverage of the accelerator routes, the device-decode force
+# the benchmark's CPU rehearsal sets, and the compile-cache switch
+_ENV_SWITCHES = {"HORAEDB_HOST_AGG", "HORAEDB_DEVICE_DECODE",
+                 "HORAEDB_FUSED_AGG", "HORAEDB_DEVCOL_STACK",
+                 "HORAEDB_COMPILE_CACHE"}
+
+
+def _unknown_env_switch(node: ast.AST) -> Optional[str]:
+    """The HORAEDB_* name of an environment access outside
+    _ENV_SWITCHES: `os.environ.get/pop/setdefault(NAME, ...)`,
+    `os.getenv(NAME)`, `os.environ[NAME]`, `NAME in os.environ`."""
+    def environ(n: ast.AST) -> bool:
+        return isinstance(n, ast.Attribute) and n.attr == "environ"
+
+    name = None
+    if isinstance(node, ast.Call) and node.args \
+            and isinstance(node.func, ast.Attribute) \
+            and (environ(node.func.value) or node.func.attr == "getenv"):
+        name = node.args[0]
+    elif isinstance(node, ast.Subscript) and environ(node.value):
+        name = node.slice
+    elif isinstance(node, ast.Compare) and len(node.comparators) == 1 \
+            and environ(node.comparators[0]):
+        name = node.left
+    if isinstance(name, ast.Constant) and isinstance(name.value, str) \
+            and name.value.startswith("HORAEDB_") \
+            and name.value not in _ENV_SWITCHES:
+        return name.value
+    return None
+
+
 def _bare_jax_jit(node: ast.Attribute) -> bool:
     """Any `jax.jit` reference outside common/deviceprof.py: the
     compile ledger only sees seams that route through deviceprof.jit —
@@ -711,6 +750,16 @@ def lint_file(path: pathlib.Path) -> list[str]:
                     "profiler, and recompile-storm watchdog see them "
                     "(GET /debug/device; docs/observability.md); noqa "
                     "with a reason for intentional unprofiled sites")
+        elif ("horaedb_tpu" in path.parts
+                and (switch := _unknown_env_switch(node)) is not None):
+            src = lines[node.lineno - 1] if node.lineno <= len(lines) else ""
+            if "noqa" not in src:
+                problems.append(
+                    f"{path}:{node.lineno}: environment switch "
+                    f"{switch} is not one of "
+                    f"{sorted(_ENV_SWITCHES)} — select a route from "
+                    "what the code can observe (platform, size, "
+                    "residency), not from a new variable")
         elif (isinstance(node, ast.Call) and "horaedb_tpu" in path.parts
                 and "parallel" not in path.parts
                 and _mesh_construction_outside_parallel(node)):
