@@ -2716,7 +2716,10 @@ def run_config16(rows: int, iters: int) -> dict:
     ts = T0 + np.repeat(
         np.arange(per_host, dtype=np.int64) * interval, hosts)
     host_id = np.tile(np.arange(hosts, dtype=np.int32), per_host)
-    vals = (rng.random(n) * 100).astype(np.float64)
+    # quarters: a cell's float32 sum is exact in any association, so
+    # the byte-identity gate below holds whether a slice's grids are
+    # scattered (the host control) or reduced by runs (device decode)
+    vals = np.round(rng.random(n) * 400) / 4
     names = pa.array([f"host_{i:03d}" for i in range(hosts)])
     _check_i32_span(np.asarray([span]), "config16")
     k_cold = max(3, iters // 3)
@@ -4543,7 +4546,9 @@ def run_config22(rows: int, iters: int) -> dict:
                 ts.sort()
                 names = [f"host_{i:03d}" for i in
                          rng.integers(0, n_hosts, n)]
-                vals = rng.random(n) * 100
+                # quarters: sums exact in float32 in any association
+                # (the decode legs reduce by runs, the control scatters)
+                vals = np.round(rng.random(n) * 400) / 4
                 b = pa.record_batch(
                     [pa.array(names), pa.array(ts),
                      pa.array(vals, type=pa.float64())], schema=schema)

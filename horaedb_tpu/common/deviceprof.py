@@ -56,7 +56,7 @@ from collections import deque
 from typing import Any, Callable, Optional
 
 from horaedb_tpu.utils.metrics import registry
-from horaedb_tpu.utils.tracing import phase, trace_add
+from horaedb_tpu.utils.tracing import phase, phase_passed, trace_add
 
 logger = logging.getLogger(__name__)
 # storms land next to slow queries and watchdog stalls: one stream an
@@ -366,7 +366,8 @@ class DeviceProfiler:
         """The d2h seam, the sync split from the copy: first
         `block_until_ready` (charged to `scan.device_wait` and
         device_exec_seconds{fn}; where every leaf is ready already
-        nothing waits: an observation of 0 and no span), then `np.asarray` of every leaf
+        nothing waits: an observation of 0 in both and no span), then
+        `np.asarray` of every leaf
         (the `scan.d2h` phase span, and the seconds of
         device_transfer_seconds_total{direction="d2h"}).  Returns the
         same pytree with numpy leaves."""
@@ -376,6 +377,7 @@ class DeviceProfiler:
         if all(leaf.is_ready() for leaf in jax.tree_util.tree_leaves(x)
                if isinstance(leaf, jax.Array)):
             self.observe_exec(fn, 0.0)  # the seam was passed: waited 0
+            phase_passed("scan.device_wait", table)
         else:
             self.block_until_ready(x, fn=fn, table=table)
         with phase("scan.d2h", table, fn=fn):

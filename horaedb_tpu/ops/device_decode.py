@@ -45,16 +45,25 @@ itself does:
                   keeps f32 per-cell accumulation order — and therefore
                   the grids' bytes — identical to the host path;
   aggregate     — ops.downsample.window_local_partials over the sorted,
-                  masked rows: the SAME partial-grid kernel the host
-                  window path vmaps, so the emitted part has the exact
-                  conventions storage/combine.py folds.
+                  masked rows: the partial-grid kernel the host window
+                  path vmaps, so the emitted part has the exact
+                  conventions storage/combine.py folds.  Where the
+                  slice's rows decode with (group, ts) never falling
+                  (plan_segment decides it per slice: the served
+                  shape, one field grouped by its series) every cell
+                  is one run of rows and the kernel reduces by runs
+                  with nothing scattered (downsample.run_aggregate;
+                  scan_decode_reduce_total{kind} counts both).
 
 The output is one per-segment part `(group_values, bucket_lo, grids)`
 — the shape `read._flush_window_batch` produces — so everything
 downstream (sparse/dense combine, top-k pushdown, the delta-summation
-parts memo) is untouched and the host-decode path remains the
-bit-identity control ([scan.decode] mode = "host"; the seeded chaos
-suite byte-compares the two, tests/test_device_decode.py).
+parts memo) is untouched and the host-decode path remains the control
+([scan.decode] mode = "host"; the seeded chaos suite byte-compares the
+two, tests/test_device_decode.py): count, min, max and last to the
+bit on every slice, sums to the bit where the slice scatters or the
+cells' sums are exact in float32 (the suite's integer values), and to
+float32 rounding in another association otherwise.
 
 Ineligible plans/segments fall back to host decode with an explicit
 per-reason counter (`scan_decode_fallback_total{reason=}`) so a
@@ -147,6 +156,23 @@ _DECODE_ROWS = {
         "a slice resident on the device: nothing crossed)"
     ).labels(side=side)
     for side in ("stored", "uploaded")
+}
+
+
+# how often the run reduction engages: every planned dispatch by the
+# reduction its slice takes
+_DECODE_REDUCE = {
+    kind: registry.counter(
+        "scan_decode_reduce_total",
+        "planned fused decode dispatches by how their grids are "
+        "reduced: runs = the slice's rows arrive with (group, ts) "
+        "never falling, so every cell is one contiguous run of rows "
+        "and nothing is scattered (ops/downsample.run_aggregate); "
+        "scatter = the slice breaks that order (two fields admitted "
+        "by one In, a group column behind another varying key) and "
+        "pays one scatter update per padded row per grid"
+    ).labels(kind=kind)
+    for kind in ("runs", "scatter")
 }
 
 
@@ -423,6 +449,32 @@ def _lex_sorted_np(keys: list) -> bool:
     return True
 
 
+def _cells_sorted(es, route: str, pk_names: list, group_col: str,
+                  ts_col: str) -> bool:
+    """Whether the rows the program decodes from this (narrowed)
+    segment come out with (group code, ts) never falling, so that every
+    (group, bucket) cell is one contiguous run of rows and the grids
+    can be reduced by runs.  A property of the slice's host columns,
+    decided once where the slice is planned.  Rows that arrive in
+    order are checked as they lie (one numpy pass, as the route's own
+    check); rows the device puts in (pk, seq) order are in (group, ts)
+    order when both are PK keys, group ahead of ts, and every other PK
+    key ahead of ts holds one value over the slice (a query's Eq on
+    the field).  A superset is checked (rows a window's range leaves
+    will drop included), so the answer holds for every window."""
+    if not es.n:
+        return True
+    group, ts = es.columns[group_col], es.columns[ts_col]
+    if route == "presorted":
+        return _lex_sorted_np([group, ts])
+    if group_col not in pk_names or ts_col not in pk_names:
+        return False
+    gi, ti = pk_names.index(group_col), pk_names.index(ts_col)
+    return gi < ti and all(
+        int(es.columns[nm].min()) == int(es.columns[nm].max())
+        for nm in pk_names[:ti] if nm != group_col)
+
+
 def decode_rows_core(cols: tuple, n_valid, leaf_consts: tuple,
                      run_offsets, *, key_slots: tuple, num_pks: int,
                      group_pos: int, val_slot: int, leaf_prog: tuple,
@@ -526,19 +578,42 @@ def _rows_in_order(cols: tuple, valid, iota, n_valid, run_offsets, *,
     return valid_s, keys_s, val_s
 
 
+def decode_partials(cols: tuple, n_valid, leaf_consts: tuple,
+                    run_offsets, shift, lo, total, bucket_ms, *,
+                    key_slots: tuple, num_pks: int, group_pos: int,
+                    ts_pos: int, val_slot: int, leaf_prog: tuple,
+                    route: str, num_runs: int, g_pad: int, width: int,
+                    which: tuple, cells_sorted: bool):
+    """One slice's traced body, decode to partial grids: the ONE place
+    the single-device dispatch below and the mesh's per-slot program
+    (parallel/scan.mesh_decode_partials) get their grids, so the two
+    stay byte-identical whichever reduction a slice takes.  The slice's
+    own group codes are the grid's rows (no remap).  `cells_sorted`
+    (static, decided per slice by plan_segment) says the decoded rows'
+    (group, bucket) never falls, and the grids are reduced by runs
+    (ops/downsample.run_aggregate); a slice without it scatters.
+    Returns ({partial grids}, kept_rows)."""
+    keys_s, gid, val_s, n_rows = decode_rows_core(
+        cols, n_valid, leaf_consts, run_offsets, key_slots=key_slots,
+        num_pks=num_pks, group_pos=group_pos, val_slot=val_slot,
+        leaf_prog=leaf_prog, route=route, num_runs=num_runs)
+    grids = downsample.window_local_partials(
+        keys_s[ts_pos], gid, val_s, None, shift, lo, total, bucket_ms,
+        num_groups=g_pad, num_buckets=width, which=which,
+        cells_sorted=cells_sorted)
+    return grids, n_rows
+
+
 @deviceprof.jit(static_argnames=(
     "key_slots", "num_pks", "group_pos", "ts_pos", "val_slot",
     "leaf_prog", "g_pad", "width", "which", "route",
-    "num_runs"))
+    "num_runs", "cells_sorted"))
 def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
-                          shift, lo, total, bucket_ms, run_offsets, *,
-                          key_slots: tuple, num_pks: int,
-                          group_pos: int, ts_pos: int,
-                          val_slot: int, leaf_prog: tuple,
-                          g_pad: int, width: int, which: tuple,
-                          route: str = "sorted",
-                          num_runs: int = 0):
-    """THE fused dispatch: encoded columns in, partial grids out.
+                          shift, lo, total, bucket_ms, run_offsets,
+                          **static):
+    """THE fused dispatch: encoded columns in, partial grids out —
+    decode_partials as one compiled program (`static` is its keyword
+    set, every one a static argument).
 
     `cols` is the tuple of uploaded int32 code columns (pad capacity);
     `key_slots` indexes the sort keys into it — the first `num_pks`
@@ -549,22 +624,15 @@ def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
     the sorted key outputs; `val_slot` indexes the f32 value column
     (carried, not a key).  `leaf_prog` is the static (column-slot,
     opcode) program from compile_leaves with `leaf_consts` its traced
-    constants.  Row ordering/dedup semantics live in decode_rows_core
-    (shared with the mesh round program).
+    constants.  Row ordering/dedup semantics live in decode_rows_core,
+    the reduction in decode_partials (both shared with the mesh round
+    program).
 
     Dropped rows (padding, leaf-filtered, dup-shadowed) are masked to
     gid = -1, never compacted — static shapes, no host round trip.
     Returns ({partial grids}, kept_rows)."""
-    keys_s, gid, val_s, n_rows = decode_rows_core(
-        cols, n_valid, leaf_consts, run_offsets, key_slots=key_slots,
-        num_pks=num_pks, group_pos=group_pos, val_slot=val_slot,
-        leaf_prog=leaf_prog, route=route, num_runs=num_runs)
-    ts_s = keys_s[ts_pos]
-    grids = downsample.window_local_partials(
-        ts_s, gid, val_s, jnp.arange(g_pad, dtype=jnp.int32),
-        shift, lo, total, bucket_ms, num_groups=g_pad,
-        num_buckets=width, which=which)
-    return grids, n_rows
+    return decode_partials(cols, n_valid, leaf_consts, run_offsets,
+                           shift, lo, total, bucket_ms, **static)
 
 
 # ---------------------------------------------------------------------------
@@ -714,6 +782,8 @@ class SegmentSlice:
     sort_skipped: Optional[str]   # scan_decode_sort_skipped_total's route
     run_offsets: Optional[np.ndarray]
     num_runs: int
+    cells_sorted: bool        # decoded rows' (group, ts) never falls:
+    #                           the grids reduce by runs, not scatters
     cols_dev: Optional[tuple] = None
     key_consts_dev: tuple = ()
     offs_dev: object = None
@@ -771,8 +841,8 @@ class DecodePlan:
         return (self.key_slots, self.num_pks, self.group_pos,
                 self.ts_pos, self.val_slot, self.leaf_prog,
                 tuple(len(c) for c in self.consts), self.route,
-                self.num_runs, self.local_ok, len(self.upload_names),
-                self.which)
+                self.num_runs, self.cells_sorted, self.local_ok,
+                len(self.upload_names), self.which)
 
 
 def _is_key_leaf(leaf) -> bool:
@@ -894,6 +964,7 @@ def plan_segment(es, group_col: str, ts_col: str, value_col: str,
             run_offsets[:len(offs)] = offs
             run_offsets[len(rl)] = es.n  # real runs end at n
     g = len(g_enc.dictionary)
+    cells_sorted = _cells_sorted(es, route, pk_names, group_col, ts_col)
     return SegmentSlice(
         es=es, src_rows=src_rows, n=es.n, cap=cap, admissible=admissible,
         encodings={nm: encs[nm] for nm in upload_names},
@@ -907,7 +978,7 @@ def plan_segment(es, group_col: str, ts_col: str, value_col: str,
         ts_pos=key_names.index(ts_col), val_slot=slot_of[value_col],
         key_prog=key_prog, key_consts=key_consts, route=route,
         sort_skipped=sort_skipped, run_offsets=run_offsets,
-        num_runs=num_runs)
+        num_runs=num_runs, cells_sorted=cells_sorted)
 
 
 def plan_window(seg: SegmentSlice, spec, leaves,
@@ -947,6 +1018,7 @@ def plan_window(seg: SegmentSlice, spec, leaves,
     else:
         note_fallback("kway_runs")
         _SORT_RAN.inc()
+    _DECODE_REDUCE["runs" if seg.cells_sorted else "scatter"].inc()
     _DECODE_ROWS["stored"].inc(seg.src_rows)
     if seg.cols_dev is None:
         _DECODE_ROWS["uploaded"].inc(seg.n)
@@ -1022,7 +1094,8 @@ def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
         group_pos=seg.group_pos, ts_pos=seg.ts_pos,
         val_slot=seg.val_slot, leaf_prog=dp.leaf_prog,
         g_pad=seg.g_pad, width=dp.use_width, which=dp.which,
-        route=seg.route, num_runs=seg.num_runs)
+        route=seg.route, num_runs=seg.num_runs,
+        cells_sorted=seg.cells_sorted)
     return DecodeDispatch(outs=outs, n_rows=n_rows,
                           values=seg.values, lo=dp.lo, w_eff=dp.w_eff,
                           bucket_ms=dp.bucket_ms,

@@ -148,7 +148,8 @@ def _segmented_time_combine(state: dict, seg_ids, time_n: int) -> dict:
 def mesh_decode_partials(mesh, *, num_groups: int, num_buckets: int,
                          which: tuple, key_slots: tuple, num_pks: int,
                          group_pos: int, ts_pos: int, val_slot: int,
-                         leaf_prog: tuple, route: str, num_runs: int):
+                         leaf_prog: tuple, route: str, num_runs: int,
+                         cells_sorted: bool):
     """The mesh-placed FUSED decode round: each time slot starts from
     its segment's raw encoded sidecar buffers and runs leaf-filter →
     (k-way merge | sort | presorted) → keep-last dedup → bucket
@@ -156,7 +157,8 @@ def mesh_decode_partials(mesh, *, num_groups: int, num_buckets: int,
     decode shards along the time axis with the aggregation instead of
     serializing ahead of it on one chip (ROADMAP item 1).
 
-    Static decode geometry (key_slots/leaf_prog/route/...) comes from
+    Static decode geometry (key_slots/leaf_prog/route/cells_sorted/...)
+    comes from
     the round's DecodePlan group (ops/device_decode.plan_dispatch);
     the dispatcher only batches plans whose DecodePlan.static_key()
     agree, so one compiled program serves the whole round.
@@ -187,17 +189,14 @@ def mesh_decode_partials(mesh, *, num_groups: int, num_buckets: int,
     def shard_fn(cols, n_valid, leaf_consts, run_offsets, shift, lo,
                  seg_ids, total, bucket_ms):
         _check_block_is_one(cols[0])
-        keys_s, gid, val_s, n_rows = device_decode.decode_rows_core(
+        p, n_rows = device_decode.decode_partials(
             tuple(c[0] for c in cols), n_valid[0],
             tuple(c[0] for c in leaf_consts), run_offsets[0],
+            shift[0], lo[0], total, bucket_ms[0],
             key_slots=key_slots, num_pks=num_pks, group_pos=group_pos,
-            val_slot=val_slot, leaf_prog=leaf_prog, route=route,
-            num_runs=num_runs)
-        p = downsample.window_local_partials(
-            keys_s[ts_pos], gid, val_s,
-            jnp.arange(num_groups, dtype=jnp.int32), shift[0], lo[0],
-            total, bucket_ms[0], num_groups=num_groups,
-            num_buckets=num_buckets, which=which)
+            ts_pos=ts_pos, val_slot=val_slot, leaf_prog=leaf_prog,
+            route=route, num_runs=num_runs, g_pad=num_groups,
+            width=num_buckets, which=which, cells_sorted=cells_sorted)
         p = _series_slice(p, gb)
         state = _segmented_time_combine(p, seg_ids, time_n)
         return ({k: v[None] for k, v in state.items()}, n_rows[None])

@@ -3225,7 +3225,7 @@ class ParquetReader:
                 num_pks=dp0.num_pks, group_pos=dp0.group_pos,
                 ts_pos=dp0.ts_pos, val_slot=dp0.val_slot,
                 leaf_prog=dp0.leaf_prog, route=dp0.route,
-                num_runs=dp0.num_runs)
+                num_runs=dp0.num_runs, cells_sorted=dp0.cells_sorted)
             self._mesh_run_fns[fn_key] = fn
         out, _kept = fn(cols_dev, nv_dev, consts_dev, offs_dev,
                         shift_dev, lo_dev, put(seg_ids),

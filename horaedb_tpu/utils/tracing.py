@@ -689,13 +689,25 @@ _PHASE_SECONDS = registry.histogram(
 _PHASE_CHILDREN: dict = {}
 
 
-def phase(name: str, table: str, **fields) -> span:
-    """A span of one scan phase on `table`."""
+def _phase_child(name: str, table: str):
     hist = _PHASE_CHILDREN.get((name, table))
     if hist is None:
         hist = _PHASE_CHILDREN[name, table] = _PHASE_SECONDS.labels(
             phase=name, table=table)
-    return span(name, hist=hist, table=table, **fields)
+    return hist
+
+
+def phase(name: str, table: str, **fields) -> span:
+    """A span of one scan phase on `table`."""
+    return span(name, hist=_phase_child(name, table), table=table,
+                **fields)
+
+
+def phase_passed(name: str, table: str) -> None:
+    """A phase whose seam was passed with nothing to do: an observation
+    of 0 and no span, so that the phase's series is on /metrics (and
+    reads no time) where a scan reached the seam and never waited."""
+    _phase_child(name, table).observe(0.0)
 
 
 def clear_phases(table: str) -> None:

@@ -320,20 +320,27 @@ def narrow_wreq(rows):
     return WriteRequest(rb, TimeRange.new(min(cols[3]), max(cols[3]) + 1))
 
 
+def narrow_writes(route: str) -> list:
+    """The row lists open_narrow_storage writes, one a run."""
+    rng = random.Random(SEED + 7)
+    writes = [narrow_rows(rng, range(NARROW_TICKS))]
+    if route != "presorted":
+        # rewrites of every fourth tick (keep-last must win) and ticks
+        # the first run lacks: the concatenation is unsorted
+        writes.append(narrow_rows(
+            rng, list(range(0, NARROW_TICKS, 4))
+            + list(range(NARROW_TICKS, NARROW_TICKS + 8)), base=0.5))
+    return writes
+
+
 async def open_narrow_storage(runtimes, route: str):
     """One segment that takes `route`: one SST for `presorted`, two
     interleaved ones with duplicate keys across them otherwise."""
     s = await CloudObjectStorage.open(
         "db", SEGMENT_MS, MemoryObjectStore(), NARROW_SCHEMA, 4,
         storage_config(decode={"mode": "device"}), runtimes=runtimes)
-    rng = random.Random(SEED + 7)
-    await s.write(narrow_wreq(narrow_rows(rng, range(NARROW_TICKS))))
-    if route != "presorted":
-        # rewrites of every fourth tick (keep-last must win) and ticks
-        # the first run lacks: the concatenation is unsorted
-        await s.write(narrow_wreq(narrow_rows(
-            rng, list(range(0, NARROW_TICKS, 4))
-            + list(range(NARROW_TICKS, NARROW_TICKS + 8)), base=0.5)))
+    for rows in narrow_writes(route):
+        await s.write(narrow_wreq(rows))
     return s
 
 
@@ -850,6 +857,133 @@ def test_resident_counter_is_exported_at_rest():
     text = registry.render()
     for outcome in ("hit", "miss", "bypass"):
         assert f'scan_decode_resident_total{{outcome="{outcome}"}}' in text
+
+
+# ---------------------------------------------------------------------------
+# the reduction a slice takes: by runs where its (group, ts) never
+# falls, by scatters where it does (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+# what the leaves admit -> (key leaf, fields admitted)
+REDUCE_LEAVES = {
+    # one field: rows in (host, ts) order, every cell one run of rows
+    "runs": (F.Eq("field", "f1"), ("f1",)),
+    # two fields by one In: within a host ts starts over at the second
+    # field, so a cell's rows lie in two stretches
+    "scatter": (F.In("field", ["f0", "f1"]), ("f0", "f1")),
+}
+
+
+def reduce_kinds():
+    return {k: c.value for k, c in device_decode._DECODE_REDUCE.items()}
+
+
+def narrow_reference(route: str, fields, lo: int, hi: int) -> dict:
+    """{host: {agg: (buckets,) f64}} of what open_narrow_storage wrote
+    (a later write of a key replacing the earlier), over [lo, hi) by
+    300 s buckets, in numpy."""
+    latest = {(h, f, t): v for rows in narrow_writes(route)
+              for _m, h, f, t, v in rows}
+    n = -(-(hi - lo) // 300_000)
+    out: dict = {}
+    # (host, field, ts) order: the order the rows decode in, so that on
+    # a tie of timestamps `last` is the later FIELD's value
+    for (h, f, t), v in sorted(latest.items()):
+        if f not in fields or not lo <= t < hi:
+            continue
+        g = out.setdefault(h, {
+            "count": np.zeros(n), "sum": np.zeros(n),
+            "min": np.full(n, np.inf), "max": np.full(n, -np.inf),
+            "last": np.zeros(n), "last_ts": np.full(n, -1)})
+        b = (t - lo) // 300_000
+        g["count"][b] += 1
+        g["sum"][b] += v
+        g["min"][b] = min(g["min"][b], v)
+        g["max"][b] = max(g["max"][b], v)
+        if t >= g["last_ts"][b]:
+            g["last_ts"][b], g["last"][b] = t, v
+    return out
+
+
+def assert_matches_reference(got, ref: dict, ctx: str):
+    values, grids = got
+    assert [str(v) for v in values] == sorted(ref), ctx
+    for row, host in enumerate(sorted(ref)):
+        want = ref[host]
+        occupied = want["count"] > 0
+        assert np.array_equal(np.asarray(grids["count"])[row],
+                              want["count"]), (ctx, host)
+        for a in ("min", "max", "last"):
+            g = np.asarray(grids[a], dtype=np.float64)[row]
+            assert np.array_equal(g[occupied], want[a][occupied]), \
+                (ctx, host, a)
+        g = np.asarray(grids["sum"], dtype=np.float64)[row]
+        err = np.abs(g[occupied] - want["sum"][occupied]) \
+            / np.maximum(np.abs(want["sum"][occupied]), 1e-30)
+        assert err.max() <= 1e-6, (ctx, host)
+
+
+@pytest.mark.parametrize("kind", sorted(REDUCE_LEAVES))
+@pytest.mark.parametrize("route", ["presorted", "kway", "sorted"])
+def test_reduction_follows_the_order_of_the_slice(runtimes, monkeypatch,
+                                                  route, kind):
+    """plan_segment decides from the narrowed columns whether a slice's
+    cells are runs of rows; the program is compiled for that answer,
+    `scan_decode_reduce_total{kind}` counts every planned dispatch by
+    it, and either way the grids are numpy's and the host control's.  A
+    resident hit re-dispatches under the kind its slice carries and
+    compiles nothing."""
+    leaf, fields = REDUCE_LEAVES[kind]
+    other = "scatter" if kind == "runs" else "runs"
+    if route == "sorted":  # decline the k-way merge: the full sort runs
+        monkeypatch.setattr(device_decode, "_KWAY_MAX_RUNS", 1)
+    spy = _PlanSpy(monkeypatch)
+
+    def query(lo, hi):
+        pred = F.And((leaf, F.TimeRangePred("ts", lo, hi)))
+        return (ScanRequest(range=TimeRange.new(lo, hi), predicate=pred),
+                narrow_spec(lo, hi))
+
+    async def go():
+        s = await open_narrow_storage(runtimes, route)
+        try:
+            memo_off(s)
+            with _ForceXlaAgg():
+                clear_caches(s)
+                k0, c0 = reduce_kinds(), resident_outcomes()
+                miss_a = await s.scan_aggregate(*query(*WINDOW_A))
+                k1, c1 = reduce_kinds(), resident_outcomes()
+                assert moved(k0, k1) == {kind: 1, other: 0}
+                assert moved(c0, c1) == {"hit": 0, "miss": 1, "bypass": 0}
+                (_es, plan), = spy.plans()
+                assert plan.route == route
+                assert plan.cells_sorted == (kind == "runs")
+                compiles = compiles_so_far()
+                hit_b = await s.scan_aggregate(*query(*WINDOW_B))
+                k2, c2 = reduce_kinds(), resident_outcomes()
+                assert moved(k1, k2) == {kind: 1, other: 0}
+                assert moved(c1, c2) == {"hit": 1, "miss": 0, "bypass": 0}
+                assert compiles_so_far() == compiles
+                host_b = await host_control(s, *query(*WINDOW_B))
+            assert_matches_reference(
+                miss_a, narrow_reference(route, fields, *WINDOW_A),
+                f"{route} {kind} window A")
+            assert_matches_reference(
+                hit_b, narrow_reference(route, fields, *WINDOW_B),
+                f"{route} {kind} window B")
+            # count/min/max/last to the byte against the scatter-ordered
+            # control; its sums too, on these integer-valued cells
+            _assert_same(hit_b, host_b, f"{route} {kind} hit-vs-host")
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_reduce_counter_is_exported_at_rest():
+    text = registry.render()
+    for kind in ("runs", "scatter"):
+        assert f'scan_decode_reduce_total{{kind="{kind}"}}' in text
 
 
 # ---------------------------------------------------------------------------

@@ -84,7 +84,8 @@ def assert_sync_seam_per_dispatch(seams: int, dispatches, waits):
     once per dispatch.  By that seam's contract a dispatch still
     running at its finalize leaves a `scan.device_wait` span and one
     that has finished (XLA-CPU over a few hundred rows) an observation
-    of 0 and no span: which of the two is the device's pace, that one
+    of 0 (in device_exec_seconds and in the phase's histogram) and no
+    span: which of the two is the device's pace, that one
     of them happened per dispatch is the route's."""
     assert dispatches and seams == len(dispatches)
     assert len(waits) <= seams
@@ -205,8 +206,16 @@ class TestPhaseSpans:
         data_spans = [c for c in walk(tree) if c["name"] in SCAN_PHASES
                       and c["fields"]["table"] == "data"]
         for p in SCAN_PHASES:
-            assert after[p] - before[p] == sum(
-                1 for c in data_spans if c["name"] == p), p
+            spans_of_p = sum(1 for c in data_spans if c["name"] == p)
+            if p == "scan.device_wait":
+                # the download's sync seam observes every pass, with a
+                # span only where something still ran: the series is
+                # there (and reads 0) on a route that never waits
+                assert spans_of_p <= after[p] - before[p]
+                if plan_route == "device_decode":
+                    assert after[p] - before[p] == seams
+                continue
+            assert after[p] - before[p] == spans_of_p, p
 
     def test_narrowing_runs_inside_group_prep_and_adds_no_span(
             self, monkeypatch):
