@@ -168,6 +168,15 @@ def device_memory() -> list[dict]:
     return out
 
 
+def device_bytes_limit() -> Optional[int]:
+    """The memory one device offers (`bytes_limit`; the least over the
+    local devices), or None where the backend reports none — what a
+    device-resident byte budget is derived from (storage/read.py)."""
+    limits = [d["bytes_limit"] for d in device_memory()
+              if d["bytes_limit"]]
+    return min(limits) if limits else None
+
+
 class MemAccount:
     """One byte-holding component's ledger entry.
 

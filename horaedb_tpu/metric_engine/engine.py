@@ -483,7 +483,7 @@ class MetricEngine:
             self._chunk_cache = ByteLRU(
                 tables["data"].reader.cache_budget_bytes,
                 hits=_CHUNK_CACHE_HITS, misses=_CHUNK_CACHE_MISSES,
-                evictions=_CHUNK_CACHE_EVICTIONS, trace_tier="chunk")
+                evictions=(_CHUNK_CACHE_EVICTIONS,), trace_tier="chunk")
             # memory plane: the chunked engine's decoded-sample LRU is
             # a byte budget like any reader cache (common/memledger.py)
             self._chunk_mem_account = memledger.register(

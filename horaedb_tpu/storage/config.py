@@ -271,7 +271,11 @@ class ScanConfig:
     # (segment, SST set, columns) so writes/compaction invalidate
     # structurally.  The cache accounts BYTES (column widths + memo
     # allowance); this row knob converts at _CACHE_BYTES_PER_ROW unless
-    # cache_max_bytes overrides it.
+    # cache_max_bytes overrides it.  Governs the windows' account, the
+    # stack cache and the route gate (read.py: cache_budget_bytes); the
+    # device-decode slices' account is sized from the device's reported
+    # bytes_limit and falls back to this budget only where the backend
+    # reports none.
     cache_max_rows: int = 4 << 20
     # explicit budget in bytes for the scan cache (0 = derive from
     # cache_max_rows).  Cached scan windows are HOST-resident (RAM: the

@@ -43,7 +43,10 @@ import jax.numpy as jnp
 from horaedb_tpu.common import deviceprof
 from horaedb_tpu.common.deadline import checkpoint as deadline_checkpoint
 from horaedb_tpu.common.error import Error, ensure
-from horaedb_tpu.common.memledger import ledger as memledger
+from horaedb_tpu.common.memledger import (
+    device_bytes_limit,
+    ledger as memledger,
+)
 from horaedb_tpu.common.tenant import charge_scan_bytes
 from horaedb_tpu.objstore import NotFoundError, ObjectStore
 from horaedb_tpu.ops import downsample as downsample_ops
@@ -253,6 +256,19 @@ _PREFETCH_SEGMENTS = 4
 # rows -> bytes conversion for the legacy cache_max_rows knob: a typical
 # engine window is ~4 int32/f32 columns (16B) plus the memo allowance
 _CACHE_BYTES_PER_ROW = 32
+# the share of the device's reported memory (memory_stats()
+# ["bytes_limit"]) that the scan cache's device-decode slices may hold.
+# Half: slices are the one thing that should FILL the chip (a resident
+# slice is a segment never read, narrowed or uploaded again), but they
+# share it with what no account of this reader bounds — a dispatch's
+# temporaries (several times its slice at capacity 1,048,576), the
+# compiled programs' constants, the downloads in flight of four
+# concurrent queries — and with the stack cache and the windows' memos,
+# which keep the `cache_bytes` they had.  A share, not a row count:
+# what fits is the device's to say (ROADMAP C7).  Per reader, as
+# `cache_bytes` is: the data table's is the one a fleet-wide scan fills
+# (TSBS cpu-only at scale 1000: twelve slices of one field, 302 MB).
+_DEVICE_SLICE_SHARE = 0.5
 # fused replay plans kept per reader (weakref-only entries; see
 # ParquetReader._replay_cache)
 _REPLAY_SLOTS = 8
@@ -457,7 +473,22 @@ class ParquetReader:
         # public: consumers that bypass the scan cache (chunked-mode
         # engine LRU) size their own caches off the same budget
         self.cache_budget_bytes = cache_bytes
-        self.scan_cache = ScanCache(cache_bytes)
+        # the slices' own budget, from the device (read once, here): a
+        # share of what it reports, or `cache_bytes` where the backend
+        # reports nothing (XLA-CPU).  A cache turned off stays off for
+        # both accounts.  Never the route gate: that stays
+        # cache_budget_bytes
+        device_limit = device_bytes_limit()
+        self.slice_budget_bytes = (
+            int(device_limit * _DEVICE_SLICE_SHARE)
+            if device_limit and cache_bytes > 0 else cache_bytes)
+        self.scan_cache = ScanCache(cache_bytes, self.slice_budget_bytes)
+        if device_limit:
+            logger.info(
+                "scan cache %s: device slices may hold %d B (%.2f of the "
+                "device's bytes_limit %d), windows %d B", root_path,
+                self.slice_budget_bytes, _DEVICE_SLICE_SHARE, device_limit,
+                cache_bytes)
         # flush-stack LRU: stacked (B, cap) aggregation inputs reused by
         # repeat queries over cached windows.  Separately byte-accounted
         # (stacks are far larger than the per-window memo allowance) and
@@ -559,6 +590,15 @@ class ParquetReader:
                 f"scan_cache:{root_path}",
                 lambda r: r._scan_cache_resident_bytes(), anchor=self,
                 kind="scan_cache", budget=cache_bytes, owner=root_path),
+            # the cache's other account: device-decode slices are jnp
+            # arrays (padded device columns, no memo), so like the
+            # stacks they are host RSS on the CPU backend alone
+            memledger.register(
+                f"scan_cache_device:{root_path}",
+                lambda r: r.scan_cache.slice_account.total_bytes,
+                anchor=self, kind="scan_cache_device",
+                budget=self.slice_budget_bytes, owner=root_path,
+                host=jax.default_backend() == "cpu"),
             # stacks are jnp arrays: host RAM on the CPU backend, HBM
             # on accelerators — there they are NOT host RSS (they show
             # under memory_device_bytes) and must not be subtracted
@@ -600,7 +640,7 @@ class ParquetReader:
         clear-on-close gauge discipline — scan_cache_bytes{tier=} and
         the ledger's account gauges must read 0 afterwards)."""
         self.drop_hbm_state()
-        self.scan_cache.clear()
+        self.scan_cache.close()
         self.encoded_cache.clear()
         self.parts_memo.lru.clear()
         self._scalar_cache.clear()
@@ -638,18 +678,16 @@ class ParquetReader:
         return run
 
     def _scan_cache_resident_bytes(self) -> int:
-        """Actual bytes the tier-1 cache holds: column buffers at
-        their allocated (capacity-padded) widths plus MATERIALIZED
+        """Actual bytes the scan cache's WINDOWS hold: column buffers
+        at their allocated (capacity-padded) widths plus MATERIALIZED
         memo bytes — the ledger's pull gauge.  Differs from
         scan_cache.total_bytes, which charges the worst-case memo
         allowance up front (eviction must bound the budget; the
-        ledger must report residency).  Event-loop owned, like the
-        cache itself."""
+        ledger must report residency).  The slices are the
+        scan_cache_device account's: charged at what they hold.
+        Event-loop owned, like the cache itself."""
         total = 0
         for entry in self.scan_cache.values():
-            if isinstance(entry, device_decode.SegmentSlice):
-                total += entry.nbytes  # padded device columns, no memo
-                continue
             for w in entry:
                 total += sum(int(c.dtype.itemsize) * w.capacity
                              for c in w.columns.values())
@@ -668,6 +706,8 @@ class ParquetReader:
         if not memledger.enabled or active_trace() is None:
             return None
         return [("scan_cache", self.scan_cache.total_bytes),
+                ("scan_cache_device",
+                 self.scan_cache.slice_account.total_bytes),
                 ("stack_cache", self._stack_cache_bytes),
                 ("encoded_cache", self.encoded_cache.total_bytes),
                 ("parts_memo", self.parts_memo.lru.total_bytes)]
@@ -982,7 +1022,7 @@ class ParquetReader:
         window's DecodePlan over the resident slice (a hit), a
         DevicePart where the window's own leaves provably match
         nothing, or None (a miss: the segment is read)."""
-        entry = self.scan_cache.get(
+        entry = self.scan_cache.get_slice(
             self._decode_slice_key(seg, slice_columns))
         got = None
         if entry is not None:
@@ -1599,18 +1639,23 @@ class ParquetReader:
     def cache_stats(self) -> dict:
         """The /stats cache section: every reader-owned cache tier's
         residency and effectiveness, one dict per tier."""
-        slices = self.scan_cache.slices()
+        accounts = self.scan_cache.account_stats()
+        slices = self.scan_cache.slice_account
         return {
             "scan_cache": {
-                "entries": len(self.scan_cache),
-                "bytes": self.scan_cache.total_bytes,
+                # both accounts summed; `max_bytes` is the windows'
+                # budget (and the route gate's number)
+                "entries": sum(a["entries"] for a in accounts.values()),
+                "bytes": sum(a["bytes"] for a in accounts.values()),
                 "max_bytes": self.scan_cache.max_bytes,
-                "hits": self.scan_cache.hits,
-                "misses": self.scan_cache.misses,
+                "hits": self.scan_cache.hits + slices.hits,
+                "misses": self.scan_cache.misses + slices.misses,
                 # of those entries and bytes, the device-decode slices
                 # resident on the device (ops/device_decode.py)
-                "decode_slices": len(slices),
-                "decode_slice_bytes": sum(e.nbytes for e in slices),
+                "decode_slices": accounts["slice"]["entries"],
+                "decode_slice_bytes": accounts["slice"]["bytes"],
+                # budget, bytes, entries, evicted, declined of each
+                "accounts": accounts,
             },
             "encoded_cache": self.encoded_cache.stats(),
             "parts_memo": self.parts_memo.stats(),

@@ -600,15 +600,17 @@ def test_resident_hit_is_the_miss_byte_for_byte(runtimes, route):
                 c1, r1 = resident_outcomes(), decode_row_sides()
                 assert moved(c0, c1) == {"hit": 0, "miss": 1, "bypass": 0}
                 # the slice: one field's rows in their capacity bucket,
-                # six 4-byte columns, charged to the one byte budget
-                # and reported by the ledger's account as it stands
+                # six 4-byte columns, charged to the slices' account
+                # and reported by its ledger account as it stands
                 kept = r1[1] - r0[1]
                 stats = s.reader.cache_stats()["scan_cache"]
                 nbytes = encode.pad_capacity(int(kept)) * 4 * 6
                 assert 0 < kept < r1[0] - r0[0]
                 assert (stats["entries"], stats["decode_slices"]) == (1, 1)
                 assert stats["bytes"] == stats["decode_slice_bytes"] \
-                    == s.reader._scan_cache_resident_bytes() == nbytes
+                    == s.reader.scan_cache.slice_account.total_bytes \
+                    == nbytes
+                assert s.reader._scan_cache_resident_bytes() == 0
                 programs = device_decode._decode_aggregate_jit._cache_size()
                 compiles, reads = compiles_so_far(), tier2.hits
                 h2d = deviceprof.profiler.snapshot()["transfer"]["h2d"]
@@ -879,10 +881,15 @@ def reduce_kinds():
 
 
 def narrow_reference(route: str, fields, lo: int, hi: int) -> dict:
-    """{host: {agg: (buckets,) f64}} of what open_narrow_storage wrote
-    (a later write of a key replacing the earlier), over [lo, hi) by
-    300 s buckets, in numpy."""
-    latest = {(h, f, t): v for rows in narrow_writes(route)
+    """rows_reference of what open_narrow_storage wrote."""
+    return rows_reference(narrow_writes(route), fields, lo, hi)
+
+
+def rows_reference(writes: list, fields, lo: int, hi: int) -> dict:
+    """{host: {agg: (buckets,) f64}} of the row lists `writes` in the
+    order written (a later write of a key replacing the earlier), over
+    [lo, hi) by 300 s buckets, in numpy."""
+    latest = {(h, f, t): v for rows in writes
               for _m, h, f, t, v in rows}
     n = -(-(hi - lo) // 300_000)
     out: dict = {}
@@ -921,6 +928,16 @@ def assert_matches_reference(got, ref: dict, ctx: str):
         err = np.abs(g[occupied] - want["sum"][occupied]) \
             / np.maximum(np.abs(want["sum"][occupied]), 1e-30)
         assert err.max() <= 1e-6, (ctx, host)
+        # the two grids derived from those: all seven are checked
+        if "last_ts" in grids:
+            g = np.asarray(grids["last_ts"], dtype=np.float64)[row]
+            assert np.array_equal(g[occupied], want["last_ts"][occupied]), \
+                (ctx, host, "last_ts")
+        if "avg" in grids:
+            g = np.asarray(grids["avg"], dtype=np.float64)[row]
+            avg = want["sum"][occupied] / want["count"][occupied]
+            assert np.abs(g[occupied] - avg).max() \
+                <= 1e-6 * np.abs(avg).max(), (ctx, host, "avg")
 
 
 @pytest.mark.parametrize("kind", sorted(REDUCE_LEAVES))
@@ -984,6 +1001,351 @@ def test_reduce_counter_is_exported_at_rest():
     text = registry.render()
     for kind in ("runs", "scatter"):
         assert f'scan_decode_reduce_total{{kind="{kind}"}}' in text
+
+
+# ---------------------------------------------------------------------------
+# the slices' own account: a budget derived from the device, an LRU
+# order and counts apart from the windows' (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+SLICE_BYTES = encode.pad_capacity((NARROW_HOSTS - 1) * NARROW_TICKS) * 4 * 6
+
+
+class CountingStore(MemoryObjectStore):
+    """Counts every read the store is asked for."""
+
+    def __init__(self):
+        super().__init__()
+        self.reads = 0
+
+    async def get(self, path):
+        self.reads += 1
+        return await super().get(path)
+
+    async def get_range(self, path, start, end):
+        self.reads += 1
+        return await super().get_range(path, start, end)
+
+
+def report_device_limit(monkeypatch, limit):
+    """The reader under test opens on a device that reports `limit`
+    bytes (None: one that reports nothing, as XLA-CPU does)."""
+    monkeypatch.setattr("horaedb_tpu.storage.read.device_bytes_limit",
+                        lambda: limit)
+
+
+def limit_for_slices(n: float) -> int:
+    """A reported limit whose derived slice budget holds `n` slices."""
+    from horaedb_tpu.storage.read import _DEVICE_SLICE_SHARE
+
+    return int((n * SLICE_BYTES + 64) / _DEVICE_SLICE_SHARE)
+
+
+def account_events(kind: str) -> dict:
+    fam = registry.family("scan_cache_account_events_total")
+    return {e: fam.labels(tier="hbm", kind=kind, event=e).value
+            for e in ("evicted", "declined")}
+
+
+async def open_sliced_storage(runtimes, segments: int, store=None,
+                              **scan):
+    """`segments` one-SST segments of the narrow rows under a WINDOWS
+    budget smaller than one slice; returns the storage and its
+    writes."""
+    s = await CloudObjectStorage.open(
+        "db", SEGMENT_MS, store or MemoryObjectStore(), NARROW_SCHEMA, 4,
+        storage_config(decode={"mode": "device"},
+                       cache_max_bytes=SLICE_BYTES - 100, **scan),
+        runtimes=runtimes)
+    rng = random.Random(SEED + 13)
+    writes = []
+    for seg in range(segments):
+        writes.append([(m, h, f, ts + seg * SEGMENT_MS, v)
+                       for m, h, f, ts, v
+                       in narrow_rows(rng, range(NARROW_TICKS))])
+        await s.write(narrow_wreq(writes[-1]))
+    memo_off(s)
+    return s, writes
+
+
+def test_slices_over_the_windows_budget_stay_on_a_device_that_holds_them(
+        runtimes, monkeypatch):
+    """Three segments' slices are three times the windows budget (which
+    holds none of them) and fit what the device's share allows: the
+    second query dispatches all three from the device, with no store
+    call, tier-2 probe, upload or compile, and both answers are
+    numpy's."""
+    report_device_limit(monkeypatch, limit_for_slices(3))
+
+    async def go():
+        store = CountingStore()
+        s, writes = await open_sliced_storage(runtimes, 3, store)
+        try:
+            assert s.reader.cache_budget_bytes < SLICE_BYTES \
+                < 3 * SLICE_BYTES <= s.reader.slice_budget_bytes
+            lo, hi = WINDOW_A[0], 2 * SEGMENT_MS + WINDOW_A[1]
+            tier2 = s.reader.encoded_cache
+            with _ForceXlaAgg():
+                clear_caches(s)
+                c0 = resident_outcomes()
+                first = await s.scan_aggregate(*field_query(lo, hi))
+                c1 = resident_outcomes()
+                assert moved(c0, c1) == {"hit": 0, "miss": 3, "bypass": 0}
+                acct = s.reader.cache_stats()["scan_cache"]["accounts"]
+                assert acct["slice"] == {
+                    "budget_bytes": s.reader.slice_budget_bytes,
+                    "bytes": 3 * SLICE_BYTES, "entries": 3,
+                    "evicted": 0, "declined": 0}
+                assert (acct["windows"]["bytes"],
+                        acct["windows"]["entries"]) == (0, 0)
+                reads, probes = store.reads, tier2.hits + tier2.misses
+                compiles = compiles_so_far()
+                h2d = deviceprof.profiler.snapshot()["transfer"]["h2d"]
+                again = await s.scan_aggregate(*field_query(lo, hi))
+                other = await s.scan_aggregate(
+                    *field_query(lo + 300_000, hi - 300_000))
+                assert moved(c1, resident_outcomes()) \
+                    == {"hit": 6, "miss": 0, "bypass": 0}
+                assert store.reads == reads
+                assert tier2.hits + tier2.misses == probes
+                assert deviceprof.profiler.snapshot()["transfer"]["h2d"] \
+                    == h2d
+                assert compiles_so_far() == compiles
+            _assert_same(first, again, "hit vs miss")
+            assert_matches_reference(
+                first, rows_reference(writes, ("f1",), lo, hi), "miss")
+            assert_matches_reference(
+                other, rows_reference(writes, ("f1",), lo + 300_000,
+                                      hi - 300_000), "hit, other window")
+        finally:
+            await s.close()
+
+    run(go())
+
+
+@pytest.mark.parametrize("holds", ["one_of_two", "none"])
+def test_a_device_budget_under_one_query_counts_on_its_own_account(
+        runtimes, monkeypatch, holds):
+    """A device that holds one of a query's two slices, or not even
+    one: the slice account evicts, or declines, and says so under its
+    own kind; the windows account counts nothing; every answer is
+    numpy's."""
+    report_device_limit(monkeypatch, limit_for_slices(
+        {"one_of_two": 1, "none": 0.9}[holds]))
+    tier_evictions = registry.family("scan_cache_evictions_total") \
+        .labels(tier="hbm")
+
+    async def go():
+        s, writes = await open_sliced_storage(runtimes, 2)
+        try:
+            lo, hi = WINDOW_A[0], SEGMENT_MS + WINDOW_A[1]
+            with _ForceXlaAgg():
+                clear_caches(s)
+                e0 = {k: account_events(k) for k in ("slice", "windows")}
+                t0, c0 = tier_evictions.value, resident_outcomes()
+                first = await s.scan_aggregate(*field_query(lo, hi))
+                second = await s.scan_aggregate(
+                    *field_query(lo + 60_000, hi + 60_000))
+                got = moved(c0, resident_outcomes())
+                events = moved(e0["slice"], account_events("slice"))
+                acct = s.reader.scan_cache.account_stats()
+            if holds == "one_of_two":
+                assert got == {"hit": 1, "miss": 3, "bypass": 0}
+                assert events == {"evicted": 2, "declined": 0}
+                assert (acct["slice"]["entries"],
+                        acct["slice"]["bytes"]) == (1, SLICE_BYTES)
+            else:
+                assert got == {"hit": 0, "miss": 4, "bypass": 0}
+                assert events == {"evicted": 0, "declined": 4}
+                assert (acct["slice"]["entries"],
+                        acct["slice"]["bytes"]) == (0, 0)
+            assert (acct["slice"]["evicted"], acct["slice"]["declined"]) \
+                == (events["evicted"], events["declined"])
+            # the tier's total is the sum; the windows' account is still
+            assert tier_evictions.value - t0 == events["evicted"]
+            assert account_events("windows") == e0["windows"]
+            assert (acct["windows"]["evicted"],
+                    acct["windows"]["declined"]) == (0, 0)
+            assert_matches_reference(
+                first, rows_reference(writes, ("f1",), lo, hi), holds)
+            assert_matches_reference(
+                second, rows_reference(writes, ("f1",), lo + 60_000,
+                                       hi + 60_000), holds)
+        finally:
+            await s.close()
+
+    run(go())
+
+
+@pytest.mark.parametrize("reported, cache_max_bytes, want", [
+    # a share of what the device reports, whatever the windows have
+    (16 * 2**30, 1 << 20, "share"),
+    (limit_for_slices(3), SLICE_BYTES - 100, "share"),
+    # XLA-CPU reports nothing: the budget the slices had before
+    (None, 1 << 20, "cache_bytes"),
+    # a cache turned off is off for both accounts
+    (16 * 2**30, 0, "cache_bytes"),
+])
+def test_slice_budget_is_derived_from_the_device(
+        runtimes, monkeypatch, reported, cache_max_bytes, want):
+    from horaedb_tpu.common.memledger import ledger
+    from horaedb_tpu.storage.read import _DEVICE_SLICE_SHARE
+
+    report_device_limit(monkeypatch, reported)
+
+    async def go():
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), NARROW_SCHEMA, 4,
+            storage_config(decode={"mode": "device"},
+                           cache_max_bytes=cache_max_bytes,
+                           cache_max_rows=0),
+            runtimes=runtimes)
+        try:
+            r = s.reader
+            budget = (int(reported * _DEVICE_SLICE_SHARE)
+                      if want == "share" else cache_max_bytes)
+            assert r.slice_budget_bytes == budget
+            assert r.scan_cache.slice_account.max_bytes == budget
+            # the windows, the stacks and the route gate keep theirs
+            assert r.scan_cache.max_bytes == r.cache_budget_bytes \
+                == r._stack_cache_max == cache_max_bytes
+            acct, = [a for a in ledger.snapshot()["accounts"]
+                     ["scan_cache_device"]["instances"]]
+            assert acct["budget"] == budget
+            budgets = registry.family("scan_cache_account_budget_bytes")
+            assert budgets.labels(tier="hbm", kind="slice").value == budget
+            assert budgets.labels(tier="hbm", kind="windows").value \
+                == cache_max_bytes
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_device_bytes_limit_reads_the_ledgers_device_reader(monkeypatch):
+    from horaedb_tpu.common import memledger
+
+    def devices(*limits):
+        return lambda: [{"device": f"tpu:{i}", "bytes_in_use": 1,
+                         "bytes_limit": lim, "peak_bytes_in_use": None}
+                        for i, lim in enumerate(limits)]
+
+    # this backend (XLA-CPU) reports no memory_stats at all
+    assert memledger.device_bytes_limit() is None
+    monkeypatch.setattr(memledger, "device_memory", devices(900, 700))
+    assert memledger.device_bytes_limit() == 700
+    monkeypatch.setattr(memledger, "device_memory", devices(None))
+    assert memledger.device_bytes_limit() is None
+    monkeypatch.setattr(memledger, "device_memory", devices())
+    assert memledger.device_bytes_limit() is None
+
+
+def test_slices_on_the_device_account_miss_after_a_write_and_a_compaction(
+        runtimes, monkeypatch):
+    """Under the derived budget as under the old one, the SST ids are
+    the key: a write into a segment and a compaction of it each miss
+    that segment's slice alone, and the answer is numpy's over the
+    rows the store then holds."""
+    report_device_limit(monkeypatch, limit_for_slices(4))
+
+    async def go():
+        s, writes = await open_sliced_storage(runtimes, 2)
+        try:
+            rng = random.Random(SEED + 17)
+            lo, hi = WINDOW_A[0], SEGMENT_MS + WINDOW_A[1]
+
+            async def query(what, misses):
+                c0 = resident_outcomes()
+                got = await s.scan_aggregate(*field_query(lo, hi))
+                assert moved(c0, resident_outcomes()) == {
+                    "hit": 2 - misses, "miss": misses, "bypass": 0}, what
+                assert_matches_reference(
+                    got, rows_reference(writes, ("f1",), lo, hi), what)
+                return got
+
+            with _ForceXlaAgg():
+                clear_caches(s)
+                loaded = await query("as loaded", 2)
+                await query("resident", 0)
+                # rewrites of every fourth tick of segment 0
+                writes.append(narrow_rows(
+                    rng, list(range(0, NARROW_TICKS, 4)), base=0.25))
+                await s.write(narrow_wreq(writes[-1]))
+                written = await query("after a write", 1)
+                await query("resident again", 0)
+                task = await s.compact_scheduler.picker.pick_candidate()
+                await s.compact_scheduler.executor.execute(task)
+                compacted = await query("after a compaction", 1)
+                await query("resident once more", 0)
+            assert loaded[1]["last"].tobytes() \
+                != written[1]["last"].tobytes()
+            _assert_same(written, compacted, "a compaction moves no value")
+            # the stale slices were never served and only age out
+            assert s.reader.scan_cache.account_stats()["slice"][
+                "entries"] == 4
+        finally:
+            await s.close()
+
+    run(go())
+
+
+def test_slice_account_leaves_the_ledger_and_its_gauges_on_close(
+        runtimes, monkeypatch):
+    from horaedb_tpu.common.memledger import ledger
+
+    report_device_limit(monkeypatch, limit_for_slices(2))
+    gauge = registry.family("scan_cache_account_bytes") \
+        .labels(tier="hbm", kind="slice")
+    entries = registry.family("scan_cache_account_entries") \
+        .labels(tier="hbm", kind="slice")
+    budgets = registry.family("scan_cache_account_budget_bytes")
+
+    async def go():
+        before, held = gauge.value, entries.value
+        s, _writes = await open_sliced_storage(runtimes, 2)
+        try:
+            with _ForceXlaAgg():
+                await s.scan_aggregate(*field_query(
+                    WINDOW_A[0], SEGMENT_MS + WINDOW_A[1]))
+            assert gauge.value - before == 2 * SLICE_BYTES
+            assert entries.value - held == 2
+            sampled = ledger.sample_once()["accounts"]
+            assert sampled["scan_cache_device"] == 2 * SLICE_BYTES
+            # the windows' account no longer reports the slices
+            assert sampled["scan_cache"] == 0
+        finally:
+            await s.close()
+        assert "scan_cache_device" not in ledger.kinds()
+        assert ledger.sample_once()["accounts"].get(
+            "scan_cache_device", 0) == 0
+        assert (gauge.value, entries.value) == (before, held)
+        for kind in ("slice", "windows"):
+            assert budgets.labels(tier="hbm", kind=kind).value == 0
+
+    run(go())
+
+
+def test_account_series_are_rendered_for_both_kinds():
+    """What /metrics serves of an open cache: budget, bytes and entries
+    of each account, its evictions and declines, and the tier's own
+    families beside them."""
+    from horaedb_tpu.storage.scan_cache import ScanCache
+
+    cache = ScanCache(4_096, slice_max_bytes=8_192)
+    try:
+        text = registry.render()
+        for kind, budget in (("windows", 4_096), ("slice", 8_192)):
+            labels = f'kind="{kind}",tier="hbm"'
+            assert f"scan_cache_account_budget_bytes{{{labels}}} " \
+                f"{budget}" in text
+            assert f"scan_cache_account_bytes{{{labels}}}" in text
+            assert f"scan_cache_account_entries{{{labels}}}" in text
+            for event in ("evicted", "declined"):
+                assert "scan_cache_account_events_total{" \
+                    f'event="{event}",{labels}}}' in text
+        assert 'scan_cache_evictions_total{tier="hbm"}' in text
+    finally:
+        cache.close()
 
 
 # ---------------------------------------------------------------------------

@@ -516,16 +516,31 @@ def _windows(capacity: int) -> list:
         n_valid=capacity, capacity=capacity)]
 
 
-def test_slices_share_the_windows_budget_and_lru_order():
+def _events(kind: str) -> dict:
+    from horaedb_tpu.utils import registry
+
+    fam = registry.family("scan_cache_account_events_total")
+    return {e: fam.labels(tier="hbm", kind=kind, event=e).value
+            for e in ("evicted", "declined")}
+
+
+def test_windows_and_slices_never_evict_each_other():
+    """One class, two accounts: a budget, an LRU order and counts each.
+    Filling either to eviction leaves the other as it was."""
     from horaedb_tpu.storage.scan_cache import (
         ScanCache,
         segment_cache_key,
         windows_nbytes,
     )
+    from horaedb_tpu.utils import registry
 
-    windows = _windows(64)
+    windows = _windows(128)
     w_bytes = windows_nbytes(windows)
-    cache = ScanCache(max_bytes=w_bytes + 2_500)
+    tier_evictions = registry.family("scan_cache_evictions_total") \
+        .labels(tier="hbm")
+    t0 = tier_evictions.value
+    e0 = {k: _events(k) for k in ("windows", "slice")}
+    cache = ScanCache(max_bytes=w_bytes + 100, slice_max_bytes=2_500)
     kw = segment_cache_key(0, [1], ("a",))
     # the window is not in a slice's key; the key leaves' values are
     k1 = segment_cache_key(0, [1], ("a", "decode", "(eq field 'f1')"))
@@ -535,36 +550,78 @@ def test_slices_share_the_windows_budget_and_lru_order():
     one, two = _Slice(1_000), _Slice(1_200)
     cache.put_slice(k1, one)
     cache.put_slice(k2, two)
-    assert cache.total_bytes == w_bytes + 2_200 and len(cache) == 3
-    assert cache.slices() == [one, two]
-    assert cache.values() == [windows, one, two]
-    # LRU over both kinds: the windows become MRU, the older slice goes
-    assert cache.get(kw) is windows
+    # 2,200 B of slices beside a windows account with 100 B of room
+    assert cache.total_bytes == w_bytes and len(cache) == 1
+    assert cache.values() == [windows] and cache.slices() == [one, two]
+    assert cache.account_stats() == {
+        "windows": {"budget_bytes": w_bytes + 100, "bytes": w_bytes,
+                    "entries": 1, "evicted": 0, "declined": 0},
+        "slice": {"budget_bytes": 2_500, "bytes": 2_200, "entries": 2,
+                  "evicted": 0, "declined": 0}}
+    # a key of one account is not found in the other
+    assert cache.get(k1) is None and cache.get_slice(kw) is None
+    # the slices' own LRU: the older one goes, the windows stay
+    assert cache.get_slice(k1) is one
     three = _Slice(1_100)
-    cache.put_slice(segment_cache_key(3_600_000, [2], ("a", "decode")),
-                    three)
-    assert cache.get(k1) is None and cache.get(k2) is two
-    assert cache.total_bytes == w_bytes + 2_300
+    k3 = segment_cache_key(3_600_000, [2], ("a", "decode"))
+    cache.put_slice(k3, three)
+    assert cache.get_slice(k2) is None and cache.slices() == [one, three]
+    assert cache.get(kw) is windows
     # a changed SST set is another key: nothing to invalidate
-    assert cache.get(segment_cache_key(
-        0, [1, 9], ("a", "decode", "(eq field 'f2')"))) is None
-    # larger than the whole budget: declined, nothing evicted for it
-    cache.put_slice(k1, _Slice(w_bytes + 2_501))
-    assert cache.get(k1) is None and len(cache) == 3
+    assert cache.get_slice(segment_cache_key(
+        0, [1, 9], ("a", "decode", "(eq field 'f1')"))) is None
+    # larger than the slices' whole budget, though the windows' would
+    # hold it: declined, nothing evicted for it on either side
+    assert 2_501 < w_bytes + 100
+    cache.put_slice(k2, _Slice(2_501))
+    assert cache.get_slice(k2) is None and cache.slices() == [one, three]
+    # the windows' own LRU: another list evicts the first, no slice
+    other = _windows(128)
+    cache.put(segment_cache_key(3_600_000, [2], ("a",)), other)
+    assert cache.values() == [other] and cache.slices() == [one, three]
+    stats = cache.account_stats()
+    assert (stats["windows"]["evicted"], stats["windows"]["declined"],
+            stats["slice"]["evicted"], stats["slice"]["declined"]) \
+        == (1, 0, 1, 1)
+    assert {k: {e: _events(k)[e] - e0[k][e] for e in e0[k]}
+            for k in e0} == {
+        "windows": {"evicted": 1, "declined": 0},
+        "slice": {"evicted": 1, "declined": 1}}
+    assert tier_evictions.value - t0 == 2  # the tier's sum of both
     # the HBM-evicted state drops the slices and keeps the windows
     cache.drop_slices()
-    assert cache.slices() == [] and cache.values() == [windows]
+    assert cache.slices() == [] and cache.values() == [other]
     assert cache.total_bytes == w_bytes
-    cache.clear()
+    cache.close()
     assert cache.total_bytes == 0 and len(cache) == 0
+    assert all(a["bytes"] == 0 and a["entries"] == 0
+               for a in cache.account_stats().values())
+
+
+def test_one_budget_given_serves_both_accounts_apart():
+    """ScanCache(n): each account gets n, and they still do not
+    share it (the CPU backend's reader: no device to ask)."""
+    from horaedb_tpu.storage.scan_cache import ScanCache, windows_nbytes
+
+    windows = _windows(64)
+    w_bytes = windows_nbytes(windows)
+    cache = ScanCache(max_bytes=w_bytes)
+    cache.put(("w",), windows)
+    cache.put_slice(("s",), _Slice(w_bytes))
+    assert cache.values() == [windows] and len(cache.slices()) == 1
+    assert [a["budget_bytes"] for a in cache.account_stats().values()] \
+        == [w_bytes, w_bytes]
+    cache.close()
 
 
 def test_ledger_account_and_stats_report_a_resident_slice(
         runtimes, monkeypatch):
     """One device-decode aggregate leaves its segment's slice in the
-    scan cache: the `scan_cache` ledger account reports the padded
-    device columns' bytes (no memo allowance), /stats counts the entry
-    apart, drop_hbm_state releases it, and close() leaves nothing."""
+    scan cache's slice account: the `scan_cache_device` ledger account
+    reports the padded device columns' bytes (no memo allowance) and
+    the windows' `scan_cache` account none of them, /stats counts the
+    entry under its account, drop_hbm_state releases it, and close()
+    leaves nothing."""
     from horaedb_tpu.common import memledger
     from horaedb_tpu.ops.downsample import ALL_AGGS
     from horaedb_tpu.storage.read import AggregateSpec
@@ -572,9 +629,9 @@ def test_ledger_account_and_stats_report_a_resident_slice(
     monkeypatch.setenv("HORAEDB_DEVICE_DECODE", "1")
     monkeypatch.setenv("HORAEDB_HOST_AGG", "0")
 
-    def account_bytes():
+    def account_bytes(kind="scan_cache_device"):
         kinds = memledger.ledger.snapshot()["accounts"]
-        return kinds["scan_cache"]["bytes"] if "scan_cache" in kinds else 0
+        return kinds[kind]["bytes"] if kind in kinds else 0
 
     async def go():
         s = await open_storage(MemoryObjectStore(), runtimes)
@@ -591,8 +648,11 @@ def test_ledger_account_and_stats_report_a_resident_slice(
             nbytes = encode.pad_capacity(300) * 4 * 4
             assert (stats["entries"], stats["decode_slices"]) == (1, 1)
             assert stats["bytes"] == stats["decode_slice_bytes"] == nbytes
-            assert s.reader._scan_cache_resident_bytes() == nbytes
+            assert stats["accounts"]["slice"]["bytes"] == nbytes
+            assert stats["accounts"]["windows"]["bytes"] == 0
+            assert s.reader._scan_cache_resident_bytes() == 0
             assert account_bytes() == nbytes
+            assert account_bytes("scan_cache") == 0
             s.reader.drop_hbm_state()
             assert s.reader.cache_stats()["scan_cache"]["entries"] == 0
             assert account_bytes() == 0
@@ -601,7 +661,7 @@ def test_ledger_account_and_stats_report_a_resident_slice(
             assert account_bytes() == nbytes
         finally:
             await s.close()
-        assert account_bytes() == 0
+        assert account_bytes() == 0 == account_bytes("scan_cache")
 
     run(go())
 
@@ -623,6 +683,15 @@ def test_stats_cache_section(runtimes):
             assert stats["scan_cache"]["bytes"] >= 0
             assert stats["scan_cache"]["decode_slices"] == 0
             assert stats["scan_cache"]["decode_slice_bytes"] == 0
+            # budget, bytes, entries, evicted, declined of each account
+            accounts = stats["scan_cache"]["accounts"]
+            assert set(accounts) == {"windows", "slice"}
+            assert accounts["windows"]["budget_bytes"] \
+                == stats["scan_cache"]["max_bytes"] \
+                == s.reader.cache_budget_bytes
+            assert accounts["slice"] == {
+                "budget_bytes": s.reader.slice_budget_bytes, "bytes": 0,
+                "entries": 0, "evicted": 0, "declined": 0}
         finally:
             await s.close()
 

@@ -1000,7 +1000,7 @@ def _lint_server_routes(path: pathlib.Path, tree: ast.AST,
 # added here (and their owner must register the account) or to the
 # exempt set below with the reason they hold no resident bytes.
 _BUDGET_FIELD_ACCOUNTS = {
-    "cache_max_bytes": "scan_cache",        # HBM windows + stacks (read.py)
+    "cache_max_bytes": "scan_cache",        # windows + stacks (read.py)
     "tier2_max_bytes": "encoded_cache",     # host-RAM encoded parts
     "memo_max_bytes": "parts_memo",         # aggregate-partial memo
     "inflight_bytes": "pipeline_inflight",  # pipeline in-flight budget
