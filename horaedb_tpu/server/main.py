@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import dataclasses
+import json
 import logging
 import math
 import random
@@ -34,6 +35,8 @@ from collections import deque
 from typing import Optional
 
 import numpy as np
+import pyarrow as pa
+import pyarrow.compute as pc
 from aiohttp import web
 
 from horaedb_tpu.common import Error, ensure, now_ms
@@ -98,6 +101,15 @@ _ACTIVE_QUERIES = registry.gauge(
     "server_active_queries", "queries currently executing")
 _QUEUED_QUERIES = registry.gauge(
     "server_queued_queries", "queries waiting for an admission slot")
+_RESPOND_CELLS = registry.counter(
+    "respond_cells_total",
+    "grid cells encoded into downsample responses")
+_RESPOND_BYTES = registry.counter(
+    "respond_bytes_total", "body bytes of downsample responses")
+_RESPOND_ENCODE_SECONDS = registry.counter(
+    "respond_encode_seconds_total",
+    "wall seconds inside the downsample response encoder (the lazy "
+    "download of device grids excluded)")
 
 
 class _ServiceRate:
@@ -1384,9 +1396,8 @@ def build_app(state: ServerState) -> web.Application:
                 with span("respond"):
                     body_out = _downsample_json(out)
                     if impl is not None and out["tsids"]:
-                        body_out["aggs"][fn] = _grid_json(
-                            impl(out["aggs"], bucket_ms))
-                    return web.json_response(
+                        body_out["aggs"][fn] = impl(out["aggs"], bucket_ms)
+                    return _downsample_response(
                         _attach_partial(body_out, meta))
             tbl, meta = await _engine_query(metric, filters, rng, field)
             return web.json_response(_attach_partial({
@@ -1422,7 +1433,7 @@ def build_app(state: ServerState) -> web.Application:
         except Error as e:
             return _error_response(e)
         with span("respond"):
-            return web.json_response(_downsample_json(out))
+            return _downsample_response(_downsample_json(out))
 
     @routes.post("/query_multi")
     async def query_multi(req: web.Request) -> web.Response:
@@ -1449,8 +1460,8 @@ def build_app(state: ServerState) -> web.Application:
         except Error as e:
             return _error_response(e)
         with span("respond"):
-            return web.json_response({f: _downsample_json(out)
-                                      for f, out in outs.items()})
+            return _downsample_response(
+                {f: _downsample_json(out) for f, out in outs.items()})
 
     @routes.post("/query_arrow")
     async def query_arrow(req: web.Request) -> web.Response:
@@ -1641,17 +1652,21 @@ def build_app(state: ServerState) -> web.Application:
     return app
 
 
-def _grid_json(grid) -> list:
-    out = []
-    for row in grid.tolist():
-        out.append([None if isinstance(x, float) and math.isnan(x) else x
-                    for x in row])
-    return out
+class _Grid(np.ndarray):
+    """A response grid: the array of float64 cells that goes out as a
+    list of rows, and truthy as that list would be (non-empty), so that
+    code written against the JSON shape (`if grid and grid[0]`:
+    benchmark/tests/broken_launcher.py) reads it unchanged."""
+
+    def __bool__(self) -> bool:
+        return len(self) > 0
 
 
 def _downsample_json(out: dict) -> dict:
     """THE wire shape of a downsample result, shared by /query,
-    /query_topk and /query_multi so the endpoints cannot drift."""
+    /query_topk and /query_multi so the endpoints cannot drift.  Each
+    grid is a _Grid, the response's own copy of the cells widened to
+    the doubles a client parses; _downsample_response writes them."""
     aggs = out["aggs"]
     # the fused route hands back device grids: their lazy download is
     # here, the sync split from the copy (a scan.d2h span under
@@ -1663,7 +1678,90 @@ def _downsample_json(out: dict) -> dict:
                                               table="data")}
     return {"tsids": [str(t) for t in out["tsids"]],
             "num_buckets": out["num_buckets"],
-            "aggs": {k: _grid_json(v) for k, v in aggs.items()}}
+            "aggs": {k: v.astype(np.float64).view(_Grid)
+                     for k, v in aggs.items()}}
+
+
+# An answer of fewer cells than this is written cell by cell.  The
+# columnar pass gives the GIL up around each of its nine pyarrow calls
+# and, on a server whose pool threads want it, waits to get it back:
+# 0.9 ms of the loop's thread a response at 420 cells against 0.3 ms of
+# per-cell Python (PERF.md §6, PR 33: the point-query cells read 1.5 %
+# slower with it), while a cell costs 0.72 us by cell and 0.18-0.24 us
+# in the pass: the two cross near 1,500 cells.
+_COLUMNAR_MIN_CELLS = 1024
+
+
+def _grids_text(grids: list) -> list[str]:
+    """The JSON text of each 2-D grid (an array of rows).  A cell
+    prints as the shortest decimal that parses back to its double (a
+    float32 cell widened: `50.400001525878906`, never `50.4`), an empty
+    cell (NaN) as `null`, an infinity as json.dumps writes it, a
+    negative zero as `-0.0` (a parser may read `-0` as an integer and
+    drop the sign).  From _COLUMNAR_MIN_CELLS up the cells of all grids
+    are formatted in ONE columnar pass: no Python float, list or repr
+    per cell, pyarrow's kernels run with the GIL released, and an
+    integral value prints without a fraction (`360`); a smaller answer
+    goes through json.dumps (`360.0`).  Both texts parse to the same
+    doubles (tests/test_respond_encode.py runs every case down both)."""
+    if not grids:
+        return []
+    if sum(np.size(g) for g in grids) < _COLUMNAR_MIN_CELLS:
+        return [json.dumps([[None if x != x else x for x in row]
+                            for row in np.asarray(g, np.float64).tolist()])
+                for g in grids]
+    grids = [np.asarray(g, dtype=np.float64) for g in grids]
+    cells = np.concatenate([g.reshape(-1) for g in grids])
+    text = pc.cast(pa.array(cells), pa.string())
+    for mask, word in ((np.isnan(cells), "null"),
+                       (cells == np.inf, "Infinity"),
+                       (cells == -np.inf, "-Infinity"),
+                       ((cells == 0) & np.signbit(cells), "-0.0")):
+        if mask.any():
+            text = pc.if_else(pa.array(mask), word, text)
+    # cells -> rows -> grids: two joins over offsets taken from the
+    # shapes, so grids of different shapes share the pass
+    n_rows = [g.shape[0] for g in grids]
+    for lengths, sep in ((np.repeat([g.shape[1] for g in grids], n_rows),
+                          ", "), (n_rows, "], [")):
+        offsets = np.zeros(len(lengths) + 1, dtype=np.int32)
+        np.cumsum(lengths, out=offsets[1:])
+        text = pc.binary_join(
+            pa.ListArray.from_arrays(pa.array(offsets), text), sep)
+    return [f"[[{rows}]]" if g.shape[0] else "[]"
+            for g, rows in zip(grids, text.to_pylist())]
+
+
+def _grids_of(node) -> list:
+    """The grids of a response body, in the order they are written."""
+    if isinstance(node, dict):
+        return [g for v in node.values() for g in _grids_of(v)]
+    return [node] if isinstance(node, np.ndarray) else []
+
+
+def _json_text(node, grid_texts) -> str:
+    """json.dumps' text of `node`, each grid taken from `grid_texts`."""
+    if isinstance(node, dict):
+        return "{%s}" % ", ".join(
+            f"{json.dumps(k)}: {_json_text(v, grid_texts)}"
+            for k, v in node.items())
+    if isinstance(node, np.ndarray):
+        return next(grid_texts)
+    return json.dumps(node)
+
+
+def _downsample_response(body: dict) -> web.Response:
+    """The /query* response of a _downsample_json body (or of
+    /query_multi's {field: body}): json.dumps' text for everything but
+    the grids, which _grids_text writes in one pass over all of them."""
+    t0 = time.perf_counter()
+    grids = _grids_of(body)
+    payload = _json_text(body, iter(_grids_text(grids))).encode()
+    _RESPOND_CELLS.inc(sum(g.size for g in grids))
+    _RESPOND_BYTES.inc(len(payload))
+    _RESPOND_ENCODE_SECONDS.inc(time.perf_counter() - t0)
+    return web.Response(body=payload, content_type="application/json",
+                        charset="utf-8")
 
 
 def _build_store(config: ServerConfig):
