@@ -26,6 +26,7 @@ path and can layer on later without changing this API.
 from __future__ import annotations
 
 import asyncio
+import time
 from typing import Optional
 
 import pyarrow as pa
@@ -432,6 +433,20 @@ class SampleManager:
             await self.table.write(WriteRequest(
                 batch, TimeRange.new(lo, hi + 1)))
 
+
+# moved once a /query_multi request, never per row or cell: a span costs
+# 24 us on a GIL-bound server (ROADMAP C8), so the single-field path
+# that the point queries share gets none of this
+_MULTI_QUERIES = registry.counter(
+    "query_multi_total", "multi-field downsample queries")
+_MULTI_FIELDS = registry.counter(
+    "query_multi_fields_total",
+    "fields scanned by multi-field downsample queries (one pushdown "
+    "scan each; fields a rollup tier served are not among them)")
+_MULTI_SCAN_SECONDS = registry.counter(
+    "query_multi_scan_seconds_total",
+    "wall seconds inside the per-field scans of multi-field downsample "
+    "queries")
 
 _CHUNK_CACHE_HITS = registry.counter(
     "chunk_decode_cache_hits_total",
@@ -1323,12 +1338,19 @@ class MetricEngine:
         field's rows — N fields cost one pass over the union, not N
         (bench config 3 reports this as the redundancy factor).  A
         shared-window variant (push In(field_id, all) once, mask each
-        field post-merge) was measured 4.6x SLOWER on the host path:
-        with device-layout sidecars the leaf-filtered load is cheap,
+        field post-merge) was measured 4.6x SLOWER: a host-path run on
+        the CPU rung (bench/suite.py config 3), not a chip number.
+        With device-layout sidecars the leaf-filtered load is cheap,
         while N masked aggregations over the UNION of rows cost N full
-        passes.
+        passes.  What the chip makes of this path is read in the
+        benchmark's cell `s1000_double_groupby_all` (PERF.md).
+
+        Traced like query_downsample, as children of the request's
+        root: one `resolve` span around the shared resolve and one
+        `downsample` span a field scanned (`field=` names it).
         """
         ensure(len(fields) > 0, "fields must be non-empty")
+        _MULTI_QUERIES.inc()
         if self.chunked_data:
             return {f: await self.query_downsample(
                 metric, filters, time_range, bucket_ms, field=f, aggs=aggs)
@@ -1343,7 +1365,8 @@ class MetricEngine:
         if covered:
             # per-field routing with ONE shared resolve: covered fields
             # read their rollup tier, the rest reuse (mid, tsids) below
-            with span("rollup_plan", metric=metric, bucket_ms=bucket_ms):
+            with span("resolve", metric=metric), \
+                    span("rollup_plan", metric=metric, bucket_ms=bucket_ms):
                 mid = await self.metric_manager.resolve(metric,
                                                         time_range)
                 tsids = (None if mid is None else
@@ -1364,13 +1387,16 @@ class MetricEngine:
                 return out
         parts = None
         if resolved is None:
-            parts = await self._data_pred_parts(metric, filters,
-                                                time_range,
-                                                ts_leaf=not aligned)
+            with span("resolve", metric=metric):
+                parts = await self._data_pred_parts(metric, filters,
+                                                    time_range,
+                                                    ts_leaf=not aligned)
         # deliberately SEQUENTIAL: each scan already pipelines its own
         # IO against pool work, and gathering all fields was measured
-        # 2x slower (config 3's redundancy factor 1.4x -> 2.7x) — ten
-        # interleaved merges thrash the worker pool and caches
+        # 2x slower (config 3's redundancy factor 1.4x -> 2.7x; a
+        # host-path run on the CPU rung, as above) — ten interleaved
+        # merges thrash the worker pool and caches
+        t0 = time.perf_counter()
         for f in remaining:
             if resolved is not None:
                 pred = self._pred_from_resolved(resolved, f, time_range,
@@ -1379,9 +1405,12 @@ class MetricEngine:
                 pred = (None if parts is None else
                         And([parts[0], Eq("field_id", field_id_of(f))]
                             + parts[1:]))
-            out[f] = await self._scan_downsample(pred, time_range,
-                                                 bucket_ms, num_buckets,
-                                                 aggs)
+            with span("downsample", metric=metric, bucket_ms=bucket_ms,
+                      field=f):
+                out[f] = await self._scan_downsample(
+                    pred, time_range, bucket_ms, num_buckets, aggs)
+        _MULTI_FIELDS.inc(len(remaining))
+        _MULTI_SCAN_SECONDS.inc(time.perf_counter() - t0)
         return out
 
     async def _downsample_chunked(self, metric: str, filters, time_range,

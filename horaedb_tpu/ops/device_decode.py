@@ -430,6 +430,19 @@ def _narrow_to_key_leaves(es, prog: tuple, consts: tuple, pad_capacity):
     return es.keep_rows(mask)
 
 
+def _own_dictionary(enc):
+    """`enc` with a dictionary that owns its memory.  A sidecar load
+    hands dictionaries out as views of the fetched object's bytes
+    (storage/sidecar.py), and a view of a thousand ids keeps the whole
+    object alive for as long as anything holds it: a resident slice of
+    a 173 MB segment would pin 173 MB of host memory that no account
+    sees (ten fields' twelve slices at TSBS scale 1000: 20.8 GB)."""
+    d = enc.dictionary
+    if d is None or d.base is None:
+        return enc
+    return dataclasses.replace(enc, dictionary=d.copy())
+
+
 def _lex_sorted_np(keys: list) -> bool:
     """Host twin of read._is_lex_sorted over unpadded encoded columns:
     one vectorized compare pass decides whether the device program can
@@ -965,12 +978,16 @@ def plan_segment(es, group_col: str, ts_col: str, value_col: str,
             run_offsets[len(rl)] = es.n  # real runs end at n
     g = len(g_enc.dictionary)
     cells_sorted = _cells_sorted(es, route, pk_names, group_col, ts_col)
+    # the slice outlives the segment (resident in the scan cache; its
+    # group values in every part a dispatch returns), so it owns the
+    # dictionaries it keeps
+    encodings = {nm: _own_dictionary(encs[nm]) for nm in upload_names}
     return SegmentSlice(
         es=es, src_rows=src_rows, n=es.n, cap=cap, admissible=admissible,
-        encodings={nm: encs[nm] for nm in upload_names},
+        encodings=encodings,
         ts_epoch=int(ts_enc.epoch), local_ok=ts_enc.kind == "offset",
         g=g, g_pad=max(8, 1 << (g - 1).bit_length()),
-        values=g_enc.dictionary, upload_names=upload_names,
+        values=encodings[group_col].dictionary, upload_names=upload_names,
         key_slots=tuple(slot_of[nm] for nm in key_names),
         num_pks=len(pk_names),
         # group/ts positions INSIDE the sorted key outputs

@@ -755,6 +755,8 @@ class TestGatherStaleOwner:
     def test_resolver_failure_still_partial(self):
         async def go():
             c = await self._seed_cluster()()
+            # repoint_region leaves the old backend to its caller
+            real = c.regions[1]
             try:
                 rng = TimeRange.new(T0, T0 + HOUR)
                 c.repoint_region(1, _StaleBackend(1))
@@ -767,6 +769,7 @@ class TestGatherStaleOwner:
                 assert meta.partial and meta.missing_regions == [1]
             finally:
                 await c.close()
+                await real.close()
 
         run(go())
 
@@ -1662,6 +1665,8 @@ class TestLeaseRouting:
         async def go():
             store = MemoryObjectStore()
             c = await self._seeded(store)
+            # repoint_region leaves the old backend to its caller
+            real = c.regions[1]
             try:
                 rng = TimeRange.new(T0, T0 + HOUR)
                 c.repoint_region(1, _StaleBackend(1))
@@ -1674,6 +1679,7 @@ class TestLeaseRouting:
                 assert meta.partial and meta.missing_regions == [1]
             finally:
                 await c.close()
+                await real.close()
 
         run(go())
 
