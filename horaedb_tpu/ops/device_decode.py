@@ -74,6 +74,7 @@ silently-ineligible plan is visible instead of quietly slow
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from dataclasses import dataclass
 from typing import Optional
@@ -192,6 +193,25 @@ _RESIDENT = {
     ).labels(outcome=outcome)
     for outcome in ("hit", "miss", "bypass")
 }
+
+
+# how often the batch engages: every slice dispatched, by whether it
+# shared its call
+_BATCH_SLICES = {
+    mode: registry.counter(
+        "scan_decode_batch_slices_total",
+        "slices of device-decode plans by how they reached the device: "
+        "batched = inside one program call with the plan's other "
+        "resident slices (execute_batch); single = by a call of their "
+        "own (execute_plan: a miss, a group of one, a mesh round's "
+        "declined plan)"
+    ).labels(mode=mode)
+    for mode in ("batched", "single")
+}
+_BATCH_CALLS = registry.counter(
+    "scan_decode_batch_total",
+    "calls of the batched fused decode program (two or more resident "
+    "slices of one plan a call)")
 
 
 def note_resident(outcome: str, n: int = 1) -> None:
@@ -617,10 +637,13 @@ def decode_partials(cols: tuple, n_valid, leaf_consts: tuple,
     return grids, n_rows
 
 
-@deviceprof.jit(static_argnames=(
+_PROGRAM_STATICS = (
     "key_slots", "num_pks", "group_pos", "ts_pos", "val_slot",
     "leaf_prog", "g_pad", "width", "which", "route",
-    "num_runs", "cells_sorted"))
+    "num_runs", "cells_sorted")
+
+
+@deviceprof.jit(static_argnames=_PROGRAM_STATICS)
 def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
                           shift, lo, total, bucket_ms, run_offsets,
                           **static):
@@ -646,6 +669,66 @@ def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
     Returns ({partial grids}, kept_rows)."""
     return decode_partials(cols, n_valid, leaf_consts, run_offsets,
                            shift, lo, total, bucket_ms, **static)
+
+
+_LANES = 128  # a tile's width on the device; every capacity's divisor
+
+
+@deviceprof.jit(name="_decode_aggregate_jit",
+                static_argnames=_PROGRAM_STATICS)
+def _decode_batch_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
+                      nums, **static):
+    """The fused dispatch for several resident slices of one plan in
+    ONE call: decode_partials, the same traced body, once a slice
+    under a loop, so that the body is compiled once whatever the
+    number of slices.  Booked under `_decode_aggregate_jit`'s ledger
+    name: the route's program, another number of slices a call.
+
+    `cols`, `key_consts` and `run_offsets` hold one entry a slice, as
+    SegmentSlice keeps them on the device (stacked here, inside the
+    program: the one extra pass over the rows); `nums` is the call's
+    one host array, int32 [1 + slices, 3 + window constants]: row 0 is
+    (live slices, total buckets, bucket_ms), row 1 + i slice i's
+    (rows, shift, first bucket) and the constants of its leaves that
+    are not key leaves, in leaf order.  The loop's trip count is the
+    traced number of LIVE slices, so the filler that rounds the slices
+    up to a power of two (execute_batch) is stacked and never run.
+    Returns ({grid: [slices, g_pad, width]}, kept_rows[slices])."""
+    leaf_prog = static["leaf_prog"]
+    # a column is stacked as [slices, cap / 128, 128]: a slice of the
+    # stack is then whole tiles, cut out as it lies; as [slices, cap]
+    # eight slices share every tile and each cut reads them all
+    cap = cols[0][0].shape[0]
+    stacked = tuple(jnp.stack([x.reshape(-1, _LANES) for x in c])
+                    for c in zip(*cols))
+    keyed_stacked = tuple(jnp.stack(c) for c in zip(*key_consts))
+    offs = jnp.stack(run_offsets)
+    live, total, bucket_ms = nums[0, 0], nums[0, 1], nums[0, 2]
+
+    def one(i):
+        row = nums[1 + i]
+        keyed = iter(keyed_stacked)
+        consts, at = [], 3
+        for _slot, op in leaf_prog:
+            if op in (_OP_EQ, _OP_IN):
+                consts.append(next(keyed)[i])
+            else:  # compile_leaves: a range is two numbers, an edge one
+                width = 2 if op == _OP_RANGE else 1
+                consts.append(row[at:at + width])
+                at += width
+        return decode_partials(
+            tuple(c[i].reshape(cap) for c in stacked), row[0],
+            tuple(consts), offs[i],
+            row[1], row[2], total, bucket_ms, **static)
+
+    def step(i, acc):
+        return jax.tree_util.tree_map(lambda a, o: a.at[i].set(o),
+                                      acc, one(i))
+
+    acc = jax.tree_util.tree_map(
+        lambda o: jnp.zeros((len(cols),) + o.shape, o.dtype),
+        jax.eval_shape(one, jnp.int32(0)))
+    return jax.lax.fori_loop(0, live, step, acc)
 
 
 # ---------------------------------------------------------------------------
@@ -699,7 +782,6 @@ class DecodeDispatch:
 
     def finalize(self) -> DevicePart:
         t0 = time.perf_counter()
-        g = len(self.values)
         # the sync, then the copy, each charged to its own phase: the
         # wait holds the device's queue and the program's execution
         # (the jit call returned immediately); the full (g_pad, width)
@@ -707,30 +789,70 @@ class DecodeDispatch:
         # counts what moved, not what was kept
         host = deviceprof.download(self.outs, fn="_decode_aggregate_jit",
                                    table=self.table)
-        # mirror _flush_window_batch's emission exactly: slice to the
-        # real group count and the query-clipped width, then re-base
-        # window-local last_ts to range_start-relative int64.  The
-        # slices COPY (ascontiguousarray): a view would pin the full
-        # (g_pad, width) download while nbytes counted only the slice
-        # — the PartsMemo views-pin-bases defect, not repeated here
-        grids = {k: np.ascontiguousarray(v[:g, :self.w_eff])
-                 for k, v in host.items()}
-        if "last_ts" in grids:
-            lt = grids["last_ts"].astype(np.int64)
-            grids["last_ts"] = np.where(
-                grids["count"] > 0, lt + self.lo * self.bucket_ms, lt)
-        n_rows = int(self.n_rows)
-        nbytes = sum(int(a.nbytes) for a in grids.values())
-        part = DevicePart(
-            part=(self.values, self.lo, grids), n_valid=n_rows,
-            nbytes=nbytes,
-            resident=None if self.slice is None
+        part = _finished_part(
+            host, self.n_rows, self.values, self.lo, self.w_eff,
+            self.bucket_ms, resident=None if self.slice is None
             else self.slice.resident())
         observe_decode_stage(self.t_dispatch
                              + (time.perf_counter() - t0),
                              rows=self.src_rows,
                              nbytes=self.upload_bytes)
         return part
+
+
+def _finished_part(host: dict, n_rows, values, lo: int, w_eff: int,
+                   bucket_ms: int, resident=None) -> DevicePart:
+    """One slice's downloaded (g_pad, width) grids as the part the
+    host fold takes.  Mirrors _flush_window_batch's emission exactly:
+    slice to the real group count and the query-clipped width, then
+    re-base window-local last_ts to range_start-relative int64.  The
+    slices COPY: a view would pin the full download (a batch's: every
+    slice's grids, and a slice's leading rows are contiguous as they
+    lie) while nbytes counted only the slice — the PartsMemo
+    views-pin-bases defect, not repeated here."""
+    g = len(values)
+    grids = {k: np.array(v[:g, :w_eff], order="C")
+             for k, v in host.items()}
+    if "last_ts" in grids:
+        lt = grids["last_ts"].astype(np.int64)
+        grids["last_ts"] = np.where(
+            grids["count"] > 0, lt + lo * bucket_ms, lt)
+    return DevicePart(
+        part=(values, lo, grids), n_valid=int(n_rows),
+        nbytes=sum(int(a.nbytes) for a in grids.values()),
+        resident=resident)
+
+
+class BatchDispatch:
+    """Several resident slices' in-flight fused dispatch (execute_batch):
+    one program call issued, one download to come.  finalize() gives
+    one DevicePart a plan, in the plans' order, each what its own
+    DecodeDispatch would have given."""
+
+    __slots__ = ("outs", "n_rows", "plans", "t_dispatch", "table")
+
+    def __init__(self, outs, n_rows, plans, t_dispatch, table=""):
+        self.outs = outs
+        self.n_rows = n_rows
+        self.plans = plans
+        self.t_dispatch = t_dispatch
+        self.table = table
+
+    def finalize(self) -> list:
+        t0 = time.perf_counter()
+        host, n_rows = deviceprof.download(
+            (self.outs, self.n_rows), fn="_decode_aggregate_jit",
+            table=self.table)
+        parts = [
+            _finished_part({k: v[i] for k, v in host.items()}, n_rows[i],
+                           dp.values, dp.lo, dp.w_eff, dp.bucket_ms)
+            for i, dp in enumerate(self.plans)]
+        # the stage twin, once a batch, with the batch's rows
+        observe_decode_stage(self.t_dispatch
+                             + (time.perf_counter() - t0),
+                             rows=sum(dp.src_rows for dp in self.plans),
+                             nbytes=0)
+        return parts
 
 
 # stage attribution twins ride the same labeled families as every other
@@ -856,6 +978,23 @@ class DecodePlan:
                 tuple(len(c) for c in self.consts), self.route,
                 self.num_runs, self.cells_sorted, self.local_ok,
                 len(self.upload_names), self.which)
+
+    def statics(self) -> dict:
+        """The static arguments of the fused program, single or
+        batched (_PROGRAM_STATICS), for this plan."""
+        return dict(
+            key_slots=self.key_slots, num_pks=self.num_pks,
+            group_pos=self.group_pos, ts_pos=self.ts_pos,
+            val_slot=self.val_slot, leaf_prog=self.leaf_prog,
+            g_pad=self.g_pad, width=self.use_width, which=self.which,
+            route=self.route, num_runs=self.num_runs,
+            cells_sorted=self.cells_sorted)
+
+    def batch_key(self) -> tuple:
+        """Everything that must match for two plans of one query to
+        share one call of the batched program (execute_batch): the
+        static arguments, the rows' capacity and the grids' shape."""
+        return (self.static_key(), self.cap, self.g_pad, self.use_width)
 
 
 def _is_key_leaf(leaf) -> bool:
@@ -1103,16 +1242,12 @@ def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
         next(keyed) if op in (_OP_EQ, _OP_IN) else jnp.asarray(c)
         for (_slot, op), c in zip(dp.leaf_prog, dp.consts))
 
+    _BATCH_SLICES["single"].inc()
     outs, n_rows = _decode_aggregate_jit(
         seg.cols_dev, seg.n, consts_dev,
         np.int32(dp.shift), np.int32(dp.lo),
         np.int32(dp.num_buckets), np.int32(dp.bucket_ms), seg.offs_dev,
-        key_slots=seg.key_slots, num_pks=seg.num_pks,
-        group_pos=seg.group_pos, ts_pos=seg.ts_pos,
-        val_slot=seg.val_slot, leaf_prog=dp.leaf_prog,
-        g_pad=seg.g_pad, width=dp.use_width, which=dp.which,
-        route=seg.route, num_runs=seg.num_runs,
-        cells_sorted=seg.cells_sorted)
+        **dp.statics())
     return DecodeDispatch(outs=outs, n_rows=n_rows,
                           values=seg.values, lo=dp.lo, w_eff=dp.w_eff,
                           bucket_ms=dp.bucket_ms,
@@ -1121,3 +1256,111 @@ def execute_plan(dp: DecodePlan, table: str = "") -> DecodeDispatch:
                           src_rows=seg.src_rows, table=table,
                           slice=seg if fresh and seg.admissible
                           else None)
+
+
+# the stacked columns of a batched call are a temporary that no cache
+# account is charged for: a call stacks at most this many bytes
+_BATCH_MAX_STACK_BYTES = 512 << 20
+
+# the batched programs some call has compiled, as the numbers of
+# slices each batch_key() has one for, and the lock a program's first
+# call takes: clients that reach a new program together (a server's
+# first queries) compile it once, not once each
+_BATCH_COMPILED: dict = {}
+_BATCH_COMPILE_LOCK = threading.Lock()
+
+
+def _compiled_slots(dp: "DecodePlan", n: int) -> Optional[int]:
+    """The least number of slices, `n` or more and within the stack's
+    budget, that a compiled batched program of dp's batch_key() takes;
+    None where no call has compiled one."""
+    return min((slots for slots in _BATCH_COMPILED.get(dp.batch_key(), ())
+                if n <= slots
+                and slots * dp.seg.nbytes <= _BATCH_MAX_STACK_BYTES),
+               default=None)
+
+
+def execute_batch(plans: list, table: str = "") -> BatchDispatch:
+    """Issue ONE call of the batched program for two or more plans of
+    equal batch_key() whose slices are resident: the slices' device
+    arrays as they lie, and one host array of the window's numbers.
+    The number of slices is part of the program's shape, so it is
+    rounded up to a power of two as rows are to their capacity (six
+    and seven segments share a program); the filler repeats the first
+    slice and is never run.  A batch that a compiled program already
+    holds takes that one, its further slots filler too, before it
+    mints a smaller one of its own: a query that the parts memo served
+    some segments of brings fewer slices than its neighbours, and a
+    compile (45 s at 1,048,576 rows) costs more than the filler's
+    stack ever will."""
+    t0 = time.perf_counter()
+    first = plans[0]
+    slots = _compiled_slots(first, len(plans))
+    if slots is not None:
+        outs, n_rows = _call_batch(plans, slots)
+    else:
+        with _BATCH_COMPILE_LOCK:
+            # whoever held the lock may have compiled one that fits
+            slots = (_compiled_slots(first, len(plans))
+                     or 1 << (len(plans) - 1).bit_length())
+            outs, n_rows = _call_batch(plans, slots)
+            key = first.batch_key()
+            _BATCH_COMPILED[key] = tuple(
+                {*_BATCH_COMPILED.get(key, ()), slots})
+    _BATCH_CALLS.inc()
+    _BATCH_SLICES["batched"].inc(len(plans))
+    return BatchDispatch(outs=outs, n_rows=n_rows, plans=plans,
+                         t_dispatch=time.perf_counter() - t0, table=table)
+
+
+def _call_batch(plans: list, slots: int):
+    """One call of the `slots`-slice batched program over `plans`."""
+    first = plans[0]
+    rows = [np.concatenate(
+        [np.asarray([dp.n, dp.shift, dp.lo], dtype=np.int32),
+         *(c for (_slot, op), c in zip(dp.leaf_prog, dp.consts)
+           if op not in (_OP_EQ, _OP_IN))]) for dp in plans]
+    nums = np.zeros((1 + slots, len(rows[0])), dtype=np.int32)
+    nums[0, :3] = len(plans), first.num_buckets, first.bucket_ms
+    nums[1:1 + len(rows)] = rows
+    segs = [dp.seg for dp in plans] + [first.seg] * (slots - len(plans))
+    return _decode_batch_jit(
+        tuple(seg.cols_dev for seg in segs),
+        tuple(seg.key_consts_dev for seg in segs),
+        tuple(seg.offs_dev for seg in segs), nums, **first.statics())
+
+
+def dispatch_resident(plans: list, table: str = "") -> list:
+    """Issue every plan of a query whose slice is resident: those that
+    may share a program (equal batch_key()) as one batched call a
+    group, cut where a call's stacked columns would pass
+    _BATCH_MAX_STACK_BYTES, and what is left over (a group of one) by
+    execute_plan.  Returns [(positions in `plans`, dispatch)], all in
+    flight, for finalize_resident."""
+    groups: dict = {}
+    for pos, dp in enumerate(plans):
+        groups.setdefault(dp.batch_key(), []).append(pos)
+    issued = []
+    for group in groups.values():
+        room = _BATCH_MAX_STACK_BYTES // plans[group[0]].seg.nbytes
+        per_call = 1 << (room.bit_length() - 1) if room >= 2 else 1
+        for at in range(0, len(group), per_call):
+            call = group[at:at + per_call]
+            if len(call) > 1:
+                issued.append((call, execute_batch(
+                    [plans[p] for p in call], table)))
+            else:
+                issued.append((call, execute_plan(plans[call[0]], table)))
+    return issued
+
+
+def finalize_resident(issued: list) -> list:
+    """Download and shape what dispatch_resident issued: one DevicePart
+    a plan, in the plans' order."""
+    parts: dict = {}
+    for positions, dispatch in issued:
+        if isinstance(dispatch, BatchDispatch):
+            parts.update(zip(positions, dispatch.finalize()))
+        else:
+            parts[positions[0]] = dispatch.finalize()
+    return [parts[pos] for pos in range(len(parts))]

@@ -906,15 +906,29 @@ class ParquetReader:
             probe.fields["cached"] = len(cached)
             if slice_columns is not None:
                 probe.fields["resident"] = len(resident)
+        # the resident slices go to the device as ONE pool job, and
+        # their parts into `cached`.  Beside segments to read the job
+        # is a task that the first resident segment's turn awaits, so
+        # that device work still overlaps the reads
+        resident_job: Optional[asyncio.Future] = None
+        if resident:
+            deadline_checkpoint()
+            job = self._resident_batch_windows(plan, resident)
+            if to_read:
+                resident_job = asyncio.ensure_future(job)
+                cached.update(dict.fromkeys(resident, resident_job))
+            else:
+                cached.update(await job)
         if self.pipeline_on() and self._pipeline_has_io(plan, to_read):
             plan.pipeline_active = True
             pipe_iter = self._cached_windows_pipelined(
-                plan, cached, to_read, resident, slice_columns)
+                plan, cached, to_read, slice_columns)
             try:
                 async for out in pipe_iter:
                     yield out
             finally:
                 await pipe_iter.aclose()
+                await self._abandon(resident_job)
             return
 
         # the shared _segment_feed owns the streamed/bulk split and the
@@ -928,27 +942,14 @@ class ParquetReader:
         feed = self._segment_feed(plan, to_read).__aiter__()
         pending: "deque[tuple[SegmentPlan, str, list, float]]" = deque()
         exhausted = False
-        # what the pump works through, in plan order: the segments to
-        # read (the feed's order) and, between them, the resident ones
-        work = iter([seg for seg in plan.segments
-                     if id(seg) not in cached])
 
         async def pump() -> None:
             nonlocal exhausted
-            wseg = next(work, None)
-            if wseg is None:
+            try:
+                fseg, is_streamed, table, read_s = await feed.__anext__()
+            except StopAsyncIteration:
                 exhausted = True
                 return
-            if id(wseg) in resident:
-                # nothing to read: one pool job issues the program on
-                # the resident arrays, ahead of the yield position like
-                # any other dispatch
-                pending.append((wseg, "bulk", await self._run_pool(
-                    plan.pool, self._dispatch_resident_slice,
-                    resident[id(wseg)]), 0.0))
-                return
-            fseg, is_streamed, table, read_s = await feed.__anext__()
-            assert fseg is wseg
             if is_streamed:
                 # a marker only: the actual streaming happens when this
                 # segment reaches the yield position
@@ -967,7 +968,7 @@ class ParquetReader:
                 # instead of finishing a doomed scan
                 deadline_checkpoint()
                 if id(seg) in cached:
-                    yield seg, cached[id(seg)], 0.0
+                    yield seg, await self._cached_entry(cached, seg), 0.0
                     continue
                 while len(pending) <= self._MERGE_LOOKAHEAD and not exhausted:
                     await pump()
@@ -985,6 +986,24 @@ class ParquetReader:
                 yield seg, windows, read_s
         finally:
             await feed.aclose()
+            await self._abandon(resident_job)
+
+    @staticmethod
+    async def _abandon(job: Optional[asyncio.Future]) -> None:
+        """A scan that ends, finished or abandoned, leaves no task
+        behind it."""
+        if job is not None:
+            job.cancel()
+            await asyncio.gather(job, return_exceptions=True)
+
+    @staticmethod
+    async def _cached_entry(cached: dict, seg: SegmentPlan) -> list:
+        """A cached segment's windows; for one whose resident slice
+        went out with the plan's batch, that job's part of it."""
+        windows = cached[id(seg)]
+        if isinstance(windows, asyncio.Future):
+            windows = (await windows)[id(seg)]
+        return windows
 
     # ---- device-decode slices in the scan cache ----------------------------
 
@@ -1034,16 +1053,24 @@ class ParquetReader:
         device_decode.note_resident("miss" if got is None else "hit")
         return got
 
-    def _dispatch_resident_slice(self, dp: "device_decode.DecodePlan"
-                                 ) -> list:
-        """Pool-side dispatch of a hit, as _dispatch_device_decode
-        leaves a miss: one in-flight fused dispatch."""
-        with self._phase("scan.dispatch", h2d_bytes=0):
-            return [device_decode.execute_plan(dp, self.table)]
+    async def _resident_batch_windows(self, plan: ScanPlan,
+                                      resident: dict) -> dict:
+        """The plan's resident slices through ONE pool job: their
+        windows lists, by segment as `resident` is."""
+        parts = await self._run_pool(
+            plan.pool, self._resident_batch_parts,
+            list(resident.values()))
+        return {key: [part] for key, part in zip(resident, parts)}
 
-    def _resident_slice_windows(self, dp: "device_decode.DecodePlan"
-                                ) -> list:
-        return self._finalize_windows(self._dispatch_resident_slice(dp))
+    def _resident_batch_parts(self, plans: list) -> list:
+        """Pool-side: every hit of a plan dispatched (one `scan.dispatch`
+        phase: a batched call a group of slices that may share a
+        program, a call of its own for one left over), then downloaded
+        and shaped, one DevicePart a plan."""
+        with self._phase("scan.dispatch", h2d_bytes=0,
+                         slices=len(plans)):
+            issued = device_decode.dispatch_resident(plans, self.table)
+        return device_decode.finalize_resident(issued)
 
     def _admit_decode_slice(self, seg: SegmentPlan,
                             slice_columns: Optional[tuple],
@@ -1100,7 +1127,6 @@ class ParquetReader:
 
     async def _cached_windows_pipelined(self, plan: ScanPlan,
                                         cached: dict, to_read: list,
-                                        resident: dict,
                                         slice_columns: Optional[tuple]):
         """Pipelined twin of the pump below: fetch and decode/merge run
         as background stages (storage/pipeline.py) while this consumer
@@ -1117,15 +1143,7 @@ class ParquetReader:
                 # same position as the pump's
                 deadline_checkpoint()
                 if id(seg) in cached:
-                    yield seg, cached[id(seg)], 0.0
-                    continue
-                if id(seg) in resident:
-                    # a device-decode slice resident on the device:
-                    # dispatch and finalize in one pool job (the
-                    # stages have nothing to fetch or decode for it)
-                    yield seg, await self._run_pool(
-                        plan.pool, self._resident_slice_windows,
-                        resident[id(seg)]), 0.0
+                    yield seg, await self._cached_entry(cached, seg), 0.0
                     continue
                 got, windows, read_s = await pipe.next_segment()
                 assert got is seg

@@ -549,6 +549,12 @@ def moved(before: dict, after: dict) -> dict:
     return {k: after[k] - before[k] for k in after}
 
 
+def batch_counts() -> dict:
+    """Slices by how they reached the device, and batched calls."""
+    return {**{m: c.value for m, c in device_decode._BATCH_SLICES.items()},
+            "calls": device_decode._BATCH_CALLS.value}
+
+
 def compiles_so_far() -> int:
     """What `device.compiles_in_window` reads: the compile ledger."""
     return sum(f["compiles"] for f in deviceprof.profiler.snapshot()["fns"])
@@ -1073,8 +1079,8 @@ def test_slices_over_the_windows_budget_stay_on_a_device_that_holds_them(
     """Three segments' slices are three times the windows budget (which
     holds none of them) and fit what the device's share allows: the
     second query dispatches all three from the device, with no store
-    call, tier-2 probe, upload or compile, and both answers are
-    numpy's."""
+    call, tier-2 probe or upload, in one call of the batched program,
+    the third with no compile either, and all answers are numpy's."""
     report_device_limit(monkeypatch, limit_for_slices(3))
 
     async def go():
@@ -1099,13 +1105,19 @@ def test_slices_over_the_windows_budget_stay_on_a_device_that_holds_them(
                 assert (acct["windows"]["bytes"],
                         acct["windows"]["entries"]) == (0, 0)
                 reads, probes = store.reads, tier2.hits + tier2.misses
-                compiles = compiles_so_far()
                 h2d = deviceprof.profiler.snapshot()["transfer"]["h2d"]
+                b0 = batch_counts()
                 again = await s.scan_aggregate(*field_query(lo, hi))
+                # three hits of one plan share ONE call of the batched
+                # program (four slices' shape), compiled by the first
+                # query that finds them together and by none after it
+                compiles = compiles_so_far()
                 other = await s.scan_aggregate(
                     *field_query(lo + 300_000, hi - 300_000))
                 assert moved(c1, resident_outcomes()) \
                     == {"hit": 6, "miss": 0, "bypass": 0}
+                assert moved(b0, batch_counts()) \
+                    == {"batched": 6, "single": 0, "calls": 2}
                 assert store.reads == reads
                 assert tier2.hits + tier2.misses == probes
                 assert deviceprof.profiler.snapshot()["transfer"]["h2d"] \

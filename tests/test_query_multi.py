@@ -275,6 +275,12 @@ def decode_dispatches() -> int:
                if f["fn"] == "_decode_aggregate_jit")
 
 
+def batch_counts() -> dict:
+    """Slices by how they reached the device, and batched calls."""
+    return {**{m: c.value for m, c in device_decode._BATCH_SLICES.items()},
+            "calls": device_decode._BATCH_CALLS.value}
+
+
 def multi_counters() -> dict:
     return {n: registry.counter(n).value
             for n in ("query_multi_total", "query_multi_fields_total",
@@ -291,14 +297,21 @@ def multi_counters() -> dict:
 def test_every_grid_of_every_field_is_the_references(served, window):
     """A start off the bucket grid, a window over both segment edges,
     a host silent for whole buckets: all seven grids of all ten fields,
-    each field from a fused decode dispatch a segment."""
-    n0, c0 = decode_dispatches(), resident_outcomes()
+    each field's every segment through the fused decode program: a
+    slice that missed by a call of its own, a field's resident slices
+    in ONE call (whichever window runs first misses, the other
+    hits)."""
+    n0, c0, b0 = decode_dispatches(), resident_outcomes(), batch_counts()
     body = served.run(served.multi(*window))
     served.check(body, window, "served")
     probed = moved(c0, resident_outcomes())
-    assert probed["hit"] + probed["miss"] == len(FIELDS) * SEGMENTS
+    slices = len(FIELDS) * SEGMENTS
     assert probed["bypass"] == 0
-    assert decode_dispatches() - n0 == len(FIELDS) * SEGMENTS
+    assert (probed["hit"], probed["miss"]) in ((0, slices), (slices, 0))
+    sent = moved(b0, batch_counts())
+    assert sent == {"single": probed["miss"], "batched": probed["hit"],
+                    "calls": len(FIELDS) if probed["hit"] else 0}
+    assert decode_dispatches() - n0 == sent["single"] + sent["calls"]
     # the silent host's empty cells are in the answer, not left out
     counts = np.array(body[FIELDS[0]]["aggs"]["count"])
     assert (counts == 0).any() and (counts == HOUR // TICK_MS).any()

@@ -48,7 +48,7 @@ ROUTES = {
     # no slice of it)
     "device_decode": (DECODE_ENV, set(SCAN_PHASES), "device_decode"),
     # every segment's narrowed slice resident on the device: nothing
-    # to narrow and nothing to group, one dispatch a segment
+    # to narrow and nothing to group, one dispatch for them all
     "device_decode_hit": (DECODE_ENV,
                           set(SCAN_PHASES) - {"scan.group_prep"},
                           "device_decode"),
@@ -81,7 +81,8 @@ def sync_seams(fn: str) -> int:
 
 def assert_sync_seam_per_dispatch(seams: int, dispatches, waits):
     """The device-decode route passes `deviceprof.download`'s sync seam
-    once per dispatch.  By that seam's contract a dispatch still
+    once per dispatch (a batch of resident slices is one dispatch and
+    one download).  By that seam's contract a dispatch still
     running at its finalize leaves a `scan.device_wait` span and one
     that has finished (XLA-CPU over a few hundred rows) an observation
     of 0 (in device_exec_seconds and in the phase's histogram) and no
@@ -172,12 +173,21 @@ class TestPhaseSpans:
                 seams, dispatches,
                 [c for c in phases if c["name"] == "scan.device_wait"])
             assert got_phases | {"scan.device_wait"} == want_phases
-            # one dispatch a segment, from the device or after an
-            # upload: a hit's carries no bytes
+            # a miss is a dispatch of its own, after its upload; the
+            # plan's hits go out together: ONE dispatch phase that says
+            # how many slices it took, carries no bytes, and is synced
+            # and downloaded once
             hit = route == "device_decode_hit"
-            assert probes == {"hit": len(dispatches) if hit else 0,
-                              "miss": 0 if hit else len(dispatches),
-                              "bypass": 0}
+            if hit:
+                batch, = dispatches
+                assert batch["fields"]["slices"] >= 2
+                assert probes == {"hit": batch["fields"]["slices"],
+                                  "miss": 0, "bypass": 0}
+                assert len([c for c in phases
+                            if c["name"] == "scan.d2h"]) == 1
+            else:
+                assert probes == {"hit": 0, "miss": len(dispatches),
+                                  "bypass": 0}
             assert all((c["fields"]["h2d_bytes"] == 0) == hit
                        for c in dispatches)
         else:
@@ -464,7 +474,8 @@ class TestSeams:
 
         async def go(client, engine):
             sizes = await compiles_per_query(
-                client, engine, [device_decode._decode_aggregate_jit])
+                client, engine, [device_decode._decode_aggregate_jit,
+                                 device_decode._decode_batch_jit])
             return (sizes, d2h.labels(direction="d2h").value,
                     deviceprof.profiler.snapshot()["transfer"]["d2h"])
 
@@ -472,8 +483,11 @@ class TestSeams:
         assert seconds > 0.0
         assert ledger["seconds"] > 0.0 and ledger["bytes"] > 0
         # as before the scopes: at most a program a segment for the
-        # first query, and none for its repeats
-        assert sizes[0][0] <= 2 and sizes[1:] == [[0], [0]]
+        # first query (its slices miss) and none for its repeats, whose
+        # resident slices share ONE batched program, compiled once by
+        # the first query that finds them together
+        assert sizes[0][0] <= 2 and [q[0] for q in sizes[1:]] == [0, 0]
+        assert sizes[0][1] == 0 and sum(q[1] for q in sizes) <= 1
 
     def test_download_waits_in_a_span_only_where_something_runs(self):
         """`deviceprof.download`'s sync half: a computation still
