@@ -4858,7 +4858,9 @@ def run_config23(rows: int, iters: int) -> dict:
             "compile_ms": round(c.get("stage_device_compile_ms", 0.0), 2),
             "dispatch_ms": round(
                 c.get("stage_device_dispatch_ms", 0.0), 2),
-            "exec_ms": round(c.get("stage_device_exec_ms", 0.0), 2),
+            "exec_ms": round(sum(
+                sp["duration_ms"] for sp in trace.spans
+                if sp["name"] == "scan.device_wait"), 2),
             "transfer_ms": round(xfer, 2),
         }
         device_stage_ms = float(c.get("stage_device_ms", 0.0))

@@ -13,8 +13,9 @@ ledger entry answering the three questions XLA keeps to itself:
                     that churned instead of "it was slow once"
   where did the wall go?   per-dispatch host time (trace/cache-lookup/
                     enqueue) vs the host's wait for the device at the
-                    sync seams (block_until_ready, download) — a cold
-                    query's slow-log entry states whether it paid
+                    sync seams (block_until_ready, download), which
+                    is the `scan.device_wait` phase and nothing else —
+                    a cold query's trace states whether it paid
                     compilation, dispatch overhead, or waited for the
                     device.  The wait is NOT a kernel time: it holds
                     the device's queue (other queries' programs ahead
@@ -81,11 +82,6 @@ _DISPATCH_SECONDS = registry.histogram(
     "device_dispatch_seconds",
     "host-side dispatch wall per cached call (trace-cache lookup + "
     "argument processing + async enqueue), per jitted function")
-_EXEC_SECONDS = registry.histogram(
-    "device_exec_seconds",
-    "the host's wait for the device at a sync seam (block_until_ready, "
-    "download), per jitted function: queue behind other programs plus "
-    "execution, not a kernel time")
 _TRANSFER_BYTES = registry.counter(
     "device_transfer_bytes_total",
     "bytes moved across the host/device boundary at the device_put "
@@ -147,7 +143,7 @@ class FnRecord:
 
     __slots__ = ("name", "compiles", "compile_seconds", "last_compile_s",
                  "last_key", "dispatches", "dispatch_seconds",
-                 "execs", "exec_seconds", "storms", "storm_active",
+                 "storms", "storm_active",
                  "_window", "_churn", "_prev_key")
 
     def __init__(self, name: str) -> None:
@@ -161,8 +157,6 @@ class FnRecord:
         self.last_key: Optional[tuple] = None
         self.dispatches = 0
         self.dispatch_seconds = 0.0
-        self.execs = 0
-        self.exec_seconds = 0.0
         self.storms = 0
         self.storm_active = False
         self._window: deque = deque()
@@ -179,8 +173,6 @@ class FnRecord:
                          else {k: repr(v) for k, v in self.last_key}),
             "dispatches": self.dispatches,
             "dispatch_seconds": round(self.dispatch_seconds, 6),
-            "execs": self.execs,
-            "exec_seconds": round(self.exec_seconds, 6),
             "storms": self.storms,
             "storm_active": self.storm_active,
         }
@@ -345,30 +337,26 @@ class DeviceProfiler:
                 len(rec._window), self.storm_window_s,
                 self.storm_threshold, churn_dim)
 
-    # ---- the exec + transfer seams ----------------------------------------
+    # ---- the sync + transfer seams ----------------------------------------
 
     def block_until_ready(self, x, fn: str = "device", table: str = ""):
         """The sync seam: wall spent here is the host blocked on the
         device (the dispatch already returned; this waits for the
         queue ahead of the computation and for the computation).
-        A `scan.device_wait` phase span of `table`.  Returns `x` so
-        call sites stay expressions."""
+        A `scan.device_wait` phase span of `table`, `fn` naming the
+        program waited for: the one record of the wait.  Returns `x`
+        so call sites stay expressions."""
         import jax
 
-        with phase("scan.device_wait", table, fn=fn):
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(x)
-            waited = time.perf_counter() - t0
-        self.observe_exec(fn, waited)
-        return out
+        with phase("scan.device_wait", table, sync=True, fn=fn):
+            return jax.block_until_ready(x)
 
     def download(self, x, fn: str = "device", table: str = ""):
         """The d2h seam, the sync split from the copy: first
-        `block_until_ready` (charged to `scan.device_wait` and
-        device_exec_seconds{fn}; where every leaf is ready already
-        nothing waits: an observation of 0 in both and no span), then
-        `np.asarray` of every leaf
-        (the `scan.d2h` phase span, and the seconds of
+        `block_until_ready` (charged to `scan.device_wait`; where
+        every leaf is ready already nothing waits: an observation of 0
+        and no span), then `np.asarray` of every leaf (the `scan.d2h`
+        phase span, and the seconds of
         device_transfer_seconds_total{direction="d2h"}).  Returns the
         same pytree with numpy leaves."""
         import jax
@@ -376,27 +364,15 @@ class DeviceProfiler:
 
         if all(leaf.is_ready() for leaf in jax.tree_util.tree_leaves(x)
                if isinstance(leaf, jax.Array)):
-            self.observe_exec(fn, 0.0)  # the seam was passed: waited 0
             phase_passed("scan.device_wait", table)
         else:
             self.block_until_ready(x, fn=fn, table=table)
-        with phase("scan.d2h", table, fn=fn):
+        with phase("scan.d2h", table, sync=True, fn=fn):
             t0 = time.perf_counter()
             out = jax.tree_util.tree_map(np.asarray, x)
             copied = time.perf_counter() - t0
         self.charge_transfer("d2h", _nbytes(out), seconds=copied)
         return out
-
-    def observe_exec(self, fn: str, seconds: float) -> None:
-        """Charge an already-measured wait for the device."""
-        if not self.enabled:
-            return
-        rec = self._record(fn)
-        with self._lock:
-            rec.execs += 1
-            rec.exec_seconds += seconds
-        _EXEC_SECONDS.labels(fn=fn).observe(seconds)
-        trace_add("stage_device_exec_ms", seconds * 1e3)
 
     def device_put(self, x, *args, **kwargs):
         """jax.device_put with h2d accounting (bytes + enqueue wall)."""
@@ -518,7 +494,7 @@ class DeviceProfiler:
         jit's own caches) survive; only accounted state resets."""
         for rec in self.records():
             for fam in (_COMPILES, _COMPILE_SECONDS, _STORMS,
-                        _DISPATCHES, _DISPATCH_SECONDS, _EXEC_SECONDS):
+                        _DISPATCHES, _DISPATCH_SECONDS):
                 fam.remove(fn=rec.name)
             with self._lock:
                 rec.reset()
@@ -538,7 +514,6 @@ profiler = DeviceProfiler()
 jit = profiler.jit
 block_until_ready = profiler.block_until_ready
 download = profiler.download
-observe_exec = profiler.observe_exec
 device_put = profiler.device_put
 charge_transfer = profiler.charge_transfer
 record_round = profiler.record_round

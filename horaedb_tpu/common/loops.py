@@ -48,6 +48,17 @@ time every collection into `process_gc_pause_seconds_total
 overlapped it: background ops (finished and in flight), collections,
 the loops that woke in it, and each pool's queue depth.
 
+The host's accounts ride on the same sampler (docs/observability.md,
+loop registry).  For as long as it lives the loop's selector is
+wrapped, so that the wall inside `select` is the loop's idle time and
+everything between two selects its busy time
+(`event_loop_select_seconds_total`, `event_loop_busy_seconds_total`);
+and every tick reads the CPU clock of the loop's thread and of the
+named pools' threads into `process_thread_cpu_seconds_total{role}`,
+`other` being the rest of the process's CPU.  Busy minus CPU of the
+loop's thread is time it held a turn and did not run: it waited for
+the GIL, or sat in a blocking call.
+
 Loops doing legitimately long single iterations (a compaction rewrite, a
 whole-table rollup backfill) pass an explicit `stall_threshold_s`
 sized to their worst case — the watchdog flags *wedged*, not *busy*.
@@ -122,6 +133,143 @@ _GC_CHILDREN = {g: _GC_PAUSE.labels(generation=str(g)) for g in (0, 1, 2)}
 _gc_recent: deque = deque(maxlen=64)
 _gc_started = [0.0, 0.0]
 _gc_hooked = False
+
+# ---- the host's accounts: every thread's CPU, by role ----------------------
+_ROLES = ("loop", *runtimes.POOLS, "other")
+_THREAD_CPU = registry.counter(
+    "process_thread_cpu_seconds_total",
+    "CPU seconds (user + system) of the process's threads by role, "
+    "read on the stall sampler's tick: loop = the thread the sampler "
+    "runs on, sst / compact / manifest = the named pools' threads, "
+    "other = the rest of time.process_time()")
+_ROLE_CPU = {r: _THREAD_CPU.labels(role=r) for r in _ROLES}
+# native thread id -> its CPU clock at the last reading, of the threads
+# the last reading named, so that the counters only move forward; and
+# the idents of the threads that run a sampler (role `loop`).  Shared
+# by every sampler of the process
+_cpu_last: dict = {}
+_loop_threads: set = set()
+_cpu_lock = threading.Lock()
+
+
+def _thread_cpu_seconds(native_id: int) -> Optional[float]:
+    """The kernel's CPU clock of one thread of this process, by the
+    clock id pthread_getcpuclockid computes from the thread's id
+    ((~tid << 3) | 6, Linux).  Nothing of the thread is dereferenced:
+    for one that has died the kernel answers EINVAL."""
+    try:
+        return time.clock_gettime((~native_id << 3) | 6)
+    except OSError:
+        return None
+
+
+def _role_of(t: threading.Thread) -> Optional[str]:
+    if t.ident in _loop_threads:
+        return "loop"
+    if t.name.startswith(runtimes.THREAD_NAME_PREFIX):
+        pool = t.name[len(runtimes.THREAD_NAME_PREFIX):].rpartition("_")[0]
+        return pool if pool in runtimes.POOLS else None
+    return None
+
+
+def sample_thread_cpu() -> None:
+    """One reading of the role account: what each named thread has
+    used since the last reading goes to its role, and `other` is moved
+    up to the process's CPU less the named roles.  What a thread used
+    before its first reading or after its last (it died, or its loop's
+    sampler ended) is in `other` too: the roles add up to
+    time.process_time() at every reading."""
+    with _cpu_lock:
+        named = {}
+        for t in threading.enumerate():
+            role, tid = _role_of(t), t.native_id
+            now = (None if role is None or tid is None
+                   else _thread_cpu_seconds(tid))
+            if now is None:
+                continue
+            named[tid] = now
+            last = _cpu_last.get(tid)
+            if last is not None and now > last:
+                _ROLE_CPU[role].inc(now - last)
+        _cpu_last.clear()
+        _cpu_last.update(named)
+        rest = time.process_time() - _THREAD_CPU.total
+        if rest > 0.0:
+            _ROLE_CPU["other"].inc(rest)
+
+
+# ---- the host's accounts: the loop's thread, busy against idle --------------
+class _LoopTurns:
+    """One event loop's selector with its `select` wrapped: the wall
+    inside it is the loop's idle time (a wake-up's wait for the GIL
+    included: no Python runs before it is taken), everything between
+    two selects is one busy turn.  Two perf_counter reads and two
+    float additions a turn; the sampler's tick moves the sums into
+    the counters (`flush`).  While a profiler session runs each busy
+    turn is also one TraceMe `horaedb/loop.turn`, so that a device gap
+    spent in work of the loop's thread that no span names reads as
+    that."""
+
+    def __init__(self, selector) -> None:
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._selector = selector
+        self._select = selector.select
+        self._busy = registry.counter(
+            "event_loop_busy_seconds_total",
+            "wall seconds of the event loop's thread between two "
+            "selects: handlers, callbacks and whatever blocked them")
+        self._idle = registry.counter(
+            "event_loop_select_seconds_total",
+            "wall seconds of the event loop's thread inside its "
+            "selector's select: the loop had nothing ready")
+        self._busy_s = self._idle_s = 0.0
+        self._turn = None
+        self._woke = time.perf_counter()
+        selector.select = self.select
+
+    def select(self, timeout=None):
+        if self._turn is not None:
+            self._turn.__exit__(None, None, None)
+            self._turn = None
+        asleep = time.perf_counter()
+        self._busy_s += asleep - self._woke
+        events = self._select(timeout)
+        self._woke = woke = time.perf_counter()
+        self._idle_s += woke - asleep
+        if self._annotation.is_enabled():
+            self._turn = self._annotation("horaedb/loop.turn")
+            self._turn.__enter__()
+        return events
+
+    def flush(self) -> None:
+        """The sums so far into the counters (on the loop's thread:
+        nothing else writes them)."""
+        busy, idle = self._busy_s, self._idle_s
+        self._busy_s = self._idle_s = 0.0
+        self._busy.inc(busy)
+        self._idle.inc(idle)
+
+    def undo(self) -> None:
+        """Give the selector its own `select` back (clear-on-close):
+        the turn under way is booked, the counters stay where they
+        are."""
+        del self._selector.select
+        self._busy_s += time.perf_counter() - self._woke
+        self.flush()
+        if self._turn is not None:
+            self._turn.__exit__(None, None, None)
+
+
+def _wrap_selector(loop) -> Optional[_LoopTurns]:
+    """The busy / select account of `loop`, or None where the loop has
+    no selector to wrap (another loop implementation: the two counters
+    are then not registered) or is wrapped already."""
+    selector = getattr(loop, "_selector", None)
+    if selector is None or "select" in selector.__dict__:
+        return None
+    return _LoopTurns(selector)
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -351,13 +499,23 @@ class LoopRegistry:
 
     async def _stall_loop(self, hb: LoopHandle) -> None:
         loop = asyncio.get_running_loop()
-        while True:
-            hb.beat()
-            due = loop.time() + STALL_PERIOD_S
-            await asyncio.sleep(STALL_PERIOD_S)
-            if self.enabled:
-                self.note_lag(max(0.0, loop.time() - due))
-            hb.ok()
+        turns = _wrap_selector(loop)
+        _loop_threads.add(threading.get_ident())
+        try:
+            while True:
+                hb.beat()
+                due = loop.time() + STALL_PERIOD_S
+                await asyncio.sleep(STALL_PERIOD_S)
+                if turns is not None:
+                    turns.flush()
+                if self.enabled:
+                    self.note_lag(max(0.0, loop.time() - due))
+                    sample_thread_cpu()
+                hb.ok()
+        finally:
+            _loop_threads.discard(threading.get_ident())
+            if turns is not None:
+                turns.undo()
 
     def note_lag(self, lag_s: float) -> None:
         """One sampler tick that came `lag_s` late (the stall line names

@@ -21,9 +21,11 @@ Every hop through `run` is timed where the work waits
 instruction on the worker (`runtime_pool_wait_seconds{pool}`: the
 pool's queue) and the worker's return to the coroutine's resumption on
 the loop (`runtime_pool_resume_seconds{pool}`: the loop's lag as this
-job feels it); with the time on the worker they are the per-trace twins
-`pool_<pool>_{wait,run,resume}_ms` and, in a traced request, the fields
-of the hop's `pool_hop` span (submit to resumption).
+job feels it); with the time on the worker they are, in a traced
+request, the fields of the hop's `pool_hop` span (submit to
+resumption).  What the workers' CPU comes to is the stall sampler's
+account (`process_thread_cpu_seconds_total{role}`, common/loops.py),
+which tells these pools' threads by `thread_name_prefix`.
 """
 
 from __future__ import annotations
@@ -39,6 +41,7 @@ from horaedb_tpu.utils.metrics import registry
 from horaedb_tpu.utils.tracing import record_hop
 
 POOLS = ("sst", "compact", "manifest")
+THREAD_NAME_PREFIX = "horaedb-"   # a pool's threads: horaedb-<pool>_<n>
 # waits are short when all is well: the default buckets start at 0.5 ms
 _WAIT_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                  0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
@@ -81,13 +84,10 @@ class Runtimes:
                  manifest_threads: int = 1):
         _LIVE.add(self)
         self._pools = {
-            "sst": ThreadPoolExecutor(sst_threads,
-                                      thread_name_prefix="horaedb-sst"),
-            "compact": ThreadPoolExecutor(
-                compact_threads, thread_name_prefix="horaedb-compact"),
-            "manifest": ThreadPoolExecutor(
-                manifest_threads, thread_name_prefix="horaedb-manifest"),
-        }
+            pool: ThreadPoolExecutor(
+                n, thread_name_prefix=THREAD_NAME_PREFIX + pool)
+            for pool, n in zip(POOLS, (sst_threads, compact_threads,
+                                       manifest_threads))}
 
     async def run(self, pool: str, fn: Callable, *args, **kwargs):
         """Run fn(*args, **kwargs) on the named pool; await the result.

@@ -110,6 +110,10 @@ _RESPOND_ENCODE_SECONDS = registry.counter(
     "respond_encode_seconds_total",
     "wall seconds inside the downsample response encoder (the lazy "
     "download of device grids excluded)")
+_RESPOND_ENCODE_CPU = registry.counter(
+    "respond_encode_cpu_seconds_total",
+    "CPU seconds of the loop's thread inside the downsample response "
+    "encoder: under its wall where the encoder waited for the GIL")
 
 
 class _ServiceRate:
@@ -1354,7 +1358,9 @@ def build_app(state: ServerState) -> web.Application:
         return metric, filters, rng, field, bucket_ms
 
     async def _read_query(req: web.Request):
-        """The `parse` span: the body and _parse_query_body's fields."""
+        """The `parse` span: the body and _parse_query_body's fields
+        (held across the await of the body, so not `sync`: it carries
+        no CPU)."""
         with span("parse"):
             body = await req.json()
             return (body, *_parse_query_body(body))
@@ -1393,7 +1399,7 @@ def build_app(state: ServerState) -> web.Application:
             if bucket_ms:
                 out, meta = await _engine_downsample(metric, filters, rng,
                                                      bucket_ms, field)
-                with span("respond"):
+                with span("respond", sync=True):
                     body_out = _downsample_json(out)
                     if impl is not None and out["tsids"]:
                         body_out["aggs"][fn] = impl(out["aggs"], bucket_ms)
@@ -1432,7 +1438,7 @@ def build_app(state: ServerState) -> web.Application:
                 largest=largest, field=field)
         except Error as e:
             return _error_response(e)
-        with span("respond"):
+        with span("respond", sync=True):
             return _downsample_response(_downsample_json(out))
 
     @routes.post("/query_multi")
@@ -1459,7 +1465,7 @@ def build_app(state: ServerState) -> web.Application:
                 metric, filters, rng, bucket_ms, fields=fields)
         except Error as e:
             return _error_response(e)
-        with span("respond"):
+        with span("respond", sync=True):
             return _downsample_response(
                 {f: _downsample_json(out) for f, out in outs.items()})
 
@@ -1754,12 +1760,14 @@ def _downsample_response(body: dict) -> web.Response:
     """The /query* response of a _downsample_json body (or of
     /query_multi's {field: body}): json.dumps' text for everything but
     the grids, which _grids_text writes in one pass over all of them."""
-    t0 = time.perf_counter()
+    t0, cpu0 = time.perf_counter(), time.thread_time()
     grids = _grids_of(body)
     payload = _json_text(body, iter(_grids_text(grids))).encode()
     _RESPOND_CELLS.inc(sum(g.size for g in grids))
     _RESPOND_BYTES.inc(len(payload))
+    cpu = time.thread_time() - cpu0
     _RESPOND_ENCODE_SECONDS.inc(time.perf_counter() - t0)
+    _RESPOND_ENCODE_CPU.inc(cpu)
     return web.Response(body=payload, content_type="application/json",
                         charset="utf-8")
 

@@ -665,9 +665,11 @@ class ParquetReader:
         memledger.reset_device_high_water()
         clear_phases(self.table)
 
-    def _phase(self, name: str, **fields):
-        """A phase span of this reader's table (utils/tracing.phase)."""
-        return phase(name, self.table, **fields)
+    def _phase(self, name: str, sync: bool = False, **fields):
+        """A phase span of this reader's table (utils/tracing.phase);
+        `sync` (no `await` inside) at every site of `scan.dispatch`,
+        `scan.d2h` and `scan.combine`: their CPU is read."""
+        return phase(name, self.table, sync=sync, **fields)
 
     def _phased(self, name: str, fn, **fields):
         """`fn` run inside a phase span: for the closures a scan hands
@@ -1067,7 +1069,7 @@ class ParquetReader:
         phase: a batched call a group of slices that may share a
         program, a call of its own for one left over), then downloaded
         and shaped, one DevicePart a plan."""
-        with self._phase("scan.dispatch", h2d_bytes=0,
+        with self._phase("scan.dispatch", sync=True, h2d_bytes=0,
                          slices=len(plans)):
             issued = device_decode.dispatch_resident(plans, self.table)
         return device_decode.finalize_resident(issued)
@@ -1239,7 +1241,7 @@ class ParquetReader:
             return None
         if isinstance(got, device_decode.DecodePlan) \
                 and not plan.decode_defer:
-            with self._phase("scan.dispatch", h2d_bytes=got.cap * 4
+            with self._phase("scan.dispatch", sync=True, h2d_bytes=got.cap * 4
                              * len(got.upload_names)):
                 got = device_decode.execute_plan(got, self.table)
         return [got]
@@ -2441,14 +2443,14 @@ class ParquetReader:
         t0 = time.perf_counter()
         # one scan.dispatch span per enqueue, not one around the loop:
         # `rounds` is lazy, and its stack building is scan.group_prep
-        with self._phase("scan.dispatch", fn="_fused_acc_init_jit"):
+        with self._phase("scan.dispatch", sync=True, fn="_fused_acc_init_jit"):
             acc = _fused_acc_init_jit(num_groups=g_pad,
                                       num_buckets=spec.num_buckets,
                                       which=spec.which)
         t_dev += time.perf_counter() - t0
         for ts_s, gid_s, val_s, remap_d, shift_d, lo_dev, _lo in rounds:
             t0 = time.perf_counter()
-            with self._phase("scan.dispatch",
+            with self._phase("scan.dispatch", sync=True,
                              fn="_fused_round_accumulate_jit"):
                 acc = _fused_round_accumulate_jit(
                     acc, ts_s, gid_s, val_s, remap_d, shift_d, lo_dev,
@@ -2456,7 +2458,7 @@ class ParquetReader:
                     which=spec.which)
             t_dev += time.perf_counter() - t0
         t0 = time.perf_counter()
-        with self._phase("scan.dispatch", fn="_fused_finalize_jit"):
+        with self._phase("scan.dispatch", sync=True, fn="_fused_finalize_jit"):
             final = _fused_finalize_jit(acc, spec.which)
             out = {k: v[:g] for k, v in final.items()}
             # the per-group any-data mask rides the same enqueue and the
@@ -3024,7 +3026,7 @@ class ParquetReader:
         # the sync, then the tail-grid copies, each its own phase
         deviceprof.block_until_ready(out, fn="mesh_run_partials",
                                      table=self.table)
-        with self._phase("scan.d2h", fn="mesh_run_partials"):
+        with self._phase("scan.d2h", sync=True, fn="mesh_run_partials"):
             t_dl = time.perf_counter()
             for s, a, b in runs:
                 lo_run, grids = self._slice_mesh_part(
@@ -3153,7 +3155,7 @@ class ParquetReader:
                         "mesh decode round failed (%s); running the "
                         "per-segment fused dispatch", exc)
                 for s, dp in chunk:
-                    with self._phase("scan.dispatch"):
+                    with self._phase("scan.dispatch", sync=True):
                         disp = device_decode.execute_plan(dp, self.table)
                     part = disp.finalize()
                     entries.append((s, part.part, 1))
@@ -3310,7 +3312,7 @@ class ParquetReader:
         a = 0
         deviceprof.block_until_ready(out, fn="mesh_decode_partials",
                                      table=self.table)
-        with self._phase("scan.d2h", fn="mesh_decode_partials"):
+        with self._phase("scan.d2h", sync=True, fn="mesh_decode_partials"):
             t_dl = time.perf_counter()
             for i in range(len(chunk)):
                 if i + 1 < len(chunk) and seg_ids[i + 1] == seg_ids[i]:
@@ -3736,7 +3738,7 @@ class ParquetReader:
     def finalize_aggregate(self, parts: list, spec: AggregateSpec,
                            top_k=None):
         """Parts to grids: the `scan.combine` phase."""
-        with self._phase("scan.combine", parts=len(parts)):
+        with self._phase("scan.combine", sync=True, parts=len(parts)):
             return self._finalize_aggregate(parts, spec, top_k)
 
     def _finalize_aggregate(self, parts: list, spec: AggregateSpec,
@@ -4218,7 +4220,7 @@ class ParquetReader:
                                          local_ok)
         total = self._dev_scalar(spec.num_buckets)
         t_dev = time.perf_counter()
-        with self._phase("scan.dispatch", windows=len(items)):
+        with self._phase("scan.dispatch", sync=True, windows=len(items)):
             stacked = _batched_window_partials_jit(
                 ts_s, gid_s, val_s, remap_d, shift_d,
                 lo_dev, total, self._dev_scalar(spec.bucket_ms),

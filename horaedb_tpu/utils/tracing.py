@@ -13,11 +13,15 @@ request-scoped (docs/observability.md):
   (compaction, manifest merge) stay observable without a trace; it
   also enters a `jax.profiler.TraceAnnotation` named `horaedb/<name>`,
   so while a profiler session runs the program's spans lie in the
-  xplane on the same clock as the device's operations;
+  xplane on the same clock as the device's operations; a span that
+  declares `sync=True` (no `await` inside) may also carry `cpu_ms`,
+  its own thread's CPU beside its wall (one such span in four reads
+  the clock);
 - `phase(name, table, **fields)` is a span of one scan phase (the
   children of `downsample`): its histogram is the labelled family
   `scan_phase_seconds{phase=,table=}`, so the data table's scans are
-  told apart from `resolve`'s scans of the index tables;
+  told apart from `resolve`'s scans of the index tables, and its CPU
+  adds to `scan_phase_cpu_seconds_total{phase=,table=}`;
 - `trace_add(name, n)` attributes counted work (object-store GETs and
   bytes, cache tier hits, per-stage wall time) to the active trace;
 - the trace context propagates across regions via the `X-Trace-Id`
@@ -153,13 +157,6 @@ class Trace:
         with self._lock:
             if not self.finished:
                 self.counters[name] = self.counters.get(name, 0) + value
-
-    def add_many(self, pairs) -> None:
-        """Several counters under one lock (a pool hop adds three)."""
-        with self._lock:
-            if not self.finished:
-                for name, value in pairs:
-                    self.counters[name] = self.counters.get(name, 0) + value
 
     # stitching bounds: a trace must stay ring-sized and exportable no
     # matter what its downstream peers send
@@ -540,6 +537,11 @@ def trace_add(name: str, value: float = 1.0) -> None:
 
 
 _TraceAnnotation = None
+# the share of `sync` spans that read their thread's CPU clock (see
+# span): a constant, which the tests set to 1
+CPU_SAMPLE = 0.25
+_thread_time = time.thread_time
+_random = random.random
 # span name -> its `span_<name>_seconds` family, so that a span looks
 # its histogram up in a plain dict and not under the registry's lock
 _SPAN_HISTS: dict = {}
@@ -570,18 +572,34 @@ class span:
     compaction/flush don't flatten into +Inf; `hist` replaces the
     family by an already-bound labelled child), records a tree span
     into the active trace when one is bound, and annotates the
-    profiler's trace (see _annotation)."""
+    profiler's trace (see _annotation).
+
+    CPU beside wall, under one rule: a span that declares `sync=True`
+    (its block holds no `await`, whichever thread runs it) may read
+    `time.thread_time()` at both ends and record `cpu_ms`; no other
+    span does, since one held across an `await` would be booked the
+    CPU of every task that ran meanwhile.  A read is a system call
+    (0.45 us in the sandbox, 5.5 us on the chip's host, where all of
+    them on every declared span cost a point query 2.5 % of its rate:
+    PERF.md §6, PR 37), so one declared span in 1 / CPU_SAMPLE, drawn
+    at random, reads the clock, and `cpu`, a counter, takes its CPU
+    seconds over CPU_SAMPLE: an estimate of the CPU of all of them.
+    Wall minus CPU is time the span stood still: it waited for the
+    GIL or sat in a blocking call."""
 
     __slots__ = ("name", "fields", "_hist", "_buckets", "_trace",
                  "_span_id", "_parent_id", "_tok", "_ann", "_wall_ms",
-                 "_t0")
+                 "_t0", "_sync", "_cpu", "_cpu0")
 
     def __init__(self, name: str, buckets: Optional[tuple] = None,
-                 hist=None, **fields) -> None:
+                 hist=None, sync: bool = False, cpu=None,
+                 **fields) -> None:
         self.name = name
         self.fields = fields
         self._hist = hist
         self._buckets = buckets
+        self._sync = sync
+        self._cpu = cpu
         self._trace = None
 
     def __enter__(self) -> "span":
@@ -599,9 +617,13 @@ class span:
         self._ann.__enter__()
         self._wall_ms = time.time() * 1e3
         self._t0 = time.perf_counter()
+        # read inside the wall's two readings: cpu_ms <= duration_ms
+        self._cpu0 = (_thread_time()
+                      if self._sync and _random() < CPU_SAMPLE else None)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        cpu = None if self._cpu0 is None else _thread_time() - self._cpu0
         elapsed = time.perf_counter() - self._t0
         self._ann.__exit__(exc_type, exc, tb)
         # failures are observed too — failure-path tail latency matters
@@ -614,32 +636,37 @@ class span:
                 f"span_{name.replace('.', '_')}_seconds",
                 f"span {name} duration", **hist_kwargs)
         hist.observe(elapsed)
+        if cpu is not None and self._cpu is not None:
+            self._cpu.inc(cpu / CPU_SAMPLE)
         trace = self._trace
         if trace is not None:
             _current_span_id.reset(self._tok)
-            trace.record({
+            record = {
                 "span_id": self._span_id, "parent_id": self._parent_id,
                 "name": self.name, "start_ms": round(self._wall_ms, 3),
                 "duration_ms": round(elapsed * 1e3, 3),
                 "status": "ok" if exc_type is None else "error",
                 "fields": {k: _field(v) for k, v in self.fields.items()},
-            })
+            }
+            if cpu is not None:
+                record["cpu_ms"] = round(cpu * 1e3, 3)
+            trace.record(record)
 
 
-# a hop whose two waits together stay under this leaves no span (its
-# counters still count): nine such hops are 3% of a 60 ms scan
+# a hop whose two waits together stay under this leaves no span (the
+# pool's histograms still count it): nine such hops are 3% of a 60 ms
+# scan
 HOP_SPAN_FLOOR_MS = 0.2
-_HOP_TWINS: dict = {}
 
 
 def record_hop(pool: str, submitted: float, started: float,
                returned: float, resumed: float) -> None:
     """One hop of a traced request through a worker pool, known by four
-    time.perf_counter readings (common/runtimes.py): the per-trace
-    twins of the pool's wait counters, and — where the hop waited —
-    one `pool_hop` child of the current span from submit to
-    resumption.  It overlaps the spans the job recorded on the worker,
-    so a span that hops between the loop and a pool is closed by its
+    time.perf_counter readings (common/runtimes.py): where the hop
+    waited, one `pool_hop` child of the current span from submit to
+    resumption, with the two waits and the time on the worker as
+    fields.  It overlaps the spans the job recorded on the worker, so
+    a span that hops between the loop and a pool is closed by its
     children, waits included.  No histogram and no profiler
     annotation: the hop is known only after the fact, and its counters
     are the caller's.  Runs on the loop's thread once per hop: kept
@@ -648,13 +675,7 @@ def record_hop(pool: str, submitted: float, started: float,
     if trace is None or trace.finished:
         return
     wait_ms = (started - submitted) * 1e3
-    run_ms = (returned - started) * 1e3
     resume_ms = (resumed - returned) * 1e3
-    names = _HOP_TWINS.get(pool)
-    if names is None:
-        names = _HOP_TWINS[pool] = tuple(
-            f"pool_{pool}_{part}_ms" for part in ("wait", "run", "resume"))
-    trace.add_many(zip(names, (wait_ms, run_ms, resume_ms)))
     if wait_ms + resume_ms < HOP_SPAN_FLOOR_MS:
         return
     trace.record({
@@ -665,7 +686,8 @@ def record_hop(pool: str, submitted: float, started: float,
                           + (submitted - trace._t0) * 1e3, 3),
         "duration_ms": round((resumed - submitted) * 1e3, 3),
         "status": "ok",
-        "fields": {"pool": pool, "wait_ms": wait_ms, "run_ms": run_ms,
+        "fields": {"pool": pool, "wait_ms": wait_ms,
+                   "run_ms": (returned - started) * 1e3,
                    "resume_ms": resume_ms},
     })
 
@@ -682,11 +704,17 @@ _PHASE_SECONDS = registry.histogram(
     "scan_phase_seconds",
     "wall seconds per scan phase (the children of the downsample "
     "span), by phase and by the table scanned")
+_PHASE_CPU = registry.counter(
+    "scan_phase_cpu_seconds_total",
+    "CPU seconds of the recording thread inside the scan phases that "
+    "declare themselves synchronous (dispatch, device_wait, d2h, "
+    "combine), by phase and by the table scanned")
 
 
 # (phase, table) -> the labelled child, looked up without the family's
 # lock; dropped with the child at the table's close
 _PHASE_CHILDREN: dict = {}
+_PHASE_CPU_CHILDREN: dict = {}
 
 
 def _phase_child(name: str, table: str):
@@ -697,10 +725,19 @@ def _phase_child(name: str, table: str):
     return hist
 
 
-def phase(name: str, table: str, **fields) -> span:
-    """A span of one scan phase on `table`."""
-    return span(name, hist=_phase_child(name, table), table=table,
-                **fields)
+def phase(name: str, table: str, sync: bool = False, **fields) -> span:
+    """A span of one scan phase on `table`; a `sync` one adds its CPU
+    (sampled and scaled: see span) to
+    `scan_phase_cpu_seconds_total{phase,table}`, so a phase whose
+    every site is `sync` reads its CPU share off the two families."""
+    cpu = None
+    if sync:
+        cpu = _PHASE_CPU_CHILDREN.get((name, table))
+        if cpu is None:
+            cpu = _PHASE_CPU_CHILDREN[name, table] = _PHASE_CPU.labels(
+                phase=name, table=table)
+    return span(name, hist=_phase_child(name, table), sync=sync, cpu=cpu,
+                table=table, **fields)
 
 
 def phase_passed(name: str, table: str) -> None:
@@ -711,10 +748,12 @@ def phase_passed(name: str, table: str) -> None:
 
 
 def clear_phases(table: str) -> None:
-    """Clear-on-close for a table's phase histograms."""
+    """Clear-on-close for a table's phase histograms and CPU counters."""
     for name in SCAN_PHASES:
         _PHASE_CHILDREN.pop((name, table), None)
         _PHASE_SECONDS.remove(phase=name, table=table)
+        _PHASE_CPU_CHILDREN.pop((name, table), None)
+        _PHASE_CPU.remove(phase=name, table=table)
 
 
 def _field(v):

@@ -32,7 +32,7 @@ from horaedb_tpu.storage.config import (
 from horaedb_tpu.storage.read import AggregateSpec, ScanRequest
 from horaedb_tpu.storage.storage import CloudObjectStorage, WriteRequest
 from horaedb_tpu.storage.types import TimeRange
-from horaedb_tpu.utils import tracing
+from horaedb_tpu.utils import registry, tracing
 
 T0 = 1_700_000_000_000
 HOUR = 3_600_000
@@ -272,10 +272,12 @@ class TestRoundTimeline:
 class TestTraceTwins:
     def test_cold_scan_records_twins_memo_repeat_does_not(self):
         """A cold device-decode aggregate pays device work, so its
-        trace carries the stage_device_* and transfer twins; the
-        identical repeat is memo-served — no jit dispatch, no twins
-        (the attribution proves WHERE wall went, so a scan that did no
-        device work must show none)."""
+        trace carries the stage_device_* and transfer twins and it
+        passes the sync seam (`scan.device_wait` observes it: the one
+        record of the wait, a span where something still ran); the
+        identical repeat is memo-served — no jit dispatch, no twins,
+        no seam (the attribution proves WHERE wall went, so a scan
+        that did no device work must show none)."""
         async def go():
             rt = _runtimes()
             s = await _open_device_storage(rt)
@@ -284,17 +286,27 @@ class TestTraceTwins:
                 _clear_caches(s)
                 tracing.recorder.configure(enabled=True, sample_rate=1.0)
 
+                def seams_passed() -> int:
+                    return sum(
+                        v for name, labels, v in registry.family(
+                            "scan_phase_seconds").samples()
+                        if name.endswith("_count")
+                        and labels.get("phase") == "scan.device_wait")
+
                 async def traced_scan():
+                    before = seams_passed()
                     trace = tracing.recorder.start("/scan")
                     with tracing.trace_scope(trace):
                         await s.scan_aggregate(*_agg_scan())
                     tracing.recorder.finish(trace)
-                    return {k: v for k, v in trace.counters.items()
-                            if k in ("stage_device_compile_ms",
-                                     "stage_device_dispatch_ms",
-                                     "stage_device_exec_ms",
-                                     "device_h2d_bytes",
-                                     "device_d2h_bytes")}
+                    out = {k: v for k, v in trace.counters.items()
+                           if k in ("stage_device_compile_ms",
+                                    "stage_device_dispatch_ms",
+                                    "device_h2d_bytes",
+                                    "device_d2h_bytes")}
+                    if seams_passed() > before:
+                        out["scan.device_wait"] = seams_passed() - before
+                    return out
 
                 with _force_xla_agg():
                     cold = await traced_scan()
@@ -302,7 +314,8 @@ class TestTraceTwins:
                     # and moved bytes both ways — all on the trace
                     assert ("stage_device_compile_ms" in cold
                             or "stage_device_dispatch_ms" in cold), cold
-                    assert "stage_device_exec_ms" in cold, cold
+                    assert "scan.device_wait" in cold, cold
+                    assert "stage_device_exec_ms" not in cold, cold
                     assert cold.get("device_h2d_bytes", 0) > 0, cold
                     assert cold.get("device_d2h_bytes", 0) > 0, cold
                     warm = await traced_scan()

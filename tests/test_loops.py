@@ -4,11 +4,15 @@ self-monitoring meta-ingest (metric_engine/meta.py)."""
 
 import asyncio
 import logging
+import threading
+import time
 
 import pytest
 
 from horaedb_tpu.common import ReadableDuration, cancel_and_wait
+from horaedb_tpu.common import loops as loops_mod
 from horaedb_tpu.common.loops import LoopRegistry, loops
+from horaedb_tpu.common.runtimes import Runtimes
 from horaedb_tpu.metric_engine import MetricEngine
 from horaedb_tpu.metric_engine.meta import MetaConfig, MetaIngest
 from horaedb_tpu.objstore import InstrumentedStore, MemoryObjectStore
@@ -273,6 +277,169 @@ class TestWatchdogOnRealLoops:
                 await e.close()
 
         run(go())
+
+
+def _role_cpu(role: str) -> float:
+    return registry.counter("process_thread_cpu_seconds_total").labels(
+        role=role).value
+
+
+def _burn_cpu(seconds: float) -> float:
+    """Spin until this thread has used `seconds` of CPU; what it used."""
+    c0 = time.thread_time()
+    while time.thread_time() - c0 < seconds:
+        pass
+    return time.thread_time() - c0
+
+
+def _flush_turns() -> None:
+    """The running loop's busy / select sums into their counters, as
+    the sampler's next tick would."""
+    asyncio.get_running_loop()._selector.select.__self__.flush()
+
+
+def _with_sampler(body):
+    """`body(reg)` on a loop whose registry runs the stall sampler (the
+    selector wrapped, the first tick past, so that the role account
+    holds what the threads used before it); then the registry's loops
+    are cancelled, as a closing loop cancels them."""
+    async def go():
+        reg = LoopRegistry()
+        reg.ensure_watchdog()
+        await asyncio.sleep(0.25)
+        try:
+            return await body(reg)
+        finally:
+            tasks = [h.task for h in reg.handles()]
+            for t in tasks:
+                t.cancel()
+            await asyncio.gather(*tasks, return_exceptions=True)
+
+    return run(go())
+
+
+class TestHostAccounts:
+    """The busy / select account of the loop's thread and the role
+    account of every thread's CPU (docs/observability.md, loop
+    registry)."""
+
+    def test_busy_and_select_add_up_to_the_loops_wall(self):
+        async def body(_reg):
+            busy = registry.counter("event_loop_busy_seconds_total")
+            idle = registry.counter("event_loop_select_seconds_total")
+            # readings taken right after a wake-up: the turn under way
+            # has booked nothing yet and is microseconds old
+            await asyncio.sleep(0.01)
+            _flush_turns()
+            b0, s0, t0 = busy.value, idle.value, time.perf_counter()
+            for _ in range(8):
+                await asyncio.sleep(0.04)
+                t = time.perf_counter()
+                while time.perf_counter() - t < 0.04:
+                    pass
+            await asyncio.sleep(0.01)
+            _flush_turns()
+            return (busy.value - b0, idle.value - s0,
+                    time.perf_counter() - t0)
+
+        busy_s, select_s, wall = _with_sampler(body)
+        assert abs(busy_s + select_s - wall) <= 0.02 * wall
+        assert 0.32 <= busy_s <= 0.32 + 0.25 * wall
+        assert select_s >= 0.30
+
+    def test_a_blocking_call_on_the_loop_is_busy_time_and_no_cpu(self):
+        async def body(_reg):
+            busy = registry.counter("event_loop_busy_seconds_total")
+            _flush_turns()
+            b0, c0 = busy.value, _role_cpu("loop")
+            time.sleep(0.4)  # a blocking call on the loop's own thread
+            await asyncio.sleep(0.25)  # a tick reads the clocks
+            _flush_turns()
+            return busy.value - b0, _role_cpu("loop") - c0
+
+        busy_s, loop_cpu = _with_sampler(body)
+        assert busy_s >= 0.4
+        assert loop_cpu < 0.1  # it stood still: busy - CPU names it
+
+    def test_the_wrap_is_undone_when_the_sampler_ends(self):
+        seen = {}
+
+        async def body(_reg):
+            sel = asyncio.get_running_loop()._selector
+            seen["sel"] = sel
+            return "select" in sel.__dict__
+
+        assert _with_sampler(body) is True
+        assert "select" not in seen["sel"].__dict__
+
+    def test_a_loop_without_a_selector_is_left_alone(self):
+        class Other:
+            pass
+
+        assert loops_mod._wrap_selector(Other()) is None
+
+    def test_a_burning_pool_job_moves_its_role_by_its_cpu(self):
+        async def body(_reg):
+            rt = Runtimes(sst_threads=1, compact_threads=1,
+                          manifest_threads=1)
+            try:
+                # a thread's CPU before its first reading is `other`'s
+                await rt.run("sst", _burn_cpu, 0.0)
+                await asyncio.sleep(0.25)
+                before = {r: _role_cpu(r) for r in ("sst", "compact")}
+                used = await rt.run("sst", _burn_cpu, 0.3)
+                await asyncio.sleep(0.25)  # a tick, the thread alive
+                return used, {r: _role_cpu(r) - v
+                              for r, v in before.items()}
+            finally:
+                rt.close()
+
+        used, moved = _with_sampler(body)
+        assert abs(moved["sst"] - used) <= 0.1 * used
+        assert moved["compact"] < 0.01
+
+    def test_the_roles_add_up_to_the_process_cpu(self):
+        async def body(_reg):
+            _burn_cpu(0.05)
+            await asyncio.sleep(0.15)
+            at_tick = (sum(_role_cpu(r) for r in loops_mod._ROLES),
+                       time.process_time())
+            loops_mod.sample_thread_cpu()
+            return at_tick, (sum(_role_cpu(r) for r in loops_mod._ROLES),
+                             time.process_time())
+
+        (tick_sum, tick_now), (read_sum, read_now) = _with_sampler(body)
+        # within one tick (0.1 s of every core the process keeps busy)
+        # of the process's clock; level with it right after a reading
+        assert 0.0 <= tick_now - tick_sum < 1.0
+        assert 0.0 <= read_now - read_sum < 0.02
+
+    def test_a_pool_shut_down_mid_run_breaks_nothing(self):
+        async def body(reg):
+            rt = Runtimes(sst_threads=2, compact_threads=1,
+                          manifest_threads=1)
+            ids = await asyncio.gather(
+                *(rt.run("sst", lambda: (time.sleep(0.05),
+                                         threading.get_native_id())[1])
+                  for _ in range(2)))
+            await asyncio.sleep(0.15)  # read while they live
+            assert set(ids) <= set(loops_mod._cpu_last)
+            rt.close()  # the threads die under the sampler
+            dead = None
+            for _ in range(100):  # the kernel reaps them in its time
+                dead = [loops_mod._thread_cpu_seconds(i) for i in ids]
+                if dead == [None] * len(ids):
+                    break
+                await asyncio.sleep(0.02)
+            await asyncio.sleep(0.25)
+            sampler, = [h for h in reg.handles()
+                        if h.name.startswith("stall-sampler")]
+            return dead, ids, sampler.alive(), sampler.consecutive_errors
+
+        dead, ids, alive, errors = _with_sampler(body)
+        assert dead == [None] * len(ids)  # EINVAL, nothing dereferenced
+        assert alive and errors == 0
+        assert not set(ids) & set(loops_mod._cpu_last)
 
 
 class TestOpTraces:

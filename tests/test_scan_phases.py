@@ -25,9 +25,10 @@ from horaedb_tpu.server.config import ServerConfig
 from horaedb_tpu.server.main import ServerState, build_app
 from horaedb_tpu.storage import read as read_mod
 from horaedb_tpu.storage.types import TimeRange
-from horaedb_tpu.utils import registry
+from horaedb_tpu.utils import registry, tracing
 from horaedb_tpu.utils.tracing import (
     SCAN_PHASES,
+    clear_phases,
     recorder,
     span,
     trace_scope,
@@ -73,10 +74,11 @@ def phase_counts(table: str) -> dict:
     return {p: fam.labels(phase=p, table=table).count for p in SCAN_PHASES}
 
 
-def sync_seams(fn: str) -> int:
-    """How often the sync seam of `fn` was passed: every pass observes
-    device_exec_seconds{fn} once, the wait it found or 0."""
-    return registry.family("device_exec_seconds").labels(fn=fn).count
+def sync_seams() -> int:
+    """How often a sync seam of the data table was passed: every pass
+    observes the `scan.device_wait` phase once, the wait it found or
+    0."""
+    return phase_counts("data")["scan.device_wait"]
 
 
 def assert_sync_seam_per_dispatch(seams: int, dispatches, waits):
@@ -85,9 +87,9 @@ def assert_sync_seam_per_dispatch(seams: int, dispatches, waits):
     one download).  By that seam's contract a dispatch still
     running at its finalize leaves a `scan.device_wait` span and one
     that has finished (XLA-CPU over a few hundred rows) an observation
-    of 0 (in device_exec_seconds and in the phase's histogram) and no
-    span: which of the two is the device's pace, that one
-    of them happened per dispatch is the route's."""
+    of 0 in the phase's histogram and no span: which of the two is the
+    device's pace, that one of them happened per dispatch is the
+    route's."""
     assert dispatches and seams == len(dispatches)
     assert len(waits) <= seams
     assert all(w["fields"]["fn"] == DECODE_FN for w in waits)
@@ -142,7 +144,7 @@ class TestPhaseSpans:
                     drop_slices(engine)
                 before = phase_counts("data")
                 index_before = phase_counts("index")
-                seams_before = sync_seams(DECODE_FN)
+                seams_before = sync_seams()
                 probes = resident_outcomes()
                 r = await client.post("/query",
                                       json=dict(body, start=start))
@@ -156,7 +158,7 @@ class TestPhaseSpans:
                 f"/debug/traces/{tid}")).json())["tree"]
             return tree, before, phase_counts("data"), index_before, \
                 phase_counts("index"), \
-                sync_seams(DECODE_FN) - seams_before, probes
+                sync_seams() - seams_before, probes
 
         tree, before, after, index_before, index_after, seams, probes = \
             run(served(go))
@@ -281,7 +283,10 @@ class TestPhaseSpans:
             assert disp["fields"]["h2d_bytes"] == 6 * 4 * 128 \
                 < 6 * 4 * stored
 
-    def test_close_clears_the_tables_phase_children(self):
+    def test_close_clears_the_tables_phase_children(self, monkeypatch):
+        for k, v in DECODE_ENV.items():
+            monkeypatch.setenv(k, v)
+
         async def go(client, _engine):
             r = await client.post("/query", json=QUERY)
             assert r.status == 200
@@ -290,9 +295,84 @@ class TestPhaseSpans:
         during = run(served(go))
         assert 'scan_phase_seconds_count{phase="scan.plan",table="data"}' \
             in during
+        assert ('scan_phase_cpu_seconds_total{phase="scan.dispatch",'
+                'table="data"}') in during
         assert 'table="data"' not in "".join(
             line for line in registry.render().splitlines()
-            if line.startswith("scan_phase_seconds"))
+            if line.startswith("scan_phase_"))
+
+    @pytest.mark.parametrize("route", sorted(ROUTES))
+    def test_spans_carry_cpu_where_no_await_can_be_inside(
+            self, route, monkeypatch):
+        """CPU beside wall under one rule: a span that declares itself
+        synchronous carries `cpu_ms` (at most its wall), whichever
+        thread runs it: every site of `scan.dispatch`, `scan.d2h`,
+        `scan.device_wait` (a pool thread here) and of `scan.combine`
+        and `respond` (the loop's).  No other span carries it: not one
+        held across an await (`downsample`, `resolve`, `parse`,
+        `scan.plan`, the root), nor `scan.windows` / `scan.group_prep`,
+        whose CPU nothing reads.  The phases' CPU also counts into
+        scan_phase_cpu_seconds_total, the encoder's into
+        respond_encode_cpu_seconds_total."""
+        env, _phases, _plan_route = ROUTES[route]
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        monkeypatch.setattr(tracing, "CPU_SAMPLE", 1.0)  # every one reads
+        cpu_fam = registry.family("scan_phase_cpu_seconds_total")
+        wall_fam = registry.family("scan_phase_seconds")
+        enc_cpu = registry.family("respond_encode_cpu_seconds_total")
+        enc_wall = registry.family("respond_encode_seconds_total")
+
+        def totals():
+            return ({p: (cpu_fam.labels(phase=p, table="data").value,
+                         wall_fam.labels(phase=p, table="data").sum)
+                     for p in SCAN_PHASES},
+                    (enc_cpu.value, enc_wall.value))
+
+        async def go(client, engine):
+            body = dict(QUERY, filters={"host": "h1"})
+            r = await client.post("/query", json=body)  # compiles
+            assert r.status == 200
+            if route == "device_decode":
+                drop_slices(engine)
+            before = totals()
+            r = await client.post("/query", json=dict(body, start=T0 + 9))
+            assert r.status == 200
+            after = totals()
+            tid = r.headers["X-Trace-Id"]
+            return (await (await client.get(
+                f"/debug/traces/{tid}")).json())["tree"], before, after
+
+        tree, (before, enc_before), (after, enc_after) = run(served(go))
+        spans = list(walk(tree))
+        by_name = {}
+        for c in spans:
+            by_name.setdefault(c["name"], []).append(c)
+        for c in spans:
+            if "cpu_ms" in c:
+                assert 0.0 <= c["cpu_ms"] <= c["duration_ms"] + 0.1, c
+        declared = {"scan.dispatch", "scan.device_wait", "scan.d2h",
+                    "scan.combine", "respond"} & set(by_name)
+        assert {"scan.dispatch", "scan.d2h", "respond"} <= declared
+        for name in by_name:
+            carried = ["cpu_ms" in c for c in by_name[name]]
+            assert all(carried) if name in declared \
+                else not any(carried), name
+        assert {"downsample", "resolve", "parse", "scan.plan",
+                "scan.windows", tree["name"]} <= set(by_name) - declared
+        for c in by_name.get("scan.device_wait", ()):
+            # the waiting thread stands still while the device runs
+            assert c["cpu_ms"] <= 0.2 * c["duration_ms"] + 0.5, c
+        # the counters moved by what the data table's spans carry
+        for p in SCAN_PHASES:
+            cpu = after[p][0] - before[p][0]
+            carried = sum(c["cpu_ms"] for c in by_name.get(p, ())
+                          if "cpu_ms" in c
+                          and c["fields"]["table"] == "data") / 1e3
+            assert cpu == pytest.approx(carried, abs=1e-4), p
+            assert cpu <= after[p][1] - before[p][1] + 1e-4, p
+        cpu, wall = (a - b for a, b in zip(enc_after, enc_before))
+        assert 0.0 < cpu <= wall + 1e-4
 
 
 class TestProfilerClock:
@@ -319,7 +399,7 @@ class TestProfilerClock:
                                      profiler_options=opts)
             try:
                 trace = recorder.start("profiled")
-                seams_before = sync_seams(DECODE_FN)
+                seams_before = sync_seams()
                 drop_slices(engine)  # every phase: read, narrow, upload
                 with trace_scope(trace):
                     # another range: the parts memo must not serve it
@@ -330,7 +410,7 @@ class TestProfilerClock:
                     await asyncio.gather(held("interleaved.a", 0.03),
                                          held("interleaved.b", 0.06))
                 return recorder.finish(trace), \
-                    sync_seams(DECODE_FN) - seams_before
+                    sync_seams() - seams_before
             finally:
                 jax.profiler.stop_trace()
 
@@ -398,11 +478,12 @@ class TestWaits:
         done = run(go())
         assert wait.count - n0 == 2 and resume.count - r0 == 2
         assert wait.sum - w0 >= 0.15  # the second job sat behind the first
-        assert done["counters"]["pool_manifest_wait_ms"] >= 150.0
-        assert done["counters"]["pool_manifest_run_ms"] >= 200.0
+        # one record a hop in the trace: the span, and no counter twin
+        assert not any(k.startswith("pool_") for k in done["counters"])
         hops = [s for s in done["spans"] if s["name"] == "pool_hop"]
         assert len(hops) == 2
         assert max(s["fields"]["wait_ms"] for s in hops) >= 150.0
+        assert max(s["fields"]["run_ms"] for s in hops) >= 200.0
         assert all(s["duration_ms"] >= s["fields"]["wait_ms"]
                    + s["fields"]["run_ms"] for s in hops)
 
@@ -489,12 +570,18 @@ class TestSeams:
         assert sizes[0][0] <= 2 and [q[0] for q in sizes[1:]] == [0, 0]
         assert sizes[0][1] == 0 and sum(q[1] for q in sizes) <= 1
 
-    def test_download_waits_in_a_span_only_where_something_runs(self):
+    def test_download_waits_in_a_span_only_where_something_runs(
+            self, monkeypatch):
         """`deviceprof.download`'s sync half: a computation still
         running at the download leaves one `scan.device_wait` span and
-        its wait in device_exec_seconds{fn}; one that has finished an
-        observation of 0 and no span; the `scan.d2h` span either way."""
+        its wait in the phase's histogram; one that has finished an
+        observation of 0 and no span; the `scan.d2h` span either way.
+        Both seams are synchronous and carry CPU beside wall: the wait
+        stands still (next to no CPU), the copy cannot exceed its
+        wall."""
         import jax.numpy as jnp
+
+        monkeypatch.setattr(tracing, "CPU_SAMPLE", 1.0)  # every one reads
 
         @jax.jit
         def slow(x):  # ~70 ms on XLA-CPU against ~0.1 ms to get here
@@ -503,24 +590,31 @@ class TestSeams:
 
         x = jnp.full((512, 512), 0.01, jnp.float32)
         jax.block_until_ready(slow(x))  # compiles
-        hist = registry.family("device_exec_seconds").labels(fn="slow")
+        hist = registry.family("scan_phase_seconds").labels(
+            phase="scan.device_wait", table="seam_t")
 
         def downloaded(y):
             n0, s0 = hist.count, hist.sum
             trace = recorder.start("seam")
             with trace_scope(trace):
                 deviceprof.download(y, fn="slow", table="seam_t")
-            names = [s["name"] for s in recorder.finish(trace)["spans"]]
-            return names, hist.count - n0, hist.sum - s0
+            spans = recorder.finish(trace)["spans"]
+            return spans, [s["name"] for s in spans], hist.count - n0, \
+                hist.sum - s0
 
         running = slow(x)
         assert not running.is_ready()
-        names, n, waited = downloaded(running)
+        spans, names, n, waited = downloaded(running)
         assert names.count("scan.device_wait") == 1 == n
         assert names.count("scan.d2h") == 1 and waited > 0.0
-        names, n, waited = downloaded(running)  # ready by now
+        wait, = [s for s in spans if s["name"] == "scan.device_wait"]
+        assert wait["fields"]["fn"] == "slow"
+        for s in spans[:-1]:  # the root carries none
+            assert 0.0 <= s["cpu_ms"] <= s["duration_ms"] + 0.1, s
+        spans, names, n, waited = downloaded(running)  # ready by now
         assert "scan.device_wait" not in names and n == 1
         assert names.count("scan.d2h") == 1 and waited == 0.0
+        clear_phases("seam_t")
 
     def test_fused_programs_compile_once_under_their_scopes(
             self, monkeypatch):

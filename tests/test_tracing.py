@@ -5,6 +5,7 @@ attribution, and the slow-query log."""
 import asyncio
 import json
 import logging
+import time
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -53,6 +54,37 @@ def _reset_recorder():
 
 
 class TestSpans:
+    def test_one_sync_span_in_four_reads_the_cpu_clock_and_counts_fourfold(
+            self, monkeypatch):
+        """A `sync` span draws whether it reads its thread's CPU clock
+        (the read is a system call); the counter takes a sampled span's
+        CPU over the sampling rate, an estimate of the CPU of them all.
+        A span that does not declare itself never reads it."""
+        draws = iter([0.1, 0.5, 0.9, 0.3] * 5)
+        monkeypatch.setattr(tracing, "_random", lambda: next(draws))
+        cpu = metrics_mod.registry.counter(
+            "test_sync_span_cpu_seconds_total", "sampled CPU of a test")
+        trace = recorder.start("cpu_sampling")
+        with trace_scope(trace):
+            for _ in range(20):
+                with span("burn", sync=True, cpu=cpu):
+                    t0 = time.thread_time()
+                    while time.thread_time() - t0 < 0.002:
+                        pass
+            with span("undeclared", cpu=cpu):
+                pass
+        spans = recorder.finish(trace)["spans"]
+        burns = [s for s in spans if s["name"] == "burn"]
+        read = [s for s in burns if "cpu_ms" in s]
+        assert len(burns) == 20 and len(read) == 5
+        assert all(2.0 <= s["cpu_ms"] <= s["duration_ms"] + 0.1
+                   for s in read)
+        assert cpu.value == pytest.approx(
+            sum(s["cpu_ms"] for s in read) / 1e3 / tracing.CPU_SAMPLE,
+            abs=1e-4)
+        undeclared, = [s for s in spans if s["name"] == "undeclared"]
+        assert "cpu_ms" not in undeclared
+
     def test_span_tree_records_nesting_fields_and_status(self):
         trace = recorder.start("root_op")
         with trace_scope(trace):

@@ -157,18 +157,22 @@ async def device_main() -> int:
         snap = deviceprof.profiler.snapshot()
         print("== compile ledger (cold mesh-decode + warm repeat) ==")
         hdr = (f"{'fn':<34s} {'comp':>4s} {'comp_ms':>8s} "
-               f"{'disp':>4s} {'disp_ms':>8s} {'exec':>4s} "
-               f"{'exec_ms':>8s}")
+               f"{'disp':>4s} {'disp_ms':>8s}")
         print(hdr)
         for rec in snap["fns"]:
-            if not (rec["compiles"] or rec["dispatches"] or rec["execs"]):
+            if not (rec["compiles"] or rec["dispatches"]):
                 continue
             print(f"{rec['fn']:<34.34s} {rec['compiles']:>4d} "
                   f"{rec['compile_seconds'] * 1e3:>8.1f} "
                   f"{rec['dispatches']:>4d} "
-                  f"{rec['dispatch_seconds'] * 1e3:>8.1f} "
-                  f"{rec['execs']:>4d} "
-                  f"{rec['exec_seconds'] * 1e3:>8.1f}")
+                  f"{rec['dispatch_seconds'] * 1e3:>8.1f}")
+        print("\n== waits for the device (scan.device_wait spans) ==")
+        for label, tr in (("cold", cold), ("warm", warm)):
+            for sp in tr.spans:
+                if sp["name"] == "scan.device_wait":
+                    print(f"  {label}: {sp['fields'].get('fn', ''):<30s} "
+                          f"{sp['duration_ms']:>8.1f} ms wall "
+                          f"{sp.get('cpu_ms', 0.0):>8.1f} ms cpu")
         print("\n== transfers ==")
         for d, t in snap["transfer"].items():
             print(f"  {d}: {t['bytes']:>10d} B in {t['count']:>3d} "
