@@ -485,6 +485,8 @@ class MetricEngine:
         # self-monitoring meta-ingest (metric_engine/meta.py); populated
         # by open() when a [meta] config enables it
         self.meta = None
+        # the worker pools the five tables share; open() hands them over
+        self._runtimes = None
         # chunked layout: the Append-mode data table bypasses the
         # reader's scan cache (host merge, uncached), so decoded sample
         # arrays get their own byte-budgeted LRU — keyed by (predicate,
@@ -670,8 +672,15 @@ class MetricEngine:
             self._chunk_cache.clear()
             memledger.deregister(self._chunk_mem_account)
             self._chunk_mem_account = None
-        if getattr(self, "_runtimes", None) is not None:
+        if self._runtimes is not None:
             self._runtimes.close()
+
+    @property
+    def runtimes(self):
+        """The named pools (common/runtimes.py) this engine's tables
+        share: the HTTP front end writes a large downsample answer on
+        `sst`, beside the scans' jobs."""
+        return self._runtimes
 
     async def stats(self) -> dict:
         """Data volume actually stored (rows/bytes per table, from the

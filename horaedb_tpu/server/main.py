@@ -112,8 +112,16 @@ _RESPOND_ENCODE_SECONDS = registry.counter(
     "download of device grids excluded)")
 _RESPOND_ENCODE_CPU = registry.counter(
     "respond_encode_cpu_seconds_total",
-    "CPU seconds of the loop's thread inside the downsample response "
+    "CPU seconds of the encoding thread (a pool thread for a large "
+    "answer, the loop's for a small one) inside the downsample response "
     "encoder: under its wall where the encoder waited for the GIL")
+_RESPOND_ENCODE_TOTAL = registry.counter(
+    "respond_encode_total",
+    "downsample responses written, by the thread that encoded them: a "
+    "worker of the `sst` pool from _RESPOND_POOL_MIN_CELLS cells up, "
+    "the event loop's own thread under it")
+_RESPOND_ENCODE = {where: _RESPOND_ENCODE_TOTAL.labels(where=where)
+                   for where in ("pool", "loop")}
 
 
 class _ServiceRate:
@@ -993,6 +1001,27 @@ def build_app(state: ServerState) -> web.Application:
                                                   bucket_ms, field=field)
         return out, None
 
+    async def _respond(outs, answer) -> web.Response:
+        """The `respond` step of /query (with bucket_ms), /query_topk
+        and /query_multi: `answer()` builds the body of the engine's
+        results `outs` (_downsample_json and what the endpoint adds),
+        _downsample_payload writes it.  From _RESPOND_POOL_MIN_CELLS
+        cells up both run as ONE job on the `sst` pool and the loop's
+        thread keeps the hop and the Response around the bytes; a
+        smaller answer, or one of an engine with no pools of its own
+        (a Cluster front), is written here on the loop's thread."""
+        cells = sum(g.size for out in outs for g in out["aggs"].values())
+        runtimes = getattr(state.engine, "runtimes", None)
+        if cells < _RESPOND_POOL_MIN_CELLS or runtimes is None:
+            with span("respond", sync=True):
+                payload = _downsample_payload(answer(), "loop")
+        else:
+            with span("respond"):
+                payload = await runtimes.run("sst", _payload_on_pool,
+                                             answer)
+        return web.Response(body=payload, content_type="application/json",
+                            charset="utf-8")
+
     @routes.get("/")
     async def hello(_req: web.Request) -> web.Response:
         return web.Response(text="Hello, horaedb-tpu!")
@@ -1399,12 +1428,14 @@ def build_app(state: ServerState) -> web.Application:
             if bucket_ms:
                 out, meta = await _engine_downsample(metric, filters, rng,
                                                      bucket_ms, field)
-                with span("respond", sync=True):
+
+                def answer() -> dict:
                     body_out = _downsample_json(out)
                     if impl is not None and out["tsids"]:
                         body_out["aggs"][fn] = impl(out["aggs"], bucket_ms)
-                    return _downsample_response(
-                        _attach_partial(body_out, meta))
+                    return _attach_partial(body_out, meta)
+
+                return await _respond([out], answer)
             tbl, meta = await _engine_query(metric, filters, rng, field)
             return web.json_response(_attach_partial({
                 "tsids": [str(t) for t in tbl.column("tsid").to_pylist()],
@@ -1438,8 +1469,7 @@ def build_app(state: ServerState) -> web.Application:
                 largest=largest, field=field)
         except Error as e:
             return _error_response(e)
-        with span("respond", sync=True):
-            return _downsample_response(_downsample_json(out))
+        return await _respond([out], lambda: _downsample_json(out))
 
     @routes.post("/query_multi")
     async def query_multi(req: web.Request) -> web.Response:
@@ -1465,9 +1495,9 @@ def build_app(state: ServerState) -> web.Application:
                 metric, filters, rng, bucket_ms, fields=fields)
         except Error as e:
             return _error_response(e)
-        with span("respond", sync=True):
-            return _downsample_response(
-                {f: _downsample_json(out) for f, out in outs.items()})
+        return await _respond(
+            outs.values(),
+            lambda: {f: _downsample_json(out) for f, out in outs.items()})
 
     @routes.post("/query_arrow")
     async def query_arrow(req: web.Request) -> web.Response:
@@ -1672,7 +1702,7 @@ def _downsample_json(out: dict) -> dict:
     """THE wire shape of a downsample result, shared by /query,
     /query_topk and /query_multi so the endpoints cannot drift.  Each
     grid is a _Grid, the response's own copy of the cells widened to
-    the doubles a client parses; _downsample_response writes them."""
+    the doubles a client parses; _downsample_payload writes them."""
     aggs = out["aggs"]
     # the fused route hands back device grids: their lazy download is
     # here, the sync split from the copy (a scan.d2h span under
@@ -1756,10 +1786,26 @@ def _json_text(node, grid_texts) -> str:
     return json.dumps(node)
 
 
-def _downsample_response(body: dict) -> web.Response:
-    """The /query* response of a _downsample_json body (or of
-    /query_multi's {field: body}): json.dumps' text for everything but
-    the grids, which _grids_text writes in one pass over all of them."""
+# An answer of at least this many cells (the grids the engine handed
+# over) is built and written on a worker of the `sst` pool, off the
+# event loop's own thread.  The pass costs the writing thread 0.18-0.24
+# us a cell, nearly all of it pyarrow kernels that run without the GIL;
+# the hop costs the waiter a contended hand-over back to the loop, 5.7
+# ms of pool resume in s100_double_groupby (PERF.md §5): the two cross
+# near 25,000-30,000 cells.  Measured on both sides (PERF.md §6): with
+# every answer on the pool those of 8,400 cells read 12 % fewer queries
+# a second (PR 38), those of 84,000 cells 52-55 % more (PR 40).
+_RESPOND_POOL_MIN_CELLS = 32768
+
+
+def _downsample_payload(body: dict, where: str) -> bytes:
+    """The bytes of the /query* response of a _downsample_json body (or
+    of /query_multi's {field: body}): json.dumps' text for everything
+    but the grids, which _grids_text writes in one pass over all of
+    them.  `where` names the thread it runs on, "pool" or "loop"; the
+    counters take the encoder's own wall and that thread's CPU, all
+    once the bytes are there: a request cancelled meanwhile counts
+    whole or not at all."""
     t0, cpu0 = time.perf_counter(), time.thread_time()
     grids = _grids_of(body)
     payload = _json_text(body, iter(_grids_text(grids))).encode()
@@ -1768,8 +1814,15 @@ def _downsample_response(body: dict) -> web.Response:
     cpu = time.thread_time() - cpu0
     _RESPOND_ENCODE_SECONDS.inc(time.perf_counter() - t0)
     _RESPOND_ENCODE_CPU.inc(cpu)
-    return web.Response(body=payload, content_type="application/json",
-                        charset="utf-8")
+    _RESPOND_ENCODE[where].inc()
+    return payload
+
+
+def _payload_on_pool(answer) -> bytes:
+    """_respond's job on a pool thread.  `respond.encode` is the `sync`
+    span that carries the CPU `respond` carries on the loop's thread."""
+    with span("respond.encode", sync=True):
+        return _downsample_payload(answer(), "pool")
 
 
 def _build_store(config: ServerConfig):

@@ -21,6 +21,7 @@ from horaedb_tpu.common.runtimes import Runtimes
 from horaedb_tpu.metric_engine import MetricEngine
 from horaedb_tpu.objstore import InstrumentedStore, MemoryObjectStore
 from horaedb_tpu.ops import device_decode
+from horaedb_tpu.server import main as server_main
 from horaedb_tpu.server.config import ServerConfig
 from horaedb_tpu.server.main import ServerState, build_app
 from horaedb_tpu.storage import read as read_mod
@@ -301,9 +302,10 @@ class TestPhaseSpans:
             line for line in registry.render().splitlines()
             if line.startswith("scan_phase_"))
 
+    @pytest.mark.parametrize("respond_on", ["loop", "pool"])
     @pytest.mark.parametrize("route", sorted(ROUTES))
     def test_spans_carry_cpu_where_no_await_can_be_inside(
-            self, route, monkeypatch):
+            self, route, respond_on, monkeypatch):
         """CPU beside wall under one rule: a span that declares itself
         synchronous carries `cpu_ms` (at most its wall), whichever
         thread runs it: every site of `scan.dispatch`, `scan.d2h`,
@@ -313,11 +315,17 @@ class TestPhaseSpans:
         `scan.plan`, the root), nor `scan.windows` / `scan.group_prep`,
         whose CPU nothing reads.  The phases' CPU also counts into
         scan_phase_cpu_seconds_total, the encoder's into
-        respond_encode_cpu_seconds_total."""
+        respond_encode_cpu_seconds_total.  `respond` is a child of the
+        root wherever the answer is written; one written on the pool
+        is awaited, so there `respond` carries no CPU and its child
+        `respond.encode`, the job on the pool thread, does."""
         env, _phases, _plan_route = ROUTES[route]
         for k, v in env.items():
             monkeypatch.setenv(k, v)
         monkeypatch.setattr(tracing, "CPU_SAMPLE", 1.0)  # every one reads
+        monkeypatch.setattr(server_main, "_RESPOND_POOL_MIN_CELLS",
+                            0 if respond_on == "pool" else 2 ** 62)
+        encoder = "respond" if respond_on == "loop" else "respond.encode"
         cpu_fam = registry.family("scan_phase_cpu_seconds_total")
         wall_fam = registry.family("scan_phase_seconds")
         enc_cpu = registry.family("respond_encode_cpu_seconds_total")
@@ -352,8 +360,16 @@ class TestPhaseSpans:
             if "cpu_ms" in c:
                 assert 0.0 <= c["cpu_ms"] <= c["duration_ms"] + 0.1, c
         declared = {"scan.dispatch", "scan.device_wait", "scan.d2h",
-                    "scan.combine", "respond"} & set(by_name)
-        assert {"scan.dispatch", "scan.d2h", "respond"} <= declared
+                    "scan.combine", encoder} & set(by_name)
+        assert {"scan.dispatch", "scan.d2h", encoder} <= declared
+        respond, = by_name["respond"]
+        assert respond in tree["children"]
+        if respond_on == "pool":
+            job, = by_name["respond.encode"]
+            assert job in respond["children"]
+            assert job["duration_ms"] <= respond["duration_ms"]
+        else:
+            assert "respond.encode" not in by_name
         for name in by_name:
             carried = ["cpu_ms" in c for c in by_name[name]]
             assert all(carried) if name in declared \

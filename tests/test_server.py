@@ -8,9 +8,11 @@ from aiohttp.test_utils import TestClient, TestServer
 
 from horaedb_tpu.metric_engine import MetricEngine
 from horaedb_tpu.objstore import MemoryObjectStore
+from horaedb_tpu.server import main as server_main
 from horaedb_tpu.server.config import ServerConfig, load_config
 from horaedb_tpu.server.main import ServerState, build_app
 from horaedb_tpu.common import Error
+from horaedb_tpu.utils import registry
 
 T0 = 1_700_000_000_000
 HOUR = 3_600_000
@@ -27,6 +29,21 @@ async def make_client():
 
 def run(coro):
     return asyncio.run(coro)
+
+
+@pytest.fixture(params=["loop", "pool"])
+def respond_on(request, monkeypatch):
+    """A downsample answer written on the event loop's own thread (as
+    every answer under _RESPOND_POOL_MIN_CELLS cells is) and on a
+    thread of the `sst` pool (as the larger ones are)."""
+    monkeypatch.setattr(server_main, "_RESPOND_POOL_MIN_CELLS",
+                        2 ** 62 if request.param == "loop" else 0)
+    written = registry.family("respond_encode_total")
+    before = {w: written.labels(where=w).value for w in ("loop", "pool")}
+    yield request.param
+    moved = {w: written.labels(where=w).value - before[w] for w in before}
+    other = "pool" if request.param == "loop" else "loop"
+    assert moved[request.param] >= 1 and moved[other] == 0
 
 
 class TestEndpoints:
@@ -108,7 +125,7 @@ class TestEndpoints:
 
         run(go())
 
-    def test_downsample_query(self):
+    def test_downsample_query(self, respond_on):
         async def go():
             client, _state, engine = await make_client()
             try:
@@ -132,7 +149,7 @@ class TestEndpoints:
 
         run(go())
 
-    def test_query_topk(self):
+    def test_query_topk(self, respond_on):
         async def go():
             client, _state, engine = await make_client()
             try:
@@ -162,7 +179,7 @@ class TestEndpoints:
 
         run(go())
 
-    def test_query_multi_field(self):
+    def test_query_multi_field(self, respond_on):
         async def go():
             client, _state, engine = await make_client()
             try:
@@ -341,7 +358,7 @@ class TestArrowIpcIngest:
 
 
 class TestRangeFunctionEndpoint:
-    def test_rate_over_http(self):
+    def test_rate_over_http(self, respond_on):
         async def go():
             client, _state, engine = await make_client()
             try:
