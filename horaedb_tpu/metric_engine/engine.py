@@ -29,6 +29,7 @@ import asyncio
 import time
 from typing import Optional
 
+import numpy as np
 import pyarrow as pa
 
 from horaedb_tpu.common.error import Error, ensure
@@ -36,8 +37,10 @@ from horaedb_tpu.common.memledger import ledger as memledger
 from horaedb_tpu.objstore import ObjectStore
 from horaedb_tpu.ops import And, Eq, In, TimeRangePred
 from horaedb_tpu.ops.downsample import ALL_AGGS
+from horaedb_tpu.ops.select import SelectSpec, compare
 from horaedb_tpu.storage.config import StorageConfig
-from horaedb_tpu.storage.read import AggregateSpec, ScanRequest
+from horaedb_tpu.storage.read import (AggregateSpec, ScanRequest,
+                                      join_on_host)
 from horaedb_tpu.storage.storage import CloudObjectStorage, WriteRequest
 from horaedb_tpu.storage.types import TimeRange, Timestamp
 from horaedb_tpu.utils import registry, span
@@ -171,6 +174,7 @@ class MetricManager:
         self.segment_ms = segment_ms
         self._seen = _SegmentSeen()
         self._resolve_cache: dict[str, tuple[int, float]] = {}
+        self._known_fields: dict[str, frozenset] = {}
 
     async def populate_metric_ids(self, samples: list[Sample]) -> None:
         by_seg: dict[int, dict] = {}
@@ -235,6 +239,26 @@ class MetricManager:
             col = b.column(b.schema.names.index("metric_name"))
             names.update(col.to_pylist())
         return sorted(names)
+
+    async def unknown_fields(self, metric_name: str, fields: list[str],
+                             time_range: TimeRange) -> list[str]:
+        """Those of `fields` that `metric_name` has no registration
+        for, in any segment.  A registration is never withdrawn, so
+        names once seen are remembered for good; a name not among
+        them is looked up, in the window first and then over the whole
+        table (only a name no write ever registered pays that, each
+        time: a first write shows at once)."""
+        known = self._known_fields.get(metric_name, frozenset())
+        for rng in (time_range,
+                    TimeRange.new(int(Timestamp.MIN), int(Timestamp.MAX))):
+            if set(fields) <= known:
+                break
+            known = known | frozenset(
+                await self.list_fields(metric_name, rng))
+            if len(self._known_fields) > 1024:
+                self._known_fields.clear()
+            self._known_fields[metric_name] = known
+        return [f for f in fields if f not in known]
 
     async def list_fields(self, metric_name: str,
                           time_range: TimeRange) -> list[str]:
@@ -447,6 +471,14 @@ _MULTI_SCAN_SECONDS = registry.counter(
     "query_multi_scan_seconds_total",
     "wall seconds inside the per-field scans of multi-field downsample "
     "queries")
+
+_ROWS_QUERIES = registry.counter(
+    "query_rows_total",
+    "row selections under a value predicate (query_rows_where)")
+_ROWS_SELECT_SECONDS = registry.counter(
+    "query_rows_select_seconds_total",
+    "wall seconds inside the select of row selections (plan, the "
+    "device's or the host's route over every segment, the combine)")
 
 _CHUNK_CACHE_HITS = registry.counter(
     "chunk_decode_cache_hits_total",
@@ -1421,6 +1453,105 @@ class MetricEngine:
         _MULTI_FIELDS.inc(len(remaining))
         _MULTI_SCAN_SECONDS.inc(time.perf_counter() - t0)
         return out
+
+    async def query_rows_where(self, metric: str,
+                               filters: list[tuple[str, str]],
+                               time_range: TimeRange, where_field: str,
+                               op: str, value: float,
+                               fields: list[str]) -> pa.Table:
+        """Every reading of `where_field` in range whose CURRENT value
+        (after last-write-wins dedup: what query() returns for it)
+        satisfies `op` (gt, ge, lt, le) against `value`, compared as
+        float32, with the values of `fields` at the same (series,
+        timestamp): TSBS's high-cpu-* shape, a predicate on the VALUE
+        answered as rows.  An Arrow table (tsid uint64, timestamp
+        int64, one nullable float32 column a field, in the order
+        asked), sorted by (tsid, timestamp); a field without a sample
+        at a selected key is null there; nothing approximate or cut.
+
+        In this data model (one stored row a sample a FIELD) that is a
+        scan of the predicate's field and a join of the others, run
+        with ONE shared resolve: on the device over the resident
+        decode slices, or by the host decode route (storage/read.py::
+        select_segments decides per segment, and counts).  A chunked
+        table scans each field by query() and joins on the host.
+
+        Traced as children of the request's root: one `resolve` span,
+        one `select` span (its children a segment: route=, rows_in=,
+        rows_out=).  Raises Error (a 400) for a field the metric does
+        not have in the range, before any scan."""
+        ensure(len(fields) > 0, "fields must be non-empty")
+        ensure(len(set(fields)) == len(fields), "fields must be distinct")
+        ensure(not {"tsid", "timestamp"} & set(fields),
+               "a field may not be named tsid or timestamp")
+        spec = SelectSpec(group_col="tsid", ts_col="timestamp",
+                          value_col="value", op=op, threshold=value)
+        _ROWS_QUERIES.inc()
+        distinct = [where_field] + [f for f in fields if f != where_field]
+        with span("resolve", metric=metric):
+            unknown = await self.metric_manager.unknown_fields(
+                metric, distinct, time_range)
+            ensure(not unknown,
+                   f"unknown field(s) {unknown} of metric {metric!r} in "
+                   f"the range")
+            parts = await self._data_pred_parts(metric, filters,
+                                                time_range)
+        t0 = time.perf_counter()
+        with span("select", metric=metric, fields=len(fields)):
+            if parts is None:
+                out = {"groups": [], "timestamps": [],
+                       "values": [[] for _ in fields],
+                       "found": [[] for _ in fields]}
+            elif self.chunked_data:
+                out = await self._rows_where_chunked(
+                    metric, filters, time_range, spec, where_field, fields)
+            else:
+                reqs = [ScanRequest(range=time_range, predicate=And(
+                    [parts[0], Eq("field_id", field_id_of(f))] + parts[1:]))
+                    for f in distinct]
+                qp = await self.tables["data"].plan_select(
+                    reqs, spec, [distinct.index(f) for f in fields])
+                out = await self.tables["data"].execute_plan(qp)
+        _ROWS_SELECT_SECONDS.inc(time.perf_counter() - t0)
+        return pa.table(
+            [pa.array(out["groups"], type=pa.uint64()),
+             pa.array(out["timestamps"], type=pa.int64())]
+            + [pa.array(v, type=pa.float32(),
+                        mask=None if f is None or not len(v)
+                        else ~np.asarray(f))
+               for v, f in zip(out["values"], out["found"])],
+            names=["tsid", "timestamp"] + list(fields))
+
+    async def _rows_where_chunked(self, metric, filters, time_range,
+                                  spec: SelectSpec, where_field: str,
+                                  fields: list[str]) -> dict:
+        """query_rows_where over a chunked table: the predicate's field
+        by query() (chunks decoded, deduplicated), its float32 values
+        put to the predicate, every other field by query() and joined
+        on the host."""
+        def columns(tbl: pa.Table):
+            return (tbl.column("tsid").to_numpy(),
+                    tbl.column("timestamp").to_numpy(),
+                    tbl.column("value").to_numpy().astype(np.float32))
+
+        groups, ts, vals = columns(await self.query(
+            metric, filters, time_range, field=where_field))
+        order = np.flatnonzero(
+            compare(vals, spec.op, np.float32(spec.threshold)))
+        order = order[np.lexsort((ts[order], groups[order]))]
+        groups, ts, vals = groups[order], ts[order], vals[order]
+        values, found = [], []
+        for f in fields:
+            if f == where_field:
+                values.append(vals)
+                found.append(np.ones(len(ts), bool))
+                continue
+            v, ok = join_on_host(groups, ts, *columns(await self.query(
+                metric, filters, time_range, field=f)))
+            values.append(v)
+            found.append(ok)
+        return {"groups": groups, "timestamps": ts, "values": values,
+                "found": found}
 
     async def _downsample_chunked(self, metric: str, filters, time_range,
                                   bucket_ms: int, num_buckets: int,

@@ -202,7 +202,8 @@ _BATCH_SLICES = {
         "scan_decode_batch_slices_total",
         "slices of device-decode plans by how they reached the device: "
         "batched = inside one program call with the plan's other "
-        "resident slices (execute_batch); single = by a call of their "
+        "resident slices (execute_batch; the row-selecting route's "
+        "calls, ops/select.py); single = by a call of their "
         "own (execute_plan: a miss, a group of one, a mesh round's "
         "declined plan)"
     ).labels(mode=mode)
@@ -210,8 +211,16 @@ _BATCH_SLICES = {
 }
 _BATCH_CALLS = registry.counter(
     "scan_decode_batch_total",
-    "calls of the batched fused decode program (two or more resident "
-    "slices of one plan a call)")
+    "calls of the batched programs (the fused decode's, two or more "
+    "resident slices of one plan a call; the row-selecting route's, a "
+    "call a field)")
+
+
+def note_batched(slices: int, calls: int) -> None:
+    """Resident slices that reached the device in batched calls of
+    another route's programs (ops/select.py)."""
+    _BATCH_SLICES["batched"].inc(slices)
+    _BATCH_CALLS.inc(calls)
 
 
 def note_resident(outcome: str, n: int = 1) -> None:
@@ -508,16 +517,16 @@ def _cells_sorted(es, route: str, pk_names: list, group_col: str,
         for nm in pk_names[:ti] if nm != group_col)
 
 
-def decode_rows_core(cols: tuple, n_valid, leaf_consts: tuple,
+def rows_sorted_kept(cols: tuple, n_valid, leaf_consts: tuple,
                      run_offsets, *, key_slots: tuple, num_pks: int,
-                     group_pos: int, val_slot: int, leaf_prog: tuple,
-                     route: str, num_runs: int):
-    """The traced decode→filter→merge→dedup body, shared by the single
-    -device fused dispatch below and the mesh's per-slot program
-    (parallel/scan.mesh_decode_partials).  Returns (keys_s, gid,
-    val_s, n_rows): rows in (pk, seq)-sorted order with dropped rows
-    masked to gid = -1 — the exact shape window_local_partials expects
-    (ts rides in keys_s[ts_pos]).
+                     val_slot: int, leaf_prog: tuple, route: str,
+                     num_runs: int):
+    """The traced decode→filter→merge→dedup body, shared by the fused
+    aggregate (decode_rows_core) and the row-selecting programs
+    (ops/select.py).  Returns (valid_s, keys_s, val_s, kept): rows in
+    (pk, seq)-sorted order, `valid_s` the rows that are real and pass
+    the leaves, `kept` those of them that are the last of their PK run
+    (the row a read returns).
 
     `route` picks how rows reach sorted order:
       presorted — they already are (host-checked / single run);
@@ -551,7 +560,24 @@ def decode_rows_core(cols: tuple, n_valid, leaf_consts: tuple,
             differs_next = differs_next | (c[:-1] != c[1:])
         kept = valid_s & jnp.concatenate(
             [differs_next | ~valid_s[1:], jnp.ones(1, dtype=bool)])
+    return valid_s, keys_s, val_s, kept
 
+
+def decode_rows_core(cols: tuple, n_valid, leaf_consts: tuple,
+                     run_offsets, *, key_slots: tuple, num_pks: int,
+                     group_pos: int, val_slot: int, leaf_prog: tuple,
+                     route: str, num_runs: int):
+    """rows_sorted_kept as the aggregate takes it, for the single
+    -device fused dispatch below and the mesh's per-slot program
+    (parallel/scan.mesh_decode_partials).  Returns (keys_s, gid,
+    val_s, n_rows): rows in (pk, seq)-sorted order with dropped rows
+    masked to gid = -1 — the exact shape window_local_partials expects
+    (ts rides in keys_s[ts_pos])."""
+    _valid_s, keys_s, val_s, kept = rows_sorted_kept(
+        cols, n_valid, leaf_consts, run_offsets, key_slots=key_slots,
+        num_pks=num_pks, val_slot=val_slot, leaf_prog=leaf_prog,
+        route=route, num_runs=num_runs)
+    with jax.named_scope("dedup"):
         gid = jnp.where(kept, keys_s[group_pos], jnp.int32(-1))
         n_rows = jnp.sum(kept.astype(jnp.int32))
     return keys_s, gid, val_s, n_rows
@@ -674,6 +700,49 @@ def _decode_aggregate_jit(cols: tuple, n_valid, leaf_consts: tuple,
 _LANES = 128  # a tile's width on the device; every capacity's divisor
 
 
+def stack_slices(cols: tuple, key_consts: tuple, run_offsets: tuple):
+    """Several slices' device arrays stacked inside a program, as the
+    batched programs take them (one entry a slice, as SegmentSlice
+    keeps them on the device): the columns, the key leaves' constants
+    and the run bounds."""
+    # a column is stacked as [slices, cap / 128, 128]: a slice of the
+    # stack is then whole tiles, cut out as it lies; as [slices, cap]
+    # eight slices share every tile and each cut reads them all
+    stacked = tuple(jnp.stack([x.reshape(-1, _LANES) for x in c])
+                    for c in zip(*cols))
+    keyed_stacked = tuple(jnp.stack(c) for c in zip(*key_consts))
+    return stacked, keyed_stacked, jnp.stack(run_offsets)
+
+
+def slice_columns(stacked: tuple, i) -> tuple:
+    """Slice `i` of stack_slices' columns, each flat again."""
+    return tuple(c[i].reshape(-1) for c in stacked)
+
+
+def slice_consts(leaf_prog: tuple, keyed_stacked: tuple, row, i,
+                 at: int) -> tuple:
+    """Slice `i`'s leaf constants in leaf order: a key leaf's from the
+    slice's own device constants, any other leaf's from `row` (the
+    call's host numbers of this slice) from position `at` on."""
+    keyed = iter(keyed_stacked)
+    consts = []
+    for _slot, op in leaf_prog:
+        if op in (_OP_EQ, _OP_IN):
+            consts.append(next(keyed)[i])
+        else:  # compile_leaves: a range is two numbers, an edge one
+            width = 2 if op == _OP_RANGE else 1
+            consts.append(row[at:at + width])
+            at += width
+    return tuple(consts)
+
+
+def window_numbers(leaf_prog: tuple, consts: tuple) -> list:
+    """The host side of slice_consts: the constants of the leaves that
+    are not key leaves, in leaf order."""
+    return [c for (_slot, op), c in zip(leaf_prog, consts)
+            if op not in (_OP_EQ, _OP_IN)]
+
+
 @deviceprof.jit(name="_decode_aggregate_jit",
                 static_argnames=_PROGRAM_STATICS)
 def _decode_batch_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
@@ -694,32 +763,16 @@ def _decode_batch_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
     traced number of LIVE slices, so the filler that rounds the slices
     up to a power of two (execute_batch) is stacked and never run.
     Returns ({grid: [slices, g_pad, width]}, kept_rows[slices])."""
-    leaf_prog = static["leaf_prog"]
-    # a column is stacked as [slices, cap / 128, 128]: a slice of the
-    # stack is then whole tiles, cut out as it lies; as [slices, cap]
-    # eight slices share every tile and each cut reads them all
-    cap = cols[0][0].shape[0]
-    stacked = tuple(jnp.stack([x.reshape(-1, _LANES) for x in c])
-                    for c in zip(*cols))
-    keyed_stacked = tuple(jnp.stack(c) for c in zip(*key_consts))
-    offs = jnp.stack(run_offsets)
+    stacked, keyed_stacked, offs = stack_slices(cols, key_consts,
+                                                run_offsets)
     live, total, bucket_ms = nums[0, 0], nums[0, 1], nums[0, 2]
 
     def one(i):
         row = nums[1 + i]
-        keyed = iter(keyed_stacked)
-        consts, at = [], 3
-        for _slot, op in leaf_prog:
-            if op in (_OP_EQ, _OP_IN):
-                consts.append(next(keyed)[i])
-            else:  # compile_leaves: a range is two numbers, an edge one
-                width = 2 if op == _OP_RANGE else 1
-                consts.append(row[at:at + width])
-                at += width
         return decode_partials(
-            tuple(c[i].reshape(cap) for c in stacked), row[0],
-            tuple(consts), offs[i],
-            row[1], row[2], total, bucket_ms, **static)
+            slice_columns(stacked, i), row[0],
+            slice_consts(static["leaf_prog"], keyed_stacked, row, i, 3),
+            offs[i], row[1], row[2], total, bucket_ms, **static)
 
     def step(i, acc):
         return jax.tree_util.tree_map(lambda a, o: a.at[i].set(o),
@@ -1137,19 +1190,14 @@ def plan_segment(es, group_col: str, ts_col: str, value_col: str,
         num_runs=num_runs, cells_sorted=cells_sorted)
 
 
-def plan_window(seg: SegmentSlice, spec, leaves,
-                width: int) -> "DecodePlan | DevicePart | str":
-    """The half of plan_dispatch that a query's window decides, from
-    the slice and the AggregateSpec alone (no host column is touched,
-    so it serves a slice the scan cache held as it serves a fresh
-    one): the shift to range-relative time, the first bucket, the grid
-    width, and the constants of every leaf that is not a key leaf.
-    Counts the dispatch (rows, route) once the plan stands."""
-    shift = seg.ts_epoch - spec.range_start
-    if abs(shift) >= 2**31:
-        return "range"
-    # every leaf in its own place: leaf_prog is a static argument of
-    # the program, so the order is part of which program runs
+def window_leaves(seg: SegmentSlice,
+                  leaves) -> "tuple | DevicePart | str":
+    """Every leaf of a window's conjunction against a slice, each in
+    its own place (leaf_prog is a static argument of the programs, so
+    the order is part of which program runs): the key leaves as the
+    slice compiled them, the others compiled now.  Returns (((upload
+    slot, opcode), ...), (host int32 constants, ...)), a DevicePart
+    where a leaf provably matches nothing, or "predicate"."""
     prog: list = []
     consts: list = []
     keyed = iter(zip(seg.key_prog, seg.key_consts))
@@ -1167,6 +1215,25 @@ def plan_window(seg: SegmentSlice, spec, leaves,
         return DevicePart(part=None, n_valid=0, nbytes=0)
     except (ValueError, OverflowError):
         return "predicate"
+    return (tuple((seg.upload_names.index(c), op) for c, op in prog),
+            tuple(consts))
+
+
+def plan_window(seg: SegmentSlice, spec, leaves,
+                width: int) -> "DecodePlan | DevicePart | str":
+    """The half of plan_dispatch that a query's window decides, from
+    the slice and the AggregateSpec alone (no host column is touched,
+    so it serves a slice the scan cache held as it serves a fresh
+    one): the shift to range-relative time, the first bucket, the grid
+    width, and the constants of every leaf that is not a key leaf.
+    Counts the dispatch (rows, route) once the plan stands."""
+    shift = seg.ts_epoch - spec.range_start
+    if abs(shift) >= 2**31:
+        return "range"
+    got = window_leaves(seg, leaves)
+    if not isinstance(got, tuple):
+        return got
+    prog, consts = got
     lo = max(0, shift // spec.bucket_ms) if seg.local_ok else 0
     use_width = width if seg.local_ok else spec.num_buckets
     if seg.sort_skipped is not None:
@@ -1181,9 +1248,7 @@ def plan_window(seg: SegmentSlice, spec, leaves,
     return DecodePlan(
         seg=seg, shift=shift, lo=lo, use_width=use_width,
         w_eff=min(use_width, spec.num_buckets - lo),
-        leaf_prog=tuple((seg.upload_names.index(c), op)
-                        for c, op in prog),
-        consts=tuple(consts), which=spec.which,
+        leaf_prog=prog, consts=consts, which=spec.which,
         bucket_ms=spec.bucket_ms, num_buckets=spec.num_buckets)
 
 
@@ -1318,8 +1383,7 @@ def _call_batch(plans: list, slots: int):
     first = plans[0]
     rows = [np.concatenate(
         [np.asarray([dp.n, dp.shift, dp.lo], dtype=np.int32),
-         *(c for (_slot, op), c in zip(dp.leaf_prog, dp.consts)
-           if op not in (_OP_EQ, _OP_IN))]) for dp in plans]
+         *window_numbers(dp.leaf_prog, dp.consts)]) for dp in plans]
     nums = np.zeros((1 + slots, len(rows[0])), dtype=np.int32)
     nums[0, :3] = len(plans), first.num_buckets, first.bucket_ms
     nums[1:1 + len(rows)] = rows

@@ -74,7 +74,7 @@ logger = logging.getLogger(__name__)
 # bypass the admission+tenant middleware chain.
 _QUERY_ENDPOINTS = frozenset({
     "/query", "/query_arrow", "/query_topk", "/query_multi",
-    "/label_values", "/label_names", "/metrics_list"})
+    "/query_rows", "/label_values", "/label_names", "/metrics_list"})
 _WRITE_ENDPOINTS = frozenset({"/write", "/write_arrow"})
 _UNGOVERNED_ENDPOINTS = frozenset({
     "/", "/toggle", "/compact", "/metrics", "/stats",
@@ -103,13 +103,16 @@ _QUEUED_QUERIES = registry.gauge(
     "server_queued_queries", "queries waiting for an admission slot")
 _RESPOND_CELLS = registry.counter(
     "respond_cells_total",
-    "grid cells encoded into downsample responses")
+    "grid cells encoded into downsample responses, and values (rows x "
+    "columns) serialized into /query_rows responses")
 _RESPOND_BYTES = registry.counter(
-    "respond_bytes_total", "body bytes of downsample responses")
+    "respond_bytes_total",
+    "body bytes of downsample and /query_rows responses")
 _RESPOND_ENCODE_SECONDS = registry.counter(
     "respond_encode_seconds_total",
     "wall seconds inside the downsample response encoder (the lazy "
-    "download of device grids excluded)")
+    "download of device grids excluded) and the /query_rows "
+    "serializer")
 _RESPOND_ENCODE_CPU = registry.counter(
     "respond_encode_cpu_seconds_total",
     "CPU seconds of the encoding thread (a pool thread for a large "
@@ -117,7 +120,8 @@ _RESPOND_ENCODE_CPU = registry.counter(
     "encoder: under its wall where the encoder waited for the GIL")
 _RESPOND_ENCODE_TOTAL = registry.counter(
     "respond_encode_total",
-    "downsample responses written, by the thread that encoded them: a "
+    "downsample and /query_rows responses written, by the thread that "
+    "encoded them: a "
     "worker of the `sst` pool from _RESPOND_POOL_MIN_CELLS cells up, "
     "the event loop's own thread under it")
 _RESPOND_ENCODE = {where: _RESPOND_ENCODE_TOTAL.labels(where=where)
@@ -1001,24 +1005,31 @@ def build_app(state: ServerState) -> web.Application:
                                                   bucket_ms, field=field)
         return out, None
 
+    async def _respond_bytes(cells: int, write) -> bytes:
+        """The `respond` step of every endpoint whose answer grows with
+        the data: `write(where)` builds the body and returns its bytes
+        (`where` names the thread it runs on, for the counters).  From
+        _RESPOND_POOL_MIN_CELLS cells up it runs as ONE job on the
+        `sst` pool and the loop's thread keeps the hop and the
+        Response around the bytes; a smaller answer, or one of an
+        engine with no pools of its own (a Cluster front), is written
+        here on the loop's thread."""
+        runtimes = getattr(state.engine, "runtimes", None)
+        if cells < _RESPOND_POOL_MIN_CELLS or runtimes is None:
+            with span("respond", sync=True):
+                return write("loop")
+        with span("respond"):
+            return await runtimes.run("sst", _payload_on_pool, write)
+
     async def _respond(outs, answer) -> web.Response:
         """The `respond` step of /query (with bucket_ms), /query_topk
         and /query_multi: `answer()` builds the body of the engine's
         results `outs` (_downsample_json and what the endpoint adds),
-        _downsample_payload writes it.  From _RESPOND_POOL_MIN_CELLS
-        cells up both run as ONE job on the `sst` pool and the loop's
-        thread keeps the hop and the Response around the bytes; a
-        smaller answer, or one of an engine with no pools of its own
-        (a Cluster front), is written here on the loop's thread."""
+        _downsample_payload writes it; where, _respond_bytes decides
+        by the grids' cells."""
         cells = sum(g.size for out in outs for g in out["aggs"].values())
-        runtimes = getattr(state.engine, "runtimes", None)
-        if cells < _RESPOND_POOL_MIN_CELLS or runtimes is None:
-            with span("respond", sync=True):
-                payload = _downsample_payload(answer(), "loop")
-        else:
-            with span("respond"):
-                payload = await runtimes.run("sst", _payload_on_pool,
-                                             answer)
+        payload = await _respond_bytes(
+            cells, lambda where: _downsample_payload(answer(), where))
         return web.Response(body=payload, content_type="application/json",
                             charset="utf-8")
 
@@ -1547,6 +1558,66 @@ def build_app(state: ServerState) -> web.Application:
                             headers=_partial_headers(meta),
                             content_type="application/vnd.apache.arrow.stream")
 
+    @routes.post("/query_rows")
+    async def query_rows(req: web.Request) -> web.Response:
+        """Rows under a VALUE predicate (TSBS high-cpu-*): every
+        (series, timestamp) in [start, end) whose current value of
+        `where.field` satisfies `where.op` (gt, ge, lt, le) against
+        `where.value`, with the fields asked at the same key.  Body:
+        {metric, filters?, start, end, where: {field, op, value},
+        fields: [..], compression?}; the answer is an Arrow IPC stream
+        (tsid, timestamp, one nullable float32 column a field), sorted
+        by (tsid, timestamp): README.md has the semantics."""
+        from horaedb_tpu.common.ipc import COMPRESSIONS
+        from horaedb_tpu.ops.select import OPS
+
+        try:
+            with span("parse"):
+                body = await req.json()
+                metric, filters, rng, _field, _bucket = \
+                    _parse_query_body(body)
+                where = body["where"]
+                where_field, op, value = (where["field"], where["op"],
+                                          where["value"])
+                fields = body["fields"]
+                if not isinstance(where_field, str):
+                    raise ValueError("where.field must be a string")
+                if op not in OPS:
+                    raise ValueError(f"where.op must be one of {OPS}")
+                if isinstance(value, bool) \
+                        or not isinstance(value, (int, float)) \
+                        or not math.isfinite(value):
+                    raise ValueError("where.value must be a finite "
+                                     "number")
+                if (not isinstance(fields, list) or not fields
+                        or not all(isinstance(f, str) for f in fields)
+                        or len(set(fields)) != len(fields)):
+                    raise ValueError("fields must be a non-empty list of "
+                                     "distinct strings")
+                compression = body.get("compression")
+                if compression not in COMPRESSIONS:
+                    raise ValueError(
+                        f"unsupported compression {compression!r}")
+        except (KeyError, TypeError, ValueError) as e:
+            return web.json_response({"error": f"bad request: {e}"},
+                                     status=400)
+        rows_where = getattr(state.engine, "query_rows_where", None)
+        if rows_where is None:
+            return web.json_response(
+                {"error": "this front end has no row selection"},
+                status=501)
+        try:
+            tbl = await rows_where(metric, filters, rng, where_field, op,
+                                   float(value), fields)
+        except Error as e:
+            return _error_response(e)
+        payload = await _respond_bytes(
+            tbl.num_rows * tbl.num_columns,
+            lambda where: _rows_payload(tbl, compression, where))
+        return web.Response(
+            body=payload,
+            content_type="application/vnd.apache.arrow.stream")
+
     @routes.get("/label_names")
     async def label_names(req: web.Request) -> web.Response:
         try:
@@ -1818,11 +1889,29 @@ def _downsample_payload(body: dict, where: str) -> bytes:
     return payload
 
 
-def _payload_on_pool(answer) -> bytes:
-    """_respond's job on a pool thread.  `respond.encode` is the `sync`
-    span that carries the CPU `respond` carries on the loop's thread."""
+def _rows_payload(tbl: pa.Table, compression, where: str) -> bytes:
+    """The bytes of a /query_rows response: `tbl` as one Arrow IPC
+    stream, counted as _downsample_payload counts its grids (a value
+    of the table a cell)."""
+    from horaedb_tpu.common.ipc import serialize_stream
+
+    t0, cpu0 = time.perf_counter(), time.thread_time()
+    payload = serialize_stream(tbl, compression)
+    _RESPOND_CELLS.inc(tbl.num_rows * tbl.num_columns)
+    _RESPOND_BYTES.inc(len(payload))
+    cpu = time.thread_time() - cpu0
+    _RESPOND_ENCODE_SECONDS.inc(time.perf_counter() - t0)
+    _RESPOND_ENCODE_CPU.inc(cpu)
+    _RESPOND_ENCODE[where].inc()
+    return payload
+
+
+def _payload_on_pool(write) -> bytes:
+    """_respond_bytes' job on a pool thread.  `respond.encode` is the
+    `sync` span that carries the CPU `respond` carries on the loop's
+    thread."""
     with span("respond.encode", sync=True):
-        return _downsample_payload(answer(), "pool")
+        return write("pool")
 
 
 def _build_store(config: ServerConfig):

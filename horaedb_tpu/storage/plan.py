@@ -79,6 +79,33 @@ class QueryPlan:
         return text
 
 
+@dataclass
+class SelectPlan:
+    """select (value predicate, after the dedup) -> join (the other
+    fields at the selected keys) over one scan a field.
+
+    `scans[0]` and `requests[0]` are the predicate's field's, the rest
+    the other distinct fields', all over the same SSTs; `asked[i]`
+    names, by index into them, the field of value column i.  Which
+    route a segment takes (the device's resident slices or the host
+    decode) is decided per segment where it runs
+    (ParquetReader.select_segments) and counted there."""
+
+    scans: list
+    requests: list
+    select: object                # ops/select.SelectSpec
+    asked: list
+
+    def describe(self) -> str:
+        spec = self.select
+        text = (f"Select: group={spec.group_col}, ts={spec.ts_col}, "
+                f"value={spec.value_col} {spec.op} {spec.threshold!r}, "
+                f"columns={list(self.asked)}\n")
+        return text + "\n".join(
+            textwrap.indent(describe_plan(scan), "  ").rstrip("\n")
+            for scan in self.scans)
+
+
 def apply_top_k(group_values: np.ndarray, grids: dict,
                 tk: TopKSpec) -> tuple[np.ndarray, dict]:
     """Host top-k over finalized grids: by the time grids exist the

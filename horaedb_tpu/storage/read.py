@@ -61,8 +61,9 @@ from horaedb_tpu.storage.types import (
     TimeRange,
 )
 from horaedb_tpu.ops import device_decode
+from horaedb_tpu.ops import select as select_ops
 from horaedb_tpu.storage import combine as combine_mod, parquet_io, sidecar
-from horaedb_tpu.utils import active_trace, phase, registry, trace_add
+from horaedb_tpu.utils import active_trace, phase, registry, span, trace_add
 from horaedb_tpu.utils.tracing import clear_phases
 
 logger = logging.getLogger(__name__)
@@ -189,6 +190,42 @@ def note_mesh_fallback(reason: str) -> None:
         _MESH_FALLBACK_CHILDREN[reason] = child
     child.inc()
     trace_add(f"mesh_fallback_{reason}", 1)
+
+
+# the row-selecting route (select_segments): every segment of a select
+# by the route that answered it and, on the host route, why
+_SELECT_SEGMENTS = registry.counter(
+    "scan_select_segments_total",
+    "segments of row selections under a value predicate by the route "
+    "that answered them: device = selected and joined on the device "
+    "from resident slices (ops/select.py), host = the host decode "
+    "route with the value leaf evaluated after the merge and a numpy "
+    "join, for `reason` (mode_host and cpu_auto are the [scan.decode] "
+    "mode's choice; every other reason also counts in "
+    "scan_decode_fallback_total)")
+_SELECT_ROWS = registry.counter(
+    "scan_select_rows_total",
+    "rows of row selections: scanned = rows of the predicate's field "
+    "in range, after the dedup, that the value predicate was put to "
+    "(the device route's programs report them; the host route's "
+    "filter does not), selected = rows that passed it, by route")
+_SELECT_CELLS = {
+    kind: registry.counter(
+        "scan_select_cells_total",
+        "value cells of the rows selected, a row a field asked: found "
+        "= the field had a sample at the row's (series, timestamp), "
+        "null = it had none"
+    ).labels(kind=kind)
+    for kind in ("found", "null")
+}
+_SELECT_CPU = registry.counter(
+    "scan_select_cpu_seconds_total",
+    "CPU seconds of the pool threads inside the device route's job of "
+    "a row selection (calls issued, the download, the rows shaped)")
+# config choices, not fallbacks: counted by route only
+_SELECT_MODE_REASONS = ("mode_host", "cpu_auto")
+_VALUE_LEAF = {"gt": filter_ops.Gt, "ge": filter_ops.Ge,
+               "lt": filter_ops.Lt, "le": filter_ops.Le}
 
 
 def _stack_counters(key: tuple):
@@ -3793,6 +3830,285 @@ class ParquetReader:
             grids["last_ts"] = grids["last_ts"] + spec.range_start
         return group_values, grids
 
+    # ---- row selection under a value predicate (ops/select.py) ------------
+
+    def _select_route(self, plan: ScanPlan) -> Optional[str]:
+        """None where a select over `plan` may run on the device, else
+        the reason it takes the host route: the [scan.decode] mode's
+        choice first (as _device_decode_plan_ok reads it; there is no
+        fused accumulator to defer to here, so `auto` on an accelerator
+        means the device), then what the plan itself rules out."""
+        mode = self._decode_mode()
+        if mode == "host":
+            return "mode_host"
+        if mode == "auto" and jax.default_backend() == "cpu":
+            return "cpu_auto"
+        if plan.mode is not UpdateMode.OVERWRITE:
+            return "append_mode"
+        if (plan.predicate is not None and not plan.pushed_complete) \
+                or not device_decode.leaf_shape_supported(
+                    plan.prune_leaves):
+            return "predicate"
+        if not self._sidecar_plan_ok(plan):
+            return "no_sidecar"
+        return None
+
+    @staticmethod
+    def _note_select(route: str, reason: str, segments: int = 1) -> None:
+        _SELECT_SEGMENTS.labels(route=route, reason=reason).inc(segments)
+        if reason and reason not in _SELECT_MODE_REASONS:
+            for _ in range(segments):
+                device_decode.note_fallback(reason)
+
+    async def select_segments(self, plans: list, spec, asked: list):
+        """Per segment, yield (segment_start, SelectedRows): the rows of
+        plans[0] (the predicate's field) whose current value passes
+        `spec`, and at their (series, timestamp) the values of the
+        fields asked (`asked`: indexes into `plans`, one a column of
+        the answer; plans[1:] are the other distinct fields, each over
+        the same segments as plans[0]).
+
+        The device route answers a segment from its fields' resident
+        decode slices (the aggregate route's: same keys, same account;
+        a miss reads, narrows and uploads the slice as that route's
+        miss does and leaves it resident for both), all segments of
+        the query in one pool job.  A segment the device route cannot
+        take, and every segment where the plan or the mode rules it
+        out, is answered by the host decode route; each with its
+        reason counted."""
+        segments = plans[0].segments
+        ensure(all([s.segment_start for s in p.segments]
+                   == [s.segment_start for s in segments] for p in plans),
+               "select: the fields' plans differ in their segments")
+        reason = self._select_route(plans[0])
+        if reason is not None:
+            self._note_select("host", reason, len(segments))
+            async for out in self._select_segments_host(
+                    plans, spec, asked, range(len(segments))):
+                yield out
+            return
+        # only the columns count: they are the slices' key, which a
+        # select shares with the aggregates over the same field
+        carrier = AggregateSpec(
+            group_col=spec.group_col, ts_col=spec.ts_col,
+            value_col=spec.value_col, range_start=0, bucket_ms=1,
+            num_buckets=1, which=("count",))
+        plans = [dc_replace(p, decode_spec=carrier) for p in plans]
+        slice_columns = [self._decode_slice_columns(p) for p in plans]
+        with self._phase("scan.windows",
+                         segments=len(segments)) as probe:
+            resident = [[self.scan_cache.get_slice(
+                self._decode_slice_key(plan.segments[k], cols))
+                for plan, cols in zip(plans, slice_columns)]
+                for k in range(len(segments))]
+            hits = sum(s is not None for row in resident for s in row)
+            device_decode.note_resident("hit", hits)
+            device_decode.note_resident(
+                "miss", len(segments) * len(plans) - hits)
+            probe.fields["resident"] = hits
+        on_device: list = []   # (position, windows: predicate's, asked)
+        on_host: list = []
+        for k, seg in enumerate(segments):
+            deadline_checkpoint()
+            windows: list = []
+            for plan, cols, seg_slice in zip(plans, slice_columns,
+                                             resident[k]):
+                if seg_slice is None:
+                    seg_slice = await self._load_select_slice(
+                        plan.segments[k], plan, cols)
+                got = (seg_slice if not isinstance(
+                    seg_slice, device_decode.SegmentSlice)
+                    else select_ops.plan_window(seg_slice,
+                                                plan.prune_leaves))
+                if isinstance(got, str):
+                    windows = got
+                    break
+                windows.append(got)
+            if isinstance(windows, str):
+                self._note_select("host", windows)
+                on_host.append(k)
+            elif windows[0] is None:
+                # the predicate's field has no row here: nothing to join
+                self._note_select("device", "")
+                yield seg.segment_start, select_ops.SelectedRows(
+                    groups=np.zeros(0, np.uint64),
+                    timestamps=np.zeros(0, np.int64),
+                    values=[np.zeros(0, np.float32)] * len(asked),
+                    found=[np.zeros(0, bool)] * len(asked), scanned=0)
+            else:
+                on_device.append(
+                    (k, [windows[0]] + [windows[j] for j in asked]))
+        if on_device:
+            deadline_checkpoint()
+            parts = await self._run_pool(
+                plans[0].pool, self._select_resident,
+                [windows for _k, windows in on_device], spec)
+            for (k, _windows), part in zip(on_device, parts):
+                self._note_select("device", "")
+                self._count_selected(part, "device")
+                yield segments[k].segment_start, part
+        if on_host:
+            async for out in self._select_segments_host(
+                    plans, spec, asked, on_host):
+                yield out
+
+    def _select_resident(self, windows: list, spec) -> list:
+        """Pool-side: the device route's one job a query."""
+        cpu0 = time.thread_time()
+        try:
+            return select_ops.select_resident(windows, spec, self._phase,
+                                              self.table)
+        finally:
+            _SELECT_CPU.inc(time.thread_time() - cpu0)
+
+    @staticmethod
+    def _count_selected(part, route: str) -> None:
+        n = len(part.timestamps)
+        if route == "device":
+            _SELECT_ROWS.labels(side="scanned", route=route).inc(
+                part.scanned)
+        _SELECT_ROWS.labels(side="selected", route=route).inc(n)
+        found = sum(int(np.count_nonzero(f)) for f in part.found)
+        _SELECT_CELLS["found"].inc(found)
+        _SELECT_CELLS["null"].inc(n * len(part.found) - found)
+        with span("select.segment", route=route, rows_in=part.scanned,
+                  rows_out=n):
+            pass
+
+    async def _load_select_slice(self, seg: SegmentPlan, plan: ScanPlan,
+                                 slice_columns: tuple):
+        """A missed slice read, planned and uploaded, and left resident
+        where the scan cache may keep it: the SegmentSlice, None where
+        the field provably has no row in the segment, or the reason
+        the segment takes the host route."""
+        if self._stream_segment(seg):
+            return "streamed"
+        table, _read_s = await self._read_segment_any(seg, plan)
+        if not isinstance(table, sidecar.EncodedSegment):
+            return "parquet"
+        seg_slice = await self._run_pool(
+            plan.pool, self._upload_select_slice, table, plan)
+        if isinstance(seg_slice, device_decode.DevicePart):
+            return None
+        if isinstance(seg_slice, device_decode.SegmentSlice) \
+                and seg_slice.admissible:
+            self.scan_cache.put_slice(
+                self._decode_slice_key(seg, slice_columns), seg_slice)
+        return seg_slice
+
+    def _upload_select_slice(self, es: "sidecar.EncodedSegment",
+                             plan: ScanPlan):
+        """Pool-side: a missed slice planned (plan_segment: the same
+        narrowing, layout and route the aggregate's miss decides) and
+        put on the device."""
+        spec = plan.decode_spec
+        with self._phase("scan.group_prep", rows=es.n):
+            got = device_decode.plan_segment(
+                es, spec.group_col, spec.ts_col, spec.value_col,
+                pk_names=self._pk_names_in(list(es.names)),
+                seq_name=SEQ_COLUMN_NAME,
+                leaves=es.pending_leaves or [],
+                max_bytes=self.config.scan.decode.max_upload_bytes,
+                pad_capacity=encode.pad_capacity)
+        if not isinstance(got, device_decode.SegmentSlice):
+            return got
+        with self._phase("scan.dispatch", sync=True, h2d_bytes=got.nbytes):
+            device_decode.upload_slice(got)
+        return got.resident()
+
+    async def _select_segments_host(self, plans: list, spec, asked: list,
+                                    positions):
+        """The host route of select_segments for the segments at
+        `positions`: the predicate's field through the host decode with
+        the value leaf evaluated after the merge (the row scan's own
+        filter), every other field asked through a row scan of the
+        same segments, joined in numpy on (series, timestamp)."""
+        keep = {plans[0].segments[k].segment_start for k in positions}
+        if not keep:
+            return
+        first = plans[0]
+        leaf = _VALUE_LEAF[spec.op](spec.value_col, spec.threshold)
+        children = (list(first.predicate.children)
+                    if isinstance(first.predicate, filter_ops.And)
+                    else [] if first.predicate is None
+                    else [first.predicate])
+
+        def kept(plan: ScanPlan, predicate) -> ScanPlan:
+            ssts = [f for seg in plan.segments
+                    if seg.segment_start in keep for f in seg.ssts]
+            return self.build_plan(
+                ssts, ScanRequest(range=plan.range, predicate=predicate),
+                use_cache=plan.use_cache, pool=plan.pool)
+
+        async def rows_of(plan: ScanPlan) -> dict:
+            """{segment start: (series, timestamps, float32 values)}"""
+            got: dict = {start: [] for start in keep}
+            scan = self.execute_segments(plan)
+            try:
+                async for start, batch in scan:
+                    if batch is not None:
+                        got[start].append(batch)
+            finally:
+                await scan.aclose()
+            out = {}
+            for start, batches in got.items():
+                cols = [np.concatenate([
+                    b.column(name).to_numpy(zero_copy_only=False)
+                    for b in batches]) if batches else np.zeros(0, kind)
+                    for name, kind in ((spec.group_col, np.uint64),
+                                       (spec.ts_col, np.int64),
+                                       (spec.value_col, np.float32))]
+                out[start] = (cols[0], cols[1].astype(np.int64),
+                              cols[2].astype(np.float32))
+            return out
+
+        selected = await rows_of(kept(first, filter_ops.And(children
+                                                            + [leaf])))
+        others = {j: await rows_of(kept(plans[j], plans[j].predicate))
+                  for j in sorted(set(asked) - {0})}
+        for start in sorted(keep):
+            groups, ts, vals = selected[start]
+            order = np.lexsort((ts, groups))
+            groups, ts, vals = groups[order], ts[order], vals[order]
+            values, found = [], []
+            for j in asked:
+                if j == 0:
+                    values.append(vals)
+                    found.append(np.ones(len(ts), bool))
+                    continue
+                v, f = join_on_host(groups, ts, *others[j][start])
+                values.append(v)
+                found.append(f)
+            part = select_ops.SelectedRows(
+                groups=groups, timestamps=ts, values=values, found=found,
+                scanned=0)
+            self._count_selected(part, "host")
+            yield start, part
+
+    def finalize_select(self, parts: list, n_fields: int) -> dict:
+        """Segments' SelectedRows to the answer's columns, sorted by
+        (series, timestamp): the `scan.combine` phase.  A field found
+        at every row has None for its flags (nothing to mask, and
+        nothing to sort: this runs on the loop's thread, once a query,
+        over every row of the answer)."""
+        with self._phase("scan.combine", sync=True, parts=len(parts)):
+            def cat(arrays, kind):
+                return (np.concatenate(arrays) if arrays
+                        else np.zeros(0, kind))
+
+            groups = cat([p.groups for p in parts], np.uint64)
+            ts = cat([p.timestamps for p in parts], np.int64)
+            order = np.lexsort((ts, groups))
+            return {
+                "groups": groups[order], "timestamps": ts[order],
+                "values": [cat([p.values[f] for p in parts],
+                               np.float32)[order]
+                           for f in range(n_fields)],
+                "found": [None if all(p.found[f].all() for p in parts)
+                          else cat([p.found[f] for p in parts],
+                                   bool)[order]
+                          for f in range(n_fields)]}
+
     def _window_groups(self, out_batch: encode.DeviceBatch,
                        spec: AggregateSpec, plan: ScanPlan):
         """Shared per-window prep: (group_values, gid_full, ts_shift) or
@@ -4608,6 +4924,33 @@ def combine_aggregate_parts(parts: list[tuple[np.ndarray, int, dict]],
     helpers, old tests) keep this name."""
     return combine_mod.combine_aggregate_parts(parts, num_buckets,
                                                which=which)
+
+
+def join_on_host(groups: np.ndarray, ts: np.ndarray, f_groups, f_ts,
+                  f_vals) -> tuple:
+    """A field's (series, timestamp) -> value rows (unique keys: the
+    scan deduplicated them) looked up at the keys (groups, ts):
+    (float32 values, found flags)."""
+    n = len(ts)
+    if not n or not len(f_ts):
+        return np.zeros(n, np.float32), np.zeros(n, bool)
+    order = np.lexsort((f_ts, f_groups))
+    f_groups, f_ts, f_vals = f_groups[order], f_ts[order], f_vals[order]
+    # both sides as one sortable number: the series' rank among the
+    # field's, then the timestamp's offset
+    series = np.unique(f_groups)
+    t0 = int(min(f_ts.min(), ts.min()))
+    width = int(max(f_ts.max(), ts.max())) - t0 + 1
+    ensure(len(series) * width < 2**62,
+           "select: (series, timestamp) span too wide to join")
+    f_key = np.searchsorted(series, f_groups).astype(np.int64) * width \
+        + (f_ts - t0)
+    rank = np.searchsorted(series, groups)
+    known = series[np.minimum(rank, len(series) - 1)] == groups
+    key = rank.astype(np.int64) * width + (ts - t0)
+    at = np.minimum(np.searchsorted(f_key, key), len(f_key) - 1)
+    found = known & (f_key[at] == key)
+    return np.where(found, f_vals[at], np.float32(0)), found
 
 
 def _is_lex_sorted(keys: list[np.ndarray]) -> bool:

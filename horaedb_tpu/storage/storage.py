@@ -464,6 +464,63 @@ class CloudObjectStorage(TimeMergeStorage):
         finally:
             self.reader._mem_delta_attribute(mem_marks)
 
+    async def scan_select(self, reqs: list, spec, asked: list,
+                          first_plans: Optional[list] = None) -> dict:
+        """Row selection under a value predicate: the rows of reqs[0]
+        (the predicate's field) whose current value passes `spec`
+        (ops/select.SelectSpec), with the values of the fields asked
+        at the same (series, timestamp).  `reqs[1:]` are the other
+        distinct fields' requests, over the same range; `asked` names,
+        by index into `reqs`, the field of each value column.  Returns
+        {groups, timestamps, values: [...], found: [...]} (a field's
+        flags None where it was found at every row), sorted by
+        (group, timestamp): see ParquetReader.select_segments for the
+        routes.  A compaction race replans the segments not yet
+        answered."""
+        done: dict[int, object] = {}
+        mem_marks = self.reader._mem_delta_marks()
+        try:
+            for attempt in range(self._SCAN_RETRIES + 1):
+                plans = (first_plans if attempt == 0
+                         and first_plans is not None
+                         else await self._plan_select(reqs))
+                for plan in plans:
+                    plan.segments = [s for s in plan.segments
+                                     if s.segment_start not in done]
+                pump = self.reader.select_segments(plans, spec, asked)
+                try:
+                    async for seg_start, part in pump:
+                        done[seg_start] = part
+                    break
+                except NotFoundError:
+                    if attempt == self._SCAN_RETRIES:
+                        raise
+                    logger.info("select raced a compaction; replanning")
+                finally:
+                    await pump.aclose()
+            return self.reader.finalize_select(
+                [done[seg] for seg in sorted(done)], len(asked))
+        finally:
+            self.reader._mem_delta_attribute(mem_marks)
+
+    async def _plan_select(self, reqs: list) -> list:
+        """A select's scan.plan phase: ONE manifest lookup, a plan a
+        field over the same SSTs."""
+        with self.reader._phase("scan.plan") as planned:
+            ensure(self.manifest is not None, "storage not opened")
+            ssts = await self.manifest.find_ssts(reqs[0].range)
+            plans = [self.reader.build_plan(ssts, req) for req in reqs]
+            planned.fields.update(route="select",
+                                  segments=len(plans[0].segments))
+        return plans
+
+    async def plan_select(self, reqs: list, spec, asked: list):
+        """The SelectPlan of a row selection (storage/plan.py)."""
+        from horaedb_tpu.storage.plan import SelectPlan
+
+        return SelectPlan(scans=await self._plan_select(reqs),
+                          requests=reqs, select=spec, asked=asked)
+
     async def build_scan_plan(self, req: ScanRequest,
                               keep_builtin: bool = False) -> ScanPlan:
         """Manifest lookup + plan build: a `scan.plan` phase span."""
@@ -503,11 +560,17 @@ class CloudObjectStorage(TimeMergeStorage):
     def execute_plan(self, qp):
         """Execute a QueryPlan.  Row-scan plans return the async batch
         iterator; aggregate plans return an awaitable of
-        (group_values, grids).  A top-k stage is pushed down into the
+        (group_values, grids), select plans an awaitable of the rows'
+        columns (scan_select).  A top-k stage is pushed down into the
         combine (scan_aggregate top_k=) so the parts path never builds
         the full groups x buckets grid.  The plan built by plan_query
         is the first attempt's scan plan — one manifest lookup per
         query, not two."""
+        from horaedb_tpu.storage.plan import SelectPlan
+
+        if isinstance(qp, SelectPlan):
+            return self.scan_select(qp.requests, qp.select, qp.asked,
+                                    first_plans=qp.scans)
         if qp.aggregate is None:
             return self.scan(qp.request, first_plan=qp.scan)
         return self.scan_aggregate(qp.request, qp.aggregate,

@@ -376,8 +376,9 @@ def test_the_pool_thread_writes_the_bytes_the_loop_thread_writes(
     async def go():
         rt = Runtimes(sst_threads=1)
         try:
-            return await rt.run("sst", server_main._payload_on_pool,
-                                lambda: body)
+            return await rt.run(
+                "sst", server_main._payload_on_pool,
+                lambda where: server_main._downsample_payload(body, where))
         finally:
             rt.close()
 

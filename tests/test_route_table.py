@@ -222,10 +222,16 @@ ROWS = [
     Row("accel_mode_device_under_budget", "device_decode", DECODE,
         accel=True, scan={"decode": {"mode": "device"}},
         n_decode=SEGMENTS),
+    # a value leaf under an AGGREGATE stays declined (the grids of rows
+    # that pass a value predicate are no route's yet); the same leaf
+    # answered as ROWS has a device route of its own: SELECT_ROWS below
     Row("mode_device_value_leaf", "parts", (),
         scan={"decode": {"mode": "device"}},
         predicate=F.Gt("v", 50.0), keep=lambda v: v > 50.0,
         decode_reason={"predicate": 1}),
+    # an In past the device's list: declined for aggregates and, by
+    # the same leaf_shape_supported, for the select
+    # (select_mode_device_oversized_in_list)
     Row("mode_device_oversized_in_list", "parts", (),
         scan={"decode": {"mode": "device"}},
         predicate=F.In("k", MANY), decode_reason={"predicate": 1}),
@@ -373,6 +379,137 @@ def test_every_plan_level_reason_has_a_row():
     assert "mesh" not in device_decode.FALLBACK_REASONS
     assert {r.route for r in ROWS} == {
         "parts", "fused_acc", "replay", "device_decode", "mesh"}
+
+
+# ---------------------------------------------------------------------------
+# the select: rows under a value predicate (ISSUE 41)
+# ---------------------------------------------------------------------------
+
+SELECT = ("_select_rows_jit", "_select_join_jit")
+
+
+@dataclasses.dataclass
+class SelectRow:
+    name: str
+    route: str                    # "device" | "host"
+    reason: str = ""              # the host route's counted reason
+    accel: bool = False
+    scan: dict = dataclasses.field(default_factory=dict)
+    env: dict = dataclasses.field(default_factory=dict)
+    hosts: tuple = tuple(range(HOSTS))
+    predicate: object = None
+    decode_reason: dict = dataclasses.field(default_factory=dict)
+    mode: UpdateMode = UpdateMode.OVERWRITE
+
+
+SELECT_ROWS = [
+    SelectRow("select_cpu_auto", "host", "cpu_auto"),
+    SelectRow("select_accel_auto", "device", accel=True),
+    SelectRow("select_accel_auto_one_host", "device", accel=True, **HOST7),
+    SelectRow("select_mode_device", "device",
+              scan={"decode": {"mode": "device"}}),
+    SelectRow("select_accel_mode_host", "host", "mode_host", accel=True,
+              scan={"decode": {"mode": "host"}}),
+    SelectRow("select_accel_decode_forced_off", "host", "mode_host",
+              accel=True, env={"HORAEDB_DEVICE_DECODE": "0"}),
+    SelectRow("select_benchmark_rehearsal_environment", "device",
+              env={"HORAEDB_HOST_AGG": "0", "HORAEDB_DEVICE_DECODE": "1"}),
+    SelectRow("select_accel_no_sidecar", "host", "no_sidecar", accel=True,
+              scan={"use_sidecar": False},
+              decode_reason={"no_sidecar": SEGMENTS}),
+    SelectRow("select_mode_device_oversized_in_list", "host", "predicate",
+              scan={"decode": {"mode": "device"}},
+              predicate=F.In("k", MANY),
+              decode_reason={"predicate": SEGMENTS}),
+]
+
+
+def select_segments() -> dict:
+    fam = read_mod._SELECT_SEGMENTS
+    return {(dict(k)["route"], dict(k)["reason"]): c.value
+            for k, c in (fam._children or {}).items()}
+
+
+@pytest.mark.parametrize("row", SELECT_ROWS,
+                         ids=[r.name for r in SELECT_ROWS])
+def test_select_route_named_is_the_route_that_ran(row, runtimes,
+                                                  monkeypatch):
+    """`v > 90` answered as rows: which route takes the segments, from
+    scan_select_segments_total{route,reason}, the device plane's ledger
+    (the select programs ran, or no program did) and the fallback
+    counter; and the rows are the reference's."""
+    from horaedb_tpu.ops.select import SelectSpec
+
+    for name in ("HORAEDB_HOST_AGG", "HORAEDB_DEVICE_DECODE",
+                 "HORAEDB_FUSED_AGG", "HORAEDB_DEVCOL_STACK"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in row.env.items():
+        monkeypatch.setenv(name, value)
+    if row.accel:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def select_calls() -> dict:
+        return {r["fn"]: r["compiles"] + r["dispatches"]
+                for r in deviceprof.profiler.snapshot()["fns"]
+                if r["fn"] in SELECT + ROUTE_FNS}
+
+    async def go():
+        world = World(seed=41)
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), SCHEMA, 2,
+            storage_config(row), runtimes=runtimes)
+        try:
+            for wr in world.write_requests():
+                await s.write(wr)
+            s.reader.scan_cache.clear()
+            s.reader.encoded_cache.clear()
+            # the window is a leaf, as the engine's resolve writes it
+            leaves = [F.TimeRangePred("ts", LO, HI)] \
+                + ([] if row.predicate is None else [row.predicate])
+            req = ScanRequest(range=TimeRange.new(LO, HI),
+                              predicate=F.And(leaves))
+            spec = SelectSpec(group_col="k", ts_col="ts", value_col="v",
+                              op="gt", threshold=90.0)
+            seg0, fns0, dec0 = select_segments(), select_calls(), \
+                decode_fallbacks()
+            qp = await s.plan_select([req], spec, [0])
+            assert "Select: group=k, ts=ts, value=v gt 90.0" \
+                in qp.describe()
+            out = await s.execute_plan(qp)
+
+            ts = np.arange(world.values.shape[1], dtype=np.int64) * TICK_MS
+            want = [(f"host_{h:02d}", int(t), world.values[h, i])
+                    for h in row.hosts for i, t in enumerate(ts)
+                    if LO <= t < HI and world.values[h, i] > 90.0]
+            assert want
+            got = list(zip([str(g) for g in out["groups"]],
+                           out["timestamps"].tolist(), out["values"][0]))
+            assert [(g, t) for g, t, _ in got] \
+                == [(g, t) for g, t, _ in want]
+            assert all(a.tobytes() == np.float32(b).tobytes()
+                       for (_, _, a), (_, _, b) in zip(got, want))
+            assert out["found"][0] is None      # found at every row
+
+            assert delta(select_segments(), seg0) \
+                == {(row.route, row.reason): SEGMENTS}
+            ran = delta(select_calls(), fns0)
+            assert ran == ({"_select_rows_jit": 1}
+                           if row.route == "device" else {}), ran
+            assert delta(decode_fallbacks(), dec0) == row.decode_reason
+        finally:
+            await s.close()
+
+    asyncio.run(go())
+
+
+def test_every_select_reason_a_plan_can_show_has_a_row():
+    """The per-segment reasons (unsorted, streamed, parquet, encoding,
+    dtype, budget) need a segment that shows them; the plan's and the
+    mode's are all here but `append_mode`: an Append table merges
+    bytes, and no value column of one can carry the predicate."""
+    assert {r.reason for r in SELECT_ROWS} == {
+        "", "cpu_auto", "mode_host", "no_sidecar", "predicate"}
+    assert {r.route for r in SELECT_ROWS} == {"device", "host"}
 
 
 # ---------------------------------------------------------------------------
