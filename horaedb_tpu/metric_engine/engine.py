@@ -41,9 +41,10 @@ from horaedb_tpu.ops.select import SelectSpec, compare
 from horaedb_tpu.storage.config import StorageConfig
 from horaedb_tpu.storage.read import (AggregateSpec, ScanRequest,
                                       join_on_host)
+from horaedb_tpu.storage.scan_cache import ByteLRU
 from horaedb_tpu.storage.storage import CloudObjectStorage, WriteRequest
 from horaedb_tpu.storage.types import TimeRange, Timestamp
-from horaedb_tpu.utils import registry, span
+from horaedb_tpu.utils import registry, span, span_note
 from horaedb_tpu.metric_engine.types import (
     Sample,
     field_id_of,
@@ -272,6 +273,77 @@ class MetricManager:
         return sorted(fields)
 
 
+# A segment of the index table is written once, when the segment first
+# sees a series, and read by every filtered query after that: its
+# POSTING LISTS, (metric_id, tag_key, tag_value) -> tsids, are kept per
+# segment and keyed by the segment's SST set (SegmentVersion.ids), the
+# scan cache's structural invalidation.  One byte-capped LRU an
+# IndexManager; a segment whose index SSTs hold more rows than
+# _POSTINGS_MAX_ROWS is never built: one build is one unfiltered scan of
+# it and a dict of its labels in Python, and at that many rows the
+# lists fit the byte cap even if every row is a label of its own
+# (131,072 x about 450 B), so nothing under the row cap is built again
+# at every query for want of room.
+_POSTINGS_MAX_BYTES = 64 << 20
+_POSTINGS_MAX_ROWS = 1 << 17
+# what a posting list costs beside its tsids and its two strings' text:
+# the key tuple, the boxed metric id, two str headers, the array view
+# and the dict slot (an estimate; CPython 3.12, 64 bit)
+_POSTINGS_KEY_BYTES = 384
+_POSTINGS = {
+    outcome: registry.counter(
+        "index_postings_total",
+        "segments of the index table a filtered query resolved its "
+        "series in, by how: hit = from the segment's posting lists kept "
+        "in memory under its SST set; build = the lists were built "
+        "first, by one unfiltered scan of the segment; bypass = by a "
+        "filtered scan of the index table (rows in a memtable, or a "
+        "segment over the row cap)"
+    ).labels(outcome=outcome)
+    for outcome in ("hit", "build", "bypass")
+}
+_POSTINGS_GAUGES = (
+    registry.gauge(
+        "index_postings_bytes",
+        "bytes of the posting lists kept in memory, summed over the "
+        "open index managers"),
+    registry.gauge(
+        "index_postings_segments",
+        "segments whose posting lists are kept in memory, summed over "
+        "the open index managers"))
+
+
+def _posting_lists(batches: list[pa.RecordBatch]) -> tuple[dict, int]:
+    """One segment's index rows as {(metric_id, tag_key, tag_value):
+    its tsids, sorted, as np.uint64} (unique: the segment's merge
+    leaves one row a key), and the bytes that holds.  The lists are
+    views of one array."""
+    tbl = pa.Table.from_batches(batches).combine_chunks()
+    if not tbl.num_rows:
+        return {}, 0
+    mid = tbl.column("metric_id").chunk(0).to_numpy()
+    tsid = tbl.column("tsid").chunk(0).to_numpy()
+    keys = tbl.column("tag_key").chunk(0).dictionary_encode()
+    vals = tbl.column("tag_value").chunk(0).dictionary_encode()
+    kc, vc = keys.indices.to_numpy(), vals.indices.to_numpy()
+    order = np.lexsort((tsid, vc, kc, mid))
+    mid, kc, vc, tsid = mid[order], kc[order], vc[order], tsid[order]
+    first = np.ones(len(tsid), dtype=bool)  # of its posting list
+    first[1:] = ((mid[1:] != mid[:-1]) | (kc[1:] != kc[:-1])
+                 | (vc[1:] != vc[:-1]))
+    starts = np.flatnonzero(first)
+    ends = np.append(starts[1:], len(tsid))
+    key_text, val_text = keys.dictionary.to_pylist(), \
+        vals.dictionary.to_pylist()
+    lists = {(m, key_text[k], val_text[v]): tsid[s:e]
+             for m, k, v, s, e in zip(
+                 mid[starts].tolist(), kc[starts].tolist(),
+                 vc[starts].tolist(), starts.tolist(), ends.tolist())}
+    nbytes = tsid.nbytes + sum(
+        _POSTINGS_KEY_BYTES + len(k) + len(v) for _, k, v in lists)
+    return lists, nbytes
+
+
 class IndexManager:
     """TSID resolution + series/tags/index registration per segment
     (ref: index/mod.rs:25-44, body from RFC:86-137)."""
@@ -283,6 +355,23 @@ class IndexManager:
         self.index = index
         self.segment_ms = segment_ms
         self._seen = _SegmentSeen()  # (segment, tsid)
+        # segment start -> (the SST ids the lists were built from, the
+        # lists): one entry a segment, so a newer version's filing
+        # drops the older one
+        self._postings = ByteLRU(_POSTINGS_MAX_BYTES,
+                                 gauges=_POSTINGS_GAUGES)
+        root = getattr(index, "root_path", "")
+        self._postings_account = memledger.register(
+            f"index_postings:{root}", lambda m: m._postings.total_bytes,
+            anchor=self, kind="index_postings",
+            budget=_POSTINGS_MAX_BYTES, owner=root)
+
+    def close(self) -> None:
+        """Clear-on-close: the lists can never be read again, and the
+        ledger account goes with them."""
+        self._postings.clear()
+        memledger.deregister(self._postings_account)
+        self._postings_account = None
 
     async def populate_series_ids(self, samples: list[Sample]) -> None:
         new: dict[int, dict[int, Sample]] = {}
@@ -336,22 +425,82 @@ class IndexManager:
                          filters: list[tuple[str, str]],
                          time_range: TimeRange) -> Optional[set[int]]:
         """Inverted-index lookup: intersect TSID sets per label filter.
-        Returns None when no filters were given (= all series)."""
+        Returns None when no filters were given (= all series).
+
+        A segment answers from its posting lists (see _POSTINGS_MAX_BYTES)
+        wherever an SST set names its content; where none does (rows
+        in a memtable: the version is None) or the segment is over the
+        row cap, the filtered scan answers for it.  Either way the
+        answer is the scan's, with no window in which an acknowledged
+        registration is not found: a write changes the segment's
+        version or makes it None."""
         if not filters:
             return None
+        versions = await self.index.segment_versions(time_range)
+        kept, scanned = [], set()
+        counts = dict.fromkeys(_POSTINGS, 0)
+        for seg, version in versions.items():
+            if version is None or version.rows > _POSTINGS_MAX_ROWS:
+                outcome = "bypass"
+                scanned.add(seg)
+            else:
+                entry = self._postings.peek_entry(seg)
+                if entry is not None and entry[0] == version.ids:
+                    outcome = "hit"
+                    self._postings.record_hit(seg)
+                    kept.append(entry[1])
+                else:
+                    outcome = "build"
+                    kept.append(await self._build_postings(
+                        seg, version.ids, time_range))
+            counts[outcome] += 1
+            _POSTINGS[outcome].inc()
+        span_note(postings=" ".join(f"{k}={n}" for k, n in counts.items()))
         result: Optional[set[int]] = None
         for key, value in filters:
-            pred = And([Eq("metric_id", metric_id), Eq("tag_key", key),
-                        Eq("tag_value", value)])
             tsids: set[int] = set()
-            for b in await _collect(self.index.scan(ScanRequest(
-                    range=time_range, predicate=pred))):
-                col = b.column(b.schema.names.index("tsid"))
-                tsids.update(col.to_pylist())
+            for lists in kept:
+                found = lists.get((metric_id, key, value))
+                if found is not None:
+                    tsids.update(found.tolist())
+            if scanned:
+                tsids |= await self._scan_tsids(metric_id, key, value,
+                                                time_range, scanned)
             result = tsids if result is None else (result & tsids)
             if not result:
                 return set()
         return result
+
+    async def _scan_tsids(self, metric_id: int, key: str, value: str,
+                          time_range: TimeRange,
+                          segments: set[int]) -> set[int]:
+        """One filter's series by a filtered scan of `segments` of the
+        index table."""
+        pred = And([Eq("metric_id", metric_id), Eq("tag_key", key),
+                    Eq("tag_value", value)])
+        tsids: set[int] = set()
+        for b in await _collect(self.index.scan(
+                ScanRequest(range=time_range, predicate=pred),
+                segment_filter=segments.__contains__)):
+            col = b.column(b.schema.names.index("tsid"))
+            tsids.update(col.to_pylist())
+        return tsids
+
+    async def _build_postings(self, seg: int, ids: tuple,
+                              time_range: TimeRange) -> dict:
+        """The posting lists of segment `seg`, by one unfiltered scan
+        of it as `time_range` selects its SSTs; filed under `ids` only
+        if that is still the segment's version once the scan is done
+        (an SST set never comes back, so equal before and after means
+        unchanged in between; what a write in between made the scan
+        read is this query's answer and nobody else's)."""
+        lists, nbytes = _posting_lists(await _collect(self.index.scan(
+            ScanRequest(range=time_range),
+            segment_filter=lambda s: s == seg)))
+        now = (await self.index.segment_versions(time_range)).get(seg)
+        if now is not None and now.ids == ids:
+            self._postings.put(seg, (ids, lists), nbytes)
+        return lists
 
     async def label_values(self, metric_id: int, tag_key: str,
                            time_range: TimeRange) -> list[str]:
@@ -527,8 +676,6 @@ class MetricEngine:
         # Budget: the data-table scan-cache bytes, which chunked mode
         # otherwise leaves unused.
         if chunked_data:
-            from horaedb_tpu.storage.scan_cache import ByteLRU
-
             self._chunk_cache = ByteLRU(
                 tables["data"].reader.cache_budget_bytes,
                 hits=_CHUNK_CACHE_HITS, misses=_CHUNK_CACHE_MISSES,
@@ -698,6 +845,7 @@ class MetricEngine:
             self.rollups = None
         for t in self.tables.values():
             await t.close()
+        self.index_manager.close()
         if self._chunk_cache is not None:
             # clear-on-close: a closed engine's decoded chunks can
             # never be read again, and the ledger account goes with it

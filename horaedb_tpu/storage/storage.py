@@ -19,7 +19,7 @@ import abc
 import asyncio
 import time
 from dataclasses import dataclass
-from typing import AsyncIterator, Optional
+from typing import AsyncIterator, NamedTuple, Optional
 
 import pyarrow as pa
 import pyarrow.compute as pc
@@ -38,7 +38,7 @@ from horaedb_tpu.storage.gc import Scrubber, ScrubReport
 from horaedb_tpu.storage.config import StorageConfig, UpdateMode
 from horaedb_tpu.storage.manifest import Manifest
 from horaedb_tpu.storage.read import ParquetReader, ScanPlan, ScanRequest
-from horaedb_tpu.storage.sst import FileMeta, SstFile, sst_path
+from horaedb_tpu.storage.sst import FileMeta, SstFile, segment_of, sst_path
 from horaedb_tpu.storage.types import (
     StorageSchema,
     TimeRange,
@@ -72,6 +72,19 @@ class WriteResult:
     size: int
 
 
+class SegmentVersion(NamedTuple):
+    """What a scan of one segment would read: the ids of the segment's
+    SSTs that a range selects, sorted, and their rows summed.  An SST
+    is immutable and its id names its object for good (a process-wide
+    counter seeded from the wall clock: storage/sst.py), so two equal
+    id tuples of one table mean the same rows — every write, flush,
+    compaction, scrub or manifest reload that changes the content
+    changes the tuple."""
+
+    ids: tuple
+    rows: int
+
+
 class TimeMergeStorage(abc.ABC):
     """Engine facade (ref: storage.rs:76-89)."""
 
@@ -86,6 +99,14 @@ class TimeMergeStorage(abc.ABC):
 
     @abc.abstractmethod
     async def compact(self) -> None: ...
+
+    @abc.abstractmethod
+    async def segment_versions(
+            self, time_range: TimeRange
+    ) -> dict[int, Optional[SegmentVersion]]:
+        """Segment start -> the version of every segment a scan of
+        `time_range` would read; None where rows outside any SST would
+        be merged in (no SST set names that content)."""
 
 
 class CloudObjectStorage(TimeMergeStorage):
@@ -159,8 +180,6 @@ class CloudObjectStorage(TimeMergeStorage):
         race mid-segment (read.py).  The range filter mirrors
         build_scan_plan's manifest.find_ssts so recovery cannot leak
         rows from SSTs the original plan excluded."""
-        from horaedb_tpu.storage.sst import segment_of
-
         ssts = await self.manifest.all_ssts()
         return [f for f in ssts
                 if segment_of(f, self.segment_duration_ms) == segment_start
@@ -544,6 +563,20 @@ class CloudObjectStorage(TimeMergeStorage):
         ensure(self.manifest is not None, "storage not opened")
         ssts = await self.manifest.find_ssts(req.range)
         return self.reader.build_plan(ssts, req, keep_builtin=keep_builtin)
+
+    async def segment_versions(
+            self, time_range: TimeRange
+    ) -> dict[int, Optional[SegmentVersion]]:
+        """The manifest lookup and the grouping of _build_scan_plan,
+        in memory and with nothing planned or read."""
+        ensure(self.manifest is not None, "storage not opened")
+        by_segment: dict[int, list[SstFile]] = {}
+        for f in await self.manifest.find_ssts(time_range):
+            by_segment.setdefault(
+                segment_of(f, self.segment_duration_ms), []).append(f)
+        return {seg: SegmentVersion(tuple(sorted(f.id for f in files)),
+                                    sum(f.meta.num_rows for f in files))
+                for seg, files in by_segment.items()}
 
     async def plan_query(self, req: ScanRequest, spec=None, top_k=None):
         """Build the composable QueryPlan every query shape routes

@@ -129,7 +129,7 @@ class Trace:
 
     __slots__ = ("trace_id", "name", "kind", "op", "slow_threshold_s",
                  "root_fields", "root_span_id", "start_ms", "_t0",
-                 "spans", "counters", "finished", "_lock")
+                 "spans", "counters", "open_fields", "finished", "_lock")
 
     def __init__(self, trace_id: str, name: str, kind: str = "query",
                  op: str = "", slow_threshold_s: Optional[float] = None,
@@ -145,6 +145,8 @@ class Trace:
         self._t0 = time.perf_counter()
         self.spans: list[dict] = []
         self.counters: dict[str, float] = {}
+        # span id -> the fields of a span still open (span_note)
+        self.open_fields: dict[str, dict] = {}
         self.finished = False
         self._lock = threading.Lock()
 
@@ -536,6 +538,17 @@ def trace_add(name: str, value: float = 1.0) -> None:
         trace.add(name, value)
 
 
+def span_note(**fields) -> None:
+    """Add fields to the innermost span open around the caller (no-op
+    outside a trace, or under its root alone): what a callee learned
+    that its caller's span should say."""
+    trace = _current_trace.get()
+    if trace is not None:
+        open_fields = trace.open_fields.get(_current_span_id.get())
+        if open_fields is not None:
+            open_fields.update(fields)
+
+
 _TraceAnnotation = None
 # the share of `sync` spans that read their thread's CPU clock (see
 # span): a constant, which the tests set to 1
@@ -613,6 +626,7 @@ class span:
                 self._parent_id = (_current_span_id.get()
                                    or trace.root_span_id)
                 self._tok = _current_span_id.set(self._span_id)
+                trace.open_fields[self._span_id] = self.fields
         self._ann = _annotation("horaedb/" + self.name, trace_id)
         self._ann.__enter__()
         self._wall_ms = time.time() * 1e3
@@ -641,6 +655,7 @@ class span:
         trace = self._trace
         if trace is not None:
             _current_span_id.reset(self._tok)
+            trace.open_fields.pop(self._span_id, None)
             record = {
                 "span_id": self._span_id, "parent_id": self._parent_id,
                 "name": self.name, "start_ms": round(self._wall_ms, 3),

@@ -531,6 +531,19 @@ class IngestStorage(TimeMergeStorage):
                 trace_add("memtable_overlay_rows", out.num_rows)
                 yield out
 
+    async def segment_versions(self, time_range):
+        """The inner table's versions, None for every segment a scan
+        of the range would overlay (a memtable or a flushing one holds
+        rows for it), those that live only in memtables included.  The
+        overlay is read BEFORE the manifest, as scan() reads it: a
+        flush in between can only turn a None into an SST set that
+        already holds its rows."""
+        overlaid = self._snapshot_overlay(time_range)
+        versions = await self.inner.segment_versions(time_range)
+        for seg in overlaid:
+            versions[seg] = None
+        return versions
+
     async def scan_aggregate(self, req: ScanRequest, spec,
                              first_plan: Optional[ScanPlan] = None,
                              top_k=None):

@@ -504,6 +504,87 @@ class TestShipAndPromote:
 
         run(go())
 
+    def test_promoted_follower_resolves_filters_as_the_scan_does(
+            self, tmp_path):
+        """The promoted follower's label filters: the series the
+        primary flushed are in SSTs it adopts through the manifest
+        (posting lists, built from them), the primary's acked tail is
+        in its replayed memtables (no SST set names that segment's
+        content: the filtered scan answers) — either way what the
+        scan of the index table answers, nothing lost."""
+        async def go():
+            from horaedb_tpu.cluster.replication import WalFollower
+            from horaedb_tpu.metric_engine import engine as engine_mod
+            from horaedb_tpu.ops import And, Eq
+            from horaedb_tpu.storage.read import ScanRequest
+
+            def outcomes():
+                return {o: c.value
+                        for o, c in engine_mod._POSTINGS.items()}
+
+            async def resolve(engine, host, rng):
+                mid = await engine.metric_manager.resolve("cpu", rng)
+                before = outcomes()
+                got = await engine.index_manager.find_tsids(
+                    mid, [("host", host)], rng)
+                how = {o: n - before[o] for o, n in outcomes().items()}
+                want = set()
+                async for b in engine.index_manager.index.scan(ScanRequest(
+                        range=rng, predicate=And([
+                            Eq("metric_id", mid), Eq("tag_key", "host"),
+                            Eq("tag_value", host)]))):
+                    want.update(b.column("tsid").to_pylist())
+                assert got == want and len(got) == 1
+                return how
+
+            clock = Clock()
+            store = MemoryObjectStore()
+            primary = await MetricEngine.open(
+                "repl/region_7", store, segment_ms=2 * HOUR,
+                wal_config=wal_config(tmp_path / "p_wal"))
+            promoted = None
+            try:
+                # the tail's series registers in the NEXT 2 h segment
+                late = T0 + 2 * HOUR
+                await primary.write([
+                    sample("cpu", [("host", f"h{i}")], T0 + i, float(i))
+                    for i in range(4)])
+                await primary.flush()
+                await primary.write([
+                    sample("cpu", [("host", "tail")], late, 9.0)])
+                hub = ReplicationHub(primary)
+                follower = WalFollower(LocalWalSource(hub, "f1"),
+                                       str(tmp_path / "mirror"), region=7)
+                await follower.poll_once()
+                hub.close()
+                await follower.close()
+                await kill_engine(primary)
+                primary = None
+                promoted, _lease = await promote(
+                    "repl", store, 7, LeaseManager(store, "repl",
+                                                   clock=clock),
+                    "node-b", str(tmp_path / "mirror"),
+                    wal_config(tmp_path / "p_wal"), segment_ms=2 * HOUR)
+                early = TimeRange.new(T0, T0 + 10)
+                assert await resolve(promoted, "h2", early) \
+                    == {"hit": 0, "build": 1, "bypass": 0}
+                assert await resolve(promoted, "h3", early) \
+                    == {"hit": 1, "build": 0, "bypass": 0}
+                both = TimeRange.new(T0, late + 10)
+                assert await resolve(promoted, "tail", both) \
+                    == {"hit": 1, "build": 0, "bypass": 1}
+                await promoted.flush()
+                assert await resolve(promoted, "tail", both) \
+                    == {"hit": 1, "build": 1, "bypass": 0}
+            finally:
+                if primary is not None:
+                    await primary.close()
+                if promoted is not None:
+                    install_fence(promoted, None)
+                    await promoted.close()
+
+        run(go())
+
     def test_follower_restart_recovers_watermark(self, tmp_path):
         """A restarted follower (fresh WalFollower over an existing
         mirror) rebuilds its shipped watermark from the mirror's own
