@@ -37,6 +37,7 @@ from horaedb_tpu.common.memledger import ledger as memledger
 from horaedb_tpu.objstore import ObjectStore
 from horaedb_tpu.ops import And, Eq, In, TimeRangePred
 from horaedb_tpu.ops.downsample import ALL_AGGS
+from horaedb_tpu.ops.last import LastSpec, combine_fields, last_on_host
 from horaedb_tpu.ops.select import SelectSpec, compare
 from horaedb_tpu.storage.config import StorageConfig
 from horaedb_tpu.storage.read import (AggregateSpec, ScanRequest,
@@ -344,6 +345,25 @@ def _posting_lists(batches: list[pa.RecordBatch]) -> tuple[dict, int]:
     return lists, nbytes
 
 
+def _series_lists(batches: list[pa.RecordBatch]) -> tuple[dict, int]:
+    """One segment's rows of the series table as {metric_id: its
+    tsids, sorted, as np.uint64} (unique: the segment's merge leaves
+    one row a series), and the bytes that holds.  The lists are views
+    of one array."""
+    tbl = pa.Table.from_batches(batches).combine_chunks()
+    if not tbl.num_rows:
+        return {}, 0
+    mid = tbl.column("metric_id").chunk(0).to_numpy()
+    tsid = tbl.column("tsid").chunk(0).to_numpy()
+    order = np.lexsort((tsid, mid))
+    mid, tsid = mid[order], tsid[order]
+    starts = np.flatnonzero(np.append(True, mid[1:] != mid[:-1]))
+    ends = np.append(starts[1:], len(tsid))
+    lists = {m: tsid[s:e] for m, s, e in zip(
+        mid[starts].tolist(), starts.tolist(), ends.tolist())}
+    return lists, tsid.nbytes + _POSTINGS_KEY_BYTES * len(lists)
+
+
 class IndexManager:
     """TSID resolution + series/tags/index registration per segment
     (ref: index/mod.rs:25-44, body from RFC:86-137)."""
@@ -436,26 +456,8 @@ class IndexManager:
         version or makes it None."""
         if not filters:
             return None
-        versions = await self.index.segment_versions(time_range)
-        kept, scanned = [], set()
-        counts = dict.fromkeys(_POSTINGS, 0)
-        for seg, version in versions.items():
-            if version is None or version.rows > _POSTINGS_MAX_ROWS:
-                outcome = "bypass"
-                scanned.add(seg)
-            else:
-                entry = self._postings.peek_entry(seg)
-                if entry is not None and entry[0] == version.ids:
-                    outcome = "hit"
-                    self._postings.record_hit(seg)
-                    kept.append(entry[1])
-                else:
-                    outcome = "build"
-                    kept.append(await self._build_postings(
-                        seg, version.ids, time_range))
-            counts[outcome] += 1
-            _POSTINGS[outcome].inc()
-        span_note(postings=" ".join(f"{k}={n}" for k, n in counts.items()))
+        kept, scanned = await self._segment_lists(
+            self.index, lambda seg: seg, _posting_lists, time_range)
         result: Optional[set[int]] = None
         for key, value in filters:
             tsids: set[int] = set()
@@ -486,20 +488,76 @@ class IndexManager:
             tsids.update(col.to_pylist())
         return tsids
 
-    async def _build_postings(self, seg: int, ids: tuple,
-                              time_range: TimeRange) -> dict:
-        """The posting lists of segment `seg`, by one unfiltered scan
-        of it as `time_range` selects its SSTs; filed under `ids` only
-        if that is still the segment's version once the scan is done
-        (an SST set never comes back, so equal before and after means
-        unchanged in between; what a write in between made the scan
-        read is this query's answer and nobody else's)."""
-        lists, nbytes = _posting_lists(await _collect(self.index.scan(
+    async def series_of(self, metric_id: int,
+                        time_range: TimeRange) -> np.ndarray:
+        """Every series of the metric registered in a segment of the
+        range, ascending (np.uint64): what a walk with no label filter
+        has to account for.  From the SERIES table, which holds a row
+        for every series (the index table has none for a series
+        without labels), under find_tsids' rules: a segment's lists
+        ({metric: its series}) kept under its SST set in the same LRU,
+        counted by the same outcomes, the filtered scan where no SST
+        set names the segment's content or it is over the row cap."""
+        kept, scanned = await self._segment_lists(
+            self.series, lambda seg: ("series", seg), _series_lists,
+            time_range)
+        found = [lists[metric_id] for lists in kept if metric_id in lists]
+        if scanned:
+            for b in await _collect(self.series.scan(
+                    ScanRequest(range=time_range,
+                                predicate=Eq("metric_id", metric_id)),
+                    segment_filter=scanned.__contains__)):
+                found.append(b.column(
+                    b.schema.names.index("tsid")).to_numpy())
+        if not found:
+            return np.zeros(0, np.uint64)
+        return np.unique(np.concatenate(found)).astype(np.uint64)
+
+    async def _segment_lists(self, table, key_of, build,
+                             time_range: TimeRange) -> tuple[list, set]:
+        """(the lists of every segment of `table` in the range whose
+        SST set names its content: kept ones where that set is the one
+        they were built from, built now where it is not; the segments
+        a filtered scan must answer for: rows in a memtable, or over
+        the row cap).  Each segment counted by its outcome, and the
+        counts noted on the open span."""
+        versions = await table.segment_versions(time_range)
+        kept, scanned = [], set()
+        counts = dict.fromkeys(_POSTINGS, 0)
+        for seg, version in versions.items():
+            if version is None or version.rows > _POSTINGS_MAX_ROWS:
+                outcome = "bypass"
+                scanned.add(seg)
+            else:
+                entry = self._postings.peek_entry(key_of(seg))
+                if entry is not None and entry[0] == version.ids:
+                    outcome = "hit"
+                    self._postings.record_hit(key_of(seg))
+                    kept.append(entry[1])
+                else:
+                    outcome = "build"
+                    kept.append(await self._build_lists(
+                        table, seg, key_of(seg), build, version.ids,
+                        time_range))
+            counts[outcome] += 1
+            _POSTINGS[outcome].inc()
+        span_note(postings=" ".join(f"{k}={n}" for k, n in counts.items()))
+        return kept, scanned
+
+    async def _build_lists(self, table, seg: int, key, build, ids: tuple,
+                           time_range: TimeRange) -> dict:
+        """The lists of segment `seg` of `table`, by one unfiltered
+        scan of it as `time_range` selects its SSTs; filed under `ids`
+        only if that is still the segment's version once the scan is
+        done (an SST set never comes back, so equal before and after
+        means unchanged in between; what a write in between made the
+        scan read is this query's answer and nobody else's)."""
+        lists, nbytes = build(await _collect(table.scan(
             ScanRequest(range=time_range),
             segment_filter=lambda s: s == seg)))
-        now = (await self.index.segment_versions(time_range)).get(seg)
+        now = (await table.segment_versions(time_range)).get(seg)
         if now is not None and now.ids == ids:
-            self._postings.put(seg, (ids, lists), nbytes)
+            self._postings.put(key, (ids, lists), nbytes)
         return lists
 
     async def label_values(self, metric_id: int, tag_key: str,
@@ -629,6 +687,15 @@ _ROWS_SELECT_SECONDS = registry.counter(
     "wall seconds inside the select of row selections (plan, the "
     "device's or the host's route over every segment, the combine)")
 
+_LAST_QUERIES = registry.counter(
+    "query_last_total",
+    "requests for the newest row of every series (query_last)")
+_LAST_SECONDS = registry.counter(
+    "query_last_seconds_total",
+    "wall seconds inside the walk of query_last requests (plan, the "
+    "device's or the host's route over every segment asked, the "
+    "combine)")
+
 _CHUNK_CACHE_HITS = registry.counter(
     "chunk_decode_cache_hits_total",
     "chunked-layout decode cache hits (the chunked scan cache)")
@@ -638,6 +705,21 @@ _CHUNK_CACHE_MISSES = registry.counter(
 _CHUNK_CACHE_EVICTIONS = registry.counter(
     "chunk_decode_cache_evictions_total",
     "chunked-layout decode cache evictions")
+
+
+def _rows_table(out: dict, fields: list[str]) -> pa.Table:
+    """A row answer's columns ({groups, timestamps, values, found}: a
+    field's flags None where it was found at every row) as the Arrow
+    table /query_rows and /query_last serve: tsid, timestamp, one
+    nullable float32 column a field."""
+    return pa.table(
+        [pa.array(out["groups"], type=pa.uint64()),
+         pa.array(out["timestamps"], type=pa.int64())]
+        + [pa.array(v, type=pa.float32(),
+                    mask=None if f is None or not len(v)
+                    else ~np.asarray(f))
+           for v, f in zip(out["values"], out["found"])],
+        names=["tsid", "timestamp"] + list(fields))
 
 
 class MetricEngine:
@@ -1661,14 +1743,7 @@ class MetricEngine:
                     reqs, spec, [distinct.index(f) for f in fields])
                 out = await self.tables["data"].execute_plan(qp)
         _ROWS_SELECT_SECONDS.inc(time.perf_counter() - t0)
-        return pa.table(
-            [pa.array(out["groups"], type=pa.uint64()),
-             pa.array(out["timestamps"], type=pa.int64())]
-            + [pa.array(v, type=pa.float32(),
-                        mask=None if f is None or not len(v)
-                        else ~np.asarray(f))
-               for v, f in zip(out["values"], out["found"])],
-            names=["tsid", "timestamp"] + list(fields))
+        return _rows_table(out, fields)
 
     async def _rows_where_chunked(self, metric, filters, time_range,
                                   spec: SelectSpec, where_field: str,
@@ -1700,6 +1775,103 @@ class MetricEngine:
             found.append(ok)
         return {"groups": groups, "timestamps": ts, "values": values,
                 "found": found}
+
+    async def query_last(self, metric: str,
+                         filters: list[tuple[str, str]],
+                         fields: list[str], start: Optional[int] = None,
+                         end: Optional[int] = None) -> pa.Table:
+        """The newest row of every series of `metric` that passes the
+        label filters: TSBS's lastpoint, a status page's and an instant
+        query's question.  For every such series with at least one
+        CURRENT sample (after last-write-wins dedup: what query()
+        returns) of a field asked in [start, end), an absent bound
+        unbounded, ONE row: tsid (uint64), timestamp (int64: the
+        greatest at which any field asked has such a sample), then one
+        nullable float32 column a field, in the order asked: the
+        field's value at exactly that timestamp, null where it has no
+        sample there.  Rows ascend by tsid.  A series without a sample
+        has no row.  Exact and whole: no look-back unless the client
+        names one, no cut after some segments; a series whose newest
+        sample lies in the oldest segment is answered.
+
+        ONE shared resolve for all fields, which also names the series
+        the walk must account for: the label filters' posting lists, or
+        every series of the metric (IndexManager.series_of); never a
+        time cut-off.  The walk (CloudObjectStorage.scan_last) takes
+        the data table's segments newest first and stops when no series
+        is missing: on the device over the resident decode slices, or
+        by the row scan (storage/read.py::last_segment decides per
+        segment, and counts).  A chunked table scans each field by
+        query() and reduces on the host.
+
+        Traced as children of the request's root: one `resolve` span,
+        one `last` span (its children a segment asked: route=,
+        series_in=, series_out=, rows_read=).  Raises Error (a 400) for
+        a field the metric does not have, before any scan; a metric
+        nobody wrote answers its columns and no row."""
+        ensure(len(fields) > 0, "fields must be non-empty")
+        ensure(len(set(fields)) == len(fields), "fields must be distinct")
+        ensure(not {"tsid", "timestamp"} & set(fields),
+               "a field may not be named tsid or timestamp")
+        rng = TimeRange.new(int(Timestamp.MIN) if start is None else start,
+                            int(Timestamp.MAX) if end is None else end)
+        ensure(rng.start < rng.end, "start must lie before end")
+        spec = LastSpec(group_col="tsid", ts_col="timestamp",
+                        value_col="value")
+        _LAST_QUERIES.inc()
+        with span("resolve", metric=metric):
+            mid = await self.metric_manager.resolve(metric, rng)
+            expect = tsids = None
+            if mid is not None:
+                unknown = await self.metric_manager.unknown_fields(
+                    metric, fields, rng)
+                ensure(not unknown,
+                       f"unknown field(s) {unknown} of metric {metric!r}")
+                tsids = await self.index_manager.find_tsids(mid, filters,
+                                                            rng)
+                expect = (await self.index_manager.series_of(mid, rng)
+                          if tsids is None
+                          else np.asarray(sorted(tsids), dtype=np.uint64))
+        t0 = time.perf_counter()
+        with span("last", metric=metric, fields=len(fields)):
+            if expect is None or not len(expect):
+                out = {"groups": [], "timestamps": [],
+                       "values": [[] for _ in fields],
+                       "found": [None for _ in fields]}
+            elif self.chunked_data:
+                out = await self._last_chunked(metric, filters, rng, fields,
+                                               expect)
+            else:
+                leaves = [Eq("metric_id", mid)]
+                if start is not None or end is not None:
+                    leaves.append(TimeRangePred(
+                        "timestamp", int(rng.start), int(rng.end)))
+                if tsids is not None:
+                    leaves.append(In("tsid", sorted(tsids)))
+                reqs = [ScanRequest(range=rng, predicate=And(
+                    [leaves[0], Eq("field_id", field_id_of(f))]
+                    + leaves[1:])) for f in fields]
+                qp = await self.tables["data"].plan_last(reqs, spec, expect)
+                out = await self.tables["data"].execute_plan(qp)
+        _LAST_SECONDS.inc(time.perf_counter() - t0)
+        return _rows_table(out, fields)
+
+    async def _last_chunked(self, metric, filters, rng: TimeRange,
+                            fields: list[str], expect) -> dict:
+        """query_last over a chunked table: every field by query()
+        (chunks decoded, deduplicated) over the whole range at once,
+        the series' last rows and their combine on the host."""
+        per_field = []
+        for f in fields:
+            tbl = await self.query(metric, filters, rng, field=f)
+            per_field.append(last_on_host(
+                tbl.column("tsid").to_numpy(),
+                tbl.column("timestamp").to_numpy(),
+                tbl.column("value").to_numpy().astype(np.float32)))
+        part = combine_fields(per_field, expect)
+        return {"groups": part.groups, "timestamps": part.timestamps,
+                "values": part.values,
+                "found": [None if f.all() else f for f in part.found]}
 
     async def _downsample_chunked(self, metric: str, filters, time_range,
                                   bucket_ms: int, num_buckets: int,

@@ -74,7 +74,8 @@ logger = logging.getLogger(__name__)
 # bypass the admission+tenant middleware chain.
 _QUERY_ENDPOINTS = frozenset({
     "/query", "/query_arrow", "/query_topk", "/query_multi",
-    "/query_rows", "/label_values", "/label_names", "/metrics_list"})
+    "/query_rows", "/query_last", "/label_values", "/label_names",
+    "/metrics_list"})
 _WRITE_ENDPOINTS = frozenset({"/write", "/write_arrow"})
 _UNGOVERNED_ENDPOINTS = frozenset({
     "/", "/toggle", "/compact", "/metrics", "/stats",
@@ -104,10 +105,10 @@ _QUEUED_QUERIES = registry.gauge(
 _RESPOND_CELLS = registry.counter(
     "respond_cells_total",
     "grid cells encoded into downsample responses, and values (rows x "
-    "columns) serialized into /query_rows responses")
+    "columns) serialized into /query_rows and /query_last responses")
 _RESPOND_BYTES = registry.counter(
     "respond_bytes_total",
-    "body bytes of downsample and /query_rows responses")
+    "body bytes of downsample, /query_rows and /query_last responses")
 _RESPOND_ENCODE_SECONDS = registry.counter(
     "respond_encode_seconds_total",
     "wall seconds inside the downsample response encoder (the lazy "
@@ -1380,17 +1381,37 @@ def build_app(state: ServerState) -> web.Application:
             return _error_response(e)
         return web.json_response({"written": written})
 
-    def _parse_query_body(body: dict):
-        """Shared /query + /query_arrow request parsing.  The dict filter
-        form loses duplicate keys; the list-of-pairs form (RemoteRegion
-        sends it) preserves them.  bucket_ms converts HERE so a
-        non-numeric value is a 400, not a 500 mid-handler."""
+    def _parse_metric_filters(body: dict):
         metric = body["metric"]
         raw_filters = body.get("filters", {})
         if isinstance(raw_filters, dict):
             filters = sorted(raw_filters.items())
         else:
             filters = sorted((str(k), str(v)) for k, v in raw_filters)
+        return metric, filters
+
+    def _parse_row_answer(body: dict):
+        """What /query_rows and /query_last ask of their answer: the
+        fields, a column each, and the stream's compression."""
+        from horaedb_tpu.common.ipc import COMPRESSIONS
+
+        fields = body["fields"]
+        if (not isinstance(fields, list) or not fields
+                or not all(isinstance(f, str) for f in fields)
+                or len(set(fields)) != len(fields)):
+            raise ValueError("fields must be a non-empty list of "
+                             "distinct strings")
+        compression = body.get("compression")
+        if compression not in COMPRESSIONS:
+            raise ValueError(f"unsupported compression {compression!r}")
+        return fields, compression
+
+    def _parse_query_body(body: dict):
+        """Shared /query + /query_arrow request parsing.  The dict filter
+        form loses duplicate keys; the list-of-pairs form (RemoteRegion
+        sends it) preserves them.  bucket_ms converts HERE so a
+        non-numeric value is a 400, not a 500 mid-handler."""
+        metric, filters = _parse_metric_filters(body)
         rng = TimeRange.new(int(body["start"]), int(body["end"]))
         field = body.get("field", "value")
         bucket_ms = body.get("bucket_ms")
@@ -1568,7 +1589,6 @@ def build_app(state: ServerState) -> web.Application:
         fields: [..], compression?}; the answer is an Arrow IPC stream
         (tsid, timestamp, one nullable float32 column a field), sorted
         by (tsid, timestamp): README.md has the semantics."""
-        from horaedb_tpu.common.ipc import COMPRESSIONS
         from horaedb_tpu.ops.select import OPS
 
         try:
@@ -1579,7 +1599,6 @@ def build_app(state: ServerState) -> web.Application:
                 where = body["where"]
                 where_field, op, value = (where["field"], where["op"],
                                           where["value"])
-                fields = body["fields"]
                 if not isinstance(where_field, str):
                     raise ValueError("where.field must be a string")
                 if op not in OPS:
@@ -1589,15 +1608,7 @@ def build_app(state: ServerState) -> web.Application:
                         or not math.isfinite(value):
                     raise ValueError("where.value must be a finite "
                                      "number")
-                if (not isinstance(fields, list) or not fields
-                        or not all(isinstance(f, str) for f in fields)
-                        or len(set(fields)) != len(fields)):
-                    raise ValueError("fields must be a non-empty list of "
-                                     "distinct strings")
-                compression = body.get("compression")
-                if compression not in COMPRESSIONS:
-                    raise ValueError(
-                        f"unsupported compression {compression!r}")
+                fields, compression = _parse_row_answer(body)
         except (KeyError, TypeError, ValueError) as e:
             return web.json_response({"error": f"bad request: {e}"},
                                      status=400)
@@ -1609,6 +1620,45 @@ def build_app(state: ServerState) -> web.Application:
         try:
             tbl = await rows_where(metric, filters, rng, where_field, op,
                                    float(value), fields)
+        except Error as e:
+            return _error_response(e)
+        payload = await _respond_bytes(
+            tbl.num_rows * tbl.num_columns,
+            lambda where: _rows_payload(tbl, compression, where))
+        return web.Response(
+            body=payload,
+            content_type="application/vnd.apache.arrow.stream")
+
+    @routes.post("/query_last")
+    async def query_last(req: web.Request) -> web.Response:
+        """The newest row of every series (TSBS lastpoint): for every
+        series of the metric that passes the filters and has a sample
+        of a field asked in [start, end), ONE row at the greatest such
+        timestamp, with every field asked at exactly that timestamp.
+        Body: {metric, filters?, fields: [..], start?, end?,
+        compression?}; an absent bound is unbounded (no look-back by
+        default).  The answer is an Arrow IPC stream (tsid, timestamp,
+        one nullable float32 column a field), ascending by tsid:
+        README.md has the semantics."""
+        try:
+            with span("parse"):
+                body = await req.json()
+                metric, filters = _parse_metric_filters(body)
+                fields, compression = _parse_row_answer(body)
+                start, end = (None if body.get(k) is None else int(body[k])
+                              for k in ("start", "end"))
+                if start is not None and end is not None and start >= end:
+                    raise ValueError("start must lie before end")
+        except (KeyError, TypeError, ValueError) as e:
+            return web.json_response({"error": f"bad request: {e}"},
+                                     status=400)
+        last = getattr(state.engine, "query_last", None)
+        if last is None:
+            return web.json_response(
+                {"error": "this front end has no last-row query"},
+                status=501)
+        try:
+            tbl = await last(metric, filters, fields, start, end)
         except Error as e:
             return _error_response(e)
         payload = await _respond_bytes(
@@ -1890,7 +1940,7 @@ def _downsample_payload(body: dict, where: str) -> bytes:
 
 
 def _rows_payload(tbl: pa.Table, compression, where: str) -> bytes:
-    """The bytes of a /query_rows response: `tbl` as one Arrow IPC
+    """The bytes of a /query_rows or /query_last response: `tbl` as one Arrow IPC
     stream, counted as _downsample_payload counts its grids (a value
     of the table a cell)."""
     from horaedb_tpu.common.ipc import serialize_stream

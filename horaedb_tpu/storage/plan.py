@@ -106,6 +106,37 @@ class SelectPlan:
             for scan in self.scans)
 
 
+@dataclass
+class LastPlan:
+    """last (the newest row of every series, a field a scan) over the
+    table's segments NEWEST FIRST, with no time range of its own: the
+    walk asks one segment for the last rows of the series still
+    missing and stops when none is missing or no segment is left.
+
+    `segments` is the manifest's answer when the plan was built,
+    (segment start, its SSTs), newest first; `requests` hold a request
+    a field asked, all over the same range (unbounded where the client
+    gave no bound); `expect` are the series the walk accounts for,
+    ascending.  Which route a segment takes (the device's resident
+    slices or the row scan) is decided per segment where it runs
+    (ParquetReader.last_segment) and counted there."""
+
+    segments: list
+    requests: list
+    last: object                  # ops/last.LastSpec
+    expect: np.ndarray
+
+    def describe(self) -> str:
+        spec = self.last
+        text = (f"Last: group={spec.group_col}, ts={spec.ts_col}, "
+                f"value={spec.value_col}, fields={len(self.requests)}, "
+                f"series={len(self.expect)}, newest first, stops when "
+                f"no series is missing\n")
+        return text + "\n".join(
+            f"  Segment {start}: {len(ssts)} sst(s)"
+            for start, ssts in self.segments)
+
+
 def apply_top_k(group_values: np.ndarray, grids: dict,
                 tk: TopKSpec) -> tuple[np.ndarray, dict]:
     """Host top-k over finalized grids: by the time grids exist the

@@ -61,6 +61,7 @@ from horaedb_tpu.storage.types import (
     TimeRange,
 )
 from horaedb_tpu.ops import device_decode
+from horaedb_tpu.ops import last as last_ops
 from horaedb_tpu.ops import select as select_ops
 from horaedb_tpu.storage import combine as combine_mod, parquet_io, sidecar
 from horaedb_tpu.utils import active_trace, phase, registry, span, trace_add
@@ -224,6 +225,26 @@ _SELECT_CPU = registry.counter(
     "a row selection (calls issued, the download, the rows shaped)")
 # config choices, not fallbacks: counted by route only
 _SELECT_MODE_REASONS = ("mode_host", "cpu_auto")
+# the last-row route (last_segment): every segment a walk asked, by the
+# route that answered it and, on the host route, why
+_LAST_SEGMENTS = registry.counter(
+    "scan_last_segments_total",
+    "segments a last-row walk asked for the newest rows of the series "
+    "still missing, by the route that answered them: device = the "
+    "series' last rows taken on the device from resident slices "
+    "(ops/last.py), host = the row scan's merged rows reduced in "
+    "numpy, for `reason` (the select's reasons, and `memtable`: rows "
+    "of the segment are not in an SST yet; mode_host, cpu_auto and "
+    "memtable are no fallbacks, every other reason also counts in "
+    "scan_decode_fallback_total)")
+_LAST_ROWS = registry.counter(
+    "scan_last_rows_total",
+    "rows of last-row walks by route: read = rows put through the "
+    "decode (the device route: the asked fields' slices as uploaded; "
+    "the host route: the rows its scans returned), answered = points "
+    "answered, a series found x a field with a sample at the row's "
+    "timestamp")
+_LAST_NO_FALLBACK = _SELECT_MODE_REASONS + ("memtable",)
 _VALUE_LEAF = {"gt": filter_ops.Gt, "ge": filter_ops.Ge,
                "lt": filter_ops.Lt, "le": filter_ops.Le}
 
@@ -4050,17 +4071,8 @@ class ParquetReader:
                         got[start].append(batch)
             finally:
                 await scan.aclose()
-            out = {}
-            for start, batches in got.items():
-                cols = [np.concatenate([
-                    b.column(name).to_numpy(zero_copy_only=False)
-                    for b in batches]) if batches else np.zeros(0, kind)
-                    for name, kind in ((spec.group_col, np.uint64),
-                                       (spec.ts_col, np.int64),
-                                       (spec.value_col, np.float32))]
-                out[start] = (cols[0], cols[1].astype(np.int64),
-                              cols[2].astype(np.float32))
-            return out
+            return {start: _scanned_columns(batches, spec)
+                    for start, batches in got.items()}
 
         selected = await rows_of(kept(first, filter_ops.And(children
                                                             + [leaf])))
@@ -4086,8 +4098,9 @@ class ParquetReader:
             yield start, part
 
     def finalize_select(self, parts: list, n_fields: int) -> dict:
-        """Segments' SelectedRows to the answer's columns, sorted by
-        (series, timestamp): the `scan.combine` phase.  A field found
+        """Segments' SelectedRows (or a last-row walk's LastRows: one
+        row a series, no series in two parts) to the answer's columns,
+        sorted by (series, timestamp): the `scan.combine` phase.  A field found
         at every row has None for its flags (nothing to mask, and
         nothing to sort: this runs on the loop's thread, once a query,
         over every row of the answer)."""
@@ -4096,7 +4109,7 @@ class ParquetReader:
                 return (np.concatenate(arrays) if arrays
                         else np.zeros(0, kind))
 
-            groups = cat([p.groups for p in parts], np.uint64)
+            groups = _cat_groups([p.groups for p in parts])
             ts = cat([p.timestamps for p in parts], np.int64)
             order = np.lexsort((ts, groups))
             return {
@@ -4108,6 +4121,95 @@ class ParquetReader:
                           else cat([p.found[f] for p in parts],
                                    bool)[order]
                           for f in range(n_fields)]}
+
+    # ---- the newest row of every series (ops/last.py) ---------------------
+
+    async def last_segment(self, plans: list, spec,
+                           missing: np.ndarray) -> "last_ops.LastRows | str":
+        """ONE segment's newest rows (ops/last.LastSpec) of the series
+        in `missing` (ascending): `plans` hold a plan a field asked,
+        each over that segment alone.  The device route answers from the fields'
+        resident decode slices (the aggregate route's: same keys, same
+        account; a miss reads, narrows and uploads the slice as that
+        route's miss does and leaves it resident for every route), the
+        fields in one pool job and one download.  Where the plan, the
+        mode or a slice rules the device out, the REASON comes back
+        and the caller answers the segment by the row scan
+        (CloudObjectStorage.scan_last: the memtable's overlay lives
+        there), reduced by last_segment_host."""
+        reason = self._select_route(plans[0])
+        if reason is not None:
+            return reason
+        seg = plans[0].segments[0]
+        # only the columns count: they are the slices' key, which the
+        # last rows share with the aggregates over the same field
+        carrier = AggregateSpec(
+            group_col=spec.group_col, ts_col=spec.ts_col,
+            value_col=spec.value_col, range_start=0, bucket_ms=1,
+            num_buckets=1, which=("count",))
+        plans = [dc_replace(p, decode_spec=carrier) for p in plans]
+        slice_columns = [self._decode_slice_columns(p) for p in plans]
+        with self._phase("scan.windows", segments=1) as probe:
+            resident = [self.scan_cache.get_slice(
+                self._decode_slice_key(plan.segments[0], cols))
+                for plan, cols in zip(plans, slice_columns)]
+            hits = sum(s is not None for s in resident)
+            device_decode.note_resident("hit", hits)
+            device_decode.note_resident("miss", len(plans) - hits)
+            probe.fields["resident"] = hits
+        windows: list = []
+        for plan, cols, seg_slice in zip(plans, slice_columns, resident):
+            deadline_checkpoint()
+            if seg_slice is None:
+                seg_slice = await self._load_select_slice(
+                    plan.segments[0], plan, cols)
+            got = (seg_slice if not isinstance(
+                seg_slice, device_decode.SegmentSlice)
+                else last_ops.plan_window(seg_slice, plan.prune_leaves,
+                                          spec.ts_col))
+            if isinstance(got, str):
+                return got
+            windows.append(got)
+        deadline_checkpoint()
+        fields = await self._run_pool(
+            plans[0].pool, last_ops.last_resident, windows, self._phase,
+            self.table)
+        part = last_ops.combine_fields(
+            fields, missing,
+            rows_read=sum(w.seg.n for w in windows if w is not None))
+        self._count_last(part, "device", "", len(missing),
+                         seg.segment_start)
+        return part
+
+    def last_segment_host(self, scanned: list, spec, missing: np.ndarray,
+                          reason: str,
+                          segment_start: int) -> "last_ops.LastRows":
+        """The host route of last_segment: `scanned` holds, a field
+        asked, the batches a row scan of the segment returned (merged,
+        deduplicated, the memtable's rows laid over them where the WAL
+        is on); the last row of every series in numpy, then the same
+        combine as the device route's."""
+        columns = [_scanned_columns(batches, spec) for batches in scanned]
+        part = last_ops.combine_fields(
+            [last_ops.last_on_host(*cols) for cols in columns], missing,
+            rows_read=sum(len(cols[0]) for cols in columns))
+        self._count_last(part, "host", reason, len(missing),
+                         segment_start)
+        return part
+
+    @staticmethod
+    def _count_last(part, route: str, reason: str, series_in: int,
+                    segment_start: int) -> None:
+        _LAST_SEGMENTS.labels(route=route, reason=reason).inc()
+        if reason and reason not in _LAST_NO_FALLBACK:
+            device_decode.note_fallback(reason)
+        _LAST_ROWS.labels(side="read", route=route).inc(part.rows_read)
+        _LAST_ROWS.labels(side="answered", route=route).inc(
+            sum(int(np.count_nonzero(f)) for f in part.found))
+        with span("last.segment", segment=segment_start, route=route,
+                  reason=reason, series_in=series_in,
+                  series_out=len(part.groups), rows_read=part.rows_read):
+            pass
 
     def _window_groups(self, out_batch: encode.DeviceBatch,
                        spec: AggregateSpec, plan: ScanPlan):
@@ -4924,6 +5026,35 @@ def combine_aggregate_parts(parts: list[tuple[np.ndarray, int, dict]],
     helpers, old tests) keep this name."""
     return combine_mod.combine_aggregate_parts(parts, num_buckets,
                                                which=which)
+
+
+def _scanned_columns(batches: list, spec) -> tuple:
+    """A row scan's batches as (groups, int64 timestamps, float32
+    values), by the columns `spec` (a SelectSpec or a LastSpec)
+    names."""
+    cols = [np.concatenate([
+        b.column(name).to_numpy(zero_copy_only=False)
+        for b in batches]) if batches else np.zeros(0, kind)
+        for name, kind in ((spec.group_col, np.uint64),
+                           (spec.ts_col, np.int64),
+                           (spec.value_col, np.float32))]
+    return cols[0], cols[1].astype(np.int64), cols[2].astype(np.float32)
+
+
+def _cat_groups(arrays: list) -> np.ndarray:
+    """Segments' group values as one array.  A slice's dictionary holds
+    an unsigned key as int64 (ops/encode: nothing past i64::MAX reaches
+    the device) where the row scan returns it unsigned, and numpy would
+    join the two, or either with an empty part of the other kind, as
+    float64, which rounds a series id: integers of both kinds are
+    joined unsigned, and an empty part decides nothing."""
+    arrays = [a for a in arrays if len(a)]
+    if not arrays:
+        return np.zeros(0, np.uint64)
+    kinds = {a.dtype.kind for a in arrays}
+    if kinds == {"i", "u"}:
+        arrays = [a.astype(np.uint64) for a in arrays]
+    return np.concatenate(arrays)
 
 
 def join_on_host(groups: np.ndarray, ts: np.ndarray, f_groups, f_ts,

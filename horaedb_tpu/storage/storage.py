@@ -21,6 +21,7 @@ import time
 from dataclasses import dataclass
 from typing import AsyncIterator, NamedTuple, Optional
 
+import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
@@ -540,6 +541,102 @@ class CloudObjectStorage(TimeMergeStorage):
         return SelectPlan(scans=await self._plan_select(reqs),
                           requests=reqs, select=spec, asked=asked)
 
+    async def scan_last(self, reqs: list, spec, expect,
+                        first_segments: Optional[list] = None,
+                        overlaid=frozenset(), scan=None) -> dict:
+        """The newest row of every series of `expect` (ascending): a
+        walk over the segments NEWEST FIRST that asks each for the last
+        rows (ops/last.LastSpec) of the series still missing, over
+        `reqs` (a request a field asked, all over one range), and stops
+        when none is missing or no segment is left: see
+        ParquetReader.last_segment for the routes.  A series found in a
+        segment is answered there whole: segments partition time, so
+        its greatest timestamp over the fields, and every field's
+        sample at it, lie in the newest segment that holds a row of it.
+        Returns {groups, timestamps, values: [...], found: [...]} (a
+        field's flags None where it was found at every row), ascending
+        by group.
+
+        `overlaid` names segments that hold rows outside any SST (the
+        WAL's memtables: wal/ingest.py); they, and every segment the
+        device route declines, are answered through `scan` (the row
+        scan, the caller's where it overlays those rows).  A compaction
+        race replans the segments not yet answered."""
+        scan = scan or self.scan
+        missing = np.asarray(expect)
+        done: dict[int, object] = {}
+        mem_marks = self.reader._mem_delta_marks()
+        try:
+            for attempt in range(self._SCAN_RETRIES + 1):
+                segments = (first_segments if attempt == 0
+                            and first_segments is not None
+                            else await self._plan_last(reqs[0].range,
+                                                       overlaid))
+                try:
+                    for start, ssts in segments:
+                        if not len(missing):
+                            break
+                        if start in done:
+                            continue
+                        part = await self._last_of_segment(
+                            start, ssts, reqs, spec, missing,
+                            start in overlaid, scan)
+                        done[start] = part
+                        missing = np.setdiff1d(missing, part.groups,
+                                               assume_unique=True)
+                    break
+                except NotFoundError:
+                    if attempt == self._SCAN_RETRIES:
+                        raise
+                    logger.info("last-row walk raced a compaction; "
+                                "replanning")
+            return self.reader.finalize_select(
+                [done[seg] for seg in sorted(done)], len(reqs))
+        finally:
+            self.reader._mem_delta_attribute(mem_marks)
+
+    async def _last_of_segment(self, start: int, ssts: list, reqs: list,
+                               spec, missing, overlaid: bool, scan):
+        reason = "memtable"
+        if not overlaid:
+            got = await self.reader.last_segment(
+                [self.reader.build_plan(ssts, req) for req in reqs], spec,
+                missing)
+            if not isinstance(got, str):
+                return got
+            reason = got
+        scanned = []
+        for req in reqs:
+            rows = scan(req, segment_filter=lambda s: s == start)
+            try:
+                scanned.append([b async for b in rows])
+            finally:
+                await rows.aclose()
+        return self.reader.last_segment_host(scanned, spec, missing, reason,
+                                             start)
+
+    async def _plan_last(self, time_range: TimeRange,
+                         overlaid=frozenset()) -> list:
+        """A last-row walk's scan.plan phase: ONE manifest lookup, the
+        segments it names (and those `overlaid` adds) newest first,
+        each with its SSTs."""
+        with self.reader._phase("scan.plan") as planned:
+            ensure(self.manifest is not None, "storage not opened")
+            by_segment: dict[int, list[SstFile]] = {
+                seg: [] for seg in overlaid}
+            for f in await self.manifest.find_ssts(time_range):
+                by_segment.setdefault(
+                    segment_of(f, self.segment_duration_ms), []).append(f)
+            planned.fields.update(route="last", segments=len(by_segment))
+        return sorted(by_segment.items(), reverse=True)
+
+    async def plan_last(self, reqs: list, spec, expect):
+        """The LastPlan of a last-row walk (storage/plan.py)."""
+        from horaedb_tpu.storage.plan import LastPlan
+
+        return LastPlan(segments=await self._plan_last(reqs[0].range),
+                        requests=reqs, last=spec, expect=np.asarray(expect))
+
     async def build_scan_plan(self, req: ScanRequest,
                               keep_builtin: bool = False) -> ScanPlan:
         """Manifest lookup + plan build: a `scan.plan` phase span."""
@@ -593,17 +690,20 @@ class CloudObjectStorage(TimeMergeStorage):
     def execute_plan(self, qp):
         """Execute a QueryPlan.  Row-scan plans return the async batch
         iterator; aggregate plans return an awaitable of
-        (group_values, grids), select plans an awaitable of the rows'
-        columns (scan_select).  A top-k stage is pushed down into the
+        (group_values, grids), select and last plans an awaitable of
+        the rows' columns (scan_select, scan_last).  A top-k stage is pushed down into the
         combine (scan_aggregate top_k=) so the parts path never builds
         the full groups x buckets grid.  The plan built by plan_query
         is the first attempt's scan plan — one manifest lookup per
         query, not two."""
-        from horaedb_tpu.storage.plan import SelectPlan
+        from horaedb_tpu.storage.plan import LastPlan, SelectPlan
 
         if isinstance(qp, SelectPlan):
             return self.scan_select(qp.requests, qp.select, qp.asked,
                                     first_plans=qp.scans)
+        if isinstance(qp, LastPlan):
+            return self.scan_last(qp.requests, qp.last, qp.expect,
+                                  first_segments=qp.segments)
         if qp.aggregate is None:
             return self.scan(qp.request, first_plan=qp.scan)
         return self.scan_aggregate(qp.request, qp.aggregate,

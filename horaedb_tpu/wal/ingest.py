@@ -553,7 +553,31 @@ class IngestStorage(TimeMergeStorage):
     async def plan_query(self, req: ScanRequest, spec=None, top_k=None):
         return await self.inner.plan_query(req, spec=spec, top_k=top_k)
 
+    async def scan_last(self, reqs: list, spec, expect) -> dict:
+        """The inner table's walk, with every segment a scan of the
+        range would overlay (a memtable or a flushing one holds rows
+        for it, those that live only in memtables included) answered
+        through this table's own scan, which lays those rows over the
+        SSTs'.  The overlay is read BEFORE the manifest, as scan()
+        reads it."""
+        overlaid = frozenset(self._snapshot_overlay(reqs[0].range))
+        return await self.inner.scan_last(reqs, spec, expect,
+                                          overlaid=overlaid, scan=self.scan)
+
     def execute_plan(self, qp):
+        from horaedb_tpu.storage.plan import LastPlan, SelectPlan
+
+        if isinstance(qp, LastPlan):
+            # the cached segments are dropped, as a row scan's plan is
+            return self.scan_last(qp.requests, qp.last, qp.expect)
+        if isinstance(qp, SelectPlan):
+            async def select():
+                # the select reads pure SST state, as an aggregate does
+                await self.flush_overlapping(qp.requests[0].range)
+                return await self.inner.scan_select(
+                    qp.requests, qp.select, qp.asked)
+
+            return select()
         if qp.aggregate is None:
             # the cached first_plan is dropped: it may predate a flush
             # racing this query (one extra manifest lookup, in memory)

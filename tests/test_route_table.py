@@ -513,6 +513,131 @@ def test_every_select_reason_a_plan_can_show_has_a_row():
 
 
 # ---------------------------------------------------------------------------
+# the last rows: the newest row of every series (ISSUE 43)
+# ---------------------------------------------------------------------------
+
+LAST = ("_last_rows_jit",)
+
+# the select's rows, route for route and reason for reason (one gate:
+# ParquetReader._select_route), and one of its own: a leaf that is not
+# a key leaf and bounds something else than the timestamp is declined
+# per segment, where the slice is planned
+LAST_ROWS = [dataclasses.replace(r, name=r.name.replace("select", "last"))
+             for r in SELECT_ROWS] + [
+    SelectRow("last_mode_device_edge_off_the_timestamp", "host",
+              "predicate", scan={"decode": {"mode": "device"}},
+              hosts=tuple(range(6, HOSTS)), predicate=F.Gt("k", "host_05"),
+              decode_reason={"predicate": SEGMENTS}),
+]
+
+
+def last_segments() -> dict:
+    fam = read_mod._LAST_SEGMENTS
+    return {(dict(k)["route"], dict(k)["reason"]): c.value
+            for k, c in (fam._children or {}).items()}
+
+
+@pytest.mark.parametrize("row", LAST_ROWS, ids=[r.name for r in LAST_ROWS])
+def test_last_route_named_is_the_route_that_ran(row, runtimes, monkeypatch):
+    """Every host's newest row under a bound: which route takes the
+    segments the walk asks, from scan_last_segments_total{route,reason},
+    the device plane's ledger (the last-row program ran once a segment,
+    or no program did) and the fallback counter; and the rows are the
+    reference's.  Host 3 reports in the older segment alone, so the
+    walk asks both."""
+    from horaedb_tpu.ops.last import LastSpec
+
+    for name in ("HORAEDB_HOST_AGG", "HORAEDB_DEVICE_DECODE",
+                 "HORAEDB_FUSED_AGG", "HORAEDB_DEVCOL_STACK"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in row.env.items():
+        monkeypatch.setenv(name, value)
+    if row.accel:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def last_calls() -> dict:
+        return {r["fn"]: r["compiles"] + r["dispatches"]
+                for r in deviceprof.profiler.snapshot()["fns"]
+                if r["fn"] in LAST + SELECT + ROUTE_FNS}
+
+    async def go():
+        world = World(seed=43)
+        quiet = min(row.hosts)
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), SCHEMA, 2,
+            storage_config(row), runtimes=runtimes)
+        try:
+            for seg, wr in enumerate(world.write_requests()):
+                if seg:     # the quiet host: no row past the first segment
+                    keep = pa.compute.not_equal(
+                        wr.batch.column(0), f"host_{quiet:02d}")
+                    wr = WriteRequest(wr.batch.filter(keep), wr.time_range)
+                await s.write(wr)
+            s.reader.scan_cache.clear()
+            s.reader.encoded_cache.clear()
+            leaves = [F.TimeRangePred("ts", LO, HI)] \
+                + ([] if row.predicate is None else [row.predicate])
+            req = ScanRequest(range=TimeRange.new(LO, HI),
+                              predicate=F.And(leaves))
+            spec = LastSpec(group_col="k", ts_col="ts", value_col="v")
+            expect = np.array(sorted(f"host_{h:02d}" for h in row.hosts),
+                              dtype=object)
+            seg0, fns0, dec0 = last_segments(), last_calls(), \
+                decode_fallbacks()
+            qp = await s.plan_last([req], spec, expect)
+            assert qp.describe().startswith(
+                "Last: group=k, ts=ts, value=v, fields=1, "
+                f"series={len(row.hosts)}, newest first")
+            out = await s.execute_plan(qp)
+
+            ts = np.arange(world.values.shape[1], dtype=np.int64) * TICK_MS
+            want = []
+            for h in row.hosts:
+                seen = (ts >= LO) & (ts < (SEGMENT_MS if h == quiet else HI))
+                i = int(np.flatnonzero(seen)[-1])
+                want.append((f"host_{h:02d}", int(ts[i]),
+                             world.values[h, i]))
+            got = list(zip([str(g) for g in out["groups"]],
+                           out["timestamps"].tolist(), out["values"][0]))
+            assert [(g, t) for g, t, _ in got] \
+                == [(g, t) for g, t, _ in want]
+            assert all(a.tobytes() == np.float32(b).tobytes()
+                       for (_, _, a), (_, _, b) in zip(got, want))
+            assert out["found"][0] is None      # found at every row
+
+            assert delta(last_segments(), seg0) \
+                == {(row.route, row.reason): SEGMENTS}
+            ran = delta(last_calls(), fns0)
+            # one call a segment asked; none where the newer segment
+            # holds no row of the one (quiet) host named: a key leaf
+            # that provably matches nothing dispatches nothing
+            assert ran == ({"_last_rows_jit":
+                            SEGMENTS - (len(row.hosts) == 1)}
+                           if row.route == "device" else {}), ran
+            assert delta(decode_fallbacks(), dec0) == row.decode_reason
+        finally:
+            await s.close()
+
+    asyncio.run(go())
+
+
+def test_every_last_reason_a_plan_can_show_has_a_row():
+    """The select's census (one gate), and `predicate` twice: the
+    plan's (an In past the device's list) and a slice's (an edge off
+    the timestamp).  The per-segment reasons of a READ (unsorted,
+    streamed, parquet, encoding, dtype, budget) need a segment that
+    shows them, and `memtable` a WAL: tests/test_query_last.py has an
+    unsorted slice and a memtable's rows."""
+    assert {r.reason for r in LAST_ROWS} == {
+        "", "cpu_auto", "mode_host", "no_sidecar", "predicate"}
+    assert {r.route for r in LAST_ROWS} == {"device", "host"}
+    assert [r.name for r in LAST_ROWS if r.reason == "predicate"] == [
+        "last_mode_device_oversized_in_list",
+        "last_mode_device_edge_off_the_timestamp"]
+    assert len(LAST_ROWS) == len(SELECT_ROWS) + 1
+
+
+# ---------------------------------------------------------------------------
 # the options that went
 # ---------------------------------------------------------------------------
 
