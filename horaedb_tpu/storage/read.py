@@ -112,6 +112,16 @@ _REPLAY_ROWS = registry.counter(
     "rows served from fused-replay hits without re-scanning")
 _REPLAY_MISSES = registry.counter(
     "scan_replay_misses_total", "fused-replay plan cache misses")
+_FUSED_AGGREGATES = {
+    c: registry.counter(
+        "scan_fused_aggregates_total",
+        "fused aggregates run, by how many device calls carried them: "
+        "`one` (a single small round of host rows: one program, one "
+        "download), `rounds` (init, an accumulate a round, finalize, "
+        "mask), `replay` (the rounds again from recorded device stacks)"
+    ).labels(calls=c)
+    for c in ("one", "rounds", "replay")
+}
 _STACK_HITS = registry.counter(
     "scan_stack_cache_hits_total",
     "per-range round-stack LRU hits (small remap/shift/lo entries)")
@@ -330,6 +340,14 @@ _DEVICE_SLICE_SHARE = 0.5
 # fused replay plans kept per reader (weakref-only entries; see
 # ParquetReader._replay_cache)
 _REPLAY_SLOTS = 8
+# Largest stacked round (batch width x capacity, rows) that a fused
+# aggregate carries to the device as numpy arguments of ONE call
+# (_fused_one_call_jit).  Under it the per-call host cost of the eager
+# stack (a device_put a column and window, pads, stacks, four jit
+# calls) outweighs what the memoized device columns save, which is
+# the upload of 12 B a row; PERF.md section 6 (PR 44) has the two unit
+# costs it rests on.
+_ONE_CALL_MAX_ROWS = 65_536
 
 # [scan.decode] modes (validated at reader open; docs/example.toml)
 DECODE_MODES = ("auto", "device", "host")
@@ -2310,7 +2328,12 @@ class ParquetReader:
         arrays (downloaded lazily by the caller — np.asarray works; the
         device work itself is complete, block_until_ready'd).  `last`
         queries additionally materialize count/last_ts on host for the
-        int64 absolute-time conversion."""
+        int64 absolute-time conversion.
+
+        A query whose windows are ONE small round of host rows (a point
+        query: one series over an hour or two) is carried by one device
+        call and one download inside one pool job (_fused_one_call): its
+        grids are numpy already, and it records no replay."""
         if counted is None:
             counted = set()
         replay_key = None
@@ -2329,6 +2352,7 @@ class ParquetReader:
                     self._replay_cache.move_to_end(replay_key)
                     self._replay_hits += 1
                     _REPLAY_HITS.inc()
+                    _FUSED_AGGREGATES["replay"].inc()
                     # `counted` gates ops metrics across race restarts,
                     # exactly like the full path's per-segment gate
                     # replay rows go to their OWN counter — nothing was
@@ -2388,6 +2412,20 @@ class ParquetReader:
         width = self._window_grid_width(spec) if local_ok \
             else spec.num_buckets
         max_w = max(1, self.config.scan.agg_batch_windows)
+        if len(items) <= max_w and _host_rows(items, spec):
+            batch_w = min(max_w, 1 << (len(items) - 1).bit_length())
+            cap = max(it[1].capacity for it in items)
+            if batch_w * cap <= _ONE_CALL_MAX_ROWS:
+                # one small round of host rows: nothing a later query
+                # could reuse is worth keeping on the device, so the
+                # round goes up as the call's arguments, everything the
+                # answer needs comes back in the same pool job, and no
+                # replay is recorded (a repeat costs this one call)
+                fused = await self._run_pool(
+                    plan.pool, self._fused_one_call, items, spec, batch_w,
+                    cap, g, g_pad, width, all_values, local_ok)
+                _FUSED_AGGREGATES["one"].inc()
+                return self._fused_result(all_values, fused, spec)
         space_fp = (g, hash(all_values.tobytes()))
         recorded_rounds: list[tuple] = []
 
@@ -2427,6 +2465,7 @@ class ParquetReader:
             return out
 
         fused = await self._run_pool(plan.pool, run_rounds)
+        _FUSED_AGGREGATES["rounds"].inc()
         if replay_key is not None:
             self._replay_cache[replay_key] = {
                 "segments": seg_records,
@@ -2527,11 +2566,52 @@ class ParquetReader:
         t_dev += time.perf_counter() - t0
         return (out, has_data), t_dev
 
+    def _fused_one_call(self, items: list, spec: AggregateSpec,
+                        batch_w: int, cap: int, g: int, g_pad: int,
+                        width: int, group_space: np.ndarray,
+                        local_ok: bool):
+        """A fused aggregate whose windows are ONE small round of host
+        rows, as one pool job: the round stacked in numpy, ONE call of
+        _fused_one_call_jit with those arrays as its arguments (the
+        upload rides the call), ONE download of the stacked grids and
+        the any-data mask.  Nothing enters the stack LRU or the windows'
+        memos.  Returns _fused_run_device_rounds' (grids, mask), as
+        numpy and cut to the query's `g` groups."""
+        t0 = time.perf_counter()
+        with self._phase("scan.group_prep", round=0):
+            args = _stack_host_cols(items, spec, batch_w, cap) \
+                + _round_small_arrays(items, spec, batch_w, g_pad,
+                                      group_space, local_ok)
+        nbytes = sum(int(a.nbytes) for a in args)
+        _STAGE_SECONDS["stack_build"].observe(time.perf_counter() - t0)
+        _STAGE_BYTES["stack_build"].inc(nbytes)
+        t0 = time.perf_counter()
+        with self._phase("scan.dispatch", sync=True,
+                         fn="_fused_one_call_jit"):
+            out = _fused_one_call_jit(
+                *args, self._dev_scalar(spec.num_buckets),
+                self._dev_scalar(spec.bucket_ms), num_groups=g_pad,
+                num_buckets=spec.num_buckets, width=width,
+                which=spec.which)
+            deviceprof.charge_transfer("h2d", nbytes)
+        stacked, last_ts, has_data = deviceprof.download(
+            out, fn="fused_one_call", table=self.table)
+        _STAGE_SECONDS["device_aggregate"].observe(
+            time.perf_counter() - t0)
+        # the finalize answers `count` and every aggregate asked
+        names = sorted(set(spec.which) | {"count"})
+        ensure(len(names) == len(stacked), "fused grids out of step")
+        grids = {k: v[:g] for k, v in zip(names, stacked)}
+        if last_ts is not None:
+            grids["last_ts"] = last_ts[:g]
+        return grids, has_data[:g]
+
     def _fused_result(self, values: np.ndarray, fused: tuple,
                       spec: AggregateSpec):
         """What the fused path does on the host before the response, in
         one copy: `fused` is _fused_run_device_rounds' (grids, per-group
-        any-data mask), synced already.
+        any-data mask), synced already, or _fused_one_call's, which are
+        numpy already and are only indexed here.
 
         The empty-group drop is the twin of finalize_aggregate's (the
         aligned fast path can register groups whose rows all fall
@@ -2546,12 +2626,16 @@ class ParquetReader:
         want = {"has": has_dev}
         if "last_ts" in grids:
             want.update(count=grids["count"], last_ts=grids["last_ts"])
-        host = deviceprof.download(want, fn="fused_rounds",
-                                   table=self.table)
+        # _fused_one_call's are on the host already: this thread (the
+        # loop's) then makes no device call
+        on_host = isinstance(has_dev, np.ndarray)
+        host = want if on_host else deviceprof.download(
+            want, fn="fused_rounds", table=self.table)
         if not host["has"].all():
             idx = np.flatnonzero(host["has"])
             values = values[idx]
-            grids = {k: jnp.take(v, idx, axis=0) for k, v in grids.items()}
+            grids = {k: v[idx] if on_host else jnp.take(v, idx, axis=0)
+                     for k, v in grids.items()}
             host = {k: v[idx] for k, v in host.items()}
         if "last_ts" in grids:
             grids["last_ts"] = np.where(
@@ -4493,19 +4577,11 @@ class ParquetReader:
             return cols + small
         t_build = time.perf_counter()
         built_bytes = 0
-        host_rows = all(
-            isinstance(it[1].columns[spec.ts_col], np.ndarray)
-            and isinstance(it[2][1], np.ndarray) for it in items)
+        host_rows = _host_rows(items, spec)
         if cols is None:
             if host_rows and (sharded or not self._devcol_stack_ok()):
-                ts_m = np.zeros((batch_w, cap), dtype=np.int32)
-                gid_m = np.full((batch_w, cap), -1, dtype=np.int32)
-                val_m = np.zeros((batch_w, cap), dtype=np.float32)
-                for d, (_seg_start, w, (_values, gid, _sh)) in \
-                        enumerate(items):
-                    ts_m[d, : w.capacity] = w.columns[spec.ts_col]
-                    gid_m[d, : w.capacity] = gid
-                    val_m[d, : w.capacity] = w.columns[spec.value_col]
+                ts_m, gid_m, val_m = _stack_host_cols(items, spec,
+                                                      batch_w, cap)
                 ts_s, gid_s, val_s = put(ts_m), put(gid_m), put(val_m)
             else:
                 ts_rows, gid_rows, val_rows = [], [], []
@@ -4546,15 +4622,8 @@ class ParquetReader:
             built_bytes += sum(int(a.nbytes) for a in cols)
             self._stack_cache_put(col_key, windows_now, cols)
         if small is None:
-            remap = np.zeros((batch_w, g_pad), dtype=np.int32)
-            shift = np.zeros(batch_w, dtype=np.int32)
-            lo = np.zeros(batch_w, dtype=np.int32)
-            for d, (_seg_start, _w, (values, _gid, sh)) in enumerate(items):
-                remap[d, : len(values)] = np.searchsorted(group_space,
-                                                          values)
-                shift[d] = sh
-                if local_ok:
-                    lo[d] = max(0, sh // spec.bucket_ms)
+            remap, shift, lo = _round_small_arrays(
+                items, spec, batch_w, g_pad, group_space, local_ok)
             small = (put(remap), put(shift), put(lo), lo)
             built_bytes += sum(int(a.nbytes) for a in small[:3])
             self._stack_cache_put(stack_key, windows_now, small)
@@ -4857,6 +4926,46 @@ def _host_window_partials(items: list, spec: AggregateSpec,
     return parts
 
 
+def _host_rows(items: list, spec: AggregateSpec) -> bool:
+    """Whether every window of a round is host rows: numpy columns and
+    a numpy group-id column (_window_groups keeps a host window's on
+    the host)."""
+    return all(isinstance(it[1].columns[spec.ts_col], np.ndarray)
+               and isinstance(it[2][1], np.ndarray) for it in items)
+
+
+def _stack_host_cols(items: list, spec: AggregateSpec, batch_w: int,
+                     cap: int):
+    """One round of host windows stacked in numpy to (batch_w, cap):
+    (ts, gid, value), a window's tail and the round's padding windows
+    reading as no row (gid -1)."""
+    ts_m = np.zeros((batch_w, cap), dtype=np.int32)
+    gid_m = np.full((batch_w, cap), -1, dtype=np.int32)
+    val_m = np.zeros((batch_w, cap), dtype=np.float32)
+    for d, (_seg_start, w, (_values, gid, _sh)) in enumerate(items):
+        ts_m[d, : w.capacity] = w.columns[spec.ts_col]
+        gid_m[d, : w.capacity] = gid
+        val_m[d, : w.capacity] = w.columns[spec.value_col]
+    return ts_m, gid_m, val_m
+
+
+def _round_small_arrays(items: list, spec: AggregateSpec, batch_w: int,
+                        g_pad: int, group_space: np.ndarray,
+                        local_ok: bool):
+    """One round's RANGE-DEPENDENT arrays, in numpy: each window's
+    group remap into `group_space`, its timestamp shift and its first
+    bucket (remap (batch_w, g_pad), shift and lo (batch_w,))."""
+    remap = np.zeros((batch_w, g_pad), dtype=np.int32)
+    shift = np.zeros(batch_w, dtype=np.int32)
+    lo = np.zeros(batch_w, dtype=np.int32)
+    for d, (_seg_start, _w, (values, _gid, sh)) in enumerate(items):
+        remap[d, : len(values)] = np.searchsorted(group_space, values)
+        shift[d] = sh
+        if local_ok:
+            lo[d] = max(0, sh // spec.bucket_ms)
+    return remap, shift, lo
+
+
 @deviceprof.jit(static_argnames=("num_groups", "num_buckets", "which"))
 def _fused_acc_init_jit(*, num_groups: int, num_buckets: int, which: tuple):
     """Query-global device accumulator grids with combine-identity
@@ -4986,6 +5095,32 @@ def _group_has_data_jit(count):
     fast path's empty-group check ever downloads."""
     with jax.named_scope("group_has_data"):
         return (count > 0).any(axis=1)
+
+
+@deviceprof.jit(static_argnames=("num_groups", "num_buckets", "width",
+                                 "which"))
+def _fused_one_call_jit(ts, gid, vals, remap, shift, lo, total, bucket_ms, *,
+                        num_groups: int, num_buckets: int, width: int,
+                        which: tuple):
+    """A fused aggregate of ONE round as one program: the accumulator's
+    identities, the round's accumulate, the finalize and the per-group
+    any-data mask, composed from the bodies of the four programs that
+    _fused_run_device_rounds calls in turn, so the grids are theirs bit
+    for bit.  Rows past the query's groups (`num_groups` is padded) stay
+    empty; the caller cuts them on the host, so the number of groups
+    mints no program.  The float32 grids leave stacked, in the order of
+    their sorted names: every leaf is a copy of its own at the download,
+    and a give-up of the GIL.  Returns (grids, `last_ts` or None, mask).
+    """
+    acc = _fused_acc_init_jit.__wrapped__(
+        num_groups=num_groups, num_buckets=num_buckets, which=which)
+    acc = _fused_round_accumulate_jit.__wrapped__(
+        acc, ts, gid, vals, remap, shift, lo, total, bucket_ms,
+        num_groups=num_groups, width=width, which=which)
+    final = _fused_finalize_jit.__wrapped__(acc, which)
+    has_data = _group_has_data_jit.__wrapped__(final["count"])
+    last_ts = final.pop("last_ts", None)
+    return jnp.stack([final[k] for k in sorted(final)]), last_ts, has_data
 
 
 @deviceprof.jit(static_argnames=("num_groups", "num_buckets", "which"))

@@ -246,7 +246,9 @@ class TestWriteQuery:
                 # span == 2h == segment_ms, bucket divides span -> aligned
                 aligned = await e.query_downsample(
                     "cpu", [], rng_q, bucket_ms=HOUR)
-                # repeat: the fused replay path must drop it too
+                # repeat (one small round: the one call again, which
+                # records no replay; tests/test_fused_one_call.py has
+                # the rounds' and the replay's drop): dropped too
                 replay = await e.query_downsample(
                     "cpu", [], rng_q, bucket_ms=HOUR)
                 # 7-minute bucket does not divide the span -> ts-leaf path

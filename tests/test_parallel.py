@@ -146,8 +146,11 @@ class TestFusedReplay:
         from horaedb_tpu.objstore import MemoryObjectStore
         from horaedb_tpu.storage.config import StorageConfig, from_dict
 
+        # more windows than a round holds, so the aggregate runs as
+        # ROUNDS: ONE small round is carried by one call and records no
+        # replay (tests/test_fused_one_call.py)
         cfg = from_dict(StorageConfig, {
-            "scan": {"max_window_rows": 512}})
+            "scan": {"max_window_rows": 512, "agg_batch_windows": 4}})
         return await MetricEngine.open(name, MemoryObjectStore(),
                                        segment_ms=7_200_000, config=cfg)
 
@@ -375,8 +378,10 @@ class TestVariedRangeStacking:
         SPAN = 8 * 3_600_000  # 4 segments
 
         async def go():
+            # 16 windows in rounds of 8: the stacks are the ROUNDS'
+            # (one small round goes up as one call's numpy arguments)
             cfg = from_dict(StorageConfig, {
-                "scan": {"max_window_rows": 512}})
+                "scan": {"max_window_rows": 512, "agg_batch_windows": 8}})
             e = await MetricEngine.open(f"varied{devcol}",
                                         MemoryObjectStore(),
                                         segment_ms=7_200_000, config=cfg)
@@ -440,7 +445,8 @@ class TestVariedRangeStacking:
 
         async def go():
             cfg = from_dict(StorageConfig, {
-                "scan": {"max_window_rows": 4096}})
+                "scan": {"max_window_rows": 4096,
+                         "agg_batch_windows": 1}})  # two rounds
             e = await MetricEngine.open("variedmemo", MemoryObjectStore(),
                                         segment_ms=7_200_000, config=cfg)
             try:

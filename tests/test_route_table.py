@@ -58,10 +58,12 @@ LO, HI = SEGMENT_MS - 1_200_000 + 7, SEGMENT_MS + 1_500_000 + 7
 
 FUSED = ("_fused_acc_init_jit", "_fused_round_accumulate_jit",
          "_fused_finalize_jit", "_group_has_data_jit")
+# a fused aggregate of ONE small round of host rows (ISSUE 44)
+ONE_CALL = ("_fused_one_call_jit",)
 DECODE = ("_decode_aggregate_jit",)
 PARTS_XLA = ("_batched_window_partials_jit",)
 MESH = ("mesh_run_partials", "mesh_decode_partials")
-ROUTE_FNS = FUSED + DECODE + PARTS_XLA + MESH
+ROUTE_FNS = FUSED + ONE_CALL + DECODE + PARTS_XLA + MESH
 
 
 @pytest.fixture(scope="module")
@@ -203,14 +205,23 @@ class Row:
 
 HOST7 = dict(hosts=(7,), predicate=F.Eq("k", "host_07"))
 MESH_ON = {"mesh": {"enabled": True}}
+ONE_WINDOW_A_ROUND = {"agg_batch_windows": 1}
 MANY = [f"host_{i:02d}" for i in range(HOSTS)] \
     + [f"absent_{i}" for i in range(30)]
 
 ROWS = [
     Row("cpu_auto", "parts", ()),
-    Row("accel_under_budget", "fused_acc", FUSED, accel=True, **HOST7),
-    Row("accel_under_budget_again", "replay", FUSED, accel=True,
+    # one host over two segments: two windows, one small round
+    Row("accel_under_budget", "fused_acc", ONE_CALL, accel=True, **HOST7),
+    # the one call records no replay: its repeat is the one call again
+    Row("accel_under_budget_again", "fused_acc", ONE_CALL, accel=True,
         warm=True, **HOST7),
+    # more windows than a round holds: the rounds' four programs, and
+    # a repeat replays their recorded stacks
+    Row("accel_under_budget_two_rounds", "fused_acc", FUSED, accel=True,
+        scan=ONE_WINDOW_A_ROUND, **HOST7),
+    Row("accel_under_budget_two_rounds_again", "replay", FUSED,
+        accel=True, scan=ONE_WINDOW_A_ROUND, warm=True, **HOST7),
     Row("accel_over_budget", "device_decode", DECODE, accel=True,
         scan=OVER_BUDGET, n_decode=SEGMENTS),
     Row("accel_over_budget_block_pruned_host", "device_decode", DECODE,
@@ -257,7 +268,7 @@ ROWS = [
         top_k=TopKSpec(k=3, by="max")),
     Row("accel_router_covers_under_budget", "parts", PARTS_XLA,
         accel=True, router=True),
-    Row("fused_forced_over_budget", "fused_acc", FUSED,
+    Row("fused_forced_over_budget", "fused_acc", ONE_CALL,
         scan=OVER_BUDGET, env={"HORAEDB_FUSED_AGG": "1"}),
     Row("accel_fused_forced_off_under_budget", "device_decode", DECODE,
         accel=True, env={"HORAEDB_FUSED_AGG": "0"}, n_decode=SEGMENTS),
@@ -344,7 +355,9 @@ def test_route_named_is_the_route_that_ran(row, runtimes, monkeypatch):
             assert set(ran) <= set(row.ran), ran
             assert bool(ran) == bool(row.ran) or row.raises, ran
             if row.route in ("fused_acc", "replay"):
-                assert set(ran) == set(FUSED)
+                assert set(ran) == set(row.ran)
+                assert ran.get("_fused_round_accumulate_jit", 0) \
+                    == (SEGMENTS if row.ran == FUSED else 0)
             if row.n_decode:
                 assert ran == {"_decode_aggregate_jit": row.n_decode}
             assert (read_mod._MESH_ROUNDS.value > rounds0) \

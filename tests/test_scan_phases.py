@@ -194,7 +194,14 @@ class TestPhaseSpans:
             assert all((c["fields"]["h2d_bytes"] == 0) == hit
                        for c in dispatches)
         else:
-            assert got_phases == want_phases
+            # one small round of host rows: ONE dispatch, named, and
+            # ONE pass of the download's sync seam (a span only where
+            # the program still ran), one copy
+            dispatch, = [c for c in phases if c["name"] == "scan.dispatch"]
+            assert dispatch["fields"]["fn"] == "_fused_one_call_jit"
+            assert seams == 1
+            assert got_phases | {"scan.device_wait"} == want_phases
+            assert len([c for c in phases if c["name"] == "scan.d2h"]) == 1
         for c in phases:
             # starts are wall clock, durations perf_counter: 1 ms slack
             assert lo - 1.0 <= c["start_ms"]
@@ -644,14 +651,23 @@ class TestSeams:
         monkeypatch.setenv("HORAEDB_HOST_AGG", "0")
         fns = [read_mod._fused_acc_init_jit,
                read_mod._fused_round_accumulate_jit,
-               read_mod._fused_finalize_jit, read_mod._group_has_data_jit]
+               read_mod._fused_finalize_jit, read_mod._group_has_data_jit,
+               read_mod._fused_one_call_jit]
 
         async def go(client, engine):
-            return await compiles_per_query(client, engine, fns)
+            sizes = await compiles_per_query(client, engine, fns)
+            # the same query as rounds (the size bound is all that
+            # stands between the two)
+            monkeypatch.setattr(read_mod, "_ONE_CALL_MAX_ROWS", 0)
+            return sizes, await compiles_per_query(client, engine, fns)
 
-        sizes = run(served(go))
-        assert all(n <= 1 for n in sizes[0])
-        assert sizes[1:] == [[0] * 4, [0] * 4]
+        one, rounds = run(served(go))
+        # one small round: the one program, which traces the four
+        # bodies and calls none of them
+        assert one[0][:4] == [0] * 4 and one[0][4] <= 1
+        assert one[1:] == [[0] * 5, [0] * 5]
+        assert all(n <= 1 for n in rounds[0]) and rounds[0][4] == 0
+        assert rounds[1:] == [[0] * 5, [0] * 5]
 
     def test_scopes_name_the_stages_in_the_lowered_program(self):
         """The scope names reach the operation metadata a profile
