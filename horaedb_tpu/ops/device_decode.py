@@ -212,8 +212,8 @@ _BATCH_SLICES = {
 _BATCH_CALLS = registry.counter(
     "scan_decode_batch_total",
     "calls of the batched programs (the fused decode's, two or more "
-    "resident slices of one plan a call; the row-selecting route's, a "
-    "call a field)")
+    "resident slices of one plan a call; the row-selecting route's, "
+    "one call with all its fields)")
 
 
 def note_batched(slices: int, calls: int) -> None:

@@ -115,7 +115,7 @@ def _last_rows_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
     request's leaves admit, deduplicated, then the last of every series
     run, compacted in row order into `series` places.
 
-    `nums` as ops/select._select_rows_jit takes it, int32 [1 + slices,
+    `nums` as ops/select._select_rows takes it, int32 [1 + slices,
     1 + window constants]: nums[0, 0] the live slices, row 1 + i slice
     i's rows and the constants of its leaves that are not key leaves.
     Returns (codes, ts, values)[slices, series] and (found, kept)

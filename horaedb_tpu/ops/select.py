@@ -50,12 +50,19 @@ of its own to keep there:
             codes go through a remap built on the host from the two
             dictionaries.
 
-A query's resident slices reach the device in one call a field (the
-batching of device_decode.execute_batch: the slices' device arrays as
-they lie, one small host array of the window's numbers, a loop over
-the live slices), and everything a query selected comes back in ONE
-download.  The work inside a call follows the rows selected, not the
-capacity: the join searches chunk by chunk up to the slice's count.
+A query's resident slices reach the device in ONE call a group of
+segments whose windows share their program (_select_rows_joined_jit:
+the select, then every field's join at the keys it left, which never
+leave the program; the batching of device_decode.execute_batch: the
+slices' device arrays as they lie, one small host array of the
+window's numbers a stack, a loop over the live slices), and everything
+a query selected comes back in ONE download.  The joined fields whose
+windows share their statics lie in one stack under one traced join
+body, so the program does not grow with the fields asked; how many a
+stack holds is part of the program's shape, and a request of fewer
+runs a program that exists with its stack filled.  The work inside a
+call follows the rows selected, not the capacity: the join searches
+chunk by chunk up to the slice's count.
 """
 
 from __future__ import annotations
@@ -86,10 +93,11 @@ _OVERFLOWS = registry.counter(
 _CALLS = {
     kind: registry.counter(
         "scan_select_calls_total",
-        "calls of the row-selecting programs: select = the predicate "
-        "field's resident slices of a query (decode, filter, dedup, "
-        "value predicate, compaction), join = one other field's "
-        "slices at the selected keys"
+        "the row-selecting program (one call a group of segments: the "
+        "predicate field's select and every other field's join at the "
+        "selected keys inside it): select = calls of the program, "
+        "join = fields joined inside them (join / select = fields a "
+        "call)"
     ).labels(kind=kind)
     for kind in ("select", "join")
 }
@@ -107,6 +115,10 @@ _RUNG: dict = {}
 # (clients that reach a new program together compile it once)
 _COMPILED: set = set()
 _COMPILE_LOCK = threading.Lock()
+
+# the fields a set of joined fields the program has run with, by the
+# rest of its shape: what a request of fewer fields may be filled to
+_WIDTHS: dict = {}
 
 
 @dataclass(frozen=True)
@@ -205,10 +217,9 @@ def _per_slice(one, live, slots: int):
     return jax.lax.fori_loop(0, live, step, acc)
 
 
-@deviceprof.jit(static_argnames=_DECODE_STATICS + ("op", "capacity"))
-def _select_rows_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
-                     nums, threshold, *, op: str, capacity: int,
-                     group_pos: int, ts_pos: int, **static):
+def _select_rows(cols: tuple, key_consts: tuple, run_offsets: tuple,
+                 nums, threshold, *, op: str, capacity: int,
+                 group_pos: int, ts_pos: int, **static):
     """The predicate field's slices of one call: per slice the rows
     the window's leaves admit, deduplicated, then tested against the
     threshold and compacted in row order into `capacity`.
@@ -220,7 +231,7 @@ def _select_rows_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
     scanned)[slices] and (series code, timestamp, kept)[slices, rows]:
     `positions` are the selected rows' places in their slice's decoded
     order and the last three that order itself (what the join holds
-    another field's slice against; they stay on the device),
+    another field's slice against; neither leaves the program),
     `selected` may pass `capacity` (an overflow: the rows past it are
     not in the arrays), `scanned` counts the rows the predicate was
     put to."""
@@ -253,20 +264,23 @@ def _select_rows_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
     return _per_slice(one, nums[0, 0], len(cols))
 
 
-@deviceprof.jit(static_argnames=_DECODE_STATICS)
-def _select_join_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
-                     nums, remap, same_series, codes, ts, pos, selected,
-                     order, *, group_pos: int, ts_pos: int, route: str,
-                     **static):
-    """Another field's slices of one call at the keys `_select_rows_jit`
-    selected (`codes`, `ts`, `pos`, `selected`, `order`: its outputs,
-    still on the device): per slice, for every selected key, the value
-    of the last row of that (series, timestamp) if the dedup kept it.
+def _select_join(cols: tuple, key_consts: tuple, run_offsets: tuple,
+                 nums, remap, same_series, segments, codes, ts, pos,
+                 selected, order, *, group_pos: int, ts_pos: int,
+                 route: str, **static):
+    """The slices of the joined fields that share these statics, at the
+    keys `_select_rows` selected (`codes`, `ts`, `pos`, `selected`,
+    `order`: its results, inside the same program): per slice, for
+    every selected key, the value of the last row of that (series,
+    timestamp) if the dedup kept it.
 
-    `remap`[slices, g_pad of the keys' slices] turns a key's series
-    code into this slice's code for the same series, -1 where this
-    slice's dictionary lacks it; `same_series`[slices] says where the
-    two dictionaries are one.  `nums` as `_select_rows_jit`.  Returns
+    The slices lie field after field, each field's live segments in
+    the select's order, the filler behind them all: slice j joins the
+    keys of the select's slice j % `segments` (the select's live
+    slices).  `remap`[slices, g_pad of the keys' slices] turns a key's
+    series code into this slice's code for the same series, -1 where
+    this slice's dictionary lacks it; `same_series`[slices] says where
+    the two dictionaries are one.  `nums` as `_select_rows`.  Returns
     (values, found)[slices, capacity]."""
     stacked, keyed_stacked, offs = stack_slices(cols, key_consts,
                                                 run_offsets)
@@ -275,12 +289,13 @@ def _select_join_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
     g_pad = remap.shape[1]
     their_code, their_ts, their_kept = order
 
-    def one(i):
-        row = nums[1 + i]
+    def one(j):
+        row = nums[1 + j]
         valid_s, keys_s, val_s, kept = rows_sorted_kept(
-            slice_columns(stacked, i), row[0],
-            slice_consts(static["leaf_prog"], keyed_stacked, row, i, 1),
-            offs[i], route=route, **static)
+            slice_columns(stacked, j), row[0],
+            slice_consts(static["leaf_prog"], keyed_stacked, row, j, 1),
+            offs[j], route=route, **static)
+        i = j % segments
         cap = val_s.shape[0]
         live = jnp.arange(capacity, dtype=jnp.int32) < selected[i]
 
@@ -311,7 +326,7 @@ def _select_join_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
                 c_key = jax.lax.dynamic_slice(codes[i], (at,), (chunk,))
                 t_key = jax.lax.dynamic_slice(ts[i], (at,), (chunk,))
                 c = jnp.where(c_key >= 0,
-                              remap[i][jnp.clip(c_key, 0, g_pad - 1)], -1)
+                              remap[j][jnp.clip(c_key, 0, g_pad - 1)], -1)
                 known = c >= 0
                 c = jnp.clip(c, 0, g_pad - 1)
                 first = starts[c]
@@ -345,13 +360,38 @@ def _select_join_jit(cols: tuple, key_consts: tuple, run_offsets: tuple,
                 return searched()    # other capacities: nothing to hold
             # the same rows kept, and every kept row the same series
             # (one dictionary) at the same timestamp
-            same = same_series[i] & jnp.all(
+            same = same_series[j] & jnp.all(
                 (kept == their_kept[i])
                 & (~kept | ((keys_s[group_pos] == their_code[i])
                             & (keys_s[ts_pos] == their_ts[i]))))
             return jax.lax.cond(same, taken, searched)
 
     return _per_slice(one, nums[0, 0], len(cols))
+
+
+@deviceprof.jit(static_argnames=("op", "capacity", "statics"))
+def _select_rows_joined_jit(keys: tuple, threshold, joins: tuple, *,
+                            op: str, capacity: int, statics: tuple):
+    """One call of the row-selecting route: the select over the
+    predicate field's slices, then every joined field's join at the
+    keys it selected, the keys never leaving the program between the
+    two.
+
+    `keys` are `_select_rows`'s four slice arguments; `joins` holds,
+    for every set of joined fields whose windows share their statics
+    (one traced join body a set, whatever its fields), `_select_join`'s
+    six; `statics` the select's and, a set, the join's static
+    arguments, as sorted items.  Returns `_select_rows`'s (codes, ts,
+    values, selected, scanned) and a set's (values, found)[slices,
+    capacity]."""
+    key_statics, join_statics = statics
+    codes, ts, vals, pos, selected, scanned, order = _select_rows(
+        *keys, threshold, op=op, capacity=capacity, **dict(key_statics))
+    joined = tuple(
+        _select_join(*join, keys[3][0, 0], codes, ts, pos, selected,
+                     order, **dict(static))
+        for join, static in zip(joins, join_statics))
+    return (codes, ts, vals, selected, scanned), joined
 
 
 # ---------------------------------------------------------------------------
@@ -417,75 +457,111 @@ def _first_call(key: tuple, call):
         return out
 
 
-def _run_group(group: list, spec: SelectSpec, n_fields: int, phase,
+def _joined_fields(group: list) -> dict:
+    """The fields a call over `group` joins (indexes into the fields
+    asked), in sets by the batch key their windows share: not a field
+    with no row in these segments (nulls), nor the predicate's own
+    (its values are the select's)."""
+    sets: dict = {}
+    for f, window in enumerate(group[0][1:]):
+        if window is None:
+            continue
+        if all(windows[1 + f].seg is windows[0].seg for windows in group):
+            continue
+        sets.setdefault(window.batch_key(), []).append(f)
+    return sets
+
+
+def _slot_nbytes(windows: list, joins: dict, widths: tuple) -> int:
+    """What one segment (`windows`: its predicate's, then one a field
+    asked) adds to a call's stacks: the predicate's slice and `widths`
+    slices a set of joined fields."""
+    return windows[0].seg.nbytes + sum(
+        width * windows[1 + fields[0]].seg.nbytes
+        for width, fields in zip(widths, joins.values()))
+
+
+def _run_group(group: list, spec: SelectSpec, joins: dict, phase,
                table: str) -> list:
-    """One group of segments whose windows share their programs
+    """One group of segments whose windows share their program
     (`group`: per segment the predicate field's SelectWindow, then one
-    SelectWindow or None a field asked): a select call, a join call a
-    field, one download; again one rung up where a slice overflowed.
-    Returns one SelectedRows a segment."""
+    SelectWindow or None a field asked; `joins`: _joined_fields of
+    them): ONE call, the select and every set's join inside it, one
+    download; again one rung up where a slice overflowed.  Returns one
+    SelectedRows a segment."""
     keys = [windows[0] for windows in group]
     first = keys[0]
+    n = len(group)
     ladder = capacity_ladder(first.seg.cap)
-    slots = 1 << (len(group) - 1).bit_length()
+    slots = 1 << (n - 1).bit_length()
     key = tuple(None if w is None else w.batch_key() for w in group[0])
     rung = min(_RUNG.get(key, 0), len(ladder) - 1)
     threshold = np.float32(spec.threshold)
+    want = tuple(map(len, joins.values()))
+    statics = (
+        tuple(sorted(first.statics().items())),
+        tuple(tuple(sorted(group[0][1 + fields[0]].statics().items()))
+              for fields in joins.values()))
     while True:
         capacity = ladder[rung]
+        shape = (key[0], tuple(joins), slots, capacity, spec.op)
+        # fewer fields of a set than a program that exists joins run
+        # that program, their stack filled as `slots` fills the
+        # segments', where the stacks stay inside the budget
+        widths = min(
+            (have for have in _WIDTHS.get(shape, ())
+             if all(h >= w for h, w in zip(have, want))
+             and _slot_nbytes(group[0], joins, have) * slots
+             <= device_decode._BATCH_MAX_STACK_BYTES),
+            key=sum, default=want)
         with phase("scan.dispatch", sync=True, h2d_bytes=0,
-                   slices=len(group) * (1 + n_fields)):
-            codes, ts, vals, pos, selected, scanned, order = _first_call(
-                ("select", key[0], slots, capacity, spec.op),
-                lambda: _select_rows_jit(
-                    *_slices_args(keys, slots), threshold, op=spec.op,
-                    capacity=capacity, **first.statics()))
+                   slices=n * len(group[0])):
+            sets = []
+            for width, fields in zip(widths, joins.values()):
+                others = [windows[1 + f] for f in fields
+                          for windows in group]
+                sets.append(
+                    _slices_args(others, width * slots)
+                    + _remap(keys * len(fields), others, width * slots))
+            out = _first_call(
+                ("select", shape, widths),
+                lambda: _select_rows_joined_jit(
+                    _slices_args(keys, slots), threshold, tuple(sets),
+                    op=spec.op, capacity=capacity, statics=statics))
+            _WIDTHS.setdefault(shape, set()).add(widths)
             _CALLS["select"].inc()
-            joined = {}
-            for f in range(n_fields):
-                others = [windows[1 + f] for windows in group]
-                if others[0] is None:
-                    continue  # the field has no row in these segments
-                if all(o.seg is k.seg for o, k in zip(others, keys)):
-                    continue  # the predicate's own field: its values
-                joined[f] = _first_call(
-                    ("join", key[0], key[1 + f], slots, capacity),
-                    lambda: _select_join_jit(
-                        *_slices_args(others, slots),
-                        *_remap(keys, others, slots), codes, ts, pos,
-                        selected, order,
-                        **others[0].statics()))
-                _CALLS["join"].inc()
-            device_decode.note_batched(len(group) * (1 + len(joined)),
-                                       1 + len(joined))
-        host = deviceprof.download(
-            (codes, ts, vals, selected, scanned, joined),
-            fn="_select_rows_jit", table=table)
-        if int(host[3][:len(group)].max(initial=0)) <= capacity:
+            _CALLS["join"].inc(sum(want))
+            device_decode.note_batched(n * (1 + sum(want)), 1)
+        (codes, ts, vals, selected, scanned), joined = deviceprof.download(
+            out, fn="_select_rows_joined_jit", table=table)
+        if int(selected[:n].max(initial=0)) <= capacity:
             break
         _OVERFLOWS.inc()
         rung += 1
     if rung > _RUNG.get(key, 0):
         _RUNG[key] = rung
-    codes, ts, vals, selected, scanned, joined = host
+    # field f's slices are rows at * n .. at * n + n of its set's
+    at_of = {f: (s, at) for s, fields in enumerate(joins.values())
+             for at, f in enumerate(fields)}
     out = []
     for i, windows in enumerate(group):
-        n = int(selected[i])
+        m = int(selected[i])
         seg = windows[0].seg
         values, found = [], []
-        for f in range(n_fields):
-            if f in joined:
-                values.append(joined[f][0][i, :n])
-                found.append(joined[f][1][i, :n])
-            elif windows[1 + f] is None:
-                values.append(np.zeros(n, np.float32))
-                found.append(np.zeros(n, bool))
+        for f, window in enumerate(windows[1:]):
+            if f in at_of:
+                s, at = at_of[f]
+                values.append(joined[s][0][at * n + i, :m])
+                found.append(joined[s][1][at * n + i, :m])
+            elif window is None:
+                values.append(np.zeros(m, np.float32))
+                found.append(np.zeros(m, bool))
             else:
-                values.append(vals[i, :n])
-                found.append(np.ones(n, bool))
+                values.append(vals[i, :m])
+                found.append(np.ones(m, bool))
         out.append(SelectedRows(
-            groups=seg.values[codes[i, :n]],
-            timestamps=ts[i, :n].astype(np.int64) + seg.ts_epoch,
+            groups=seg.values[codes[i, :m]],
+            timestamps=ts[i, :m].astype(np.int64) + seg.ts_epoch,
             values=values, found=found, scanned=int(scanned[i])))
     return out
 
@@ -496,10 +572,10 @@ def select_resident(segments: list, spec: SelectSpec, phase,
     device.  `segments`: per segment a list, the predicate field's
     SelectWindow first, then one a field asked (None where that field
     provably has no row there).  Segments whose windows may share
-    their programs go out together, cut where a call's stacked columns
-    would pass device_decode's stack budget.  Returns one SelectedRows
-    a segment, in order."""
-    n_fields = len(segments[0]) - 1
+    their program go out together, in ONE call with all their fields,
+    cut where the columns a call stacks (the predicate's slice and one
+    a joined field, a segment) would pass device_decode's stack
+    budget.  Returns one SelectedRows a segment, in order."""
     groups: dict = {}
     for pos, windows in enumerate(segments):
         groups.setdefault(
@@ -507,12 +583,12 @@ def select_resident(segments: list, spec: SelectSpec, phase,
             []).append(pos)
     out: dict = {}
     for group in groups.values():
-        room = device_decode._BATCH_MAX_STACK_BYTES \
-            // segments[group[0]][0].seg.nbytes
+        joins = _joined_fields([segments[p] for p in group])
+        room = device_decode._BATCH_MAX_STACK_BYTES // _slot_nbytes(
+            segments[group[0]], joins, tuple(map(len, joins.values())))
         per_call = max(1, 1 << (room.bit_length() - 1)) if room else 1
         for at in range(0, len(group), per_call):
             call = group[at:at + per_call]
             out.update(zip(call, _run_group(
-                [segments[p] for p in call], spec, n_fields, phase,
-                table)))
+                [segments[p] for p in call], spec, joins, phase, table)))
     return [out[pos] for pos in range(len(segments))]

@@ -23,6 +23,7 @@ from aiohttp.test_utils import TestClient, TestServer
 from pyarrow import ipc
 
 from horaedb_tpu.metric_engine import MetricEngine
+from horaedb_tpu.common import deviceprof
 from horaedb_tpu.objstore import MemoryObjectStore
 from horaedb_tpu.ops import device_decode
 from horaedb_tpu.ops import select as select_ops
@@ -42,6 +43,9 @@ TICK_MS = 60_000
 HOSTS, SEGMENTS = 6, 3
 TICKS = SEGMENTS * SEGMENT_MS // TICK_MS
 FIELDS = ["usage_user", "usage_system", "usage_idle", "usage_nice"]
+# TSBS's ten cpu fields (the benchmark's request asks them all)
+TEN = FIELDS + ["usage_iowait", "usage_irq", "usage_softirq", "usage_steal",
+                "usage_guest", "usage_guest_nice"]
 # usage_nice reports nothing for this host over these ticks: nulls
 SILENT = (2, 100, 260)
 # usage_idle was never written for the last segment at all
@@ -70,10 +74,14 @@ def arrow_body(hosts, ticks, values) -> bytes:
 
 
 class Served:
-    def __init__(self, loop):
+    def __init__(self, loop, fields=FIELDS, holes=True):
         self.loop = loop
+        self.fields = list(fields)
+        # whether usage_nice and usage_idle leave out what SILENT and
+        # IDLE_TICKS say, or every field reports at every key
+        self.holes = holes
         rng = np.random.default_rng(410041)
-        self.values = (rng.random((len(FIELDS), TICKS, HOSTS)) * 100.0
+        self.values = (rng.random((len(self.fields), TICKS, HOSTS)) * 100.0
                        ).astype(np.float32)
         # (host, field, timestamp, value) in the order acknowledged
         self.writes: list = []
@@ -89,7 +97,7 @@ class Served:
         self.client = TestClient(TestServer(build_app(
             ServerState(self.engine, ServerConfig()))))
         await self.client.start_server()
-        for f, field in enumerate(FIELDS):
+        for f, field in enumerate(self.fields):
             tick, host = np.nonzero(self.reports(f))
             await self.write(field, host, tick,
                              self.values[f][tick, host])
@@ -107,9 +115,9 @@ class Served:
 
     def reports(self, f: int) -> np.ndarray:
         out = np.ones((TICKS, HOSTS), dtype=bool)
-        if FIELDS[f] == "usage_nice":
+        if self.holes and self.fields[f] == "usage_nice":
             out[SILENT[1]:SILENT[2], SILENT[0]] = False
-        if FIELDS[f] == "usage_idle":
+        if self.holes and self.fields[f] == "usage_idle":
             out[IDLE_TICKS:] = False
         return out
 
@@ -210,12 +218,11 @@ class Served:
         return n
 
 
-@pytest.fixture(scope="module")
-def served():
+def _serve(**kwargs):
     mp = pytest.MonkeyPatch()
     mp.setenv("HORAEDB_HOST_AGG", "0")
     loop = asyncio.new_event_loop()
-    s = Served(loop)
+    s = Served(loop, **kwargs)
     try:
         s.run(s.open())
         yield s
@@ -223,6 +230,19 @@ def served():
     finally:
         loop.close()
         mp.undo()
+
+
+@pytest.fixture(scope="module")
+def served():
+    yield from _serve()
+
+
+@pytest.fixture(scope="module")
+def ten():
+    """A store of its own with all ten fields at every key: every
+    field's slice is the predicate's row for row, and all nine joined
+    share their statics."""
+    yield from _serve(fields=TEN, holes=False)
 
 
 def segments_by_route() -> dict:
@@ -239,6 +259,16 @@ def moved(before: dict, after: dict) -> dict:
 def counter(name: str, **labels) -> float:
     c = registry.counter(name)
     return (c.labels(**labels) if labels else c).value
+
+
+def program() -> dict:
+    """The row-selecting program's ledger entry: calls that compiled
+    and calls that did not."""
+    for r in deviceprof.profiler.snapshot()["fns"]:
+        if r["fn"] == "_select_rows_joined_jit":
+            return {"compiles": r["compiles"], "calls": r["compiles"]
+                    + r["dispatches"]}
+    return {"compiles": 0, "calls": 0}
 
 
 # ---------------------------------------------------------------------------
@@ -327,6 +357,54 @@ def test_a_label_filter_that_matches_no_series(served):
         WINDOW, "usage_user", "gt", 90.0, FIELDS,
         filters={"hostname": "host_99"}, hosts=set()))
     assert n == 0
+
+
+ONE_PROGRAM_CASES = {
+    # window, predicate's field, op, threshold, fields asked, calls
+    "all_fields": (WINDOW, "usage_user", "gt", 90.0, FIELDS, 2),
+    "a_subset": (WINDOW, "usage_user", "gt", 90.0,
+                 ["usage_system", "usage_user"], 1),
+    "predicates_field_not_asked": (WINDOW, "usage_system", "lt", 10.0,
+                                   ["usage_user", "usage_idle"], 2),
+    # usage_idle has no slice in the last segment: every key a null
+    "a_field_with_no_row_in_a_segment": (
+        (T0 + 4 * HOUR, T0 + 6 * HOUR), "usage_user", "gt", 90.0,
+        ["usage_idle", "usage_system"], 1),
+    # usage_nice lacks a host's samples over 160 ticks: its slices are
+    # not the predicate's row for row there, so every key is searched
+    # for (the other fields' values are taken), and some are not found
+    "a_field_that_is_searched_and_lacks_keys": (
+        (ts_of(SILENT[1] - 10), ts_of(SILENT[2] + 10)), "usage_user",
+        "gt", 50.0, ["usage_nice", "usage_system"], 1),
+    "only_the_predicates_field": (DAY, "usage_user", "ge", 95.0,
+                                  ["usage_user"], 1),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_PROGRAM_CASES))
+def test_the_one_program_answers_as_the_host_route(served, case):
+    """The select and every field's join run as ONE call a group of
+    segments that share their program (the last segment, where
+    usage_idle has no slice, is a group of its own when that field is
+    asked), and the rows, the values and the nulls are the host
+    route's byte for byte, which makes no device call."""
+    window, field, op, value, fields, calls = ONE_PROGRAM_CASES[case]
+
+    async def go():
+        c0 = program()["calls"]
+        dev = await served.rows(window, field, op, value, fields, "1")
+        c1 = program()["calls"]
+        await served.rows(window, field, op, value, fields, "0")
+        assert program()["calls"] == c1
+        assert await served.both(window, field, op, value, fields) > 0
+        return dev, c1 - c0
+    dev, ran = served.run(go())
+    assert ran == calls
+    if case == "a_field_that_is_searched_and_lacks_keys":
+        assert 0 < dev.column("usage_nice").null_count < dev.num_rows
+        assert dev.column("usage_system").null_count == 0
+    if case == "a_field_with_no_row_in_a_segment":
+        assert dev.column("usage_idle").null_count == dev.num_rows > 0
 
 
 # ---------------------------------------------------------------------------
@@ -514,17 +592,115 @@ def test_an_overflow_climbs_the_ladder_and_cuts_nothing(served):
         await served.both(DAY, "usage_user", "gt", 90.0, FIELDS)
         assert counter("scan_select_overflow_total") == o0
         assert set(select_ops._RUNG.values()) <= {0}
+        c0 = program()["calls"]
         await served.both(DAY, "usage_user", "gt", 75.0, FIELDS)
         o1 = counter("scan_select_overflow_total")
         groups = len(select_ops._RUNG)
         assert groups >= 1 and o1 - o0 == groups     # 128 -> 512
         assert set(select_ops._RUNG.values()) == {1}
+        # ONE call a group and rung: the one that overflowed, and the
+        # one a rung up
+        assert program()["calls"] - c0 == 2 * groups
+        c1 = program()["calls"]
         await served.both(DAY, "usage_user", "gt", 75.0, FIELDS)
         assert counter("scan_select_overflow_total") == o1
+        assert program()["calls"] - c1 == groups
         await served.both(DAY, "usage_user", "lt", 95.0, FIELDS)
         assert counter("scan_select_overflow_total") == o1 + groups
         assert set(select_ops._RUNG.values()) == {2}
     served.run(go())
+
+
+def test_a_ten_field_request_is_one_device_call(ten):
+    """TSBS high-cpu-all's request: the predicate's field and nine
+    more over three segments that share their program are ONE call of
+    ONE program (one select, nine fields joined inside it, thirty
+    slices batched), and the host route calls no program at all."""
+    def read() -> dict:
+        return {"calls": program()["calls"],
+                "select": counter("scan_select_calls_total", kind="select"),
+                "join": counter("scan_select_calls_total", kind="join"),
+                "batched_calls": counter("scan_decode_batch_total"),
+                "batched": counter("scan_decode_batch_slices_total",
+                                   mode="batched"),
+                "single": counter("scan_decode_batch_slices_total",
+                                  mode="single")}
+
+    async def go():
+        assert await ten.both(DAY, "usage_user", "gt", 90.0, TEN) > 0
+        c0 = read()
+        await ten.rows(DAY, "usage_user", "gt", 90.0, TEN, "1")
+        c1 = read()
+        await ten.rows(DAY, "usage_user", "gt", 90.0, TEN, "0")
+        return moved(c0, c1), moved(c1, read())
+    dev, host = ten.run(go())
+    assert dev == {"calls": 1, "select": 1, "join": 9, "batched_calls": 1,
+                   "batched": SEGMENTS * len(TEN)}
+    assert host == {}
+
+
+def test_a_request_seen_before_compiles_nothing(ten):
+    """A second request of a shape compiles nothing; one that asks
+    fewer of the fields runs the program that exists (its stack filled,
+    as a group of three segments fills four slots) and is the host
+    route's answer all the same; a field in another order neither."""
+    async def go():
+        await ten.both(DAY, "usage_user", "gt", 90.0, TEN)
+        p0 = program()
+        await ten.both(DAY, "usage_user", "gt", 90.0, TEN)
+        assert await ten.both(DAY, "usage_user", "gt", 90.0, TEN[:5]) > 0
+        assert await ten.both(DAY, "usage_user", "gt", 90.0,
+                              [TEN[7], TEN[2], TEN[9]]) > 0
+        p1 = program()
+        assert p1["compiles"] == p0["compiles"]
+        assert p1["calls"] - p0["calls"] == 3
+        # another predicate's field, with the same statics: the same
+        await ten.both(DAY, "usage_system", "gt", 90.0, TEN[:3])
+        assert program()["compiles"] == p1["compiles"]
+        # another op is another program, and more fields than any
+        # program of a shape joins one more
+        await ten.both(DAY, "usage_user", "lt", 10.0, TEN[:3])
+        assert program()["compiles"] - p1["compiles"] == 1
+        await ten.both(DAY, "usage_user", "lt", 10.0, TEN[:4])
+        assert program()["compiles"] - p1["compiles"] == 2
+        await ten.both(DAY, "usage_user", "lt", 10.0, TEN[:2])
+        assert program()["compiles"] - p1["compiles"] == 2
+    ten.run(go())
+
+
+def test_the_stack_budget_cuts_a_group_by_its_fields(ten, monkeypatch):
+    """A call stacks (1 + joined fields) x slots slices inside the
+    program: under a budget of twenty slices the ten-field request's
+    three segments go out two and one, a two-field request's in one
+    call, and both answers are the host route's."""
+    ran = []
+    run_group = select_ops._run_group
+
+    def spy(group, *args):
+        ran.append((len(group), group[0][0].seg.nbytes))
+        return run_group(group, *args)
+
+    monkeypatch.setattr(select_ops, "_run_group", spy)
+
+    async def go():
+        await ten.rows(DAY, "usage_user", "gt", 90.0, TEN, "1")
+        assert [n for n, _ in ran] == [SEGMENTS]
+        monkeypatch.setattr(device_decode, "_BATCH_MAX_STACK_BYTES",
+                            20 * ran[0][1])
+        del ran[:]
+        assert await ten.both(DAY, "usage_user", "gt", 90.0, TEN) > 0
+        assert [n for n, _ in ran] == [2, 1]
+        del ran[:]
+        assert await ten.both(DAY, "usage_user", "gt", 90.0,
+                              ["usage_user", "usage_irq"]) > 0
+        assert [n for n, _ in ran] == [SEGMENTS]
+        # under one segment's worth a call still takes one segment
+        monkeypatch.setattr(device_decode, "_BATCH_MAX_STACK_BYTES",
+                            ran[0][1])
+        del ran[:]
+        assert await ten.both(DAY, "usage_user", "gt", 90.0, TEN) > 0
+        assert [n for n, _ in ran] == [1] * SEGMENTS
+    ten.run(go())
 
 
 def test_the_rows_counters_follow_the_answer(served):

@@ -398,7 +398,7 @@ def test_every_plan_level_reason_has_a_row():
 # the select: rows under a value predicate (ISSUE 41)
 # ---------------------------------------------------------------------------
 
-SELECT = ("_select_rows_jit", "_select_join_jit")
+SELECT = ("_select_rows_joined_jit",)
 
 
 @dataclasses.dataclass
@@ -506,7 +506,7 @@ def test_select_route_named_is_the_route_that_ran(row, runtimes,
             assert delta(select_segments(), seg0) \
                 == {(row.route, row.reason): SEGMENTS}
             ran = delta(select_calls(), fns0)
-            assert ran == ({"_select_rows_jit": 1}
+            assert ran == ({"_select_rows_joined_jit": 1}
                            if row.route == "device" else {}), ran
             assert delta(decode_fallbacks(), dec0) == row.decode_reason
         finally:
