@@ -1,5 +1,4 @@
-.PHONY: test native bench clean verify lint chaos trace-demo multichip \
-	chip-smoke
+.PHONY: test native clean verify lint chaos trace-demo chip-smoke
 
 # mirrors the tier-1 invocation (fast variants of the slow suites stay
 # in-tier; `make chaos` runs the full slow schedules)
@@ -101,22 +100,6 @@ trace-demo:
 trace-demo-device:
 	JAX_PLATFORMS=cpu python tools/trace_demo.py --device
 
-# multichip dryrun (8 virtual CPU devices) with a GUARANTEED result
-# record: even a run that times out (rc=124) writes
-# bench_results/multichip_rNN.json with an explicit timeout status
-# instead of silence
-multichip:
-	python tools/multichip_run.py --devices 8 --timeout 600
-
-# the mesh-scan A/B under the same always-record discipline: runs
-# BENCH_CONFIG=22 (mesh + fused decode vs the mesh over host windows vs
-# the single-chip control, in-bench bit-identity + top-k egress
-# assertions) on the 8-virtual-device CPU mesh and ALWAYS writes
-# bench_results/multichip_rNN.json — a correctness rung; its walls are
-# not device numbers.  Real chips: `python chip_smoke.py --chips 4`
-multichip-mesh:
-	python tools/multichip_run.py --mode mesh --devices 8 --timeout 900
-
 # the served scan path on the accelerator, end to end (chip_smoke.py's
 # docstring): exits non-zero wherever JAX finds no TPU.  Run it on the
 # chip through the chip tool: `chiprun -- python chip_smoke.py`, and
@@ -128,16 +111,11 @@ chip-smoke:
 
 # the driver-facing deliverables, end to end: lint + full suite + the
 # fixed-seed chaos gate + the multi-chip dryrun on the virtual CPU mesh
-# + a small engine bench
 verify: lint test chaos
 	python -c "import __graft_entry__; __graft_entry__.dryrun_multichip(8); print('dryrun OK')"
-	BENCH_ROWS=200000 BENCH_ITERS=3 python bench.py
 
 native:
 	$(MAKE) -C native
-
-bench:
-	python bench.py
 
 clean:
 	$(MAKE) -C native clean

@@ -3,7 +3,7 @@
 
 Drives the repo's headline deployment through the entry points a user
 calls: BASELINE.json config 1 in the TSBS devops cpu-only shape
-bench.py builds (100 hosts, 10 s scrape, one field, 10,000,000 rows =
+(100 hosts, 10 s scrape, one field, 10,000,000 rows =
 100,000 ticks over 139 two-hour segments) on a LocalObjectStore on
 disk, served by `python -m horaedb_tpu.server` under docs/example.toml
 with only `port` and `data_dir` changed.  Rows go in as 1M-row Arrow
@@ -96,8 +96,8 @@ def say(msg: str) -> None:
 
 
 class Dataset:
-    """The seeded rows, time-major (every 10 s tick reports all hosts —
-    bench.py's layout), and the plain numpy answers to the queries."""
+    """The seeded rows, time-major (every 10 s tick reports all
+    hosts), and the plain numpy answers to the queries."""
 
     def __init__(self, rows: int, seed: int):
         self.ticks = max(1, rows // HOSTS)
@@ -184,7 +184,7 @@ def grid_of(resp: dict, agg: str) -> np.ndarray:
 
 
 def compare_grids(name: str, got: dict, ref: dict, rows: list[int]) -> None:
-    """The repo's own rule (bench.py, __graft_entry__): counts exact,
+    """The repo's rule (__graft_entry__ holds to it too): counts exact,
     sums/avgs to f32 rounding; selections (min/max/last) exact."""
     occupied = ref["count"][rows] > 0
     for agg in ALL_AGGS:

@@ -1606,11 +1606,10 @@ class MetricEngine:
         Fields PARTITION the data table's rows (one row per sample per
         field, RFC docs/rfcs/20240827-metric-engine.md:106-137), so the
         per-field pushdown scans below each decode only their own
-        field's rows — N fields cost one pass over the union, not N
-        (bench config 3 reports this as the redundancy factor).  A
-        shared-window variant (push In(field_id, all) once, mask each
-        field post-merge) was measured 4.6x SLOWER: a host-path run on
-        the CPU rung (bench/suite.py config 3), not a chip number.
+        field's rows — N fields cost one pass over the union, not N.
+        A shared-window variant (push In(field_id, all) once, mask each
+        field post-merge) was slower on the host path (a CPU run, not
+        a chip number).
         With device-layout sidecars the leaf-filtered load is cheap,
         while N masked aggregations over the UNION of rows cost N full
         passes.  What the chip makes of this path is read in the
@@ -1663,10 +1662,9 @@ class MetricEngine:
                                                     time_range,
                                                     ts_leaf=not aligned)
         # deliberately SEQUENTIAL: each scan already pipelines its own
-        # IO against pool work, and gathering all fields was measured
-        # 2x slower (config 3's redundancy factor 1.4x -> 2.7x; a
-        # host-path run on the CPU rung, as above) — ten interleaved
-        # merges thrash the worker pool and caches
+        # IO against pool work, and gathering all fields was slower on
+        # the host path (a CPU run, as above) — ten interleaved merges
+        # thrash the worker pool and caches
         t0 = time.perf_counter()
         for f in remaining:
             if resolved is not None:

@@ -98,7 +98,7 @@ class MetaIngest:
         self._clock = clock
         self._task: Optional[asyncio.Task] = None
         self._writing = False
-        self.paused = False  # bench A/B hook (config 12)
+        self.paused = False  # A/B hook: the loop beats, scrapes nothing
 
     async def start(self) -> None:
         if self.config.rollup and self._engine.rollups is not None:

@@ -909,8 +909,7 @@ class BatchDispatch:
 
 
 # stage attribution twins ride the same labeled families as every other
-# plan stage (docs/observability.md); read.py's plan_stage_snapshot
-# includes "device_decode" so bench diffs pick it up
+# plan stage (docs/observability.md)
 _STAGE_SECONDS = registry.histogram(
     "scan_stage_seconds", "wall seconds per merge-scan plan stage"
 ).labels(stage="device_decode")

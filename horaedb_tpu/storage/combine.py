@@ -4,9 +4,9 @@ grids.
 Every aggregation path ends here: per-window partial grids (each
 covering LOCAL buckets [lo, lo + width) of the query's bucket range)
 fold into the user-facing (groups, num_buckets) aggregate grids.  Three
-coordinated pieces kill the output-grid cliff the scale ladder measured
-(bench_results/scale_r5.md: combine/finalize materializing hosts x
-buckets float64 cells went 4.4x superlinear at 200M rows):
+coordinated pieces kill the output-grid cliff (combine/finalize
+materializing hosts x buckets float64 cells is superlinear in hosts at
+high cardinality):
 
   sparse combine   parts fold straight into the FINAL output buffers as
                    per-series bucket runs — full-group parts (the common

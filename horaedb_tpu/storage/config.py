@@ -193,7 +193,7 @@ class ScanDecodeConfig:
                  never on XLA-CPU, where host numpy decode measured
                  faster (the host_agg trade).
       "device" — force the fused dispatch wherever structurally
-                 eligible (bench A/Bs and the chaos suite's device leg;
+                 eligible (A/B tests and the chaos suite's device leg;
                  takes precedence over the fused aggregate).
       "host"   — the pre-change host decode everywhere: THE bit
                  -identity control (the seeded chaos suite

@@ -112,7 +112,7 @@ _MESH_STALLS = {
 
 
 def stall_counts() -> dict:
-    """Cumulative per-stage stall counts (bench/stats snapshots)."""
+    """Cumulative per-stage stall counts (tests read them)."""
     return {s: int(c.value) for s, c in _STALLS.items()}
 
 

@@ -76,8 +76,7 @@ _ROWS_SCANNED = registry.counter(
 
 # Per-plan-stage attribution (the reference wires ExecutionPlanMetricsSet
 # through its reader, read.rs:84; ours records real numbers): seconds,
-# rows, and bytes per pipeline stage, cumulative in the registry and
-# diffable around a query for a per-query profile (bench.py does this).
+# rows, and bytes per pipeline stage, cumulative in the registry.
 # One labeled family per unit (stage= label) instead of a metric name
 # per stage; per-QUERY attribution additionally lands on the ambient
 # trace via tracing.trace_add (docs/observability.md).
@@ -291,32 +290,6 @@ def _timed_stage(stage: str, phase_name: Optional[str] = None):
     return deco
 
 
-def plan_stage_snapshot() -> dict:
-    """Cumulative per-stage numbers; diff two snapshots to attribute a
-    query's time (bench.py's cold-path profile)."""
-    out = {}
-    for s in _PLAN_STAGES:
-        h = _STAGE_SECONDS[s]
-        out[f"{s}_s"] = round(h.sum, 6)
-        out[f"{s}_calls"] = h.count
-    for s, c in _STAGE_ROWS.items():
-        out[f"{s}_rows"] = int(c.value)
-    for s, c in _STAGE_BYTES.items():
-        out[f"{s}_bytes"] = int(c.value)
-    from horaedb_tpu.storage import pipeline as pipeline_mod
-
-    stalls = pipeline_mod.stall_counts()
-    for s in pipeline_mod.PIPELINE_STAGES:
-        h = pipeline_mod.STAGE_SECONDS[s]
-        out[f"pipeline_{s}_s"] = round(h.sum, 6)
-        out[f"pipeline_{s}_calls"] = h.count
-        out[f"pipeline_stalls_{s}"] = stalls[s]
-        # rows/bytes too: bench A/Bs diff decoded-window bytes against
-        # the device path's encoded-bytes-uploaded (config 16)
-        out[f"pipeline_{s}_rows"] = int(pipeline_mod.STAGE_ROWS[s].value)
-        out[f"pipeline_{s}_bytes"] = int(
-            pipeline_mod.STAGE_BYTES[s].value)
-    return out
 # segment tables held in memory at once by _prefetch_tables (bounds BOTH
 # the row-scan and aggregate paths — including compaction's scan);
 # fallback when scan.prefetch_segments is 0/unset
@@ -1710,10 +1683,10 @@ class ParquetReader:
         windows — round stacks, fused-replay plans, per-window memos
         (device column copies, aggregation grids) — while KEEPING the
         post-merge windows themselves, which live in host RAM.  This is
-        the 'HBM evicted' state the bench ladder measures: the next
-        query re-stacks/re-uploads from
-        host windows instead of re-reading and re-merging.  (Tests and
-        benchmarks only; production eviction is the LRUs' own.)"""
+        the 'HBM evicted' rung of the cache ladder: the next query
+        re-stacks/re-uploads from host windows instead of re-reading
+        and re-merging.  (close() and tests only; production eviction
+        is the LRUs' own.)"""
         with self._stack_cache_lock:
             # includes the mesh decode round stacks — the fused path's
             # uploaded (time, capacity) column matrices share this LRU
@@ -2136,30 +2109,6 @@ class ParquetReader:
 
     # ---- aggregate pushdown ------------------------------------------------
 
-    async def execute_aggregate(self, plan: ScanPlan, spec: AggregateSpec
-                                ) -> tuple[np.ndarray, dict]:
-        """Run the merge + downsample entirely on device, returning
-        (group_values, finalized grids) combined across all segments and
-        windows.  group_values are decoded host values (e.g. tsids) in
-        sorted order; each grid is (len(group_values), num_buckets)."""
-        marks = self._mem_delta_marks()
-        try:
-            if self.fused_aggregate_ok(plan):
-                return await self.execute_aggregate_fused(plan, spec)
-            # collected per segment and folded in segment order:
-            # memo-served segments may yield out of plan order, and the
-            # combine fold order is part of the bit-identity contract
-            done: dict[int, list] = {}
-            async for seg_start, seg_parts in self.aggregate_segments(
-                    plan, spec):
-                done[seg_start] = seg_parts
-            parts = [p for s in sorted(done) for p in done[s]]
-            return self.finalize_aggregate(parts, spec)
-        finally:
-            # cold scans move megabytes into the cache tiers; the trace
-            # shows which account they landed in
-            self._mem_delta_attribute(marks)
-
     def router_covers(self, plan: ScanPlan) -> bool:
         """Whether the attached near-data router would serve any of
         this plan's segments.  scan_aggregate consults it ahead of the
@@ -2248,7 +2197,7 @@ class ParquetReader:
 
     def _decode_mode(self) -> str:
         """Resolved [scan.decode] mode: HORAEDB_DEVICE_DECODE=1/0
-        forces device/host over the config (the bench/chaos override
+        forces device/host over the config (the test/chaos override
         convention of HORAEDB_FUSED_AGG and friends)."""
         import os
 

@@ -110,8 +110,7 @@ def test_lint_rejects_reexec_and_retired_plugin_name(tmp_path):
     plugin = 'POOL = "PALLAS_' + lint._PLUGIN_NAME.upper() + '_POOL_IPS"\n'
     # the name inside a longer word is not the plug-in
     fine = f"WORD = 't{lint._PLUGIN_NAME}omy'\n"
-    for rel in ("horaedb_tpu/x.py", "tools/x.py", "bench.py",
-                "chip_smoke.py"):
+    for rel in ("horaedb_tpu/x.py", "tools/x.py", "chip_smoke.py"):
         path = tmp_path / rel
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(reexec)

@@ -177,8 +177,7 @@ def test_top_k_requires_ranking_agg():
 def test_top_k_materialized_cells_bounded():
     """The pushdown's materialized output is O(k x buckets x aggs),
     independent of group cardinality — asserted via the
-    scan_combine_materialized_cells_total counter the bench's top-k
-    leg also reads."""
+    scan_combine_materialized_cells_total counter."""
     rng = np.random.default_rng(SEED + 2)
     num_buckets, k = 16, 3
     deltas = []

@@ -504,9 +504,9 @@ class TestLintRule:
 def test_existing_jax_jit_sites_enumerated():
     """The bare-jax.jit rule's ground truth: every current `jax.jit`
     reference under horaedb_tpu/ lives in common/deviceprof.py (the
-    one seam) or carries a reasoned noqa (the bench suite's unprofiled
-    baselines) — enumerated here so a new site fails THIS test with a
-    readable location even before lint runs."""
+    one seam); none is waived by a noqa — enumerated here so a new
+    site fails THIS test with a readable location even before lint
+    runs."""
     import ast
     import pathlib
 
@@ -536,4 +536,4 @@ def test_existing_jax_jit_sites_enumerated():
         f"bare jax.jit outside common/deviceprof.py: {unprofiled}"
     # waivers are a conscious, enumerated set: growing it means a seam
     # the compile ledger will never see — update this list deliberately
-    assert waived_files <= {"bench/suite.py"}, waived_files
+    assert not waived_files, waived_files

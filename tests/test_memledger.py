@@ -521,14 +521,3 @@ class TestServerSurface:
         with pytest.raises(Exception,
                            match="soft_limit must not exceed"):
             load_config(str(bad))
-
-
-class TestBenchSmoke:
-    @pytest.mark.slow
-    def test_config18_runs(self):
-        from horaedb_tpu.bench.suite import run_config18
-
-        r = run_config18(rows=20_000, iters=2)
-        assert r["unit"] == "ms" and r["value"] > 0
-        assert "unattributed_delta_fraction" in r["accuracy"]
-        assert "on_overhead_pct" in r["overhead"]

@@ -618,8 +618,8 @@ def test_close_evicts_mesh_decode_state(runtimes):
             r.drop_hbm_state()
             assert not r._stack_cache and r._stack_cache_bytes == 0
             assert not r._scalar_cache
-            # compiled programs deliberately survive eviction (the
-            # bench's warm-vs-evicted legs compare recompile-free)
+            # compiled programs deliberately survive eviction (a
+            # warm and an evicted run then compare recompile-free)
             assert r._mesh_run_fns
             assert r._mesh_state_bytes == 0
         finally:
@@ -735,7 +735,7 @@ def test_lint_env_switch_rule(tmp_path):
         "import os\n\n"
         + "".join(f'{n[8:]} = os.environ.get("{n}", "")\n'
                   for n in sorted(lint._ENV_SWITCHES))
-        + 'ROWS = os.environ.get("BENCH_ROWS")\n'
+        + 'SEED = os.environ.get("TORTURE_SEED")\n'
         + 'DOC = "HORAEDB_MERGE_IMPL went in PR 30"\n')
     assert not lint.lint_file(fine)
     outside = tmp_path / "tests" / "x.py"

@@ -24,6 +24,33 @@ def test_native_library_builds():
         "image, so this should never fail here")
 
 
+def test_native_build_renames_a_finished_library_into_place(tmp_path):
+    """xdist workers on a fresh checkout all build on first use: the
+    linker must write beside the target and `mv` the finished file over
+    it, or a worker loads another's half-written library ("file too
+    short") and serves numpy fallbacks for its lifetime.  A stand-in
+    compiler records where it was told to write."""
+    import os
+    import pathlib
+    import shutil
+    import subprocess
+
+    src = pathlib.Path(native.__file__).resolve().parents[2] / "native"
+    shutil.copy(src / "Makefile", tmp_path)
+    (tmp_path / "horaedb_native.cpp").write_text("// stand-in\n")
+    cxx = tmp_path / "cxx.sh"
+    cxx.write_text('#!/bin/sh\nwhile [ "$1" != "-o" ]; do shift; done\n'
+                   'echo "$2" > written_to; echo built > "$2"\n')
+    cxx.chmod(0o755)
+    subprocess.run(["make", "-C", str(tmp_path), f"CXX={cxx}"], check=True,
+                   capture_output=True, timeout=60, cwd=tmp_path)
+    target = tmp_path / "libhoraedb_native.so"
+    assert target.read_text() == "built\n"
+    written_to = (tmp_path / "written_to").read_text().strip()
+    assert os.path.basename(written_to) != target.name
+    assert not os.path.exists(tmp_path / written_to)   # moved, not copied
+
+
 class TestSnapshotCodec:
     def test_roundtrip(self):
         recs = records(1000)

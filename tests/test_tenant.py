@@ -407,8 +407,8 @@ class TestTenantMiddleware:
                                     "dash": {"weight": 4.0}}},
                 max_concurrent_queries=4))
             try:
-                # the registry is process-global (the config-15 bench
-                # smoke also sheds an "abuser" tenant): assert deltas
+                # the registry is process-global (another test may
+                # also shed an "abuser" tenant): assert deltas
                 m0 = await (await client.get("/metrics")).text()
                 shed0 = metric_value(
                     m0, 'server_queries_shed_total{tenant="abuser"') or 0
