@@ -36,6 +36,7 @@ from horaedb_tpu.common.error import Error, ensure
 from horaedb_tpu.common.memledger import ledger as memledger
 from horaedb_tpu.objstore import ObjectStore
 from horaedb_tpu.ops import And, Eq, In, TimeRangePred
+from horaedb_tpu.ops import buckets as buckets_ops
 from horaedb_tpu.ops.downsample import ALL_AGGS
 from horaedb_tpu.ops.last import LastSpec, combine_fields, last_on_host
 from horaedb_tpu.ops.select import SelectSpec, compare
@@ -693,6 +694,16 @@ _LAST_QUERIES = registry.counter(
 _LAST_SECONDS = registry.counter(
     "query_last_seconds_total",
     "wall seconds inside the walk of query_last requests (plan, the "
+    "device's or the host's route over every segment asked, the "
+    "combine)")
+
+_BUCKETS_QUERIES = registry.counter(
+    "query_buckets_total",
+    "requests for the newest buckets of one field over all series "
+    "(query_buckets)")
+_BUCKETS_SECONDS = registry.counter(
+    "query_buckets_seconds_total",
+    "wall seconds inside the walk of query_buckets requests (plan, the "
     "device's or the host's route over every segment asked, the "
     "combine)")
 
@@ -1870,6 +1881,104 @@ class MetricEngine:
         return {"groups": part.groups, "timestamps": part.timestamps,
                 "values": part.values,
                 "found": [None if f.all() else f for f in part.found]}
+
+    async def query_buckets(self, metric: str,
+                            filters: list[tuple[str, str]], field: str,
+                            bucket_ms: int, limit: int, aggs: list[str],
+                            start: Optional[int] = None,
+                            end: Optional[int] = None) -> pa.Table:
+        """One field of `metric` aggregated ACROSS every series that
+        passes the label filters, by time bucket, the `limit` newest
+        buckets: TSBS's groupby-orderby-limit, a dashboard's fleet
+        panel, PromQL's `max(...)` without `by`.  Buckets are
+        epoch-aligned: bucket k is [k * bucket_ms, (k + 1) *
+        bucket_ms).  A bucket EXISTS if some such series has a CURRENT
+        sample (after last-write-wins dedup: what query() returns) of
+        `field` in it at start <= timestamp < end, an absent bound
+        unbounded.  The answer holds the `limit` newest existing
+        buckets (fewer if fewer exist), DESCENDING by bucket start, one
+        row each: bucket (int64, its start), count (int64: the samples
+        folded, over all series), then a float32 column an aggregate
+        asked, in the order asked, of max, min (bit for bit a stored
+        value), sum, avg (float32-rounded).  The bucket that holds
+        `end` is answered from its samples before `end` alone.  Exact
+        and whole: no look-back unless the client names one, no cut
+        after some segments, and no rollup tier is read (a rollup cell
+        is a series' cell).
+
+        ONE resolve (the label filters' posting lists; no filter stays
+        "every series": no series set is built).  The walk
+        (CloudObjectStorage.scan_buckets) takes the data table's
+        segments newest first from `end`'s and stops when `limit`
+        buckets exist that no older segment can add to: on the device
+        over the resident decode slice of the field, or by the row
+        scan (storage/read.py::buckets_segment decides per segment,
+        and counts).  A chunked table scans the field by query() and
+        folds on the host.
+
+        Traced as children of the request's root: one `resolve` span,
+        one `buckets` span (its children a segment asked: route=,
+        reason=, rows_read=, rows_used=, buckets_out=).  Raises Error
+        (a 400) for a field the metric does not have or an aggregate
+        that does not exist, before any scan; a metric nobody wrote
+        answers its columns and no row."""
+        ensure(len(aggs) > 0 and len(set(aggs)) == len(aggs),
+               "aggs must be a non-empty list of distinct aggregates")
+        unknown = [a for a in aggs if a not in buckets_ops.AGGS]
+        ensure(not unknown, f"unknown aggregate(s) {unknown}; of "
+                            f"{list(buckets_ops.AGGS)}")
+        ensure(bucket_ms >= 1, "bucket_ms must be at least 1")
+        ensure(1 <= limit <= buckets_ops.MAX_LIMIT,
+               f"limit must lie in 1..{buckets_ops.MAX_LIMIT}")
+        rng = TimeRange.new(int(Timestamp.MIN) if start is None else start,
+                            int(Timestamp.MAX) if end is None else end)
+        ensure(rng.start < rng.end, "start must lie before end")
+        spec = buckets_ops.BucketsSpec(
+            group_col="tsid", ts_col="timestamp", value_col="value",
+            bucket_ms=int(bucket_ms), aggs=tuple(aggs))
+        _BUCKETS_QUERIES.inc()
+        with span("resolve", metric=metric):
+            mid = await self.metric_manager.resolve(metric, rng)
+            tsids = None
+            if mid is not None:
+                unknown = await self.metric_manager.unknown_fields(
+                    metric, [field], rng)
+                ensure(not unknown,
+                       f"unknown field(s) {unknown} of metric {metric!r}")
+                tsids = await self.index_manager.find_tsids(mid, filters,
+                                                            rng)
+        t0 = time.perf_counter()
+        with span("buckets", metric=metric, field=field, limit=limit):
+            if mid is None or (tsids is not None and not tsids):
+                out = buckets_ops.answer_columns(buckets_ops.Merged(),
+                                                 spec, limit)
+            elif self.chunked_data:
+                tbl = await self.query(metric, filters, rng, field=field)
+                merged = buckets_ops.Merged()
+                merged.add(buckets_ops.buckets_on_host(
+                    tbl.column("timestamp").to_numpy(),
+                    tbl.column("value").to_numpy().astype(np.float32),
+                    spec, int(rng.start), int(rng.end)), limit)
+                out = buckets_ops.answer_columns(merged, spec, limit)
+            else:
+                # the time leaf always rides, bounded or not: one
+                # program whatever bounds a request names
+                leaves = [Eq("metric_id", mid),
+                          Eq("field_id", field_id_of(field)),
+                          TimeRangePred("timestamp", int(rng.start),
+                                        int(rng.end))]
+                if tsids is not None:
+                    leaves.append(In("tsid", sorted(tsids)))
+                qp = await self.tables["data"].plan_buckets(
+                    ScanRequest(range=rng, predicate=And(leaves)), spec,
+                    limit)
+                out = await self.tables["data"].execute_plan(qp)
+        _BUCKETS_SECONDS.inc(time.perf_counter() - t0)
+        return pa.table(
+            [pa.array(out["bucket"], type=pa.int64()),
+             pa.array(out["count"], type=pa.int64())]
+            + [pa.array(out[a], type=pa.float32()) for a in aggs],
+            names=["bucket", "count"] + list(aggs))
 
     async def _downsample_chunked(self, metric: str, filters, time_range,
                                   bucket_ms: int, num_buckets: int,

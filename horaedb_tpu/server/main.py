@@ -74,8 +74,8 @@ logger = logging.getLogger(__name__)
 # bypass the admission+tenant middleware chain.
 _QUERY_ENDPOINTS = frozenset({
     "/query", "/query_arrow", "/query_topk", "/query_multi",
-    "/query_rows", "/query_last", "/label_values", "/label_names",
-    "/metrics_list"})
+    "/query_rows", "/query_last", "/query_buckets", "/label_values",
+    "/label_names", "/metrics_list"})
 _WRITE_ENDPOINTS = frozenset({"/write", "/write_arrow"})
 _UNGOVERNED_ENDPOINTS = frozenset({
     "/", "/toggle", "/compact", "/metrics", "/stats",
@@ -105,10 +105,12 @@ _QUEUED_QUERIES = registry.gauge(
 _RESPOND_CELLS = registry.counter(
     "respond_cells_total",
     "grid cells encoded into downsample responses, and values (rows x "
-    "columns) serialized into /query_rows and /query_last responses")
+    "columns) serialized into /query_rows, /query_last and "
+    "/query_buckets responses")
 _RESPOND_BYTES = registry.counter(
     "respond_bytes_total",
-    "body bytes of downsample, /query_rows and /query_last responses")
+    "body bytes of downsample, /query_rows, /query_last and "
+    "/query_buckets responses")
 _RESPOND_ENCODE_SECONDS = registry.counter(
     "respond_encode_seconds_total",
     "wall seconds inside the downsample response encoder (the lazy "
@@ -1668,6 +1670,58 @@ def build_app(state: ServerState) -> web.Application:
             body=payload,
             content_type="application/vnd.apache.arrow.stream")
 
+    @routes.post("/query_buckets")
+    async def query_buckets(req: web.Request) -> web.Response:
+        """One field aggregated ACROSS series by time bucket, the
+        newest buckets first (TSBS groupby-orderby-limit): over every
+        series of the metric that passes the filters, the `limit`
+        newest epoch-aligned buckets of `bucket_ms` that hold a sample
+        of `field` in [start, end), each with its count and the
+        aggregates asked.  Body: {metric, filters?, field, bucket_ms,
+        limit, aggs: [max|min|sum|avg, ..], start?, end?,
+        compression?}; an absent bound is unbounded (no look-back by
+        default).  The answer is an Arrow IPC stream (bucket, count,
+        one float32 column an aggregate), descending by bucket:
+        README.md has the semantics."""
+        from horaedb_tpu.common.ipc import COMPRESSIONS
+
+        try:
+            with span("parse"):
+                body = await req.json()
+                metric, filters = _parse_metric_filters(body)
+                field, aggs = body["field"], body["aggs"]
+                if not isinstance(field, str):
+                    raise ValueError("field must be a string")
+                if not isinstance(aggs, list) \
+                        or not all(isinstance(a, str) for a in aggs):
+                    raise ValueError("aggs must be a list of strings")
+                bucket_ms, limit = int(body["bucket_ms"]), int(body["limit"])
+                start, end = (None if body.get(k) is None else int(body[k])
+                              for k in ("start", "end"))
+                compression = body.get("compression")
+                if compression not in COMPRESSIONS:
+                    raise ValueError(
+                        f"unsupported compression {compression!r}")
+        except (KeyError, TypeError, ValueError) as e:
+            return web.json_response({"error": f"bad request: {e}"},
+                                     status=400)
+        buckets = getattr(state.engine, "query_buckets", None)
+        if buckets is None:
+            return web.json_response(
+                {"error": "this front end has no bucket query"},
+                status=501)
+        try:
+            tbl = await buckets(metric, filters, field, bucket_ms, limit,
+                                aggs, start, end)
+        except Error as e:
+            return _error_response(e)
+        payload = await _respond_bytes(
+            tbl.num_rows * tbl.num_columns,
+            lambda where: _rows_payload(tbl, compression, where))
+        return web.Response(
+            body=payload,
+            content_type="application/vnd.apache.arrow.stream")
+
     @routes.get("/label_names")
     async def label_names(req: web.Request) -> web.Response:
         try:
@@ -1940,7 +1994,7 @@ def _downsample_payload(body: dict, where: str) -> bytes:
 
 
 def _rows_payload(tbl: pa.Table, compression, where: str) -> bytes:
-    """The bytes of a /query_rows or /query_last response: `tbl` as one Arrow IPC
+    """The bytes of a /query_rows, /query_last or /query_buckets response: `tbl` as one Arrow IPC
     stream, counted as _downsample_payload counts its grids (a value
     of the table a cell)."""
     from horaedb_tpu.common.ipc import serialize_stream

@@ -137,6 +137,38 @@ class LastPlan:
             for start, ssts in self.segments)
 
 
+@dataclass
+class BucketsPlan:
+    """buckets (one field folded over ALL series by time bucket, the
+    `limit` newest buckets that hold a sample) over the table's
+    segments NEWEST FIRST, with an open lower bound unless the client
+    named one: the walk asks one segment for its buckets and stops when
+    `limit` buckets exist and none of them can still gain from an
+    older segment, or no segment is left.
+
+    `segments` is the manifest's answer when the plan was built,
+    (segment start, its SSTs), newest first; `request` is the one
+    field's scan over the range (unbounded where the client gave no
+    bound).  Which route a segment takes (the device's resident slice
+    or the row scan) is decided per segment where it runs
+    (ParquetReader.buckets_segment) and counted there."""
+
+    segments: list
+    request: object               # storage/read.ScanRequest
+    buckets: object               # ops/buckets.BucketsSpec
+    limit: int
+
+    def describe(self) -> str:
+        spec = self.buckets
+        text = (f"Buckets: ts={spec.ts_col}, value={spec.value_col}, "
+                f"bucket_ms={spec.bucket_ms}, aggs={list(spec.aggs)}, "
+                f"over all series, newest first, stops at {self.limit} "
+                f"bucket(s) that no older segment can add to\n")
+        return text + "\n".join(
+            f"  Segment {start}: {len(ssts)} sst(s)"
+            for start, ssts in self.segments)
+
+
 def apply_top_k(group_values: np.ndarray, grids: dict,
                 tk: TopKSpec) -> tuple[np.ndarray, dict]:
     """Host top-k over finalized grids: by the time grids exist the

@@ -60,6 +60,7 @@ from horaedb_tpu.storage.types import (
     StorageSchema,
     TimeRange,
 )
+from horaedb_tpu.ops import buckets as buckets_ops
 from horaedb_tpu.ops import device_decode
 from horaedb_tpu.ops import last as last_ops
 from horaedb_tpu.ops import select as select_ops
@@ -254,6 +255,24 @@ _LAST_ROWS = registry.counter(
     "answered, a series found x a field with a sample at the row's "
     "timestamp")
 _LAST_NO_FALLBACK = _SELECT_MODE_REASONS + ("memtable",)
+# the bucket route (buckets_segment): every segment a walk asked, by
+# the route that answered it and, on the host route, why
+_BUCKETS_SEGMENTS = registry.counter(
+    "scan_buckets_segments_total",
+    "segments a bucket walk asked for their buckets over all series, "
+    "by the route that answered them: device = the field's resident "
+    "slice folded on the device (ops/buckets.py), host = the row "
+    "scan's merged rows folded in numpy, for `reason` (the select's "
+    "reasons, `buckets`: the segment's grid is wider than the device "
+    "folds, and `memtable`: rows of the segment are not in an SST "
+    "yet; mode_host, cpu_auto and memtable are no fallbacks, every "
+    "other reason also counts in scan_decode_fallback_total)")
+_BUCKETS_ROWS = registry.counter(
+    "scan_buckets_rows_total",
+    "rows of bucket walks by route: read = rows put through the "
+    "decode (the device route: the field's slice as uploaded; the "
+    "host route: the rows its scan returned), used = rows that fell "
+    "into a bucket the request answered")
 _VALUE_LEAF = {"gt": filter_ops.Gt, "ge": filter_ops.Ge,
                "lt": filter_ops.Lt, "le": filter_ops.Le}
 
@@ -4174,12 +4193,7 @@ class ParquetReader:
         if reason is not None:
             return reason
         seg = plans[0].segments[0]
-        # only the columns count: they are the slices' key, which the
-        # last rows share with the aggregates over the same field
-        carrier = AggregateSpec(
-            group_col=spec.group_col, ts_col=spec.ts_col,
-            value_col=spec.value_col, range_start=0, bucket_ms=1,
-            num_buckets=1, which=("count",))
+        carrier = _slice_carrier(spec)
         plans = [dc_replace(p, decode_spec=carrier) for p in plans]
         slice_columns = [self._decode_slice_columns(p) for p in plans]
         with self._phase("scan.windows", segments=1) as probe:
@@ -4243,6 +4257,92 @@ class ParquetReader:
                   reason=reason, series_in=series_in,
                   series_out=len(part.groups), rows_read=part.rows_read):
             pass
+
+    # ---- one field over all series by time bucket (ops/buckets.py) -------
+
+    async def buckets_segment(
+            self, plan: ScanPlan, spec,
+            segment_ms: int) -> "buckets_ops.SegmentBuckets | str":
+        """ONE segment's buckets (ops/buckets.BucketsSpec) within the
+        bounds of `plan` (over that segment alone; its time leaf holds
+        the request's bounds).  The device route answers from the
+        field's resident decode slice (the aggregate route's: same
+        key, same account; a miss reads, narrows and uploads the slice
+        as that route's miss does and leaves it resident for every
+        route), in one pool job and one download, and keeps nothing
+        else: no grid, no answer.  Where the plan, the mode or the
+        slice rules the device out, the REASON comes back and the
+        caller answers the segment by the row scan
+        (CloudObjectStorage.scan_buckets: the memtable's overlay lives
+        there), folded by buckets_segment_host."""
+        reason = self._select_route(plan)
+        if reason is not None:
+            return reason
+        seg = plan.segments[0]
+        plan = dc_replace(plan, decode_spec=_slice_carrier(spec))
+        cols = self._decode_slice_columns(plan)
+        with self._phase("scan.windows", segments=1) as probe:
+            seg_slice = self.scan_cache.get_slice(
+                self._decode_slice_key(seg, cols))
+            hit = seg_slice is not None
+            device_decode.note_resident("hit" if hit else "miss")
+            probe.fields["resident"] = int(hit)
+        deadline_checkpoint()
+        if seg_slice is None:
+            seg_slice = await self._load_select_slice(seg, plan, cols)
+        window = (seg_slice if not isinstance(
+            seg_slice, device_decode.SegmentSlice)
+            else buckets_ops.plan_window(
+                seg_slice, plan.prune_leaves, seg.segment_start,
+                segment_ms, spec.bucket_ms))
+        if isinstance(window, str):
+            return window
+        deadline_checkpoint()
+        part = await self._run_pool(
+            plan.pool, buckets_ops.buckets_resident, window, spec,
+            self._phase, self.table)
+        return self._note_buckets(part, "device", "", seg.segment_start)
+
+    def buckets_segment_host(
+            self, scanned: list, spec, time_range: TimeRange, reason: str,
+            segment_start: int) -> "buckets_ops.SegmentBuckets":
+        """The host route of buckets_segment: `scanned` holds the
+        batches a row scan of the segment returned (merged,
+        deduplicated, the memtable's rows laid over them where the WAL
+        is on), folded in numpy."""
+        _groups, ts, vals = _scanned_columns(scanned, spec)
+        part = buckets_ops.buckets_on_host(
+            ts, vals, spec, int(time_range.start), int(time_range.end))
+        return self._note_buckets(part, "host", reason, segment_start)
+
+    @staticmethod
+    def _note_buckets(part, route: str, reason: str, segment_start: int):
+        part.route, part.reason = route, reason
+        part.segment_start = segment_start
+        _BUCKETS_SEGMENTS.labels(route=route, reason=reason).inc()
+        if reason and reason not in _LAST_NO_FALLBACK:
+            device_decode.note_fallback(reason)
+        _BUCKETS_ROWS.labels(side="read", route=route).inc(part.rows_read)
+        return part
+
+    def finalize_buckets(self, parts: list, merged, spec,
+                         limit: int) -> dict:
+        """A walk's segments (newest first) and their merged buckets to
+        the answer's columns: the `scan.combine` phase, and the
+        segments' account, which waits for the answer (a row is used
+        where its bucket is answered)."""
+        with self._phase("scan.combine", sync=True, parts=len(parts)):
+            out = buckets_ops.answer_columns(merged, spec, limit)
+        for part in parts:
+            used = int(part.count[np.isin(part.starts,
+                                          out["bucket"])].sum())
+            _BUCKETS_ROWS.labels(side="used", route=part.route).inc(used)
+            with span("buckets.segment", segment=part.segment_start,
+                      route=part.route, reason=part.reason,
+                      rows_read=part.rows_read, rows_used=used,
+                      buckets_out=len(part.starts)):
+                pass
+        return out
 
     def _window_groups(self, out_batch: encode.DeviceBatch,
                        spec: AggregateSpec, plan: ScanPlan):
@@ -5110,6 +5210,17 @@ def combine_aggregate_parts(parts: list[tuple[np.ndarray, int, dict]],
     helpers, old tests) keep this name."""
     return combine_mod.combine_aggregate_parts(parts, num_buckets,
                                                which=which)
+
+
+def _slice_carrier(spec) -> AggregateSpec:
+    """The decode_spec under which a route that is no aggregate (a
+    LastSpec's, a BucketsSpec's) probes and admits decode slices: only
+    the columns count, they are the slices' key, which those routes
+    share with the aggregates over the same field."""
+    return AggregateSpec(
+        group_col=spec.group_col, ts_col=spec.ts_col,
+        value_col=spec.value_col, range_start=0, bucket_ms=1,
+        num_buckets=1, which=("count",))
 
 
 def _scanned_columns(batches: list, spec) -> tuple:

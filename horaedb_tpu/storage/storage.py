@@ -110,6 +110,17 @@ class TimeMergeStorage(abc.ABC):
         be merged in (no SST set names that content)."""
 
 
+async def _scan_segment(scan, req, start: int) -> list:
+    """The batches `scan` (a row scan: merged, deduplicated, the
+    memtable's rows overlaid where it is the WAL wrapper's) returns for
+    the one segment that begins at `start`."""
+    rows = scan(req, segment_filter=lambda s: s == start)
+    try:
+        return [b async for b in rows]
+    finally:
+        await rows.aclose()
+
+
 class CloudObjectStorage(TimeMergeStorage):
     def __init__(self, root_path: str, segment_duration_ms: int,
                  store: ObjectStore, user_schema: pa.Schema,
@@ -541,6 +552,42 @@ class CloudObjectStorage(TimeMergeStorage):
         return SelectPlan(scans=await self._plan_select(reqs),
                           requests=reqs, select=spec, asked=asked)
 
+    async def _walk_newest_first(self, time_range: TimeRange,
+                                 first_segments: Optional[list], overlaid,
+                                 ask, answered, what: str) -> dict:
+        """THE newest-first walk, under the stop rule of its caller:
+        the segments of `time_range` (and those `overlaid` adds) newest
+        first, `ask(start, ssts)` awaited for one segment at a time
+        until `answered()` says that no older segment can change the
+        answer, or no segment is left.  Returns {segment start: what
+        `ask` returned}.  A compaction race (NotFoundError) replans the
+        segments not yet answered; the memtable's marks are attributed
+        as a scan's are."""
+        done: dict[int, object] = {}
+        mem_marks = self.reader._mem_delta_marks()
+        try:
+            for attempt in range(self._SCAN_RETRIES + 1):
+                segments = (first_segments if attempt == 0
+                            and first_segments is not None
+                            else await self._plan_last(time_range,
+                                                       overlaid))
+                try:
+                    for start, ssts in segments:
+                        if answered():
+                            break
+                        if start in done:
+                            continue
+                        done[start] = await ask(start, ssts)
+                    break
+                except NotFoundError:
+                    if attempt == self._SCAN_RETRIES:
+                        raise
+                    logger.info("%s walk raced a compaction; replanning",
+                                what)
+            return done
+        finally:
+            self.reader._mem_delta_attribute(mem_marks)
+
     async def scan_last(self, reqs: list, spec, expect,
                         first_segments: Optional[list] = None,
                         overlaid=frozenset(), scan=None) -> dict:
@@ -564,36 +611,75 @@ class CloudObjectStorage(TimeMergeStorage):
         race replans the segments not yet answered."""
         scan = scan or self.scan
         missing = np.asarray(expect)
-        done: dict[int, object] = {}
-        mem_marks = self.reader._mem_delta_marks()
-        try:
-            for attempt in range(self._SCAN_RETRIES + 1):
-                segments = (first_segments if attempt == 0
-                            and first_segments is not None
-                            else await self._plan_last(reqs[0].range,
-                                                       overlaid))
-                try:
-                    for start, ssts in segments:
-                        if not len(missing):
-                            break
-                        if start in done:
-                            continue
-                        part = await self._last_of_segment(
-                            start, ssts, reqs, spec, missing,
-                            start in overlaid, scan)
-                        done[start] = part
-                        missing = np.setdiff1d(missing, part.groups,
-                                               assume_unique=True)
-                    break
-                except NotFoundError:
-                    if attempt == self._SCAN_RETRIES:
-                        raise
-                    logger.info("last-row walk raced a compaction; "
-                                "replanning")
-            return self.reader.finalize_select(
-                [done[seg] for seg in sorted(done)], len(reqs))
-        finally:
-            self.reader._mem_delta_attribute(mem_marks)
+
+        async def ask(start: int, ssts: list):
+            nonlocal missing
+            part = await self._last_of_segment(
+                start, ssts, reqs, spec, missing, start in overlaid, scan)
+            missing = np.setdiff1d(missing, part.groups,
+                                   assume_unique=True)
+            return part
+
+        done = await self._walk_newest_first(
+            reqs[0].range, first_segments, overlaid, ask,
+            lambda: not len(missing), "last-row")
+        return self.reader.finalize_select(
+            [done[seg] for seg in sorted(done)], len(reqs))
+
+    async def scan_buckets(self, req, spec, limit: int,
+                           first_segments: Optional[list] = None,
+                           overlaid=frozenset(), scan=None) -> dict:
+        """The `limit` newest buckets (ops/buckets.BucketsSpec: one
+        field folded over ALL series by epoch-aligned time bucket) that
+        hold a sample of `req`'s range: the same walk, asking each
+        segment for its buckets (ParquetReader.buckets_segment has the
+        routes), under another stop rule: `limit` buckets exist and the
+        oldest of them begins at or after the start of the oldest
+        segment read, so that no older segment, which holds only older
+        timestamps, can add a row to any of them.  A bucket that
+        straddles two segments is the fold of both parts.  Returns
+        {bucket, count, an array an aggregate asked}, descending by
+        bucket.  `overlaid`, `scan` and the compaction race as
+        scan_last."""
+        from horaedb_tpu.ops.buckets import Merged
+
+        scan = scan or self.scan
+        merged = Merged()
+        oldest_read = None
+
+        async def ask(start: int, ssts: list):
+            nonlocal oldest_read
+            part = await self._buckets_of_segment(
+                start, ssts, req, spec, start in overlaid, scan)
+            merged.add(part, limit)
+            # a replan after a compaction race may name a newer segment
+            oldest_read = start if oldest_read is None \
+                else min(oldest_read, start)
+            return part
+
+        def answered() -> bool:
+            newest = merged.newest(limit)
+            return len(newest) == limit and newest[-1] >= oldest_read
+
+        done = await self._walk_newest_first(
+            req.range, first_segments, overlaid, ask, answered, "buckets")
+        return self.reader.finalize_buckets(
+            [done[seg] for seg in sorted(done, reverse=True)], merged,
+            spec, limit)
+
+    async def _buckets_of_segment(self, start: int, ssts: list, req, spec,
+                                  overlaid: bool, scan):
+        reason = "memtable"
+        if not overlaid:
+            got = await self.reader.buckets_segment(
+                self.reader.build_plan(ssts, req), spec,
+                self.segment_duration_ms)
+            if not isinstance(got, str):
+                return got
+            reason = got
+        return self.reader.buckets_segment_host(
+            await _scan_segment(scan, req, start), spec, req.range, reason,
+            start)
 
     async def _last_of_segment(self, start: int, ssts: list, reqs: list,
                                spec, missing, overlaid: bool, scan):
@@ -605,20 +691,14 @@ class CloudObjectStorage(TimeMergeStorage):
             if not isinstance(got, str):
                 return got
             reason = got
-        scanned = []
-        for req in reqs:
-            rows = scan(req, segment_filter=lambda s: s == start)
-            try:
-                scanned.append([b async for b in rows])
-            finally:
-                await rows.aclose()
+        scanned = [await _scan_segment(scan, req, start) for req in reqs]
         return self.reader.last_segment_host(scanned, spec, missing, reason,
                                              start)
 
     async def _plan_last(self, time_range: TimeRange,
                          overlaid=frozenset()) -> list:
-        """A last-row walk's scan.plan phase: ONE manifest lookup, the
-        segments it names (and those `overlaid` adds) newest first,
+        """A newest-first walk's scan.plan phase: ONE manifest lookup,
+        the segments it names (and those `overlaid` adds) newest first,
         each with its SSTs."""
         with self.reader._phase("scan.plan") as planned:
             ensure(self.manifest is not None, "storage not opened")
@@ -636,6 +716,13 @@ class CloudObjectStorage(TimeMergeStorage):
 
         return LastPlan(segments=await self._plan_last(reqs[0].range),
                         requests=reqs, last=spec, expect=np.asarray(expect))
+
+    async def plan_buckets(self, req, spec, limit: int):
+        """The BucketsPlan of a bucket walk (storage/plan.py)."""
+        from horaedb_tpu.storage.plan import BucketsPlan
+
+        return BucketsPlan(segments=await self._plan_last(req.range),
+                           request=req, buckets=spec, limit=limit)
 
     async def build_scan_plan(self, req: ScanRequest,
                               keep_builtin: bool = False) -> ScanPlan:
@@ -696,7 +783,8 @@ class CloudObjectStorage(TimeMergeStorage):
         the full groups x buckets grid.  The plan built by plan_query
         is the first attempt's scan plan — one manifest lookup per
         query, not two."""
-        from horaedb_tpu.storage.plan import LastPlan, SelectPlan
+        from horaedb_tpu.storage.plan import (BucketsPlan, LastPlan,
+                                              SelectPlan)
 
         if isinstance(qp, SelectPlan):
             return self.scan_select(qp.requests, qp.select, qp.asked,
@@ -704,6 +792,9 @@ class CloudObjectStorage(TimeMergeStorage):
         if isinstance(qp, LastPlan):
             return self.scan_last(qp.requests, qp.last, qp.expect,
                                   first_segments=qp.segments)
+        if isinstance(qp, BucketsPlan):
+            return self.scan_buckets(qp.request, qp.buckets, qp.limit,
+                                     first_segments=qp.segments)
         if qp.aggregate is None:
             return self.scan(qp.request, first_plan=qp.scan)
         return self.scan_aggregate(qp.request, qp.aggregate,

@@ -564,12 +564,24 @@ class IngestStorage(TimeMergeStorage):
         return await self.inner.scan_last(reqs, spec, expect,
                                           overlaid=overlaid, scan=self.scan)
 
+    async def scan_buckets(self, req: ScanRequest, spec,
+                           limit: int) -> dict:
+        """The inner table's bucket walk, its overlaid segments
+        answered through this table's own scan, as scan_last."""
+        overlaid = frozenset(self._snapshot_overlay(req.range))
+        return await self.inner.scan_buckets(req, spec, limit,
+                                             overlaid=overlaid,
+                                             scan=self.scan)
+
     def execute_plan(self, qp):
-        from horaedb_tpu.storage.plan import LastPlan, SelectPlan
+        from horaedb_tpu.storage.plan import (BucketsPlan, LastPlan,
+                                              SelectPlan)
 
         if isinstance(qp, LastPlan):
             # the cached segments are dropped, as a row scan's plan is
             return self.scan_last(qp.requests, qp.last, qp.expect)
+        if isinstance(qp, BucketsPlan):
+            return self.scan_buckets(qp.request, qp.buckets, qp.limit)
         if isinstance(qp, SelectPlan):
             async def select():
                 # the select reads pure SST state, as an aggregate does

@@ -651,6 +651,124 @@ def test_every_last_reason_a_plan_can_show_has_a_row():
 
 
 # ---------------------------------------------------------------------------
+# the buckets: one field over ALL series by time bucket (ISSUE 48)
+# ---------------------------------------------------------------------------
+
+BUCKETS = ("_buckets_jit",)
+
+
+@dataclasses.dataclass
+class BucketsRow(SelectRow):
+    bucket_ms: int = BUCKET_MS
+
+
+# the select's rows, route for route and reason for reason (one gate:
+# ParquetReader._select_route), and one of its own: a grid wider than
+# the device folds is declined per segment, where the slice is planned
+BUCKETS_ROWS = [BucketsRow(**dict(
+    dataclasses.asdict(r), name=r.name.replace("select", "buckets"),
+    predicate=r.predicate, mode=r.mode)) for r in SELECT_ROWS] + [
+    BucketsRow("buckets_mode_device_grid_wider_than_the_device_folds",
+               "host", "buckets", scan={"decode": {"mode": "device"}},
+               bucket_ms=2_000, decode_reason={"buckets": SEGMENTS}),
+]
+
+
+def buckets_segments() -> dict:
+    fam = read_mod._BUCKETS_SEGMENTS
+    return {(dict(k)["route"], dict(k)["reason"]): c.value
+            for k, c in (fam._children or {}).items()}
+
+
+@pytest.mark.parametrize("row", BUCKETS_ROWS,
+                         ids=[r.name for r in BUCKETS_ROWS])
+def test_buckets_route_named_is_the_route_that_ran(row, runtimes,
+                                                   monkeypatch):
+    """max and min over the hosts named by time bucket, every bucket of
+    a window across the boundary (a `limit` past what it holds, so the
+    walk asks both segments): which route takes them, from
+    scan_buckets_segments_total{route,reason}, the device plane's
+    ledger (the bucket program ran once a segment, or no program did)
+    and the fallback counter; and the buckets are the reference's."""
+    from horaedb_tpu.ops.buckets import BucketsSpec
+
+    for name in ("HORAEDB_HOST_AGG", "HORAEDB_DEVICE_DECODE",
+                 "HORAEDB_FUSED_AGG", "HORAEDB_DEVCOL_STACK"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in row.env.items():
+        monkeypatch.setenv(name, value)
+    if row.accel:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def calls() -> dict:
+        return {r["fn"]: r["compiles"] + r["dispatches"]
+                for r in deviceprof.profiler.snapshot()["fns"]
+                if r["fn"] in BUCKETS + LAST + SELECT + ROUTE_FNS}
+
+    async def go():
+        world = World(seed=48)
+        s = await CloudObjectStorage.open(
+            "db", SEGMENT_MS, MemoryObjectStore(), SCHEMA, 2,
+            storage_config(row), runtimes=runtimes)
+        try:
+            for wr in world.write_requests():
+                await s.write(wr)
+            s.reader.scan_cache.clear()
+            s.reader.encoded_cache.clear()
+            leaves = [F.TimeRangePred("ts", LO, HI)] \
+                + ([] if row.predicate is None else [row.predicate])
+            req = ScanRequest(range=TimeRange.new(LO, HI),
+                              predicate=F.And(leaves))
+            spec = BucketsSpec(group_col="k", ts_col="ts", value_col="v",
+                               bucket_ms=row.bucket_ms,
+                               aggs=("max", "min"))
+            seg0, fns0, dec0 = buckets_segments(), calls(), \
+                decode_fallbacks()
+            qp = await s.plan_buckets(req, spec, 10_000)
+            assert qp.describe().startswith(
+                f"Buckets: ts=ts, value=v, bucket_ms={row.bucket_ms}, "
+                f"aggs=['max', 'min'], over all series, newest first")
+            out = await s.execute_plan(qp)
+
+            ts = np.arange(world.values.shape[1], dtype=np.int64) * TICK_MS
+            seen = np.flatnonzero((ts >= LO) & (ts < HI))
+            cell = ts[seen] // row.bucket_ms * row.bucket_ms
+            want = sorted(set(cell.tolist()), reverse=True)
+            vals = world.values[list(row.hosts)]
+            assert out["bucket"].tolist() == want
+            assert out["count"].tolist() == [
+                int((cell == b).sum()) * len(row.hosts) for b in want]
+            for agg, fold in (("max", np.max), ("min", np.min)):
+                ref = np.array([fold(vals[:, seen[cell == b]])
+                                for b in want], dtype=np.float32)
+                assert out[agg].tobytes() == ref.tobytes(), agg
+
+            assert delta(buckets_segments(), seg0) \
+                == {(row.route, row.reason): SEGMENTS}
+            assert delta(calls(), fns0) == (
+                {"_buckets_jit": SEGMENTS} if row.route == "device"
+                else {})
+            assert delta(decode_fallbacks(), dec0) == row.decode_reason
+        finally:
+            await s.close()
+
+    asyncio.run(go())
+
+
+def test_every_buckets_reason_a_plan_can_show_has_a_row():
+    """The select's census (one gate), and `buckets`: a segment cut
+    into more cells than the device folds.  The per-segment reasons of
+    a READ (unsorted, streamed, parquet, encoding, dtype, budget) need
+    a segment that shows them, and `memtable` a WAL:
+    tests/test_query_buckets.py has an unsorted slice and a memtable's
+    rows; `range` (a bucket of 25 days or more) needs such a bucket."""
+    assert {r.reason for r in BUCKETS_ROWS} == {
+        "", "cpu_auto", "mode_host", "no_sidecar", "predicate", "buckets"}
+    assert {r.route for r in BUCKETS_ROWS} == {"device", "host"}
+    assert len(BUCKETS_ROWS) == len(SELECT_ROWS) + 1
+
+
+# ---------------------------------------------------------------------------
 # the options that went
 # ---------------------------------------------------------------------------
 
